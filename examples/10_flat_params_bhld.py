@@ -47,7 +47,6 @@ def main(argv=None):
         args.steps = 4
 
     import jax
-    jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np
     import optax

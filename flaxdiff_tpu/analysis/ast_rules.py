@@ -225,7 +225,8 @@ class SilentExceptRule(AstRule):
            "pass`) — record a resilience event or log before "
            "swallowing")
     docs = "docs/OBSERVABILITY.md"
-    roots = ("flaxdiff_tpu", "scripts", "train.py", "bench.py")
+    roots = ("flaxdiff_tpu", "scripts", "train.py", "bench.py",
+             "chip_smoke.py")
 
     @staticmethod
     def _catches_everything(handler: ast.ExceptHandler) -> bool:

@@ -110,8 +110,8 @@ class AstRule(Rule):
     tree — matching the old standalone-script semantics.
     """
 
-    roots: Tuple[str, ...] = ("flaxdiff_tpu", "scripts",
-                              "train.py", "bench.py")
+    roots: Tuple[str, ...] = ("flaxdiff_tpu", "scripts", "train.py",
+                              "bench.py", "chip_smoke.py")
     dirs: Tuple[str, ...] = ()
 
     def applies(self, relpath: str, scoped: bool = True) -> bool:

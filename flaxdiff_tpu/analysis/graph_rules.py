@@ -283,8 +283,10 @@ class RngReuseRule(GraphRule):
 # callback-leak
 # ---------------------------------------------------------------------------
 
+# jax 0.9 gives jax.debug.print its own primitive (debug_print); only
+# jax.debug.callback still traces to debug_callback
 _CALLBACK_PRIMS = frozenset({"pure_callback", "io_callback",
-                             "debug_callback"})
+                             "debug_callback", "debug_print"})
 
 
 @register

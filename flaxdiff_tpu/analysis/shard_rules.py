@@ -67,12 +67,13 @@ from .framework import (COMM_BUDGET, COMM_DEFAULT_BUDGET, Finding,
 # ---------------------------------------------------------------------------
 
 _COLLECTIVE_PRIMS = frozenset({
-    "psum", "psum2", "pmax", "pmin", "ppermute", "all_to_all",
+    "psum", "psum_invariant", "pmax", "pmin", "ppermute", "all_to_all",
     "all_gather", "reduce_scatter", "pbroadcast",
 })
-# shard_map's check_rep rewrite renames psum to psum2 — one logical
-# collective, one name in every report
-_PRIM_ALIASES = {"psum2": "psum"}
+# under shard_map's varying-axes check (check_vma, the default) a psum
+# traces as psum_invariant — one logical collective, one name in every
+# report
+_PRIM_ALIASES = {"psum_invariant": "psum"}
 
 
 def _numel(aval) -> int:
@@ -352,12 +353,6 @@ def _canon_spec(spec, rank: int) -> Tuple[Tuple[str, ...], ...]:
     return tuple(dims[:rank])
 
 
-def _canon_names(names: Dict[int, Tuple[str, ...]], rank: int
-                 ) -> Tuple[Tuple[str, ...], ...]:
-    """shard_map in_names/out_names entry -> the same canonical form."""
-    return tuple(tuple(names.get(d, ())) for d in range(rank))
-
-
 def _sharded(canon: Tuple[Tuple[str, ...], ...]) -> bool:
     return any(canon)
 
@@ -408,18 +403,19 @@ def _walk_specs(jaxpr, in_specs: List, st: _ReshardState) -> List:
             # finding, and it resets tracking to the declared layout
         elif prim == "shard_map":
             st.boundaries += 1
-            in_names = eqn.params.get("in_names", ())
-            out_names = eqn.params.get("out_names", ())
-            for i, (tok, names) in enumerate(zip(ins, in_names)):
+            # the shard_map eqn carries its specs as PartitionSpecs
+            for i, (tok, spec) in enumerate(
+                    zip(ins, eqn.params["in_specs"])):
                 if tok is None:
                     continue
-                expect = _canon_names(dict(names), _rank(eqn.invars[i]))
+                expect = _canon_spec(spec, _rank(eqn.invars[i]))
                 if _sharded(tok) and _sharded(expect) and tok != expect:
                     st.mismatches.append(
                         f"operand {i} enters shard_map as {expect} but "
                         f"was last laid out as {tok}")
-            outs = [_canon_names(dict(names), _rank(v))
-                    for names, v in zip(out_names, eqn.outvars)]
+            outs = [_canon_spec(spec, _rank(v))
+                    for spec, v in zip(eqn.params["out_specs"],
+                                       eqn.outvars)]
         elif prim == "scan":
             body = closed_parts(eqn.params["jaxpr"])
             n_consts = eqn.params.get("num_consts", 0)

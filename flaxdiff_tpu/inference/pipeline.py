@@ -190,6 +190,10 @@ class DiffusionInferencePipeline:
             params = unflatten_params(template, params)
             if ema is not None and is_flat_params(ema):
                 ema = unflatten_params(template, ema)
+        # one upload to the default device: host leaves would cross to
+        # the device again on every sampler call and serving round
+        params = jax.device_put(params)
+        ema = jax.device_put(ema) if ema is not None else None
         return DiffusionInferencePipeline.from_config(
             config, params=params, ema_params=ema, autoencoder=autoencoder)
 

@@ -29,10 +29,9 @@ def _flash_interpret() -> bool:
 
 @functools.cache
 def _flash_on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    # a backend that fails to initialise raises here: training on XLA
+    # attention because the chip was missing must not look like success
+    return jax.devices()[0].platform == "tpu"
 
 
 def attention_backend_available(backend: str = "flash") -> bool:
@@ -82,33 +81,24 @@ def _flash_specs(mesh, n_batch: int, n_heads: int):
     mesh.batch_spec), heads over the tensor axis (Megatron head-parallel
     attention). Everything else must stay unsharded inside the kernel.
     """
+    from ..parallel.context import batch_shard_axes
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    batch_axes = tuple(a for a in ("data", "fsdp") if sizes.get(a, 1) > 1)
     head_axis = "tensor" if sizes.get("tensor", 1) > 1 else None
     if sizes.get("seq", 1) > 1:
         return None   # a >1 seq axis belongs to the ring backend
-    n = int(np.prod([sizes[a] for a in batch_axes])) if batch_axes else 1
-    if n_batch % max(n, 1) != 0:
+    batch_axes = batch_shard_axes(mesh, n_batch)
+    if batch_axes is None:
         return None
     if head_axis and n_heads % sizes[head_axis] != 0:
         return None
     return batch_axes, head_axis
 
 
-def _shard_map_compat(body, mesh, spec):
-    """shard_map with the jax-version compat policy in ONE place: the
-    import moved out of experimental, and the replication-check kwarg
-    was renamed check_rep -> check_vma (pallas_call primitives carry no
-    varying-axis info, so the check must be off either way)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    kwargs = dict(mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
-    try:
-        return shard_map(body, check_vma=False, **kwargs)
-    except TypeError:
-        return shard_map(body, check_rep=False, **kwargs)
+def _shard_map_qkv(body, mesh, spec):
+    """shard_map over (q, k, v) with one spec; check_vma off because
+    pallas_call primitives carry no varying-axis info."""
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)
 
 
 def _shard_mapped_flash(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -118,23 +108,24 @@ def _shard_mapped_flash(q: jax.Array, k: jax.Array, v: jax.Array,
                         block_k: Optional[int] = None) -> jax.Array:
     """Run the Pallas kernel per-device under shard_map.
 
-    A pallas_call is opaque to GSPMD — under plain jit on a >1-device
-    mesh the partitioner would replicate its operands rather than
-    partition the custom call. shard_map makes the parallelism explicit:
+    A pallas_call is opaque to GSPMD — in a program compiled for more
+    than one device jax refuses to lower one outside a shard_map
+    ("Mosaic kernels cannot be automatically partitioned"). shard_map
+    makes the parallelism explicit:
     each device runs the kernel on its [b/dp, L, h/tp, d] shard; batch
     and head sharding need no collectives (to_out's contraction over
     sharded heads gets its all-reduce from GSPMD outside the kernel).
     """
     from .flash_attention import flash_attention
 
-    b_spec = (tuple(batch_axes) if len(batch_axes) > 1
-              else (batch_axes[0] if batch_axes else None))
-    spec = jax.sharding.PartitionSpec(b_spec, None, head_axis, None)
+    from ..parallel.context import batch_partition_entry
+    spec = jax.sharding.PartitionSpec(batch_partition_entry(batch_axes),
+                                      None, head_axis, None)
     body = lambda a, b, c: flash_attention(a, b, c, scale=scale,
                                            block_q=block_q,
                                            block_k=block_k,
                                            interpret=interpret)
-    return _shard_map_compat(body, mesh, spec)(q, k, v)
+    return _shard_map_qkv(body, mesh, spec)(q, k, v)
 
 
 def _shard_mapped_flash_bhld(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -151,9 +142,9 @@ def _shard_mapped_flash_bhld(q: jax.Array, k: jax.Array, v: jax.Array,
     layout win exactly on the production configs)."""
     from .flash_attention import flash_attention_bh
 
-    b_spec = (tuple(batch_axes) if len(batch_axes) > 1
-              else (batch_axes[0] if batch_axes else None))
-    spec = jax.sharding.PartitionSpec(b_spec, head_axis, None, None)
+    from ..parallel.context import batch_partition_entry
+    spec = jax.sharding.PartitionSpec(batch_partition_entry(batch_axes),
+                                      head_axis, None, None)
 
     def body(ql, kl, vl):
         bl, hl = ql.shape[0], ql.shape[1]
@@ -163,7 +154,7 @@ def _shard_mapped_flash_bhld(q: jax.Array, k: jax.Array, v: jax.Array,
                                  block_k=block_k, interpret=interpret)
         return out.reshape(bl, hl, out.shape[1], out.shape[2])
 
-    return _shard_map_compat(body, mesh, spec)(q, k, v)
+    return _shard_map_qkv(body, mesh, spec)(q, k, v)
 
 
 def _seq_parallel_gate(q: jax.Array, k: jax.Array,
@@ -242,7 +233,7 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         d = q.shape[-1]
         scale_eff = scale if scale is not None else 1.0 / (d ** 0.5)
         # On a >1-device mesh the kernel must be shard-mapped (GSPMD
-        # replicates opaque custom calls); shapes that don't tile the
+        # cannot partition a Mosaic call); shapes that don't tile the
         # mesh fall back to partitionable XLA attention instead.
         # per-shape autotuner plan (None fields when inactive/uncached:
         # dispatch keeps the exact env/default behavior)
@@ -270,19 +261,24 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                               interpret=_flash_interpret())
         return out[..., :d] if pad else out
     if backend == "flash" and not attention_backend_available("flash"):
-        import warnings
-        warnings.warn("backend='flash' requested but no TPU is available; "
-                      "falling back to XLA attention", stacklevel=2)
+        raise _flash_unavailable()
     return _xla_attention(q, k, v, scale=scale,
                           force_fp32_for_softmax=force_fp32_for_softmax)
+
+
+def _flash_unavailable() -> RuntimeError:
+    return RuntimeError(
+        "backend='flash' needs a TPU (or FLAXDIFF_FLASH_INTERPRET=1); "
+        f"the default device is {jax.devices()[0].platform!r}. Use "
+        "backend='auto' to take XLA attention off-TPU.")
 
 
 def _prebuilt_usable() -> bool:
     """Prebuilt kernel is dispatchable here: kernel importable, a real
     TPU backend, and NOT a >1-device mesh — like any pallas_call the
     prebuilt kernel is opaque to GSPMD, and unlike the first-party path
-    it has no shard_map wrapper yet, so a multi-device mesh would
-    silently replicate the full global q/k/v per device."""
+    it has no shard_map wrapper yet, so in a multi-device program jax
+    would refuse to lower it."""
     if not attention_backend_available("prebuilt"):
         return False
     from ..parallel.context import get_active_mesh
@@ -441,10 +437,7 @@ def dot_product_attention_bhld(q: jax.Array, k: jax.Array, v: jax.Array,
                  and lq >= 128)
     if not use_flash:
         if backend == "flash" and not attention_backend_available("flash"):
-            import warnings
-            warnings.warn("backend='flash' requested but no TPU is "
-                          "available; falling back to XLA attention",
-                          stacklevel=2)
+            raise _flash_unavailable()
         return _xla_attention_bhld(
             q, k, v, scale=scale,
             force_fp32_for_softmax=force_fp32_for_softmax)

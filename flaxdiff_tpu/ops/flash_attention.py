@@ -39,15 +39,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 LANES = 128
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; support both
-# so the kernels run on every image this repo targets.
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
-
-def _compiler_params(**kwargs):
-    return _COMPILER_PARAMS_CLS(**kwargs)
-
 # Test hook: interpret mode normally shrinks the lane-replicated scratch
 # to width 1, which skips the lane resize paths real TPU hits (the d<128
 # native-head-dim bug the r3 bench's attnpad stage caught lived there).
@@ -311,7 +302,7 @@ def _fwd_impl(q3, k3, v3, scale, block_q, block_k, interpret,
             pltpu.VMEM((bq, lanes), jnp.float32),   # running sum
             pltpu.VMEM((bq, d), jnp.float32),       # output accumulator
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qb, kb, vb)
@@ -360,7 +351,7 @@ def _bwd_impl(q3, k3, v3, out_bh, lse, g3, scale, block_q, block_k,
         out_specs=pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, lq_pad, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qb, kb, vb, gb, delta, lse)
@@ -391,7 +382,7 @@ def _bwd_impl(q3, k3, v3, out_bh, lse, g3, scale, block_q, block_k,
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qb, kb, vb, gb, delta, lse)

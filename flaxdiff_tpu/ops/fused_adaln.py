@@ -76,10 +76,8 @@ def _interpret_env() -> bool:
 
 @functools.cache
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    # no except: a backend that cannot initialise is an error, not "cpu"
+    return jax.devices()[0].platform == "tpu"
 
 
 def fused_adaln_active() -> bool:
@@ -105,6 +103,19 @@ def _use_pallas(interpret: bool, force_pallas: bool) -> Tuple[bool, bool]:
     if _env_mode() == "xla":
         return False, interpret
     return (_on_tpu() or interpret), interpret
+
+
+def _dispatch(fused, operands, xla, interpret: bool, force_pallas: bool):
+    """Call a fused op whose operands ALL carry the batch on dim 0. On
+    the Pallas path under an active multi-device mesh the kernels run
+    on each device's batch shard (parallel/context.py
+    per_device_over_batch); on the XLA path `fused` already is the
+    composition GSPMD partitions."""
+    if not _use_pallas(interpret, force_pallas)[0]:
+        return fused(*operands)
+    from ..parallel.context import per_device_over_batch
+    return per_device_over_batch(fused, operands,
+                                 (True,) * len(operands), xla)
 
 
 def _pad_rows(x: jax.Array, blk: int) -> jax.Array:
@@ -352,9 +363,12 @@ def fused_ln_modulate(x: jax.Array, scale: jax.Array, shift: jax.Array,
     """``modulate(LayerNorm(x), scale, shift)`` in one HBM pass.
     x: [B, L, C]; scale/shift: [B, 1, C]. Differentiable; falls back to
     the exact XLA composition off-TPU / on unsupported shapes."""
+    xla = lambda x_, s_, b_: _xla_ln_modulate(x_, ((s_, b_),), eps)[0]
     if not force_pallas and not _modulator_shapes_ok(x, scale, shift):
-        return _xla_ln_modulate(x, ((scale, shift),), eps)[0]
-    return _ln_mod1(eps, interpret, force_pallas, x, scale, shift)
+        return xla(x, scale, shift)
+    return _dispatch(functools.partial(_ln_mod1, eps, interpret,
+                                       force_pallas),
+                     (x, scale, shift), xla, interpret, force_pallas)
 
 
 def fused_ln_modulate2(x: jax.Array,
@@ -368,9 +382,13 @@ def fused_ln_modulate2(x: jax.Array,
     branches share the same un-affined LayerNorm). Clip the mlp pair
     BEFORE calling (jnp.clip stays in XLA; its VJP chains through the
     custom_vjp boundary exactly)."""
+    xla = lambda x_, s1_, b1_, s2_, b2_: _xla_ln_modulate(
+        x_, ((s1_, b1_), (s2_, b2_)), eps)
     if not force_pallas and not _modulator_shapes_ok(x, s1, b1, s2, b2):
-        return _xla_ln_modulate(x, ((s1, b1), (s2, b2)), eps)
-    return _ln_mod2(eps, interpret, force_pallas, x, s1, b1, s2, b2)
+        return xla(x, s1, b1, s2, b2)
+    return _dispatch(functools.partial(_ln_mod2, eps, interpret,
+                                       force_pallas),
+                     (x, s1, b1, s2, b2), xla, interpret, force_pallas)
 
 
 # ---------------------------------------------------------------------------
@@ -386,7 +404,7 @@ def _gate_res_kernel(x_ref, g_ref, h_ref, o_ref):
 def _gate_res_bwd_kernel(g_ref, h_ref, dout_ref, dh_ref, pg_ref):
     dout = dout_ref[0]
     dh_ref[0] = (g_ref[0] * dout).astype(dh_ref.dtype)
-    pg_ref[0] = jnp.sum(
+    pg_ref[0, 0] = jnp.sum(
         dout.astype(jnp.float32) * h_ref[0].astype(jnp.float32),
         axis=0, keepdims=True)                           # [1, C]
 
@@ -451,15 +469,18 @@ def _gate_res_bwd(interpret, force_pallas, res, g):
         ],
         out_specs=[
             pl.BlockSpec((1, blk, c), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1, c), lambda i, j: (i, j, 0)),
+            # [B, nblk, 1, C]: the block's last two dims equal the
+            # array's (the Pallas TPU block-shape rule; a (1, 1, C)
+            # block of [B, nblk, C] does not lower once nblk > 1)
+            pl.BlockSpec((1, 1, 1, c), lambda i, j: (i, j, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, l_pad, c), h.dtype),
-            jax.ShapeDtypeStruct((b, nblk, c), jnp.float32),
+            jax.ShapeDtypeStruct((b, nblk, 1, c), jnp.float32),
         ],
         interpret=interpret,
     )(gate, hr, gr)
-    dgate = jnp.sum(pg, axis=1)[:, None, :].astype(gate.dtype)
+    dgate = jnp.sum(pg, axis=1).astype(gate.dtype)      # [B, 1, C]
     # dx == the cotangent itself: no kernel, no copy
     return g.astype(x_dtype), dgate, dh[:, :l]
 
@@ -473,10 +494,13 @@ def fused_gate_residual(x: jax.Array, gate: jax.Array, h: jax.Array,
     """``x + gate * h`` — the AdaLN-Zero gated-residual epilogue.
     x/h: [B, L, C]; gate: [B, 1, C]. Differentiable (dgate's L-reduction
     rides the dh pass)."""
+    xla = lambda x_, g_, h_: x_ + g_ * h_
     if not force_pallas and not (
             _modulator_shapes_ok(x, gate) and h.shape == x.shape):
-        return x + gate * h
-    return _gate_res(x, gate, h, interpret, force_pallas)
+        return xla(x, gate, h)
+    return _dispatch(
+        lambda x_, g_, h_: _gate_res(x_, g_, h_, interpret, force_pallas),
+        (x, gate, h), xla, interpret, force_pallas)
 
 
 # ---------------------------------------------------------------------------
@@ -589,7 +613,12 @@ def fused_geglu(proj: jax.Array, interpret: bool = False,
     """``val * gelu(gate)`` where ``gate, val = split(proj, 2, -1)`` —
     the GEGLUFeedForward activation over the packed projection.
     proj: [B, L, 2F]. Differentiable; exact XLA composition off-TPU."""
-    if not force_pallas and not (proj.ndim == 3
-                                 and proj.shape[-1] % 2 == 0):
+    # Mosaic streams each half as its own lane block, so F must fill
+    # whole 128-lane tiles; the interpreter has no such rule
+    if not force_pallas and not (
+            proj.ndim == 3 and proj.shape[-1] % 2 == 0
+            and (_interpret_env() or interpret
+                 or proj.shape[-1] % 256 == 0)):
         return _xla_geglu(proj)
-    return _geglu(proj, interpret, force_pallas)
+    return _dispatch(lambda p_: _geglu(p_, interpret, force_pallas),
+                     (proj,), _xla_geglu, interpret, force_pallas)

@@ -48,6 +48,21 @@ def _fused_norm_interpret() -> bool:
     return os.environ.get("FLAXDIFF_FUSED_NORM") == "interpret"
 
 
+def _use_pallas(interpret: bool, force_pallas: bool):
+    """(run_pallas, interpret): Pallas on TPU or under the interpreter,
+    the XLA composition elsewhere. FLAXDIFF_FUSED_NORM=xla is the A/B
+    escape hatch the bench's ablate stage uses to measure whether the
+    fused kernel pays for the layout copies around its custom calls
+    in-context on real hardware."""
+    if _fused_norm_interpret():
+        interpret = True
+    if force_pallas:
+        return True, interpret
+    if os.environ.get("FLAXDIFF_FUSED_NORM") == "xla":
+        return False, interpret
+    return (jax.devices()[0].platform == "tpu" or interpret), interpret
+
+
 def _member_mask(c: int, groups: int) -> jnp.ndarray:
     cg = c // groups
     ch = jax.lax.broadcasted_iota(jnp.int32, (c, groups), 0)
@@ -262,17 +277,8 @@ def _impl_stats(x: jax.Array, scale: jax.Array, bias: jax.Array,
     orig_shape = x.shape
     b = x.shape[0]
 
-    if _fused_norm_interpret():
-        interpret = True
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if not force_pallas and not (on_tpu or interpret):
-        return (_xla_groupnorm_silu(x, scale, bias, groups, eps,
-                                    apply_silu), None, None)
-    if not force_pallas and os.environ.get("FLAXDIFF_FUSED_NORM") == "xla":
-        # A/B escape hatch: the r3 trace showed ~750 layout copies/step
-        # around the pallas custom calls — the bench's ablate stage uses
-        # this to measure whether the fused kernel pays for its copies
-        # in-context on real hardware
+    run_pallas, interpret = _use_pallas(interpret, force_pallas)
+    if not run_pallas:
         return (_xla_groupnorm_silu(x, scale, bias, groups, eps,
                                     apply_silu), None, None)
 
@@ -378,6 +384,17 @@ def fused_groupnorm_silu(x: jax.Array, scale: jax.Array, bias: jax.Array,
                          apply_silu: bool = True,
                          interpret: bool = False,
                          force_pallas: bool = False) -> jax.Array:
-    """x: [B, H, W, C] (or [B, L, C]); scale/bias: [C]. Differentiable."""
-    return _fused_gn_silu(x, scale, bias, groups, eps, apply_silu,
-                          interpret, force_pallas)
+    """x: [B, H, W, C] (or [B, L, C]); scale/bias: [C]. Differentiable.
+    Under an active multi-device mesh the kernels run on each device's
+    batch shard (parallel/context.py per_device_over_batch)."""
+    def fused(x_, s_, b_):
+        return _fused_gn_silu(x_, s_, b_, groups, eps, apply_silu,
+                              interpret, force_pallas)
+
+    if not _use_pallas(interpret, force_pallas)[0]:
+        return fused(x, scale, bias)    # the XLA composition: GSPMD's
+    from ..parallel.context import per_device_over_batch
+    return per_device_over_batch(
+        fused, (x, scale, bias), (True, False, False),
+        functools.partial(_xla_groupnorm_silu, groups=groups, eps=eps,
+                          apply_silu=apply_silu))

@@ -95,9 +95,6 @@ def prebuilt_flash_attention_bhld(q: jax.Array, k: jax.Array, v: jax.Array,
 def prebuilt_available() -> bool:
     try:
         _mod()
-    except Exception:
+    except ImportError:
         return False
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"

@@ -12,9 +12,11 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-from typing import Optional
+import math
+from typing import Callable, Optional, Sequence, Tuple
 
-from jax.sharding import Mesh
+import jax
+from jax.sharding import Mesh, PartitionSpec
 
 _active_mesh: contextvars.ContextVar[Optional[Mesh]] = \
     contextvars.ContextVar("flaxdiff_tpu_active_mesh", default=None)
@@ -55,3 +57,52 @@ def seq_parallel_active() -> bool:
     axis = get_seq_axis()
     return (mesh is not None and axis in mesh.axis_names
             and mesh.shape[axis] > 1)
+
+
+def batch_shard_axes(mesh: Mesh, n_batch: int) -> Optional[Tuple[str, ...]]:
+    """The >1-sized data-like axes (data x fsdp — matching
+    mesh.batch_spec) a batch of `n_batch` rows shards over; None when
+    the batch does not tile them."""
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    axes = tuple(a for a in ("data", "fsdp") if sizes.get(a, 1) > 1)
+    if n_batch % math.prod(sizes[a] for a in axes) != 0:
+        return None
+    return axes
+
+
+def batch_partition_entry(batch_axes: Sequence[str]):
+    """The dim-0 PartitionSpec entry for `batch_axes`."""
+    if len(batch_axes) > 1:
+        return tuple(batch_axes)
+    return batch_axes[0] if batch_axes else None
+
+
+def per_device_over_batch(kernel_fn: Callable, operands: Sequence[jax.Array],
+                          batched: Sequence[bool],
+                          xla_fn: Callable) -> jax.Array:
+    """Run an opaque (pallas_call) op on each device's batch shard.
+
+    GSPMD cannot partition a Mosaic kernel: in a program compiled for
+    more than one device, jax refuses to lower a pallas_call that is not
+    inside a shard_map ("Mosaic kernels cannot be automatically
+    partitioned" — seen on a 2x2 v5e, PR 21). shard_map makes the batch
+    split explicit. `batched[i]` says operand i carries the batch on
+    dim 0 (operand 0 must); the rest (per-channel scale/bias) are
+    replicated, and shard_map's transpose psums their cotangents over
+    the batch axes. Every output carries the batch on dim 0. On a mesh
+    with no >1 batch axis (pure tensor/seq parallelism) the specs are
+    fully replicated and every device runs the whole batch. With no
+    active multi-device mesh this is `kernel_fn(*operands)`; a batch
+    that does not tile the mesh takes `xla_fn(*operands)`, which GSPMD
+    can partition."""
+    mesh = get_active_mesh()
+    if mesh is None or mesh.devices.size == 1:
+        return kernel_fn(*operands)
+    axes = batch_shard_axes(mesh, operands[0].shape[0])
+    if axes is None:
+        return xla_fn(*operands)
+    spec = PartitionSpec(batch_partition_entry(axes))
+    in_specs = tuple(spec if b else PartitionSpec() for b in batched)
+    # check_vma off: pallas_call primitives carry no varying-axis info
+    return jax.shard_map(kernel_fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=spec, check_vma=False)(*operands)

@@ -33,10 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..typing import PyTree
 
@@ -124,13 +121,9 @@ def pipeline_blocks(block_fn: Callable[[PyTree, jax.Array, Any], jax.Array],
         outs = jnp.where(idx == n_stages - 1, outs, 0)
         return jax.lax.psum(outs, axis)
 
-    kwargs = dict(mesh=mesh, in_specs=(p_spec, x_spec, c_spec),
-                  out_specs=x_spec)
-    try:
-        # ppermute/psum on masked bubbles carry no varying-axis info
-        fn = shard_map(_shard, check_vma=False, **kwargs)
-    except TypeError:
-        fn = shard_map(_shard, check_rep=False, **kwargs)
+    # ppermute/psum on masked bubbles carry no varying-axis info
+    fn = shard_map(_shard, mesh=mesh, in_specs=(p_spec, x_spec, c_spec),
+                   out_specs=x_spec, check_vma=False)
     outs = fn(stacked_params, xs, conds)
     return outs.reshape(batch, *x.shape[1:])
 

@@ -39,10 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 _LANES = 128
 _DEFAULT_CHUNK = 1024
@@ -323,12 +320,9 @@ def ring_self_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     def body(q, k, v):   # custom_vjp args must be positional
         return ring_attention_sharded(q, k, v, seq_axis, scale,
                                       _DEFAULT_CHUNK, None, False)
-    kwargs = dict(mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
-    try:
-        # pallas_call primitives carry no varying-axis info; skip the check
-        fn = shard_map(body, check_vma=False, **kwargs)
-    except TypeError:
-        fn = shard_map(body, check_rep=False, **kwargs)
+    # pallas_call primitives carry no varying-axis info; skip the check
+    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                   out_specs=spec, check_vma=False)
     return fn(q, k, v)
 
 

@@ -31,10 +31,7 @@ from jax.sharding import Mesh
 
 from .ring_attention import seq_shard_spec
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 _NEG = -1e30
 

@@ -41,22 +41,28 @@ _PEAK_FLOPS_BF16 = {
 }
 
 
-def device_peak_flops(device: Optional[Any] = None) -> Optional[float]:
-    """Peak bf16 FLOP/s of `device` (default: first local device).
+def peak_for_kind(table: dict, kind: str, what: str) -> Optional[float]:
+    """Exact-key lookup of a per-chip peak. A TPU kind the table does
+    not list is an error (a prefix match would hand an unknown
+    "TPU v5..." the v5p peak and every utilization after it would be
+    wrong); a non-TPU kind has no peak, so utilization is unreportable
+    there rather than wrong."""
+    if kind in table:
+        return table[kind]
+    if kind.startswith("TPU"):
+        raise KeyError(f"no {what} entry for device_kind {kind!r}: add "
+                       "the published figure and its source to the table")
+    return None
 
-    Returns None on hosts where the peak is unknown (e.g. CPU test
-    meshes) — MFU is then unreportable rather than wrong."""
+
+def device_peak_flops(device: Optional[Any] = None) -> Optional[float]:
+    """Peak bf16 FLOP/s of `device` (default: first local device); None
+    off-TPU (e.g. CPU test meshes), KeyError for an unlisted TPU."""
     if device is None:
         device = jax.local_devices()[0]
-    kind = getattr(device, "device_kind", "")
-    if kind in _PEAK_FLOPS_BF16:
-        return _PEAK_FLOPS_BF16[kind]
-    # longest-prefix fallback ("TPU v5 lite chip" style variants)
-    best = None
-    for name, flops in _PEAK_FLOPS_BF16.items():
-        if kind.startswith(name) and (best is None or len(name) > best[0]):
-            best = (len(name), flops)
-    return best[1] if best else None
+    return peak_for_kind(_PEAK_FLOPS_BF16,
+                         getattr(device, "device_kind", ""),
+                         "peak bf16 FLOP/s")
 
 
 def compiled_flops(jitted_fn, *args, **kwargs) -> Optional[float]:
@@ -66,17 +72,13 @@ def compiled_flops(jitted_fn, *args, **kwargs) -> Optional[float]:
     the compiler schedules against, so rematerialization (jax.checkpoint)
     and fusion decisions are included, unlike hand-derived analytic counts.
     Under SPMD jit the executable is the per-device program, so the figure
-    is already per-chip. Returns None if the backend exposes no analysis.
+    is already per-chip. Returns None only when the backend's analysis
+    carries no flops entry; a failed lowering or compile raises.
     """
-    try:
-        compiled = jitted_fn.lower(*args, **kwargs).compile()
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returned [dict]
-            cost = cost[0] if cost else {}
-        flops = cost.get("flops")
-        return float(flops) if flops and flops > 0 else None
-    except Exception:
-        return None
+    compiled = jitted_fn.lower(*args, **kwargs).compile()
+    cost = compiled.cost_analysis() or {}
+    flops = cost.get("flops")
+    return float(flops) if flops and flops > 0 else None
 
 
 def _dot_general_flops(eqn) -> float:
@@ -160,17 +162,14 @@ def jaxpr_flops(jaxpr) -> float:
     return total
 
 
-def traced_model_flops(fn, *args, **kwargs) -> Optional[float]:
+def traced_model_flops(fn, *args, **kwargs) -> float:
     """`jaxpr_flops` of `fn(*args, **kwargs)` (abstract trace, no device).
 
     Per-call FLOPs at true shapes. NOTE: pallas_call bodies are opaque to
     tracing — call this on a variant of the program whose attention uses
     the "xla" backend to get the full model count."""
-    try:
-        closed = jax.make_jaxpr(fn)(*args, **kwargs)
-        return jaxpr_flops(closed.jaxpr)
-    except Exception:
-        return None
+    closed = jax.make_jaxpr(fn)(*args, **kwargs)
+    return jaxpr_flops(closed.jaxpr)
 
 
 def mfu(flops_per_step: float, step_time_s: float,

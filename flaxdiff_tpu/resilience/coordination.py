@@ -477,12 +477,7 @@ class JaxDistributedTransport(Transport):
     def offer_json(self, name: str, obj) -> None:
         key = f"{self._ns}/ag/{name}/{self.process_index}"
         payload = json.dumps(obj)
-        try:
-            self._client.key_value_set(key, payload, allow_overwrite=True)
-        except TypeError:
-            # older jax: no allow_overwrite kwarg; a duplicate-key error
-            # then means our real contribution is already up — fine
-            self._client.key_value_set(key, payload)
+        self._client.key_value_set(key, payload, allow_overwrite=True)
 
     def _try_get(self, key: str, timeout: float):
         try:
@@ -501,10 +496,7 @@ class JaxDistributedTransport(Transport):
     def put_json(self, name: str, obj) -> None:
         key = f"{self._ns}/kv/{name}"
         payload = json.dumps(obj)
-        try:
-            self._client.key_value_set(key, payload, allow_overwrite=True)
-        except TypeError:
-            self._client.key_value_set(key, payload)
+        self._client.key_value_set(key, payload, allow_overwrite=True)
 
     def get_json(self, name: str, timeout: float = 0.0):
         raw = self._try_get(f"{self._ns}/kv/{name}", timeout)

@@ -327,46 +327,45 @@ def read_devprof(path: str) -> List[Dict[str, Any]]:
 
 # -- reconciliation ------------------------------------------------------------
 
+def _env_peak(name: str) -> Optional[float]:
+    """A positive float from env `name`, else None (unset, malformed
+    or non-positive)."""
+    try:
+        v = float(os.environ.get(name, ""))
+    except ValueError:
+        return None
+    return v if v > 0 else None
+
+
+def device_peak_bytes_per_s(device=None) -> Optional[float]:
+    """Peak HBM bytes/s of `device` (default: first local device) by
+    exact `device_kind`; None off-TPU, KeyError for an unlisted TPU —
+    the contract of `profiling.device_peak_flops`."""
+    from ..profiling import peak_for_kind
+    if device is None:
+        import jax
+        device = jax.local_devices()[0]
+    return peak_for_kind(_PEAK_HBM_BYTES_PER_S,
+                         str(getattr(device, "device_kind", "")),
+                         "peak HBM bytes/s")
+
+
 def resolved_peak_flops() -> Optional[float]:
     """Peak FLOP/s: FLAXDIFF_PEAK_FLOPS env override first (the only
     way to get measured MFU on backends the table does not know, e.g.
     CPU CI), else the chip table via `profiling.device_peak_flops`."""
-    env = os.environ.get("FLAXDIFF_PEAK_FLOPS")
-    if env:
-        try:
-            v = float(env)
-            return v if v > 0 else None
-        except ValueError:
-            return None
-    try:
-        from ..profiling import device_peak_flops
-        return device_peak_flops()
-    except Exception:  # noqa: BLE001 — no backend is a valid state
-        return None
+    if os.environ.get("FLAXDIFF_PEAK_FLOPS"):
+        return _env_peak("FLAXDIFF_PEAK_FLOPS")
+    from ..profiling import device_peak_flops
+    return device_peak_flops()
 
 
 def resolved_peak_bytes_per_s() -> Optional[float]:
     """Peak HBM bytes/s for the roofline ridge: env override
     FLAXDIFF_PEAK_BYTES_PER_S first, else the chip table."""
-    env = os.environ.get("FLAXDIFF_PEAK_BYTES_PER_S")
-    if env:
-        try:
-            v = float(env)
-            return v if v > 0 else None
-        except ValueError:
-            return None
-    try:
-        import jax
-        kind = str(getattr(jax.local_devices()[0], "device_kind", ""))
-    except Exception:  # noqa: BLE001 — no backend is a valid state
-        return None
-    if kind in _PEAK_HBM_BYTES_PER_S:
-        return _PEAK_HBM_BYTES_PER_S[kind]
-    best = None
-    for name, bw in _PEAK_HBM_BYTES_PER_S.items():
-        if kind.startswith(name) and (best is None or len(name) > best[0]):
-            best = (len(name), bw)
-    return best[1] if best else None
+    if os.environ.get("FLAXDIFF_PEAK_BYTES_PER_S"):
+        return _env_peak("FLAXDIFF_PEAK_BYTES_PER_S")
+    return device_peak_bytes_per_s()
 
 
 def reconcile(row: Dict[str, Any], program: Dict[str, Any], *,

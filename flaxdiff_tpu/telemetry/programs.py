@@ -263,9 +263,7 @@ class ProgramRegistry:
                 _note_probe_failure("collectives", kind, e)
         if self.deep:
             try:
-                cost = jitted.lower(*args).compile().cost_analysis()
-                if isinstance(cost, (list, tuple)):   # older jax: [dict]
-                    cost = cost[0] if cost else {}
+                cost = jitted.lower(*args).compile().cost_analysis() or {}
                 f = cost.get("flops")
                 b = cost.get("bytes accessed")
                 flops_cost = float(f) if f and f > 0 else None

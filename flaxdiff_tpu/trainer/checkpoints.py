@@ -349,7 +349,17 @@ class Checkpointer:
         # "sharding info not provided ... unsafe when restoring on a
         # different topology"; np.ndarray is genuinely topology-free)
         import numpy as np
-        item = self._mgr.item_metadata(step)["state"]
+        from etils import epath
+
+        # The tree's structure comes from the step's own metadata, read
+        # with an explicit handler: a manager that has not saved in this
+        # process has no handler registered for "state", and its
+        # item_metadata() then holds None — restore args built from
+        # None restore every leaf "as saved", a jax.Array on the
+        # writer's devices, which is exactly what this method exists
+        # to avoid.
+        item = ocp.PyTreeCheckpointHandler().metadata(
+            epath.Path(self._mgr.directory) / str(step) / "state").tree
         restore_args = jax.tree_util.tree_map(
             lambda _: ocp.RestoreArgs(restore_type=np.ndarray), item)
         import warnings

@@ -47,10 +47,7 @@ def _block_until_ready(x) -> None:
 
 def _is_ready(x) -> bool:
     """Non-blocking completion query (False = still in flight)."""
-    try:
-        return bool(x.is_ready())
-    except AttributeError:      # non-jax leaf / very old jax
-        return True
+    return bool(x.is_ready())
 
 
 def _fetch_losses(arrs):
@@ -1292,9 +1289,8 @@ class DiffusionTrainer:
         profile_at = max(1, min(cfg.profile_at_step,
                                 max(total_steps - cfg.profile_steps + 1, 1)))
 
-        # Pipelined dispatch (the r5 perf lever — BENCH_r05 measured
-        # 0.892x the reference binary with per-step host syncs as the
-        # named culprit): H2D upload rides a background thread
+        # Pipelined dispatch (per-step host syncs serialize the host
+        # against the device): H2D upload rides a background thread
         # (prefetch_to_device), dispatch runs up to pipeline_depth
         # steps ahead of the device, and the ONLY mandatory host sync
         # is the log-cadence loss-window fetch. try/finally: an

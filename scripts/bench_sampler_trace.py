@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Profile DDIM-50 inference at 256^2 on chip and break the latency down.
 
-VERDICT r3 next #7: 1153 ms (23 ms/NFE) was recorded but never
+1153 ms (23 ms/NFE) was recorded (BENCH_r03_midround.json) but never
 examined. This captures a device trace of the compiled sampler scan in
 three configurations — unconditional, CFG (guidance>0: the 2x-batched
 model call), and CFG+EMA-style second param tree — then attributes
@@ -40,10 +40,11 @@ def main(argv=None):
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
 
-    from flaxdiff_tpu.utils import apply_jax_platforms_env
-    apply_jax_platforms_env()
     import jax
     import jax.numpy as jnp
+
+    from flaxdiff_tpu.utils import configure_compilation_cache
+    configure_compilation_cache()
 
     from flaxdiff_tpu.models.unet import Unet
     from flaxdiff_tpu.predictors import EpsilonPredictionTransform

@@ -26,8 +26,8 @@ Inputs (auto-detected per argument):
   as a `devprof` stage from the last parsed window: per-op-family ms
   UP is worse, measured MFU / achieved comm bandwidth DOWN is worse,
   op counts and predicted comm bytes are neutral program-shape facts.
-- a **bench result file** (the final JSON line of `bench.py`, e.g.
-  `BENCH_r05.json`): compares numeric leaves per stage.
+- a **bench result file** (the final JSON line of `bench.py`, saved
+  to a file): compares numeric leaves per stage.
 
 Direction is inferred from the metric name: `*_ms` / `*latency*` /
 `p50|p99|max` / `compile`-style names regress UP; `*speedup*` /
@@ -51,7 +51,7 @@ tests/test_tools.py.
 
 Usage:
     python scripts/compare_runs.py runA/telemetry runB/telemetry
-    python scripts/compare_runs.py BENCH_r03.json BENCH_r05.json \
+    python scripts/compare_runs.py bench_A.json bench_B.json \
         --threshold 0.10 --stage-threshold serve=0.25 --json
 """
 from __future__ import annotations

@@ -218,18 +218,11 @@ def test_monitored_step_under_shard_map(mesh, rng):
     """The numerics aux composes with a model whose forward runs inside
     shard_map over the mesh — per-module norms come out finite and the
     gradient flows to the replicated weights."""
-    try:
-        from jax import shard_map
+    from jax import shard_map
 
-        def smap(body, in_specs, out_specs):
-            return shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except ImportError:                              # older jax
-        from jax.experimental.shard_map import shard_map
-
-        def smap(body, in_specs, out_specs):
-            return shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
+    def smap(body, in_specs, out_specs):
+        return shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
     bspec = P(("data", "fsdp"))
 

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from flaxdiff_tpu.profiling import (MFUMeter, compiled_flops,
                                     device_peak_flops, jaxpr_flops, mfu,
@@ -28,9 +29,17 @@ def test_peak_flops_table():
         device_kind = "Banana 9000"
     assert device_peak_flops(Unknown()) is None
 
+    # exact keys only: a prefix match would hand an unlisted "TPU v5..."
+    # the v5p peak, so an unknown TPU is an error, not a default
     class Variant:
         device_kind = "TPU v4 megacore"
-    assert device_peak_flops(Variant()) == 275e12
+    with pytest.raises(KeyError, match="TPU v4 megacore"):
+        device_peak_flops(Variant())
+    from flaxdiff_tpu.telemetry.devprof import device_peak_bytes_per_s
+    assert device_peak_bytes_per_s(FakeDev()) == 819e9
+    assert device_peak_bytes_per_s(Unknown()) is None
+    with pytest.raises(KeyError, match="TPU v4 megacore"):
+        device_peak_bytes_per_s(Variant())
 
 
 def test_meter_accumulates():

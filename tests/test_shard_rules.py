@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from flaxdiff_tpu.analysis import framework
@@ -29,11 +30,6 @@ from flaxdiff_tpu.parallel import create_mesh
 from flaxdiff_tpu.parallel.partition import (partition_coverage,
                                              fsdp_sharding_tree,
                                              with_named_constraint)
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 
 @pytest.fixture(scope="module")
@@ -354,12 +350,8 @@ def test_traced_ring_chunked_matches_reference(devices, rng):
     def ring8(q, k, v):
         body = (lambda a, b, c:
                 ra.ring_attention_sharded(a, b, c, "seq", None, 8))
-        try:
-            fn = shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
-                           out_specs=spec, check_vma=False)
-        except TypeError:
-            fn = shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
-                           out_specs=spec, check_rep=False)
+        fn = shard_map(body, mesh=mesh, in_specs=(spec,) * 3,
+                       out_specs=spec, check_vma=False)
         return fn(q, k, v)
 
     np.testing.assert_allclose(np.asarray(ring8(q, k, v)),

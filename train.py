@@ -172,12 +172,14 @@ def parse_args(argv=None):
                         "changes the checkpointed state tree by one "
                         "[N] leaf, so pick per run")
     p.add_argument("--compilation_cache_dir", default=None,
-                   help="persistent XLA compilation cache directory: "
-                        "relaunches (and coordinated restarts) reload "
-                        "compiled programs instead of paying the jit "
-                        "compile again — the fit loop detects the warm "
-                        "first step and attributes it productive "
-                        "instead of compile badput")
+                   help="persistent XLA compilation cache directory "
+                        "(default <checkout>/.jax_cache; ignored when "
+                        "JAX_COMPILATION_CACHE_DIR is set, which jax "
+                        "reads itself): relaunches and coordinated "
+                        "restarts reload compiled programs instead of "
+                        "paying the jit compile again — the fit loop "
+                        "detects the warm first step and attributes it "
+                        "productive instead of compile badput")
     p.add_argument("--prometheus_textfile", default=None,
                    help="also export the telemetry snapshot to this path "
                         "in Prometheus text format (atomic rename; "
@@ -256,42 +258,14 @@ def parse_args(argv=None):
     return p.parse_args(argv)
 
 
-def configure_compilation_cache(cache_dir):
-    """Enable JAX's persistent compilation cache rooted at `cache_dir`.
-
-    Thresholds are zeroed so even small programs (the monitored twin,
-    eval samplers) cache — a coordinated restart then pays ~no compile
-    badput, and the trainer's warm-first-step reclassification keeps
-    the goodput account honest about it. Returns True when the cache
-    was configured (False on a jax too old to support it — the run
-    proceeds uncached rather than dying)."""
-    import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-        except AttributeError:
-            pass        # knob added after the min_compile_time one
-    except AttributeError:
-        import warnings
-        warnings.warn("this jax has no persistent compilation cache "
-                      "config; --compilation_cache_dir ignored",
-                      stacklevel=2)
-        return False
-    return True
-
-
 def main(argv=None):
     args = parse_args(argv)
 
     import jax
 
-    from flaxdiff_tpu.utils import apply_jax_platforms_env
-    apply_jax_platforms_env()
-    if args.compilation_cache_dir:
-        configure_compilation_cache(args.compilation_cache_dir)
+    from flaxdiff_tpu.utils import configure_compilation_cache
+    cache_dir = configure_compilation_cache(args.compilation_cache_dir)
+    print(f"compilation cache: {cache_dir}")
     if args.flash_tune_cache:
         from flaxdiff_tpu.ops import autotune as _flash_autotune
         _flash_autotune.activate(args.flash_tune_cache)
@@ -305,7 +279,7 @@ def main(argv=None):
     from flaxdiff_tpu.inference.registry import build_model
     from flaxdiff_tpu.inputs import (CLIPTextEncoder, ConditionalInputConfig,
                                      DiffusionInputConfig, HashTextEncoder)
-    from flaxdiff_tpu.parallel import create_mesh
+    from flaxdiff_tpu.parallel import create_mesh, use_mesh
     from flaxdiff_tpu.predictors import get_transform
     from flaxdiff_tpu.samplers import SAMPLER_REGISTRY
     from flaxdiff_tpu.schedulers import get_schedule
@@ -536,7 +510,7 @@ def main(argv=None):
     # stalls, ...) into the run log as structured records, in addition
     # to the counter metrics fit merges at log cadence
     from flaxdiff_tpu.trainer import attach_resilience
-    attach_resilience(logger)
+    detach_resilience = attach_resilience(logger)
 
     # Telemetry (docs/OBSERVABILITY.md): phase timings, goodput ledger,
     # trace spans, pod aggregation. Installed as the process-global hub
@@ -800,9 +774,16 @@ def main(argv=None):
             else:
                 eval_scope = None
             try:
-                result = validator.run(trainer.get_params(use_ema=True),
-                                       conditioning=cond, unconditional=unc,
-                                       batch=real_batch)
+                # under the training mesh: the params are sharded over
+                # it, so the sampler compiles for every device, and a
+                # Pallas kernel in a multi-device program must sit in a
+                # shard_map (jax refuses to partition a Mosaic call) —
+                # which the kernels' dispatch only does under a mesh
+                with use_mesh(mesh):
+                    result = validator.run(
+                        trainer.get_params(use_ema=True),
+                        conditioning=cond, unconditional=unc,
+                        batch=real_batch)
             finally:
                 if eval_scope is not None:
                     eval_scope.close()
@@ -826,6 +807,7 @@ def main(argv=None):
     if jax.process_index() != 0:
         if telemetry is not None:
             telemetry.close()
+        detach_resilience()
         logger.finish()
         ckpt.wait_until_finished()
         return hist
@@ -861,6 +843,9 @@ def main(argv=None):
                   f"{t['total_s']:.0f}s attributed wall-clock "
                   f"(incarnation {t['incarnations']}); report: "
                   f"python scripts/diagnose_run.py {args.telemetry_dir}")
+    # the event log outlives this call: a subscriber left behind would
+    # write the next in-process run's events into this closed logger
+    detach_resilience()
     logger.finish()
     ckpt.wait_until_finished()
     print(f"done: {done} steps, final loss {hist['final_loss']:.4f}")
