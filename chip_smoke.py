@@ -19,12 +19,16 @@ UNet (depth and step counts cut, weights random from a seed):
   5 fsdp     phase 2 again under --mesh_fsdp <device_count>, when there
              is more than one device
 
-The last stdout line is one JSON object: "ok", the device triple, and
-per-phase wall and compile seconds. The first failed phase ends the run
-with a non-zero exit status; nothing is caught that is not re-raised.
+The last stdout line is one JSON object with exactly two keys,
+  {"ok": true|false, "device": {"platform": ..., "kind": ..., "count": N}}
+(the device as jax reports it). The line before it, `report {...}`,
+carries per-phase wall and compile seconds and each phase's numbers; the
+same report is written to chiprun_out/chip_smoke.json. The first failed
+phase ends the run with a non-zero exit status and "ok": false; nothing
+is caught that is not re-raised.
 
 `--rehearse` is the only other mode: tiny shapes, the three interpret
-hooks, JAX_PLATFORMS=cpu, output marked "rehearsal": true — for
+hooks, JAX_PLATFORMS=cpu, report marked "rehearsal": true — for
 debugging the command where there is no chip, and for the tests.
 Without the flag and without a chip the script fails in phase 0, before
 anything compiles, and prints no result line.
@@ -517,8 +521,10 @@ def main(argv=None) -> int:
         with open(os.path.join("chiprun_out", "chip_smoke.json"), "w") as f:
             json.dump(report, f, indent=1)
         sys.stderr.flush()
-        # the last stdout line
-        print(json.dumps(report), flush=True)
+        print("report " + json.dumps(report), flush=True)
+        # the last stdout line: these two keys and no others
+        print(json.dumps({"ok": report["ok"], "device": device}),
+              flush=True)
     return 0
 
 

@@ -29,11 +29,19 @@ def _run_smoke(tmp_path, *args, timeout):
 def test_rehearsal_runs_every_phase(tmp_path):
     """`--rehearse`: tiny shapes, the Pallas interpreter, every phase
     (with the 8 virtual devices the suite forces, the FSDP phase too),
-    one JSON object as the last stdout line, marked as a rehearsal."""
+    the strict two-key JSON object as the last stdout line and the full
+    report, marked as a rehearsal, on the line before it."""
     proc = _run_smoke(tmp_path, "--rehearse", timeout=1500)
     assert proc.returncode == 0, proc.stderr[-3000:]
-    final = json.loads(proc.stdout.strip().splitlines()[-1])
+    lines = proc.stdout.strip().splitlines()
+    # the driver's contract: exactly these keys, nothing else
+    last = json.loads(lines[-1])
+    assert set(last) == {"ok", "device"} and last["ok"] is True
+    assert set(last["device"]) == {"platform", "kind", "count"}
+    assert lines[-2].startswith("report ")
+    final = json.loads(lines[-2][len("report "):])
     assert final["ok"] is True and final["rehearsal"] is True
+    assert final["device"] == last["device"]
     assert final["device"]["platform"] == "cpu"
     assert final["device"]["count"] == jax.device_count()
     want = {"device", "kernels", "train", "sample", "serve"}
