@@ -289,6 +289,7 @@ def _fwd_impl(q3, k3, v3, scale, block_q, block_k, interpret,
     res = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, kv_len=kv_len,
                           block_k=bk),
+        name="fdt_flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
@@ -346,6 +347,7 @@ def _bwd_impl(q3, k3, v3, out_bh, lse, g3, scale, block_q, block_k,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, kv_len=kv_len,
                           block_k=bk),
+        name="fdt_flash_bwd_dq",
         grid=(bh, lq_pad // bq, lk_pad // bk),
         in_specs=qkv_specs,
         out_specs=pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
@@ -368,6 +370,7 @@ def _bwd_impl(q3, k3, v3, out_bh, lse, g3, scale, block_q, block_k,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, kv_len=kv_len,
                           block_k=bk),
+        name="fdt_flash_bwd_dkv",
         grid=(bh, lk_pad // bk, lq_pad // bq),
         in_specs=kv_specs,
         out_specs=[

@@ -234,6 +234,7 @@ def _ln_mod_impl(x, pairs, eps, interpret, force_pallas, save_stats):
 
     res = pl.pallas_call(
         functools.partial(_ln_mod_kernel, eps=eps, nviews=nviews),
+        name="fdt_adaln_mod_fwd",
         grid=(b, nblk),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -273,6 +274,7 @@ def _ln_mod_bwd(x, pairs, mean, rstd, gs, interpret):
 
     dx, psums = pl.pallas_call(
         functools.partial(_ln_mod_bwd_kernel, nviews=nviews),
+        name="fdt_adaln_mod_bwd",
         grid=(b, nblk),
         in_specs=in_specs,
         out_specs=[
@@ -420,6 +422,7 @@ def _gate_res_impl(x, gate, h, interpret, force_pallas):
     out_dtype = jnp.result_type(x.dtype, gate.dtype, h.dtype)
     out = pl.pallas_call(
         _gate_res_kernel,
+        name="fdt_adaln_gate_fwd",
         grid=(b, l_pad // blk),
         in_specs=[
             pl.BlockSpec((1, blk, c), lambda i, j: (i, j, 0)),
@@ -461,6 +464,7 @@ def _gate_res_bwd(interpret, force_pallas, res, g):
     nblk = l_pad // blk
     dh, pg = pl.pallas_call(
         _gate_res_bwd_kernel,
+        name="fdt_adaln_gate_bwd",
         grid=(b, nblk),
         in_specs=[
             pl.BlockSpec((1, 1, c), lambda i, j: (i, 0, 0)),
@@ -561,6 +565,7 @@ def _geglu_impl(proj, interpret, force_pallas):
                                   lambda i, k, j=j: (i, k, j))
     out = pl.pallas_call(
         _geglu_kernel,
+        name="fdt_adaln_geglu_fwd",
         grid=(b, l_pad // blk),
         in_specs=[half(0), half(1)],
         out_specs=pl.BlockSpec((1, blk, f), lambda i, k: (i, k, 0)),
@@ -595,6 +600,7 @@ def _geglu_bwd(interpret, force_pallas, proj, g):
                                   lambda i, k, j=j: (i, k, j))
     dproj = pl.pallas_call(
         _geglu_bwd_kernel,
+        name="fdt_adaln_geglu_bwd",
         grid=(b, l_pad // blk),
         in_specs=[half(0), half(1),
                   pl.BlockSpec((1, blk, f), lambda i, k: (i, k, 0))],

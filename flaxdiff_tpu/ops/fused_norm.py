@@ -202,6 +202,7 @@ def _pallas_gn_silu_bwd(x, scale, bias, mean_c, rstd_c, g, groups,
     gsums, csums = pl.pallas_call(
         functools.partial(_gn_bwd_stats_kernel, groups=groups, hw=hw,
                           block_hw=blk, apply_silu=apply_silu),
+        name="fdt_gn_silu_bwd_sums",
         grid=(b, nblk),
         in_specs=[
             pl.BlockSpec((1, blk, c), lambda i, j: (i, j, 0)),
@@ -234,6 +235,7 @@ def _pallas_gn_silu_bwd(x, scale, bias, mean_c, rstd_c, g, groups,
 
     dx = pl.pallas_call(
         functools.partial(_gn_bwd_dx_kernel, apply_silu=apply_silu),
+        name="fdt_gn_silu_bwd_dx",
         grid=(b, nblk),
         in_specs=[
             pl.BlockSpec((1, blk, c), lambda i, j: (i, j, 0)),
@@ -291,6 +293,7 @@ def _impl_stats(x: jax.Array, scale: jax.Array, bias: jax.Array,
     sums = pl.pallas_call(
         functools.partial(_gn_stats_kernel, groups=groups, hw=hw,
                           block_hw=blk),
+        name="fdt_gn_silu_stats",
         grid=(b, nblk),
         in_specs=[pl.BlockSpec((1, blk, c), lambda i, j: (i, j, 0))],
         out_specs=pl.BlockSpec((1, 1, 2, groups), lambda i, j: (i, j, 0, 0)),
@@ -319,6 +322,7 @@ def _impl_stats(x: jax.Array, scale: jax.Array, bias: jax.Array,
     # Pass 2: normalize + affine + SiLU per block.
     out = pl.pallas_call(
         functools.partial(_gn_norm_kernel, apply_silu=apply_silu),
+        name="fdt_gn_silu_apply",
         grid=(b, nblk),
         in_specs=[
             pl.BlockSpec((1, blk, c), lambda i, j: (i, j, 0)),

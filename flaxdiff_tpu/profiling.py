@@ -254,10 +254,3 @@ def trace(logdir: str, host_tracer_level: int = 2):
                 record_event("trace_failed", "profiler.stop_trace",
                              detail=f"{type(e).__name__}: {e} "
                                     f"(logdir={logdir})")
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named TraceAnnotation visible in profiler timelines."""
-    with jax.profiler.TraceAnnotation(name):
-        yield

@@ -430,7 +430,8 @@ class DiffusionSampler:
         elif cached:
             flags = jnp.asarray(self.cache_plan.flags(num_steps))
 
-        def program(params, x_init, key, cond, uncond, mask=None, known=None):
+        def sampler_scan(params, x_init, key, cond, uncond, mask=None,
+                         known=None):
             denoise = self._denoise_fn(params, cond, uncond)
             if spatial:
                 denoise_comp = self._denoise_composed_fn(
@@ -519,7 +520,7 @@ class DiffusionSampler:
                 x0 = mask * x0 + (1.0 - mask) * known
             return x0
 
-        compiled = jax.jit(program)
+        compiled = jax.jit(sampler_scan)
         # Program-evidence plumb-through (telemetry/programs.py): when
         # the active hub carries a registry, the first invocation of
         # this solo program is timed and registered under its cache
@@ -659,8 +660,8 @@ class DiffusionSampler:
         Returns (x, keys, state) carries. Rows never interact, so a
         padded round is output-invariant for the real rows.
         """
-        def program(params, x, keys, pairs, n_act, offsets, cond, uncond,
-                    state):
+        def sampler_chunk(params, x, keys, pairs, n_act, offsets, cond,
+                          uncond, state):
             def row(x_r, key, row_pairs, n, off, c, u, st):
                 denoise = self._denoise_fn(params, c, u)
 
@@ -685,7 +686,7 @@ class DiffusionSampler:
             return jax.vmap(row)(x, keys, pairs, n_act, offsets,
                                  cond, uncond, state)
 
-        return jax.jit(program)
+        return jax.jit(sampler_chunk)
 
     def make_cached_chunk_program(self, round_steps: int):
         """Continuous-batching round WITH the diffusion cache: the
@@ -709,8 +710,8 @@ class DiffusionSampler:
         unchanged from `make_chunk_program` — a refresh-every-step
         plan is bit-identical to the uncached chunk path (tested).
         """
-        def program(params, x, keys, pairs, n_act, offsets, cond, uncond,
-                    state, flags, taps):
+        def sampler_chunk_cached(params, x, keys, pairs, n_act, offsets,
+                                 cond, uncond, state, flags, taps):
             def make_step(mode):
                 def step_all(x_c, subs, st, tp, pair_i, i):
                     def row(x_r, sub, s_r, tp_r, pr, off, c, u):
@@ -761,7 +762,7 @@ class DiffusionSampler:
                  flags))
             return x_o, keys_o, state_o, taps_o
 
-        return jax.jit(program)
+        return jax.jit(sampler_chunk_cached)
 
     def make_spatial_chunk_program(self, round_steps: int):
         """Continuous-batching round with the COMPOSED timestep x
@@ -785,8 +786,8 @@ class DiffusionSampler:
         refresh than its plan scheduled — round-mates can only grant
         extra fidelity. Token selection runs per-row inside the vmap
         (each row picks its own top-k from its own carries)."""
-        def program(params, x, keys, pairs, n_act, offsets, cond, uncond,
-                    state, codes, taps, refs):
+        def sampler_chunk_spatial(params, x, keys, pairs, n_act, offsets,
+                                  cond, uncond, state, codes, taps, refs):
             def make_step(mode):
                 def step_all(x_c, subs, st, tp, rf, pair_i, i):
                     def row(x_r, sub, s_r, tp_r, rf_r, pr, off, c, u):
@@ -839,14 +840,14 @@ class DiffusionSampler:
                  codes))
             return x_o, keys_o, state_o, taps_o, refs_o
 
-        return jax.jit(program)
+        return jax.jit(sampler_chunk_spatial)
 
     def make_terminal_program(self):
         """Terminal denoise for rows whose trajectory just completed:
         the solo program's final `denoise(x, steps[-1])` call, vmapped
         with each row's OWN terminal step value (spacings of different
         NFE need not end at bit-identical values)."""
-        def program(params, x, t_term, cond, uncond):
+        def sampler_terminal(params, x, t_term, cond, uncond):
             def row(x_r, t_r, c, u):
                 denoise = self._denoise_fn(params, c, u)
                 x0, _ = denoise(x_r, jnp.full((x_r.shape[0],), t_r))
@@ -854,7 +855,7 @@ class DiffusionSampler:
 
             return jax.vmap(row)(x, t_term, cond, uncond)
 
-        return jax.jit(program)
+        return jax.jit(sampler_terminal)
 
     def trajectory_inputs(self, num_steps: int,
                           start: Optional[float] = None,

@@ -130,12 +130,11 @@ class SamplerProgramEngine:
         self.telemetry = telemetry
         self._programs: Dict[tuple, Any] = {}
         # last dispatched round's provenance (program kind/key, bucket,
-        # live steps, cache-plan codes) — written by advance()/
-        # finalize() on the single dispatch thread, read by the
+        # live steps, cache-plan codes) — written by advance() on the
+        # single dispatch thread, read by the
         # scheduler's request tracer right after the call. Host-side
         # dicts only; None until the first round.
         self.last_round_info: Optional[Dict[str, Any]] = None
-        self.last_finalize_info: Optional[Dict[str, Any]] = None
 
     # -- keys -----------------------------------------------------------------
     def _plan_for(self, req: SampleRequest):
@@ -300,6 +299,11 @@ class SamplerProgramEngine:
                 if use_ema else self.pipeline.params)
 
     # -- batched rounds -------------------------------------------------------
+    def _span(self, name: str, **args):
+        """A phase of a round on the dispatch thread (`serve.stack`,
+        `serve.launch`, `serve.unstack`): per phase, never per row."""
+        return self.telemetry.span(name, cat="serving", args=args)
+
     def _stack_rows(self, rows: List[RequestState], bucket: int):
         """Stack per-row carries, replicating row 0 into padding slots
         (inert: n_act = 0 keeps their carry unchanged, and their output
@@ -332,106 +336,87 @@ class SamplerProgramEngine:
         group = rows[0].group
         ds = self._sampler_for(rows[0].req)
         plan = rows[0].plan             # group-uniform (plan is in the key)
-        x, keys, state, cond, uncond, taps, refs = \
-            self._stack_rows(rows, bucket)
-
-        pad = bucket - len(rows)
-        chunk_pairs, n_act, offsets = [], [], []
-        for r in rows + [rows[0]] * pad:
-            live = max(0, min(r.remaining, round_steps))
-            sl = r.pairs[r.done:r.done + round_steps]
-            if sl.shape[0] == 0:        # exhausted padding row
-                sl = jnp.broadcast_to(r.pairs[-1:], (round_steps, 2))
-            elif sl.shape[0] < round_steps:
-                sl = jnp.concatenate(
-                    [sl, jnp.broadcast_to(
-                        sl[-1:], (round_steps - sl.shape[0], 2))], axis=0)
-            chunk_pairs.append(sl)
-            n_act.append(live)
-            offsets.append(r.done)
-        pairs = jnp.stack(chunk_pairs)
-        n_act_a = jnp.asarray(n_act, jnp.int32)
-        offsets_a = jnp.asarray(offsets, jnp.int32)
-
-        t0 = time.perf_counter()
-        refs_n = None
+        span = self._span
         sched_row = None        # cache-plan step codes this round ran
-        if plan is None:
-            kind_used = "chunk"
+        with span("serve.stack"):
+            x, keys, state, cond, uncond, taps, refs = \
+                self._stack_rows(rows, bucket)
+
+            pad = bucket - len(rows)
+            chunk_pairs, n_act, offsets = [], [], []
+            for r in rows + [rows[0]] * pad:
+                live = max(0, min(r.remaining, round_steps))
+                sl = r.pairs[r.done:r.done + round_steps]
+                if sl.shape[0] == 0:        # exhausted padding row
+                    sl = jnp.broadcast_to(r.pairs[-1:], (round_steps, 2))
+                elif sl.shape[0] < round_steps:
+                    sl = jnp.concatenate(
+                        [sl, jnp.broadcast_to(
+                            sl[-1:], (round_steps - sl.shape[0], 2))],
+                        axis=0)
+                chunk_pairs.append(sl)
+                n_act.append(live)
+                offsets.append(r.done)
+            prog_args = (self._params_for(group), x, keys,
+                         jnp.stack(chunk_pairs),
+                         jnp.asarray(n_act, jnp.int32),
+                         jnp.asarray(offsets, jnp.int32),
+                         cond, uncond, state)
+            if plan is None:
+                kind_used, build = "chunk", ds.make_chunk_program
+            elif refs is not None:
+                # composed (timestep x spatial) plan: round-level step
+                # codes = per-step MAX over each row's own offset-aligned
+                # schedule (host-side numpy, zero syncs) — refresh beats
+                # spatial beats reuse, so no row gets LESS refresh than
+                # ITS plan scheduled; round-mates can only add fidelity
+                want = [0] * round_steps
+                for r in rows:
+                    w = r.codes[r.done:r.done + round_steps]
+                    for j in range(len(w)):
+                        want[j] = max(want[j], int(w[j]))
+                sched_row = want
+                kind_used = "chunk_spatial"
+                build = ds.make_spatial_chunk_program
+                prog_args += (jnp.asarray(want, jnp.int32), taps, refs)
+            else:
+                # round-level refresh flags: OR of each row's own
+                # offset-aligned schedule (host-side numpy, zero syncs) —
+                # no row ever misses ITS scheduled refresh; round-mates
+                # may grant extra free refreshes (fidelity can only
+                # improve)
+                want = [False] * round_steps
+                for r in rows:
+                    w = r.flags[r.done:r.done + round_steps]
+                    for j in range(len(w)):
+                        want[j] = want[j] or bool(w[j])
+                sched_row = [int(w) for w in want]
+                kind_used = "chunk_cached"
+                build = ds.make_cached_chunk_program
+                prog_args += (jnp.asarray(want), taps)
+
+        with span("serve.launch", kind=kind_used):
+            t0 = time.perf_counter()
             program, miss = self._get_program(
-                "chunk", group, bucket, round_steps,
-                lambda: ds.make_chunk_program(round_steps))
-            prog_args = (self._params_for(group), x, keys, pairs,
-                         n_act_a, offsets_a, cond, uncond, state)
-            x_n, keys_n, state_n = program(*prog_args)
-            taps_n = None
-        elif refs is not None:
-            # composed (timestep x spatial) plan: round-level step
-            # codes = per-step MAX over each row's own offset-aligned
-            # schedule (host-side numpy, zero syncs) — refresh beats
-            # spatial beats reuse, so no row gets LESS refresh than
-            # ITS plan scheduled; round-mates can only add fidelity
-            want = [0] * round_steps
-            for r in rows:
-                w = r.codes[r.done:r.done + round_steps]
-                for j in range(len(w)):
-                    want[j] = max(want[j], int(w[j]))
-            codes_a = jnp.asarray(want, jnp.int32)
-            kind_used = "chunk_spatial"
-            sched_row = [int(w) for w in want]
-            program, miss = self._get_program(
-                "chunk_spatial", group, bucket, round_steps,
-                lambda: ds.make_spatial_chunk_program(round_steps))
-            prog_args = (self._params_for(group), x, keys, pairs,
-                         n_act_a, offsets_a, cond, uncond, state,
-                         codes_a, taps, refs)
-            x_n, keys_n, state_n, taps_n, refs_n = program(*prog_args)
-            self.telemetry.counter("serving/cache_rows").inc(len(rows))
-            self.telemetry.counter(
-                "serving/spatial_rows").inc(len(rows))
-            refresh = spatial = reused = 0
-            for i, r in enumerate(rows):
-                for j in range(n_act[i]):
-                    refresh += int(want[j] == 2)
-                    spatial += int(want[j] == 1)
-                    reused += int(want[j] == 0)
-            self.telemetry.counter(
-                "serving/cache_refresh_steps").inc(refresh)
-            self.telemetry.counter(
-                "serving/spatial_steps").inc(spatial)
-            self.telemetry.counter(
-                "serving/cache_reused_steps").inc(reused)
-        else:
-            # round-level refresh flags: OR of each row's own
-            # offset-aligned schedule (host-side numpy, zero syncs) —
-            # no row ever misses ITS scheduled refresh; round-mates may
-            # grant extra free refreshes (fidelity can only improve)
-            want = [False] * round_steps
-            for r in rows:
-                w = r.flags[r.done:r.done + round_steps]
-                for j in range(len(w)):
-                    want[j] = want[j] or bool(w[j])
-            flags_a = jnp.asarray(want)
-            kind_used = "chunk_cached"
-            sched_row = [int(w) for w in want]
-            program, miss = self._get_program(
-                "chunk_cached", group, bucket, round_steps,
-                lambda: ds.make_cached_chunk_program(round_steps))
-            prog_args = (self._params_for(group), x, keys, pairs,
-                         n_act_a, offsets_a, cond, uncond, state,
-                         flags_a, taps)
-            x_n, keys_n, state_n, taps_n = program(*prog_args)
-            self.telemetry.counter("serving/cache_rows").inc(len(rows))
-            refresh = reused = 0
-            for i, r in enumerate(rows):
-                for j in range(n_act[i]):
-                    refresh += int(want[j])
-                    reused += int(not want[j])
-            self.telemetry.counter(
-                "serving/cache_refresh_steps").inc(refresh)
-            self.telemetry.counter(
-                "serving/cache_reused_steps").inc(reused)
-        compile_s = (time.perf_counter() - t0) if miss else 0.0
+                kind_used, group, bucket, round_steps,
+                lambda: build(round_steps))
+            # (x, keys, state), then the taps and the score-reference
+            # carries of the cached programs
+            outs = tuple(program(*prog_args)) + (None, None)
+            x_n, keys_n, state_n, taps_n, refs_n = outs[:5]
+            compile_s = (time.perf_counter() - t0) if miss else 0.0
+        if sched_row is not None:
+            # codes of a composed plan: 2 refresh, 1 spatial, 0 reuse;
+            # flags of a timestep plan: 1 refresh, 0 reuse
+            top = 2 if refs is not None else 1
+            ran = [c for n in n_act[:len(rows)] for c in sched_row[:n]]
+            count = self.telemetry.counter
+            count("serving/cache_rows").inc(len(rows))
+            count("serving/cache_refresh_steps").inc(ran.count(top))
+            count("serving/cache_reused_steps").inc(ran.count(0))
+            if refs is not None:
+                count("serving/spatial_rows").inc(len(rows))
+                count("serving/spatial_steps").inc(ran.count(1))
         if miss:
             # evidence registry (telemetry/programs.py): the program
             # just paid its compile — register it under the exact
@@ -453,19 +438,22 @@ class SamplerProgramEngine:
             self.last_round_info["codes"] = sched_row
 
         finished: List[RequestState] = []
-        for i, r in enumerate(rows):
-            r.x = x_n[i]
-            r.rng = keys_n[i]
-            r.state = jax.tree_util.tree_map(lambda a: a[i], state_n)
-            if taps_n is not None:
-                r.taps = jax.tree_util.tree_map(lambda a: a[i], taps_n)
-            if refs_n is not None:
-                r.ref = jax.tree_util.tree_map(lambda a: a[i], refs_n)
-            r.done += int(n_act[i])
-            r.rounds += 1
-            r.compile_ms += compile_s * 1e3
-            if r.remaining <= 0:
-                finished.append(r)
+        with span("serve.unstack"):
+            for i, r in enumerate(rows):
+                r.x = x_n[i]
+                r.rng = keys_n[i]
+                r.state = jax.tree_util.tree_map(lambda a: a[i], state_n)
+                if taps_n is not None:
+                    r.taps = jax.tree_util.tree_map(lambda a: a[i],
+                                                    taps_n)
+                if refs_n is not None:
+                    r.ref = jax.tree_util.tree_map(lambda a: a[i],
+                                                   refs_n)
+                r.done += int(n_act[i])
+                r.rounds += 1
+                r.compile_ms += compile_s * 1e3
+                if r.remaining <= 0:
+                    finished.append(r)
         return finished, compile_s
 
     def finalize(self, rows: List[RequestState],
@@ -475,26 +463,24 @@ class SamplerProgramEngine:
         row order, compile seconds)."""
         group = rows[0].group
         ds = self._sampler_for(rows[0].req)
-        x, _, _, cond, uncond, _, _ = self._stack_rows(rows, bucket)
-        pad = bucket - len(rows)
-        t_term = jnp.asarray(
-            [r.terminal_t for r in rows + [rows[0]] * pad], jnp.float32)
+        with self._span("serve.stack"):
+            x, _, _, cond, uncond, _, _ = self._stack_rows(rows, bucket)
+            pad = bucket - len(rows)
+            t_term = jnp.asarray(
+                [r.terminal_t for r in rows + [rows[0]] * pad],
+                jnp.float32)
+            prog_args = (self._params_for(group), x, t_term, cond, uncond)
 
-        program, miss = self._get_program(
-            "terminal", group, bucket, 0,
-            lambda: ds.make_terminal_program())
-        t0 = time.perf_counter()
-        prog_args = (self._params_for(group), x, t_term, cond, uncond)
-        x0 = program(*prog_args)
-        compile_s = (time.perf_counter() - t0) if miss else 0.0
+        with self._span("serve.launch", kind="terminal"):
+            program, miss = self._get_program(
+                "terminal", group, bucket, 0,
+                lambda: ds.make_terminal_program())
+            t0 = time.perf_counter()
+            x0 = program(*prog_args)
+            compile_s = (time.perf_counter() - t0) if miss else 0.0
         if miss:
             self._register_evidence("terminal", group, bucket, 0,
                                     program, prog_args, compile_s)
-        self.last_finalize_info = {
-            "kind": "terminal",
-            "key": str(self._program_key("terminal", group, bucket, 0)),
-            "bucket": int(bucket), "miss": bool(miss),
-        }
 
         x0 = x0[:len(rows)]
         if ds.autoencoder is not None:

@@ -446,12 +446,17 @@ class ServingScheduler:
         `DeviceLost`) models a dead chip, `serving.round` is polled
         once per row with `key="seed:<seed>:"` so a per-key plan can
         deterministically poison ONE request no matter what it is
-        batched with. One dict lookup each with no plan armed."""
-        if _faults.check("serving.device_lost"):
-            raise DeviceLost("injected fault at serving.device_lost")
-        for r in rows:
-            _faults.check("serving.round", key=f"seed:{r.req.seed}:")
-        return self.engine.advance(rows, bucket, round_steps)
+        batched with. One dict lookup each with no plan armed. The
+        whole of it is the span `serve.round`; `round` is the join key
+        to the request tracer's `round_detail` rows."""
+        with self.telemetry.span("serve.round", cat="serving", args={
+                "round": self._round_no, "bucket": bucket,
+                "rows": len(rows), "steps": round_steps}):
+            if _faults.check("serving.device_lost"):
+                raise DeviceLost("injected fault at serving.device_lost")
+            for r in rows:
+                _faults.check("serving.round", key=f"seed:{r.req.seed}:")
+            return self.engine.advance(rows, bucket, round_steps)
 
     def _fail_state(self, r: RequestState, fault: ServingFault,
                     outcome: str) -> None:
@@ -683,55 +688,74 @@ class ServingScheduler:
                 f"dispatch thread died: {e!r}", kind="scheduler_died",
                 cause=e))
 
+    def _next_round_locked(self):
+        """The lock's own work of one loop turn (the span
+        `serve.admit`): shed expired, pick the group, pop its active
+        rows, admit queued ones, brownout tier. Returns (group key,
+        rows, buckets): no key means no group has work, no rows means
+        the group had only backoff-parked entries (or every row was
+        shed)."""
+        cfg = self.config
+        self._shed_expired_locked()
+        gk = self._pick_group_locked()
+        if gk is None:
+            return None, [], cfg.batch_buckets
+        now = _now()
+        # brownout tier 3: shrink rounds to the smallest bucket
+        # (smaller blast radius + memory footprint) before any
+        # shedding happens
+        tier = (self.brownout.tier(len(self._queue), cfg.max_queue, now)
+                if self.brownout is not None else 0)
+        buckets = cfg.batch_buckets
+        if tier >= 3:
+            buckets = (min(cfg.batch_buckets),)
+        max_bucket = max(buckets)
+        rows = self._shed_expired_active(self._active.pop(gk, []), now)
+        if len(rows) > max_bucket:
+            # bucket shrink mid-group: overflow rows stay active and
+            # ride the group's next round
+            self._active[gk] = rows[max_bucket:]
+            rows = rows[:max_bucket]
+        rows += self._admit_locked(gk, max_bucket - len(rows), now)
+        if rows:
+            if tier >= 3:
+                self.telemetry.counter(
+                    "serving/brownout_bucket_shrunk").inc()
+            self._round_no += 1
+            self._last_served[gk] = self._round_no
+        return gk, rows, buckets
+
     def _dispatch_rounds(self) -> None:
         tel = self.telemetry
         cfg = self.config
+
+        def span(name, **args):
+            return tel.span(name, cat="serving", args=args)
+
         while True:
             with self._cv:
-                while not (self._queue or self._active or self._closed):
-                    self._cv.wait()
+                if not (self._queue or self._active or self._closed):
+                    with span("serve.wait"):
+                        while not (self._queue or self._active
+                                   or self._closed):
+                            self._cv.wait()
                 if self._closed and not self._draining:
                     break
-                self._shed_expired_locked()
-                gk = self._pick_group_locked()
-                if gk is None:
-                    if self._closed and not self._completions \
-                            and not self._processing:
-                        # a draining close may still see a fetch-fault
-                        # requeue from the completion thread — only
-                        # exit once nothing in flight can re-enter
-                        break
-                    self._cv.wait(0.02)
-                    continue
-                now = _now()
-                # brownout tier 3: shrink rounds to the smallest bucket
-                # (smaller blast radius + memory footprint) before any
-                # shedding happens
-                tier = (self.brownout.tier(len(self._queue),
-                                           cfg.max_queue, now)
-                        if self.brownout is not None else 0)
-                buckets = cfg.batch_buckets
-                if tier >= 3:
-                    buckets = (min(cfg.batch_buckets),)
-                max_bucket = max(buckets)
-                rows = self._shed_expired_active(
-                    self._active.pop(gk, []), now)
-                if len(rows) > max_bucket:
-                    # bucket shrink mid-group: overflow rows stay
-                    # active and ride the group's next round
-                    self._active[gk] = rows[max_bucket:]
-                    rows = rows[:max_bucket]
-                rows += self._admit_locked(gk, max_bucket - len(rows),
-                                           now)
+                with span("serve.admit"):
+                    gk, rows, buckets = self._next_round_locked()
+                if gk is None and self._closed \
+                        and not self._completions \
+                        and not self._processing:
+                    # a draining close may still see a fetch-fault
+                    # requeue from the completion thread — only
+                    # exit once nothing in flight can re-enter
+                    break
                 if not rows:
-                    # group had only backoff-parked entries (or every
-                    # row was shed): wait for the earliest retry
-                    self._cv.wait(0.02)
+                    # nothing to dispatch yet: wait for the earliest
+                    # retry (or the completion thread's requeue)
+                    with span("serve.wait"):
+                        self._cv.wait(0.02)
                     continue
-                if tier >= 3:
-                    tel.counter("serving/brownout_bucket_shrunk").inc()
-                self._round_no += 1
-                self._last_served[gk] = self._round_no
 
             if self.profiler is not None:
                 # outside the lock: the poll may parse a closing
@@ -763,15 +787,11 @@ class ServingScheduler:
                         t_disp, _now(), self._round_no)
                 live = [r for r in rows if r.remaining > 0]
                 if finished:
-                    t_fin = _now()
-                    out, _ = self.engine.finalize(
-                        finished, bucket_up(len(finished), buckets))
-                    if self.tracer.enabled:
-                        self.tracer.finalize(
-                            finished,
-                            getattr(self.engine,
-                                    "last_finalize_info", None),
-                            t_fin, _now())
+                    fin_bucket = bucket_up(len(finished), buckets)
+                    with span("serve.finalize", rows=len(finished),
+                              bucket=fin_bucket):
+                        out, _ = self.engine.finalize(finished,
+                                                      fin_bucket)
             except (KeyboardInterrupt, SystemExit):
                 raise
             except BaseException as e:  # noqa: BLE001 — fault barrier
@@ -789,9 +809,13 @@ class ServingScheduler:
                     # PR-5 bounded in-flight dispatch: never race more
                     # than max_inflight completed batches ahead of the
                     # completion thread's host sync
-                    while len(self._completions) > cfg.max_inflight:
-                        tel.counter("serving/backpressure_waits").inc()
-                        self._cv.wait()
+                    if len(self._completions) > cfg.max_inflight:
+                        with span("serve.backpressure"):
+                            while len(self._completions) \
+                                    > cfg.max_inflight:
+                                tel.counter(
+                                    "serving/backpressure_waits").inc()
+                                self._cv.wait()
         # non-draining close: rows popped mid-round missed close()'s
         # cancel sweep — resolve their futures before exiting
         with self._cv:
@@ -840,8 +864,10 @@ class ServingScheduler:
                 # fault of the FETCH, not of any request — the batch
                 # requeues for a bit-exact replay from scratch
                 _faults.check("serving.fetch")
-                _block_until_ready(out)
-                host = _device_get(out)
+                with tel.span("serve.fetch", cat="serving",
+                              args={"rows": len(rows)}):
+                    _block_until_ready(out)
+                    host = _device_get(out)
             except (KeyboardInterrupt, SystemExit):
                 raise
             except BaseException as e:  # noqa: BLE001 — fault barrier
@@ -870,26 +896,35 @@ class ServingScheduler:
                     self._cv.notify_all()
                 continue
             t_ready = _now()
-            for i, r in enumerate(rows):
-                latency_ms = (t_ready - r.submit_t) * 1e3
-                queue_ms = ((r.first_dispatch_t or r.submit_t)
-                            - r.submit_t) * 1e3
-                device_ms = max(0.0, latency_ms - queue_ms - r.compile_ms)
-                hist("serving/latency_ms").observe(latency_ms)
-                hist("serving/queue_ms").observe(queue_ms)
-                hist("serving/compile_ms").observe(r.compile_ms)
-                hist("serving/device_ms").observe(device_ms)
-                tel.counter("serving/requests_ok").inc()
-                # the trace row carries the SAME decomposition the
-                # histograms above observed — per-request span sums
-                # reconcile with the aggregates by construction
-                self.tracer.complete(r, queue_ms, r.compile_ms,
-                                     device_ms, latency_ms, t_ready)
-                r.future.set_result(SampleResult(
-                    samples=host[i], request=r.req, queue_ms=queue_ms,
-                    compile_ms=r.compile_ms, device_ms=device_ms,
-                    latency_ms=latency_ms, rounds=r.rounds,
-                    attempts=r.attempts, degraded=r.degraded))
+            with tel.span("serve.resolve", cat="serving",
+                          args={"rows": len(rows)}):
+                self._resolve(rows, host, t_ready, hist)
             with self._cv:
                 self._processing = False
                 self._cv.notify_all()
+
+    def _resolve(self, rows: List[RequestState], host, t_ready: float,
+                 hist) -> None:
+        """Per-row SLO histograms, trace row and `set_result` of one
+        fetched batch (completion thread; the span `serve.resolve`)."""
+        tel = self.telemetry
+        for i, r in enumerate(rows):
+            latency_ms = (t_ready - r.submit_t) * 1e3
+            queue_ms = ((r.first_dispatch_t or r.submit_t)
+                        - r.submit_t) * 1e3
+            device_ms = max(0.0, latency_ms - queue_ms - r.compile_ms)
+            hist("serving/latency_ms").observe(latency_ms)
+            hist("serving/queue_ms").observe(queue_ms)
+            hist("serving/compile_ms").observe(r.compile_ms)
+            hist("serving/device_ms").observe(device_ms)
+            tel.counter("serving/requests_ok").inc()
+            # the trace row carries the SAME decomposition the
+            # histograms above observed — per-request span sums
+            # reconcile with the aggregates by construction
+            self.tracer.complete(r, queue_ms, r.compile_ms,
+                                 device_ms, latency_ms, t_ready)
+            r.future.set_result(SampleResult(
+                samples=host[i], request=r.req, queue_ms=queue_ms,
+                compile_ms=r.compile_ms, device_ms=device_ms,
+                latency_ms=latency_ms, rounds=r.rounds,
+                attempts=r.attempts, degraded=r.degraded))

@@ -22,9 +22,13 @@ the ROADMAP depends on — you cannot speed up what you cannot attribute:
   aggregate   CrossHostAggregator: min/max/mean/p50/p99/spread of
               per-host metrics over the resilience Transport (real pods
               via jax.distributed; CPU tests via InMemoryTransport)
-  tracing     TraceRecorder: host-side spans (fit phases, checkpoint
-              rounds, sampler loops, recovery paths) as Chrome
-              trace-event JSON, loadable in Perfetto
+  tracing     `span(name)`: the one span primitive, a
+              `jax.profiler.TraceAnnotation` named `fdt.<name>` on the
+              profiler's clock (beside the device planes of any
+              capture); `SPANS`, the closed list of names; and
+              TraceRecorder, the second sink: the same spans as Chrome
+              trace-event JSON (`trace.json`, Perfetto) for a whole
+              job. `Telemetry.span` / `StepPhaseTimer.phase` write both
   reqtrace    RequestTracer: request-scoped serving traces — follow
               one SampleRequest through admission, queue, every
               micro-batch round (program key, bucket, step codes),

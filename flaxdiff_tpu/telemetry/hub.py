@@ -40,9 +40,16 @@ from .metrics import (JsonlExporter, LoggerExporter, MetricsRegistry,
 from .phases import StepPhaseTimer
 from .programs import PROGRAMS_FILENAME, ProgramRegistry
 from .tracing import TraceRecorder
+from .tracing import span as profiler_span
 
 TELEMETRY_JSONL = "telemetry.jsonl"
 TRACE_FILENAME = "trace.json"
+
+
+@contextlib.contextmanager
+def _both(a, b):
+    with a, b:
+        yield
 
 
 class Telemetry:
@@ -163,9 +170,16 @@ class Telemetry:
     # -- tracing -------------------------------------------------------------
     def span(self, name: str, cat: str = "run",
              args: Optional[Dict[str, object]] = None):
+        """One call site, one name, two sinks: always the profiler's
+        span `fdt.<name>` (`tracing.span`: recorded only while a
+        `jax.profiler` session runs; int / str `args` ride as its
+        stats), and the recorder's span too when the hub has one."""
+        attrs = ({k: v for k, v in args.items()
+                  if isinstance(v, (int, str))} if args else {})
+        prof = profiler_span(name, **attrs)
         if self.recorder is None:
-            return contextlib.nullcontext()
-        return self.recorder.span(name, cat=cat, args=args)
+            return prof
+        return _both(prof, self.recorder.span(name, cat=cat, args=args))
 
     def instant(self, name: str, cat: str = "event",
                 args: Optional[Dict[str, object]] = None) -> None:

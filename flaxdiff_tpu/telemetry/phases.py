@@ -14,7 +14,9 @@ into named phases:
                  host phase (the classic async-dispatch lie)
     checkpoint   save dispatch + two-phase commit round
     eval         in-loop validation/sampling
-    other        everything unattributed (loop bookkeeping, logging)
+    log_step     the log branch: loss-window fetch, callbacks, the
+                 best-state copy, aggregate/export
+    other        everything unattributed (loop bookkeeping)
 
 The invariant — tested — is that the phases of one step sum to that
 step's wall-clock exactly (`other` is the closing residual, floored at
@@ -50,6 +52,7 @@ import time
 from typing import Dict, Optional
 
 from .metrics import MetricsRegistry
+from .tracing import span
 
 PHASES = ("data_wait", "host", "device", "checkpoint", "eval")
 
@@ -83,6 +86,8 @@ class StepPhaseTimer:
         self._step: Optional[int] = None
         self._t0 = 0.0
         self._acc: Dict[str, float] = {}
+        # per open phase: the seconds its child phases took
+        self._nested: list = []
         self.last: Optional[Dict[str, float]] = None
         # the row to export for the just-ended step: window sums on a
         # sampled step, None on off-sample steps (nothing to emit — the
@@ -113,12 +118,22 @@ class StepPhaseTimer:
 
     @contextlib.contextmanager
     def phase(self, name: str):
+        """Times the block into phase `name` and opens the span
+        `fit.<name>` on the profiler's clock (`tracing.span`). A phase
+        opened inside another books its time to itself alone: the outer
+        phase gets its SELF time, so the sum invariant survives
+        nesting (`elastic` inside `log_step`)."""
         t0 = self._clock()
+        self._nested.append(0.0)
         try:
-            yield
+            with span("fit." + name):
+                yield
         finally:
-            self._acc[name] = self._acc.get(name, 0.0) \
-                + (self._clock() - t0)
+            dt = self._clock() - t0
+            inner = self._nested.pop()
+            self._acc[name] = self._acc.get(name, 0.0) + (dt - inner)
+            if self._nested:
+                self._nested[-1] += dt
 
     def observe_phase(self, name: str, seconds: float) -> None:
         """Record an externally-timed phase duration (e.g. an eval pass

@@ -22,8 +22,11 @@ Output, per traced request:
 - Chrome trace-event spans in the hub's `TraceRecorder` (`trace.json`,
   Perfetto-loadable): a `req.queue` span (submit -> first dispatch) and
   a `req.serve` span (first dispatch -> samples on host) on a per-trace
-  lane, plus shared `serve.round` / `serve.finalize` spans on the
-  dispatch lane carrying program key / bucket / rows / step codes.
+  lane. The shared `serve.round` / `serve.finalize` spans are the
+  scheduler's own (`Telemetry.span`, docs/OBSERVABILITY.md "Trace
+  spans"), on the dispatch thread's lane, carrying round / bucket /
+  rows / steps; program key and step codes are in the rows below,
+  joined by `round`.
 - One `request_trace` JSONL row in `telemetry.jsonl` with the same
   latency decomposition the result future carries — the row's
   `queue_ms + compile_ms + device_ms == latency_ms` identity is exact
@@ -249,35 +252,21 @@ class RequestTracer:
     # -- dispatch-side spans (dispatch thread; host timestamps only) --------
     def round(self, rows, info: Optional[Dict[str, Any]], t0: float,
               t1: float, round_no: int) -> None:
-        """One micro-batch round: ONE shared `serve.round` span on the
-        dispatch lane + a per-participating-request round record (the
-        same dict, it is immutable once emitted) for the drill-down."""
+        """One micro-batch round: a per-participating-request round
+        record (the same dict, it is immutable once emitted) for the
+        drill-down. The round's SPAN is the scheduler's own
+        `serve.round` (`Telemetry.span`: recorder and profiler, once
+        each); `round` joins the two."""
         if not self.enabled:
             return
         detail: Dict[str, Any] = {"round": int(round_no),
                                   "ms": round((t1 - t0) * 1e3, 3)}
         if info:
             detail.update(info)
-        self.telemetry.recorder.event_at(
-            "serve.round", t0, t1, cat="serving", args=detail,
-            tid=DISPATCH_TID)
         for r in rows:
             tr = getattr(r, "trace", None)
             if tr is not None:
                 tr.rounds.append(detail)
-
-    def finalize(self, rows, info: Optional[Dict[str, Any]], t0: float,
-                 t1: float) -> None:
-        """Terminal denoise + decode of the rows that completed."""
-        if not self.enabled:
-            return
-        detail: Dict[str, Any] = {"ms": round((t1 - t0) * 1e3, 3),
-                                  "rows": len(rows)}
-        if info:
-            detail.update(info)
-        self.telemetry.recorder.event_at(
-            "serve.finalize", t0, t1, cat="serving", args=detail,
-            tid=DISPATCH_TID)
 
     # -- completion (completion thread, after the blessed host sync) --------
     def complete(self, state, queue_ms: float, compile_ms: float,

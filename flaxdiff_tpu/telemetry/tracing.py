@@ -15,6 +15,18 @@ run that traces too finely degrades its trace, never its training).
 `save()` rewrites the whole file atomically and may be called
 repeatedly (the trainer flushes at the end of fit; crash loses at most
 the spans since the last flush).
+
+**One primitive, two sinks.** `span(name, **attrs)` below opens the
+same span on the PROFILER's clock: a `jax.profiler.TraceAnnotation`
+named `fdt.<name>` (a TraceMe: recorded only while a profiler session
+runs, about a microsecond otherwise). `Telemetry.span` always enters
+it, and the recorder's span too when the hub has one;
+`StepPhaseTimer.phase` enters it beside its own clock. So `trace.json`
+(this recorder's clock) and any `jax.profiler` capture (the
+benchmark's `--trace 1`, `profiling.trace`, `DeviceProfiler`) hold the
+same names, and the capture holds them on the clock of the device
+planes. `SPANS` is the closed list of names; docs/OBSERVABILITY.md
+"Trace spans" has the table.
 """
 from __future__ import annotations
 
@@ -23,7 +35,108 @@ import json
 import os
 import threading
 import time
-from typing import Dict, Optional
+from typing import Dict, NamedTuple, Optional, Tuple
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+# The program's prefix in a profiler capture, as `bench.` is the
+# benchmark harness's.
+SPAN_PREFIX = "fdt."
+
+class SpanDoc(NamedTuple):
+    thread: str                # dispatch | complete | fit | any
+    parent: Optional[str]      # `a | b`: either; None: a top-level span
+    attrs: Tuple[str, ...]     # integer / string stats it carries
+    covers: str
+
+
+# The closed list of span names, one place (tests walk every
+# `.span("...")` / `.phase("...")` call site of the package against it).
+SPANS: Dict[str, SpanDoc] = {k: SpanDoc(*v) for k, v in {
+    # -- serving: dispatch thread (`serving-dispatch`), per loop turn
+    "serve.wait": ("dispatch", None, (), "`_cv.wait` with nothing to "
+                   "serve: queue and active empty, or only parked "
+                   "entries"),
+    "serve.admit": ("dispatch", None, (), "under the lock: shed expired, "
+                    "pick group, pop active, admit, brownout tier"),
+    "serve.round": ("dispatch", None, ("round", "bucket", "rows", "steps"),
+                    "`_checked_advance`, first line to return"),
+    "serve.stack": ("dispatch", "serve.round | serve.finalize", (),
+                    "`_stack_rows` and the pairs / n_act / offsets "
+                    "building"),
+    "serve.launch": ("dispatch", "serve.round | serve.finalize", ("kind",),
+                     "`_get_program` and the jitted call (host side of "
+                     "the dispatch; a compile on a miss is its length)"),
+    "serve.unstack": ("dispatch", "serve.round", (), "the per-row "
+                      "write-back of x / rng / state / taps / ref"),
+    "serve.finalize": ("dispatch", None, ("rows", "bucket"),
+                       "`engine.finalize` whole: stack, launch, slice, "
+                       "optional decode, clip"),
+    "serve.backpressure": ("dispatch", None, (), "the wait while more "
+                           "than `max_inflight` batches are in flight"),
+    # -- serving: completion thread (`serving-complete`)
+    "serve.fetch": ("complete", None, ("rows",),
+                    "`_block_until_ready` + `_device_get` of a batch"),
+    "serve.resolve": ("complete", None, ("rows",), "per-row histograms "
+                      "and `set_result`"),
+    # -- fit: the training loop's thread. `fit.step` is a
+    # StepTraceAnnotation (xprof's step view keys on it); the rest are
+    # `StepPhaseTimer.phase` names and two children of the log step
+    "fit.step": ("fit", None, ("step_num",), "one loop turn"),
+    "fit.host": ("fit", "fit.step", (), "host side of `train_step`: the "
+                 "dispatch of the jitted step"),
+    "fit.data_wait": ("fit", "fit.step", (), "`next(upload)`"),
+    "fit.device": ("fit", "fit.step", (), "the sampled steps' "
+                   "`block_until_ready` (enabled hub only)"),
+    "fit.numerics": ("fit", "fit.step", (), "aux readback + detector"),
+    "fit.profile": ("fit", "fit.step", (), "DeviceProfiler open / close"),
+    "fit.elastic": ("fit", "fit.step", (), "pod quorum round"),
+    "fit.checkpoint": ("fit", "fit.step", (), "save + commit round"),
+    "fit.log_step": ("fit", "fit.step", (), "the log branch: loss-window "
+                     "fetch to the `log_t0` that closes it"),
+    "fit.loss_fetch": ("fit", "fit.log_step", (), "the blocking readback "
+                       "of the loss window"),
+    "fit.best_state_copy": ("fit", "fit.log_step", (),
+                            "`keep_best_state`'s `tree_map(jnp.copy)`"),
+    # -- operator only: rare paths, no benchmark metric reads them
+    "ckpt.save": ("any", None, ("step",), "checkpoint save dispatch"),
+    "ckpt.commit": ("any", None, ("step",), "two-phase commit round"),
+    "ckpt.restore": ("any", None, ("step",), "checkpoint restore"),
+    "ckpt.consensus_restore": ("any", None, (), "pod-agreed restore"),
+    "ckpt.save_and_commit": ("fit", "fit.checkpoint", ("step",),
+                             "in-loop save + commit"),
+    "ckpt.final_save": ("fit", None, (), "the force-save after the loop"),
+    "train.restore_at_start": ("fit", None, (), "resume before step 1"),
+    "train.rollback": ("fit", None, (), "anomaly recovery"),
+    "data.first_batch": ("fit", None, (), "the first `next(upload)`"),
+    "data.rewind_refetch": ("fit", None, (), "replay after a rollback"),
+    "elastic.restore": ("fit", None, (), "shrink / readmit restore"),
+    "elastic.shrink": ("fit", None, (), "mesh shrink transition"),
+    "elastic.quorum_rollback": ("fit", None, (), "pod-voted rollback"),
+    "numerics.provenance": ("fit", None, (), "NaN provenance re-run"),
+    "sampler.generate": ("any", None, (), "one `generate_samples` call"),
+    "validation": ("any", None, (), "train.py's in-loop validation"),
+}.items()}
+
+
+def span(name: str, **attrs):
+    """`with span("serve.stack"):` a host span on the profiler's clock,
+    named `fdt.<name>`. Integer / string `attrs` ride as TraceMe stats
+    (`round=`, `bucket=`, `rows=`, `steps=`, `kind=`): `round` is the
+    join key to `RequestTracer`'s rows. Records only while a
+    `jax.profiler` session runs."""
+    return TraceAnnotation(SPAN_PREFIX + name, **attrs)
+
+
+def traced_steps(n: int, name: str = "fit.step"):
+    """`for i in traced_steps(n):` is `for i in range(n):` with each
+    turn inside a `jax.profiler.StepTraceAnnotation` named
+    `fdt.<name>` with `step_num=i + 1`, which xprof's step view keys
+    on. A `break` or an exception in the loop body closes the open
+    turn (the generator is closed with the loop)."""
+    for i in range(n):
+        with StepTraceAnnotation(SPAN_PREFIX + name, step_num=i + 1):
+            yield i
 
 
 class TraceRecorder:

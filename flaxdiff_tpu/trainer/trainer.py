@@ -28,6 +28,7 @@ from ..predictors import PredictionTransform
 from ..resilience import events as _res_events
 from ..resilience import faults as _res_faults
 from ..telemetry import global_telemetry as _global_telemetry
+from ..telemetry.tracing import traced_steps
 from ..schedulers.common import NoiseSchedule
 from ..typing import Policy, PyTree
 from .train_state import TrainState
@@ -1336,6 +1337,7 @@ class DiffusionTrainer:
                 # phases ride in the sampled step's window sums
                 tel.record_step(timer.last_row)
             busy = (phases.get("host", 0.0) + phases.get("device", 0.0)
+                    + phases.get("log_step", 0.0)
                     + phases.get("other", 0.0))
             if idx == 0 or compile_step:
                 goodput.record_badput("compile", busy)
@@ -1391,7 +1393,7 @@ class DiffusionTrainer:
             with goodput.measure_badput("data_stall"), \
                     tel.span("data.first_batch", cat="data"):
                 global_batch = next(upload)
-            for i in range(total_steps):
+            for i in traced_steps(total_steps):
                 if watchdog is not None:
                     watchdog.beat()
                 if stop["flag"]:
@@ -1533,213 +1535,223 @@ class DiffusionTrainer:
 
                 recovered = False
                 if log_step:
-                    # THE one mandatory host sync of the window: fetch
-                    # the device-resident loss window (blocks until the
-                    # newest step settles, so it also closes dispatch —
-                    # this step was marked sampled above and the wait
-                    # landed in the device phase already).
-                    inflight.clear()
-                    if ring_n:
-                        # one device_get of the in-graph ring covers the
-                        # whole window; the newest r steps wrote slots
-                        # (step_now - r) .. (step_now - 1) mod W
-                        ring_vals = _fetch_ring(self.state.loss_ring)
-                        step_now = int(jax.device_get(self.state.step))
-                        r = min(ring_pending[0], ring_n)
-                        vals = [float(ring_vals[(step_now - r + t) % ring_n])
+                    with timer.phase("log_step"):
+                        # THE one mandatory host sync of the window: fetch
+                        # the device-resident loss window (blocks until the
+                        # newest step settles, so it also closes dispatch —
+                        # this step was marked sampled above and the wait
+                        # landed in the device phase already).
+                        inflight.clear()
+                        if ring_n:
+                            # one device_get of the in-graph ring covers the
+                            # whole window; the newest r steps wrote slots
+                            # (step_now - r) .. (step_now - 1) mod W
+                            with tel.span("fit.loss_fetch", cat="train"):
+                                ring_vals = _fetch_ring(
+                                    self.state.loss_ring)
+                                step_now = int(
+                                    jax.device_get(self.state.step))
+                            r = min(ring_pending[0], ring_n)
+                            vals = [float(
+                                ring_vals[(step_now - r + t) % ring_n])
                                 for t in range(r)]
-                        ring_pending[0] = 0
-                    else:
-                        window = loss_window
-                        loss_window = []
-                        vals = _fetch_losses([v for _, v in window])
-                    if not vals:
-                        # an elastic transition (quorum rollback /
-                        # shrink restore) emptied the window mid-cadence:
-                        # every retained slot mapped to a rewound step.
-                        # Nothing to report; treat like a recovery so
-                        # the save guard below re-arms on fresh steps.
-                        steps_in_window = 0
-                        log_t0 = time.perf_counter()
-                        recovered = True
-                    if nan_pending and vals:
-                        vals[-1], nan_pending = float("nan"), False
-                    if gate_prev is not None \
-                            and self.state.gate_events is not None:
-                        # per-window delta of the in-graph gate counter
-                        # (the window fetch above already settled the
-                        # pipeline; this read costs no extra sync).
-                        # Clamped at 0: a rollback rewinds the
-                        # cumulative counter below the baseline.
-                        ge = _fetch_gate_events(self.state.gate_events)
-                        delta = np.maximum(ge - gate_prev, 0)
-                        gate_prev = ge
-                        if int(delta.sum()):
-                            tel.counter("numerics/gate_activations") \
-                                .inc(int(delta.sum()))
-                            for part, d in zip(
-                                    ("params", "opt_state", "ema"),
-                                    delta):
-                                if int(d):
-                                    tel.counter(
-                                        f"numerics/gate_activations/"
-                                        f"{part}").inc(int(d))
-                            events.record(
-                                "gate_activated", "train.step",
-                                detail=f"in-graph non-finite gate "
-                                       f"masked {int(delta.sum())} "
-                                       f"element(s) this window "
-                                       f"(params/opt/ema = "
-                                       f"{delta.tolist()})",
-                                step=i + 1)
-                    # Mid-window non-finite losses are VISIBILITY, not a
-                    # verdict: with the in-graph gate a poisoned batch's
-                    # update never landed, so a finite cadence loss
-                    # means the state recovered on its own (the
-                    # skip_step contract) — recovery stays keyed to the
-                    # cadence-step loss exactly as before, but the
-                    # window now shows transients the old single-value
-                    # fetch could never see.
-                    n_bad = sum(1 for v in vals[:-1]
-                                if not np.isfinite(v))
-                    if n_bad:
-                        gated = ("; update(s) withheld in-graph"
-                                 if cfg.gate_nonfinite else "")
-                        events.record(
-                            "window_nonfinite", "train.step",
-                            detail=f"{n_bad} non-finite loss(es) inside "
-                                   f"the window ending at step "
-                                   f"{i + 1}{gated}",
-                            step=i + 1)
-                    # ONE code path for fault-injected and real NaNs:
-                    # the detector's hard triggers subsume the old
-                    # `isfinite or <= floor` ad-hoc check
-                    loss = vals[-1] if vals else float("nan")
-                    anomaly = (None if recovered
-                               else detector.abnormal_loss(loss,
-                                                           step=i + 1))
-                    if not recovered and elastic is not None \
-                            and cfg.anomaly_action == "rollback" \
-                            and cfg.numerics_cadence == 0:
-                        # numerics_cadence=0 quorum hole, closed: with
-                        # no cadence step the hard verdict surfaces
-                        # HERE, and a unilateral local rollback would
-                        # silently fork the pod. Every member reaches
-                        # every log step in lockstep, so the vote is
-                        # collective by construction — healthy members
-                        # vote False each window, the anomalous one
-                        # votes True, and the pod decides together
-                        # (rollback_all restores + clears the window
-                        # inside _elastic_quorum). A failed round never
-                        # falls back to the unilateral path: that is
-                        # the fork this guard exists to prevent.
-                        with timer.phase("elastic"):
-                            verdict = _elastic_quorum(
-                                anomaly is not None, i + 1)
-                        if anomaly is not None \
-                                or verdict in ("rollback_all", "evicted"):
+                            ring_pending[0] = 0
+                        else:
+                            window = loss_window
+                            loss_window = []
+                            with tel.span("fit.loss_fetch", cat="train"):
+                                vals = _fetch_losses(
+                                    [v for _, v in window])
+                        if not vals:
+                            # an elastic transition (quorum rollback /
+                            # shrink restore) emptied the window mid-cadence:
+                            # every retained slot mapped to a rewound step.
+                            # Nothing to report; treat like a recovery so
+                            # the save guard below re-arms on fresh steps.
                             steps_in_window = 0
                             log_t0 = time.perf_counter()
                             recovered = True
-                    if recovered:
-                        pass    # transition emptied the window above
-                    elif anomaly is not None:
-                        landed = self._recover(loss, step=i + 1)
-                        _rewind_data(landed)
-                        steps_in_window = 0
-                        log_t0 = time.perf_counter()
-                        recovered = True
-                    else:
-                        losses.append(loss)
-                        dt = time.perf_counter() - log_t0
-                        # global batch size: `current` holds global
-                        # sharded arrays, so the leading dim IS the
-                        # global batch (no process_count multiply)
-                        bsz = jax.tree_util.tree_leaves(
-                            current)[0].shape[0]
-                        ips = steps_in_window * bsz / max(dt, 1e-9)
-                        if flops is None and peak:
-                            flops = self.step_flops(global_batch)
-                        step_mfu = (mfu(flops, dt / steps_in_window, peak)
-                                    if flops else None)
-                        if tel.programs is not None:
-                            # program evidence registry: one row per
-                            # compiled step program, at the first log
-                            # window (plus the monitored twin once it
-                            # has compiled) — per-program roofline
-                            # attribution beside the global mfu gauges
-                            self._register_program_evidence(
-                                tel, global_batch, registered_programs,
-                                (compile_busies[0] if compile_busies
-                                 else None),
-                                monitored_compiled, flops)
-                        window_steps = steps_in_window
-                        steps_in_window = 0
-                        history["steps"].append(i + 1)
-                        history["loss"].append(loss)
-                        history["imgs_per_sec"].append(ips)
-                        history["mfu"].append(step_mfu)
-                        metrics = {"imgs_per_sec": ips}
-                        finite = [v for v in vals if np.isfinite(v)]
-                        if finite:
-                            # the window fetch makes every step's loss
-                            # visible at no extra sync: report the
-                            # window mean beside the spot value
-                            metrics["loss_window_mean"] = \
-                                float(np.mean(finite))
-                        if ring_n and len(vals) <= 64:
-                            # retroactive per-step visibility: the
-                            # JsonlLogger serializes small numeric seqs,
-                            # so log_every=1 users still get every
-                            # step's loss — delivered once per window
-                            metrics["window_losses"] = list(vals)
-                        if step_mfu is not None:
-                            metrics["mfu"] = step_mfu
-                        if timed and flops and device_meter.steps:
-                            # utilization against DEVICE time (phase-
-                            # timed), not end-to-end step time: the gap
-                            # between the two numbers IS the host/input
-                            # overhead the phase breakdown localizes
-                            device_meter.flops_per_step = flops
-                            mfu_dev = device_meter.mfu()
-                            if mfu_dev is not None:
-                                metrics["mfu_device"] = mfu_dev
-                        # resilience counters ride the normal metric
-                        # stream (JSONL/wandb via the callback's logger)
-                        metrics.update(events.summary())
-                        for cb in callbacks:
-                            cb(i + 1, loss, metrics)
-                        if cfg.keep_best_state and loss < self.best_loss:
-                            self.best_loss = loss
-                            self.best_state = jax.tree_util.tree_map(
-                                jnp.copy, self.state)
-                            self.best_step = i + 1
-                        if timed:
-                            tel.gauge("train/loss").set(loss)
-                            tel.gauge("train/imgs_per_sec").set(ips)
-                            # HBM gauges ride the log cadence even when
-                            # the numerics monitor is off (host-only
-                            # allocator read; self-disables off-TPU)
-                            memory.record(tel.registry)
-                            # pod-wide skew: every host contributes its
-                            # window means; rank 0 logs min/max/p50/p99.
-                            # A collective — all hosts hit log cadence
-                            # in lockstep (same SPMD-driver assumption
-                            # as the commit rounds).
-                            agg = {"step_time": dt / max(window_steps, 1),
-                                   "imgs_per_sec": ips, "loss": loss}
-                            if last_health["grad_norm"] is not None:
-                                # pod/grad_norm/spread: divergence skew —
-                                # one host drifting shows before it NaNs
-                                agg["grad_norm"] = last_health["grad_norm"]
-                            if timer.last is not None:
-                                agg["data_wait"] = timer.last.get(
-                                    "data_wait", 0.0)
-                                agg["device_time"] = timer.last.get(
-                                    "device", 0.0)
-                            tel.aggregate(agg, step=i + 1)
-                            tel.export(step=i + 1)
-                        log_t0 = time.perf_counter()
+                        if nan_pending and vals:
+                            vals[-1], nan_pending = float("nan"), False
+                        if gate_prev is not None \
+                                and self.state.gate_events is not None:
+                            # per-window delta of the in-graph gate counter
+                            # (the window fetch above already settled the
+                            # pipeline; this read costs no extra sync).
+                            # Clamped at 0: a rollback rewinds the
+                            # cumulative counter below the baseline.
+                            ge = _fetch_gate_events(self.state.gate_events)
+                            delta = np.maximum(ge - gate_prev, 0)
+                            gate_prev = ge
+                            if int(delta.sum()):
+                                tel.counter("numerics/gate_activations") \
+                                    .inc(int(delta.sum()))
+                                for part, d in zip(
+                                        ("params", "opt_state", "ema"),
+                                        delta):
+                                    if int(d):
+                                        tel.counter(
+                                            f"numerics/gate_activations/"
+                                            f"{part}").inc(int(d))
+                                events.record(
+                                    "gate_activated", "train.step",
+                                    detail=f"in-graph non-finite gate "
+                                           f"masked {int(delta.sum())} "
+                                           f"element(s) this window "
+                                           f"(params/opt/ema = "
+                                           f"{delta.tolist()})",
+                                    step=i + 1)
+                        # Mid-window non-finite losses are VISIBILITY, not a
+                        # verdict: with the in-graph gate a poisoned batch's
+                        # update never landed, so a finite cadence loss
+                        # means the state recovered on its own (the
+                        # skip_step contract) — recovery stays keyed to the
+                        # cadence-step loss exactly as before, but the
+                        # window now shows transients the old single-value
+                        # fetch could never see.
+                        n_bad = sum(1 for v in vals[:-1]
+                                    if not np.isfinite(v))
+                        if n_bad:
+                            gated = ("; update(s) withheld in-graph"
+                                     if cfg.gate_nonfinite else "")
+                            events.record(
+                                "window_nonfinite", "train.step",
+                                detail=f"{n_bad} non-finite loss(es) inside "
+                                       f"the window ending at step "
+                                       f"{i + 1}{gated}",
+                                step=i + 1)
+                        # ONE code path for fault-injected and real NaNs:
+                        # the detector's hard triggers subsume the old
+                        # `isfinite or <= floor` ad-hoc check
+                        loss = vals[-1] if vals else float("nan")
+                        anomaly = (None if recovered
+                                   else detector.abnormal_loss(loss,
+                                                               step=i + 1))
+                        if not recovered and elastic is not None \
+                                and cfg.anomaly_action == "rollback" \
+                                and cfg.numerics_cadence == 0:
+                            # numerics_cadence=0 quorum hole, closed: with
+                            # no cadence step the hard verdict surfaces
+                            # HERE, and a unilateral local rollback would
+                            # silently fork the pod. Every member reaches
+                            # every log step in lockstep, so the vote is
+                            # collective by construction — healthy members
+                            # vote False each window, the anomalous one
+                            # votes True, and the pod decides together
+                            # (rollback_all restores + clears the window
+                            # inside _elastic_quorum). A failed round never
+                            # falls back to the unilateral path: that is
+                            # the fork this guard exists to prevent.
+                            with timer.phase("elastic"):
+                                verdict = _elastic_quorum(
+                                    anomaly is not None, i + 1)
+                            if anomaly is not None \
+                                    or verdict in ("rollback_all", "evicted"):
+                                steps_in_window = 0
+                                log_t0 = time.perf_counter()
+                                recovered = True
+                        if recovered:
+                            pass    # transition emptied the window above
+                        elif anomaly is not None:
+                            landed = self._recover(loss, step=i + 1)
+                            _rewind_data(landed)
+                            steps_in_window = 0
+                            log_t0 = time.perf_counter()
+                            recovered = True
+                        else:
+                            losses.append(loss)
+                            dt = time.perf_counter() - log_t0
+                            # global batch size: `current` holds global
+                            # sharded arrays, so the leading dim IS the
+                            # global batch (no process_count multiply)
+                            bsz = jax.tree_util.tree_leaves(
+                                current)[0].shape[0]
+                            ips = steps_in_window * bsz / max(dt, 1e-9)
+                            if flops is None and peak:
+                                flops = self.step_flops(global_batch)
+                            step_mfu = (mfu(flops, dt / steps_in_window, peak)
+                                        if flops else None)
+                            if tel.programs is not None:
+                                # program evidence registry: one row per
+                                # compiled step program, at the first log
+                                # window (plus the monitored twin once it
+                                # has compiled) — per-program roofline
+                                # attribution beside the global mfu gauges
+                                self._register_program_evidence(
+                                    tel, global_batch, registered_programs,
+                                    (compile_busies[0] if compile_busies
+                                     else None),
+                                    monitored_compiled, flops)
+                            window_steps = steps_in_window
+                            steps_in_window = 0
+                            history["steps"].append(i + 1)
+                            history["loss"].append(loss)
+                            history["imgs_per_sec"].append(ips)
+                            history["mfu"].append(step_mfu)
+                            metrics = {"imgs_per_sec": ips}
+                            finite = [v for v in vals if np.isfinite(v)]
+                            if finite:
+                                # the window fetch makes every step's loss
+                                # visible at no extra sync: report the
+                                # window mean beside the spot value
+                                metrics["loss_window_mean"] = \
+                                    float(np.mean(finite))
+                            if ring_n and len(vals) <= 64:
+                                # retroactive per-step visibility: the
+                                # JsonlLogger serializes small numeric seqs,
+                                # so log_every=1 users still get every
+                                # step's loss — delivered once per window
+                                metrics["window_losses"] = list(vals)
+                            if step_mfu is not None:
+                                metrics["mfu"] = step_mfu
+                            if timed and flops and device_meter.steps:
+                                # utilization against DEVICE time (phase-
+                                # timed), not end-to-end step time: the gap
+                                # between the two numbers IS the host/input
+                                # overhead the phase breakdown localizes
+                                device_meter.flops_per_step = flops
+                                mfu_dev = device_meter.mfu()
+                                if mfu_dev is not None:
+                                    metrics["mfu_device"] = mfu_dev
+                            # resilience counters ride the normal metric
+                            # stream (JSONL/wandb via the callback's logger)
+                            metrics.update(events.summary())
+                            for cb in callbacks:
+                                cb(i + 1, loss, metrics)
+                            if cfg.keep_best_state and loss < self.best_loss:
+                                self.best_loss = loss
+                                with tel.span("fit.best_state_copy",
+                                              cat="train"):
+                                    self.best_state = \
+                                        jax.tree_util.tree_map(
+                                            jnp.copy, self.state)
+                                self.best_step = i + 1
+                            if timed:
+                                tel.gauge("train/loss").set(loss)
+                                tel.gauge("train/imgs_per_sec").set(ips)
+                                # HBM gauges ride the log cadence even when
+                                # the numerics monitor is off (host-only
+                                # allocator read; self-disables off-TPU)
+                                memory.record(tel.registry)
+                                # pod-wide skew: every host contributes its
+                                # window means; rank 0 logs min/max/p50/p99.
+                                # A collective — all hosts hit log cadence
+                                # in lockstep (same SPMD-driver assumption
+                                # as the commit rounds).
+                                agg = {"step_time": dt / max(window_steps, 1),
+                                       "imgs_per_sec": ips, "loss": loss}
+                                if last_health["grad_norm"] is not None:
+                                    # pod/grad_norm/spread: divergence skew —
+                                    # one host drifting shows before it NaNs
+                                    agg["grad_norm"] = last_health["grad_norm"]
+                                if timer.last is not None:
+                                    agg["data_wait"] = timer.last.get(
+                                        "data_wait", 0.0)
+                                    agg["device_time"] = timer.last.get(
+                                        "device", 0.0)
+                                tel.aggregate(agg, step=i + 1)
+                                tel.export(step=i + 1)
+                            log_t0 = time.perf_counter()
 
                 if not recovered and save_every and (i + 1) % save_every == 0:
                     # "Never checkpoint a NaN" (VERDICT r1 weak #4),

@@ -144,7 +144,11 @@ def test_wedged_loader_trips_watchdog(mesh, tmp_path, rng):
     def stalling_data():
         src = _data(rng)
         for i, batch in enumerate(src):
-            if i == 2:
+            # past the upload worker's prefetch depth: the loop has
+            # consumed batches (so step 1's compile, during which the
+            # watchdog is paused, is over) before the wedge is reached;
+            # at i == 2 a slow compile on a loaded machine hid it
+            if i == 8:
                 time.sleep(3.0)         # wedge >> watchdog timeout
             yield batch
 
