@@ -176,8 +176,9 @@ def _sampler_pieces(sampler_name: str, cached: bool = False,
 def chunk_program_jaxpr(sampler_name: str, rows: int = 2,
                         round_steps: int = 2):
     """The serving layer's continuous-batching round program
-    (`DiffusionSampler.make_chunk_program`) with the exact input
-    layout `SamplerProgramEngine.advance` feeds it."""
+    (`DiffusionSampler.make_chunk_program`) with the stacked input
+    layout `SamplerProgramEngine.advance`'s round program builds from
+    the rows' carries."""
     ds, params = _sampler_pieces(sampler_name)
     prog = ds.make_chunk_program(round_steps)
     x = jnp.zeros((rows, 1, 8, 8, 1), jnp.float32)
@@ -223,9 +224,9 @@ def solo_program_jaxpr(sampler_name: str = "ddim", steps: int = 4,
 def cached_chunk_program_jaxpr(sampler_name: str = "ddim",
                                rows: int = 2, round_steps: int = 2):
     """The serving layer's cached continuous-batching round
-    (`make_cached_chunk_program`) with the exact input layout
-    `SamplerProgramEngine.advance` feeds it on the cached path:
-    round-level refresh flags + per-row taps carries."""
+    (`make_cached_chunk_program`) with the stacked input layout
+    `SamplerProgramEngine.advance`'s round program builds on the
+    cached path: round-level refresh flags + per-row taps carries."""
     ds, params = _sampler_pieces(sampler_name, cached=True)
     prog = ds.make_cached_chunk_program(round_steps)
     x = jnp.zeros((rows, 1, 8, 8, 1), jnp.float32)
@@ -246,10 +247,10 @@ def cached_chunk_program_jaxpr(sampler_name: str = "ddim",
 def spatial_chunk_program_jaxpr(sampler_name: str = "ddim",
                                 rows: int = 2, round_steps: int = 2):
     """The serving layer's composed spatially-cached round
-    (`make_spatial_chunk_program`) with the exact input layout
-    `SamplerProgramEngine.advance` feeds it on the composed path:
-    round-level step codes + per-row taps AND score-reference
-    carries."""
+    (`make_spatial_chunk_program`) with the stacked input layout
+    `SamplerProgramEngine.advance`'s round program builds on the
+    composed path: round-level step codes + per-row taps AND
+    score-reference carries."""
     ds, params = _sampler_pieces(sampler_name, spatial=True)
     prog = ds.make_spatial_chunk_program(round_steps)
     x = jnp.zeros((rows, 1, 8, 8, 1), jnp.float32)

@@ -133,7 +133,17 @@ class DiffusionSampler:
                  timestep_spacing: str = "linear",
                  cache_plan: Optional[Any] = None,
                  cache_fns: Optional[Tuple[Callable, Callable]] = None):
-        self.model_fn = model_fn
+        # ONE trace of the network for every program of this sampler:
+        # the solo scan and each serving bucket's round and terminal
+        # programs call it with the same per-row shapes (the batch axis
+        # of requests is a vmap outside it), so jit's trace cache hands
+        # every later program the first one's jaxpr. Tracing the network
+        # is most of what a program costs before its first launch
+        # (PERF.md, PR 25); XLA inlines the call.
+        def sampler_model(params, x, t, cond):
+            return model_fn(params, x, t, cond)
+
+        self.model_fn = jax.jit(sampler_model)
         self.schedule = schedule
         self.transform = transform
         self.sampler = sampler
@@ -606,7 +616,11 @@ class DiffusionSampler:
             mask = jnp.broadcast_to(mask, known.shape).astype(jnp.float32)
 
         if init_samples is None:
-            x = jax.random.normal(noise_key, shape) * self.schedule.max_noise_std()
+            noise = self._compiled.get(("noise", tuple(shape)))
+            if noise is None:
+                noise = self.make_noise_program(tuple(shape))
+                self._compiled[("noise", tuple(shape))] = noise
+            x = noise(noise_key)
         else:
             x = init_samples
 
@@ -627,9 +641,13 @@ class DiffusionSampler:
 
     # -- serving programs ----------------------------------------------------
     # Builders for the serving layer's continuous-batching rounds
-    # (flaxdiff_tpu/serving/engine.py). Both are UNCACHED — the serving
+    # (flaxdiff_tpu/serving/engine.py). All are UNCACHED — the serving
     # engine owns the compiled-program cache and its hit/miss counters;
-    # a second cache here would hide misses from the SLO metrics.
+    # a second cache here would hide misses from the SLO metrics. The
+    # engine launches the round and terminal programs with the rows'
+    # carries as a tuple and stacks them INSIDE the compiled program
+    # (engine.py `_round_program`); the layouts below are the stacked
+    # ones.
     #
     # Row model: the batch axis is REQUESTS, each row a block of
     # `block_shape` samples (the request's own num_samples). Everything
@@ -640,6 +658,52 @@ class DiffusionSampler:
     # `normal(key, x.shape)` per row with the row's own key, the same
     # call a solo `generate_samples` makes, so a batched request is
     # bit-identical to its solo run (tested in tests/test_serving.py).
+
+    def make_noise_program(self, shape: Tuple[int, ...]):
+        """A trajectory's starting noise, `normal(key, shape) *
+        max_noise_std`, as a program of its own: program(key) -> x.
+        `generate_samples` and the serving engine both start from THIS
+        program's output, because the same two operations fused into a
+        larger program round differently in the last bit (tested), and
+        batched-equals-solo holds to the last bit. `max_noise_std` is
+        read once, here, and closed over."""
+        std = self.schedule.max_noise_std()
+
+        def sampler_noise(key):
+            return jax.random.normal(key, shape) * std
+
+        return jax.jit(sampler_noise)
+
+    def make_init_program(self, shape: Tuple[int, ...], params=None,
+                          uncond=None):
+        """Everything of the carry a trajectory starts from but its
+        noise, as ONE program of the seed: the two key splits
+        `generate_samples` makes (integer arithmetic: the same bits in
+        any program), the sampler state and (with a cache plan) the
+        zero cache carries. `params` and `uncond` give the cache
+        carries their shapes and nothing else.
+
+        program(seed, cond) -> (noise_key, loop_key, state, cond, taps,
+                                ref)
+          seed   `np.int64(request seed)`: what `PRNGKey` makes of a
+                 Python int, wrap-around past 32 bits included
+          cond   the request's conditioning, host or device: it comes
+                 back as a device array (its upload rides this launch)
+        """
+        def sampler_init(seed, cond):
+            rngstate = RngSeq.create(jax.random.PRNGKey(seed))
+            rngstate, noise_key = rngstate.next_key()
+            rngstate, loop_key = rngstate.next_key()
+            x = jnp.zeros(shape)            # its shape is all that counts
+            taps = ref = None
+            if self.spatial_active:
+                taps, ref = self.cache_carry_init(params, x, cond, uncond)
+            elif self.cache_active:
+                taps = self.cache_taps_init(params, x, cond, uncond)
+            return (noise_key, loop_key, self.sampler.init_state(x), cond,
+                    taps, ref)
+
+        return jax.jit(sampler_init)
 
     def make_chunk_program(self, round_steps: int):
         """One continuous-batching round: advance every row by up to

@@ -13,10 +13,25 @@ round length in continuous mode; either way NFE-heterogeneous rows
 share one program because each row's timestep pairs and live-step
 count are *inputs*, not trace constants. Cache hits/misses are counted
 at `serving/program_cache_hits` / `serving/program_cache_misses`.
-Program kinds: "chunk" (uncached), "chunk_cached" (timestep diffusion
-cache), "chunk_spatial" (composed timestep x spatial cache,
+Program kinds: "init" and "noise" (a request's starting carry from its
+seed), "chunk" (uncached), "chunk_cached" (timestep diffusion cache),
+"chunk_spatial" (composed timestep x spatial cache,
 ops/spatialcache.py), "terminal". `prewarm` compiles the hot tuples
 before admission opens.
+
+**A turn of the dispatch thread is a handful of launches and no
+read-back** (docs/SERVING.md "Run-ahead"): the runtime queues only so
+many launches behind a running program and then blocks the caller, and
+a device-to-host read waits for everything queued before it, so either
+one would stop the thread from preparing the next round under the
+running one. `prepare` is two launches (the "init" and "noise"
+programs); what depends only on (sampler, NFE, schedule) — the trajectory's step pairs
+and terminal step, as HOST values — is computed once and kept here. A
+round is one launch: its pairs / live-step counts / offsets are built
+in numpy, and the rows' carries go in as a tuple and come back as a
+tuple, stacked and unstacked INSIDE the compiled program. `finalize`
+is one launch (stack, terminal denoise, decode, clip). Every launch
+goes through `_launch`, counted at `serving/launches`.
 
 Batching model (see `DiffusionSampler.make_chunk_program`): the batch
 axis is requests, each row an independent block of the request's
@@ -33,8 +48,9 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from ..utils import RngSeq, clip_images
+from ..utils import clip_images
 from .request import SampleRequest, ServingFuture
 
 # batch buckets the scheduler pads micro-batches up to; the largest is
@@ -60,6 +76,47 @@ def nfe_bucket(n: int) -> int:
     return b
 
 
+def _stacked(rows):
+    """Per-row pytrees -> one pytree of `[R, ...]` leaves (traced: part
+    of the program that calls it)."""
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *rows)
+
+
+def _round_program(program):
+    """A chunk program (`DiffusionSampler.make_*chunk_program`: stacked
+    carries in and out) as the engine launches it:
+
+        run(params, rows, batch) -> one tuple of carries per row
+
+    `rows` holds a dict of the program's per-row arguments by name for
+    every slot of the bucket, `batch` the per-round ones (numpy). Stack,
+    scan and unstack are ONE launch; the compiled module keeps the
+    chunk program's name."""
+    def run(params, rows, batch):
+        outs = program(params, **_stacked(rows), **batch)
+        return tuple(jax.tree_util.tree_map(lambda a: a[i], outs)
+                     for i in range(len(rows)))
+
+    run.__name__ = program.__name__
+    return jax.jit(run)
+
+
+def _terminal_program(program, autoencoder):
+    """`make_terminal_program` as the engine launches it: stack, the
+    terminal denoise, decode and clip in one launch. Returns the whole
+    bucket, `[bucket, num_samples, *sample_shape]` (a cut to the real
+    rows would be a program per row count)."""
+    def run(params, rows, batch):
+        x0 = program(params, **_stacked(rows), **batch)
+        if autoencoder is not None:
+            flat = autoencoder.decode(x0.reshape((-1,) + x0.shape[2:]))
+            x0 = flat.reshape(x0.shape[:2] + flat.shape[1:])
+        return clip_images(x0)
+
+    run.__name__ = program.__name__
+    return jax.jit(run)
+
+
 class RequestState:
     """One admitted request's device-resident trajectory carry."""
 
@@ -82,8 +139,8 @@ class RequestState:
         self.x = x                  # [num_samples, *sample_shape]
         self.rng = rng              # scan RNG carry (loop key lineage)
         self.state = state          # sampler state pytree
-        self.pairs = pairs          # [nfe, 2] full trajectory pairs
-        self.terminal_t = terminal_t
+        self.pairs = pairs          # [nfe, 2] trajectory pairs (numpy)
+        self.terminal_t = terminal_t    # terminal step (Python float)
         self.nfe = int(req.diffusion_steps)
         self.done = 0               # completed trajectory steps
         self.cond = cond
@@ -129,6 +186,11 @@ class SamplerProgramEngine:
             telemetry = global_telemetry()
         self.telemetry = telemetry
         self._programs: Dict[tuple, Any] = {}
+        # constants of (sampler, NFE, schedule) and of num_samples,
+        # computed once: ([nfe, 2] pairs, terminal step) as host values,
+        # and the null context tiled to a request's num_samples
+        self._trajectories: Dict[tuple, Tuple[np.ndarray, float]] = {}
+        self._null_contexts: Dict[int, Any] = {}
         # last dispatched round's provenance (program kind/key, bucket,
         # live steps, cache-plan codes) — written by advance() on the
         # single dispatch thread, read by the
@@ -226,77 +288,115 @@ class SamplerProgramEngine:
         return (self.pipeline.ema_params
                 if use_ema else self.pipeline.params)
 
+    def _launch(self, program, *args):
+        """Every device launch of the dispatch thread goes through
+        here, counted at `serving/launches`: over `serving/rounds` it is
+        the number that has to stay under what the runtime queues
+        behind a running program (module docstring)."""
+        self.telemetry.counter("serving/launches").inc()
+        return program(*args)
+
+    def _trajectory(self, ds, nfe: int) -> Tuple[np.ndarray, float]:
+        """(`[nfe, 2]` step pairs, terminal step) as HOST values:
+        `ds.trajectory_inputs` computes them, the same spacing the solo
+        program closes over, and they are read back ONCE per (sampler,
+        NFE) — at warm-up, or at the first sight of an NFE."""
+        traj = self._trajectories.get((ds, nfe))
+        if traj is None:
+            from .scheduler import _device_get
+            pairs, terminal_t = ds.trajectory_inputs(nfe)
+            traj = (_device_get(pairs), float(_device_get(terminal_t)))
+            self._trajectories[(ds, nfe)] = traj
+        return traj
+
+    def _null_context(self, k: int):
+        """The cached null tokens at `num_samples` k, exactly as
+        `generate_samples` feeds them."""
+        if k not in self._null_contexts:
+            self._null_contexts[k] = \
+                self.pipeline.input_config.get_unconditionals(
+                    batch_size=k)[0]
+        return self._null_contexts[k]
+
     def prepare(self, req: SampleRequest, future: ServingFuture,
                 submit_t: float, admit_t: float) -> RequestState:
         """Build the device-resident carry for one request — the exact
         state a solo `generate_samples` call reaches right before its
-        scan, so the batched trajectory continues bit-identically."""
+        scan, so the batched trajectory continues bit-identically. Two
+        launches (the group's "init" program: keys, sampler state, zero
+        cache carries, the conditioning's upload; and its "noise"
+        program) and no read-back."""
         pipe = self.pipeline
         k = req.num_samples
+        conditional = bool(pipe.input_config is not None
+                           and pipe.input_config.conditions)
         cond = uncond = None
         if req.conditioning is not None:
-            cond = jnp.asarray(req.conditioning)
-            if pipe.input_config is not None and pipe.input_config.conditions:
-                uncond = pipe.input_config.get_unconditionals(
-                    batch_size=k)[0]
+            cond = req.conditioning
+            if conditional:
+                uncond = self._null_context(k)
         elif req.prompts is not None:
-            if pipe.input_config is None or not pipe.input_config.conditions:
+            if not conditional:
                 raise ValueError("pipeline has no conditioning inputs")
             c = pipe.input_config.conditions[0]
-            cond = jnp.asarray(c.encoder(list(req.prompts)))
-            uncond = pipe.input_config.get_unconditionals(batch_size=k)[0]
-        elif pipe.input_config is not None and pipe.input_config.conditions:
+            cond = c.encoder(list(req.prompts))
+            uncond = self._null_context(k)
+        elif conditional:
             # prompt-less conditional checkpoint: the cached null
             # tokens, exactly as generate_samples feeds them
-            cond = pipe.input_config.get_unconditionals(batch_size=k)[0]
+            cond = self._null_context(k)
 
         ds = self._sampler_for(req)
-        rngstate = RngSeq.create(req.seed)
-        rngstate, noise_key = rngstate.next_key()
-        rngstate, loop_key = rngstate.next_key()
+        group = self.group_key(req)
+        nfe = int(req.diffusion_steps)
 
-        resolution, channels = int(req.resolution), int(req.channels)
-        if ds.autoencoder is not None:
-            resolution = resolution // ds.autoencoder.downscale_factor
-            channels = ds.autoencoder.latent_channels
-        if req.sequence_length is not None:
-            shape = (k, req.sequence_length, resolution, resolution,
-                     channels)
-        else:
-            shape = (k, resolution, resolution, channels)
+        def shape():
+            resolution, channels = int(req.resolution), int(req.channels)
+            if ds.autoencoder is not None:
+                resolution //= ds.autoencoder.downscale_factor
+                channels = ds.autoencoder.latent_channels
+            if req.sequence_length is not None:
+                return (k, req.sequence_length, resolution, resolution,
+                        channels)
+            return (k, resolution, resolution, channels)
 
-        x = jax.random.normal(noise_key, shape) * ds.schedule.max_noise_std()
-        pairs, terminal_t = ds.trajectory_inputs(int(req.diffusion_steps))
-        state = ds.sampler.init_state(x)
-        plan = self._plan_for(req)
-        flags = taps = codes = ref = None
-        if plan is not None and ds.spatial_active:
-            # composed plan: host-side numpy code row + zero carries
-            # for BOTH the residual delta and the score reference
-            # (step 0 always refreshes, so the zeros are never
-            # consumed)
-            codes = plan.step_codes(int(req.diffusion_steps))
-            taps, ref = ds.cache_carry_init(self._params_for_req(req),
-                                            x, cond, uncond)
-        elif plan is not None:
-            # host-side numpy schedule (zero device work) + a zero taps
-            # carry shaped by eval_shape; step 0 of the plan always
-            # refreshes, so the zeros are never consumed
-            flags = plan.flags(int(req.diffusion_steps))
-            taps = ds.cache_taps_init(self._params_for_req(req), x,
-                                      cond, uncond)
-        return RequestState(
+        t0 = time.perf_counter()
+        program, miss = self._get_program(
+            "init", group, 0, 0, lambda: ds.make_init_program(
+                shape(),
+                self._params_for(group) if ds.cache_active else None,
+                uncond))
+        args = (np.int64(req.seed), cond)
+        noise_key, loop_key, state, cond, taps, ref = \
+            self._launch(program, *args)
+        # the noise is a program of its own, the one the solo path
+        # starts from (`make_noise_program`)
+        noise, _ = self._get_program(
+            "noise", group, 0, 0, lambda: ds.make_noise_program(shape()))
+        x = self._launch(noise, noise_key)
+        pairs, terminal_t = self._trajectory(ds, nfe)
+        # host-side numpy schedules of a cache plan (zero device work);
+        # step 0 of every plan refreshes, so the zero carries the init
+        # program made are never consumed
+        plan = ds.cache_plan if ds.cache_active else None
+        flags = codes = None
+        if ref is not None:
+            codes = plan.step_codes(nfe)
+        elif taps is not None:
+            flags = plan.flags(nfe)
+        st = RequestState(
             req=req, future=future, submit_t=submit_t, admit_t=admit_t,
-            group=self.group_key(req), x=x, rng=loop_key, state=state,
-            pairs=pairs, terminal_t=float(terminal_t), cond=cond,
-            uncond=uncond, plan=plan, flags=flags, taps=taps,
-            codes=codes, ref=ref)
-
-    def _params_for_req(self, req: SampleRequest):
-        use_ema = bool(req.use_ema
-                       and self.pipeline.ema_params is not None)
-        return (self.pipeline.ema_params
-                if use_ema else self.pipeline.params)
+            group=group, x=x, rng=loop_key, state=state, pairs=pairs,
+            terminal_t=terminal_t, cond=cond, uncond=uncond, plan=plan,
+            flags=flags, taps=taps, codes=codes, ref=ref)
+        if miss:            # both: they share the group's key
+            compile_s = time.perf_counter() - t0
+            st.compile_ms = compile_s * 1e3
+            self._register_evidence("init", group, 0, 0, program, args,
+                                    compile_s)
+            self._register_evidence("noise", group, 0, 0, noise,
+                                    (noise_key,), compile_s)
+        return st
 
     # -- batched rounds -------------------------------------------------------
     def _span(self, name: str, **args):
@@ -304,117 +404,92 @@ class SamplerProgramEngine:
         `serve.launch`, `serve.unstack`): per phase, never per row."""
         return self.telemetry.span(name, cat="serving", args=args)
 
-    def _stack_rows(self, rows: List[RequestState], bucket: int):
-        """Stack per-row carries, replicating row 0 into padding slots
-        (inert: n_act = 0 keeps their carry unchanged, and their output
-        is discarded)."""
-        pad = bucket - len(rows)
-        srcs = rows + [rows[0]] * pad
-
-        def stack(get):
-            return jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *[get(r) for r in srcs])
-
-        x = stack(lambda r: r.x)
-        keys = stack(lambda r: r.rng)
-        state = stack(lambda r: r.state)
-        group = rows[0].group
-        cond = stack(lambda r: r.cond) if group[7] else None
-        uncond = stack(lambda r: r.uncond) if group[8] else None
-        taps = (stack(lambda r: r.taps)
-                if rows[0].plan is not None else None)
-        refs = (stack(lambda r: r.ref)
-                if rows[0].ref is not None else None)
-        return x, keys, state, cond, uncond, taps, refs
-
     def advance(self, rows: List[RequestState], bucket: int,
                 round_steps: int) -> Tuple[List[RequestState], float]:
         """Run one round: every row advances min(remaining, round_steps)
         steps of its own trajectory. Returns (rows that completed their
         trajectory this round, compile seconds spent — 0 on a cache
-        hit)."""
+        hit). One launch; `serve.stack` is host arithmetic, and
+        `serve.unstack` hands each row its own outputs of the program."""
         group = rows[0].group
         ds = self._sampler_for(rows[0].req)
         plan = rows[0].plan             # group-uniform (plan is in the key)
         span = self._span
         sched_row = None        # cache-plan step codes this round ran
         with span("serve.stack"):
-            x, keys, state, cond, uncond, taps, refs = \
-                self._stack_rows(rows, bucket)
-
-            pad = bucket - len(rows)
-            chunk_pairs, n_act, offsets = [], [], []
-            for r in rows + [rows[0]] * pad:
-                live = max(0, min(r.remaining, round_steps))
+            # padding slots replicate row 0 (their output is discarded)
+            srcs = rows + [rows[0]] * (bucket - len(rows))
+            pairs = np.empty((bucket, round_steps, 2), np.float32)
+            n_act = np.empty((bucket,), np.int32)
+            offsets = np.empty((bucket,), np.int32)
+            for i, r in enumerate(srcs):
                 sl = r.pairs[r.done:r.done + round_steps]
-                if sl.shape[0] == 0:        # exhausted padding row
-                    sl = jnp.broadcast_to(r.pairs[-1:], (round_steps, 2))
-                elif sl.shape[0] < round_steps:
-                    sl = jnp.concatenate(
-                        [sl, jnp.broadcast_to(
-                            sl[-1:], (round_steps - sl.shape[0], 2))],
-                        axis=0)
-                chunk_pairs.append(sl)
-                n_act.append(live)
-                offsets.append(r.done)
-            prog_args = (self._params_for(group), x, keys,
-                         jnp.stack(chunk_pairs),
-                         jnp.asarray(n_act, jnp.int32),
-                         jnp.asarray(offsets, jnp.int32),
-                         cond, uncond, state)
+                if len(sl) == 0:            # exhausted padding row
+                    sl = r.pairs[-1:]
+                pairs[i, :len(sl)] = sl     # inert past n_act: the last
+                pairs[i, len(sl):] = sl[-1]     # pair again
+                n_act[i] = max(0, min(r.remaining, round_steps))
+                offsets[i] = r.done
+            carries = [{"x": r.x, "keys": r.rng, "state": r.state,
+                        "cond": r.cond, "uncond": r.uncond} for r in srcs]
+            batch = {"pairs": pairs, "n_act": n_act, "offsets": offsets}
             if plan is None:
                 kind_used, build = "chunk", ds.make_chunk_program
-            elif refs is not None:
+            elif rows[0].ref is not None:
                 # composed (timestep x spatial) plan: round-level step
                 # codes = per-step MAX over each row's own offset-aligned
                 # schedule (host-side numpy, zero syncs) — refresh beats
                 # spatial beats reuse, so no row gets LESS refresh than
                 # ITS plan scheduled; round-mates can only add fidelity
-                want = [0] * round_steps
+                want = np.zeros((round_steps,), np.int32)
                 for r in rows:
                     w = r.codes[r.done:r.done + round_steps]
-                    for j in range(len(w)):
-                        want[j] = max(want[j], int(w[j]))
-                sched_row = want
+                    want[:len(w)] = np.maximum(want[:len(w)], w)
+                sched_row = want.tolist()
                 kind_used = "chunk_spatial"
                 build = ds.make_spatial_chunk_program
-                prog_args += (jnp.asarray(want, jnp.int32), taps, refs)
+                batch["codes"] = want
+                for c, r in zip(carries, srcs):
+                    c["taps"], c["refs"] = r.taps, r.ref
             else:
                 # round-level refresh flags: OR of each row's own
                 # offset-aligned schedule (host-side numpy, zero syncs) —
                 # no row ever misses ITS scheduled refresh; round-mates
                 # may grant extra free refreshes (fidelity can only
                 # improve)
-                want = [False] * round_steps
+                want = np.zeros((round_steps,), bool)
                 for r in rows:
                     w = r.flags[r.done:r.done + round_steps]
-                    for j in range(len(w)):
-                        want[j] = want[j] or bool(w[j])
-                sched_row = [int(w) for w in want]
+                    want[:len(w)] |= w
+                sched_row = want.astype(int).tolist()
                 kind_used = "chunk_cached"
                 build = ds.make_cached_chunk_program
-                prog_args += (jnp.asarray(want), taps)
+                batch["flags"] = want
+                for c, r in zip(carries, srcs):
+                    c["taps"] = r.taps
+            prog_args = (self._params_for(group), tuple(carries), batch)
 
         with span("serve.launch", kind=kind_used):
             t0 = time.perf_counter()
             program, miss = self._get_program(
                 kind_used, group, bucket, round_steps,
-                lambda: build(round_steps))
-            # (x, keys, state), then the taps and the score-reference
-            # carries of the cached programs
-            outs = tuple(program(*prog_args)) + (None, None)
-            x_n, keys_n, state_n, taps_n, refs_n = outs[:5]
+                lambda: _round_program(build(round_steps)))
+            # per row (x, key, state), then the taps and the
+            # score-reference carries of the cached programs
+            outs = self._launch(program, *prog_args)
             compile_s = (time.perf_counter() - t0) if miss else 0.0
+        n_live = [int(n) for n in n_act[:len(rows)]]
         if sched_row is not None:
             # codes of a composed plan: 2 refresh, 1 spatial, 0 reuse;
             # flags of a timestep plan: 1 refresh, 0 reuse
-            top = 2 if refs is not None else 1
-            ran = [c for n in n_act[:len(rows)] for c in sched_row[:n]]
+            spatial = kind_used == "chunk_spatial"
+            top = 2 if spatial else 1
+            ran = [c for n in n_live for c in sched_row[:n]]
             count = self.telemetry.counter
             count("serving/cache_rows").inc(len(rows))
             count("serving/cache_refresh_steps").inc(ran.count(top))
             count("serving/cache_reused_steps").inc(ran.count(0))
-            if refs is not None:
+            if spatial:
                 count("serving/spatial_rows").inc(len(rows))
                 count("serving/spatial_steps").inc(ran.count(1))
         if miss:
@@ -432,24 +507,17 @@ class SamplerProgramEngine:
                                          round_steps)),
             "bucket": int(bucket), "rows": len(rows),
             "steps": int(round_steps), "miss": bool(miss),
-            "n_act": [int(v) for v in n_act[:len(rows)]],
+            "n_act": n_live,
         }
         if sched_row is not None:
             self.last_round_info["codes"] = sched_row
 
         finished: List[RequestState] = []
         with span("serve.unstack"):
-            for i, r in enumerate(rows):
-                r.x = x_n[i]
-                r.rng = keys_n[i]
-                r.state = jax.tree_util.tree_map(lambda a: a[i], state_n)
-                if taps_n is not None:
-                    r.taps = jax.tree_util.tree_map(lambda a: a[i],
-                                                    taps_n)
-                if refs_n is not None:
-                    r.ref = jax.tree_util.tree_map(lambda a: a[i],
-                                                   refs_n)
-                r.done += int(n_act[i])
+            for r, out, n in zip(rows, outs, n_live):
+                r.x, r.rng, r.state, r.taps, r.ref = \
+                    (tuple(out) + (None, None))[:5]
+                r.done += n
                 r.rounds += 1
                 r.compile_ms += compile_s * 1e3
                 if r.remaining <= 0:
@@ -459,35 +527,31 @@ class SamplerProgramEngine:
     def finalize(self, rows: List[RequestState],
                  bucket: int) -> Tuple[jax.Array, float]:
         """Terminal denoise + (optional) decode + clip for completed
-        rows. Returns ([R, num_samples, *sample_shape] device array in
-        row order, compile seconds)."""
+        rows, in one launch. Returns (`[bucket, num_samples,
+        *sample_shape]` device array whose first `len(rows)` entries
+        are the rows' samples in row order, compile seconds)."""
         group = rows[0].group
         ds = self._sampler_for(rows[0].req)
         with self._span("serve.stack"):
-            x, _, _, cond, uncond, _, _ = self._stack_rows(rows, bucket)
-            pad = bucket - len(rows)
-            t_term = jnp.asarray(
-                [r.terminal_t for r in rows + [rows[0]] * pad],
-                jnp.float32)
-            prog_args = (self._params_for(group), x, t_term, cond, uncond)
+            srcs = rows + [rows[0]] * (bucket - len(rows))
+            prog_args = (
+                self._params_for(group),
+                tuple({"x": r.x, "cond": r.cond, "uncond": r.uncond}
+                      for r in srcs),
+                {"t_term": np.float32([r.terminal_t for r in srcs])})
 
         with self._span("serve.launch", kind="terminal"):
             program, miss = self._get_program(
                 "terminal", group, bucket, 0,
-                lambda: ds.make_terminal_program())
+                lambda: _terminal_program(ds.make_terminal_program(),
+                                          ds.autoencoder))
             t0 = time.perf_counter()
-            x0 = program(*prog_args)
+            out = self._launch(program, *prog_args)
             compile_s = (time.perf_counter() - t0) if miss else 0.0
         if miss:
             self._register_evidence("terminal", group, bucket, 0,
                                     program, prog_args, compile_s)
-
-        x0 = x0[:len(rows)]
-        if ds.autoencoder is not None:
-            flat = x0.reshape((-1,) + x0.shape[2:])
-            flat = ds.autoencoder.decode(flat)
-            x0 = flat.reshape(x0.shape[:2] + flat.shape[1:])
-        return clip_images(x0), compile_s
+        return out, compile_s
 
     # -- program-cache pre-warming -------------------------------------------
     def prewarm(self, reqs: List[SampleRequest], round_steps: int,

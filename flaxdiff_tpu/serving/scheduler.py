@@ -17,12 +17,24 @@ Architecture (docs/SERVING.md):
   a 10-NFE request batched with a 50-NFE one returns after its own
   rounds, and its slot is refilled from the queue.
 - Completed rows are handed (still device-resident, dispatch still
-  async) to a **completion thread** that performs the only host syncs
-  — `_block_until_ready` + `_device_get`, module-level seams so tests
-  can count them, the PR-5 sync-free-loop convention. The dispatch
+  async) to a **completion thread** that performs the host syncs of a
+  result — `_block_until_ready` + `_device_get`, module-level seams so
+  tests can count them, the PR-5 sync-free-loop convention. The dispatch
   loop keeps at most `max_inflight` completed batches in flight;
   beyond that it waits (genuine backpressure, counted at
   `serving/backpressure_waits`) instead of racing the device.
+- The dispatch loop runs **one round ahead of the device and no
+  further**: a turn (admit, round, finalize) is a handful of launches
+  and no device-to-host read (serving/engine.py), so it prepares round
+  N+1 while round N runs. Its ONE wait on device work is `serve.pace`,
+  at the top of a turn: with `_ROUNDS_AHEAD` rounds unfinished (the one
+  running, one queued behind it) it waits for the older through
+  `_block_until_ready`. Unbounded, the thread would race ahead until
+  the runtime's launch queue stopped it, and admission, deadline
+  shedding, brownout and the turn between groups would be decided
+  rounds early; bounded, they are decided at most one round before
+  their round runs. `serving/rounds_overlapped` counts the rounds
+  launched while the round before was still running (`_is_ready`).
 - **close(drain=True)** stops admission, finishes queued + active
   work, and joins both threads.
 
@@ -67,11 +79,25 @@ MS_BUCKET_BOUNDS: Tuple[float, ...] = (
 
 # The scheduler's host-sync + clock primitives, module-level so tests
 # can monkeypatch counting wrappers (the PR-5 seam convention): the
-# dispatch loop itself must never block on device work.
+# dispatch loop blocks on device work in `serve.pace` and nowhere else.
 
 def _block_until_ready(x) -> None:
     import jax
     jax.block_until_ready(x)
+
+
+def _is_ready(x) -> bool:
+    """Has the device finished computing `x`? Never blocks."""
+    import jax
+    return all(leaf.is_ready() for leaf in jax.tree_util.tree_leaves(x)
+               if hasattr(leaf, "is_ready"))
+
+
+# Rounds the dispatch thread may have unfinished on the device: the one
+# running and one launched behind it (module docstring). Not a knob: one
+# queued round already hides all of a turn's host work, and every
+# further one only makes admission decide earlier.
+_ROUNDS_AHEAD = 2
 
 
 def _device_get(x):
@@ -187,6 +213,9 @@ class ServingScheduler:
         self._completions: Deque[Tuple[List[RequestState], object, float]] \
             = deque()
         self._last_served: Dict[tuple, int] = {}
+        # a carry out of each launched round, oldest first, until it is
+        # seen ready: what `serve.pace` waits on (dispatch thread only)
+        self._unfinished: Deque[Any] = deque()
         self._round_no = 0
         self._closed = False
         self._draining = False
@@ -622,6 +651,7 @@ class ServingScheduler:
             for rs in self._active.values():
                 interrupted.extend(rs)
             self._active.clear()
+            self._unfinished.clear()    # carries of the dead engine
             # DRAINING: let the completion thread settle (or fail and
             # requeue) every batch already handed to it before the old
             # engine is torn down
@@ -725,6 +755,27 @@ class ServingScheduler:
             self._last_served[gk] = self._round_no
         return gk, rows, buckets
 
+    def _pace(self) -> None:
+        """The dispatch loop's one wait on device work (the span
+        `serve.pace`): with `_ROUNDS_AHEAD` rounds unfinished, wait for
+        the older before the turn that launches the next. A round that
+        failed on the device raises here as it would at its fetch; the
+        fault barriers (round, fetch) own that, so it is recorded and
+        the loop goes on."""
+        pending = self._unfinished
+        try:
+            while pending and _is_ready(pending[0]):
+                pending.popleft()
+            if len(pending) >= _ROUNDS_AHEAD:
+                with self.telemetry.span("serve.pace", cat="serving"):
+                    _block_until_ready(pending[0])
+                pending.popleft()
+        except (KeyboardInterrupt, SystemExit):
+            raise
+        except BaseException as e:  # noqa: BLE001 — not this wait's
+            pending.popleft()
+            record_event("serving_fault", "serving.pace", detail=repr(e))
+
     def _dispatch_rounds(self) -> None:
         tel = self.telemetry
         cfg = self.config
@@ -733,6 +784,9 @@ class ServingScheduler:
             return tel.span(name, cat="serving", args=args)
 
         while True:
+            # before the lock and before admission: what is admitted,
+            # shed or degraded is decided as late as the bound allows
+            self._pace()
             with self._cv:
                 if not (self._queue or self._active or self._closed):
                     with span("serve.wait"):
@@ -775,8 +829,12 @@ class ServingScheduler:
                     r.first_dispatch_t = t_disp
 
             try:
+                if self._unfinished \
+                        and not _is_ready(self._unfinished[-1]):
+                    tel.counter("serving/rounds_overlapped").inc()
                 finished, _ = self._checked_advance(rows, bucket,
                                                     round_steps)
+                self._unfinished.append(rows[0].x)
                 if self.tracer.enabled:
                     # host timestamps + host-side dicts only: tracing
                     # must not add a single device sync to the

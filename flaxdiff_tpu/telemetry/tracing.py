@@ -54,24 +54,30 @@ class SpanDoc(NamedTuple):
 # `.span("...")` / `.phase("...")` call site of the package against it).
 SPANS: Dict[str, SpanDoc] = {k: SpanDoc(*v) for k, v in {
     # -- serving: dispatch thread (`serving-dispatch`), per loop turn
+    "serve.pace": ("dispatch", None, (), "the loop's one wait on device "
+                   "work: two rounds unfinished, `_block_until_ready` "
+                   "of the older, before `serve.admit`"),
     "serve.wait": ("dispatch", None, (), "`_cv.wait` with nothing to "
                    "serve: queue and active empty, or only parked "
                    "entries"),
     "serve.admit": ("dispatch", None, (), "under the lock: shed expired, "
-                    "pick group, pop active, admit, brownout tier"),
+                    "pick group, pop active, admit (`engine.prepare`: two "
+                    "launches a request), brownout tier"),
     "serve.round": ("dispatch", None, ("round", "bucket", "rows", "steps"),
                     "`_checked_advance`, first line to return"),
     "serve.stack": ("dispatch", "serve.round | serve.finalize", (),
-                    "`_stack_rows` and the pairs / n_act / offsets "
-                    "building"),
+                    "host arithmetic: the pairs / n_act / offsets in "
+                    "numpy and the tuple of row carries (no launch)"),
     "serve.launch": ("dispatch", "serve.round | serve.finalize", ("kind",),
-                     "`_get_program` and the jitted call (host side of "
-                     "the dispatch; a compile on a miss is its length)"),
-    "serve.unstack": ("dispatch", "serve.round", (), "the per-row "
-                      "write-back of x / rng / state / taps / ref"),
+                     "`_get_program` and the ONE jitted call: stack, "
+                     "program, unstack (host side of the dispatch; a "
+                     "compile on a miss is its length)"),
+    "serve.unstack": ("dispatch", "serve.round", (), "each row takes its "
+                      "own outputs of the program: x / rng / state / "
+                      "taps / ref (no launch)"),
     "serve.finalize": ("dispatch", None, ("rows", "bucket"),
-                       "`engine.finalize` whole: stack, launch, slice, "
-                       "optional decode, clip"),
+                       "`engine.finalize` whole: one launch for stack, "
+                       "terminal denoise, optional decode, clip"),
     "serve.backpressure": ("dispatch", None, (), "the wait while more "
                            "than `max_inflight` batches are in flight"),
     # -- serving: completion thread (`serving-complete`)

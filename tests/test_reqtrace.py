@@ -16,6 +16,7 @@ Covers the acceptance bars:
 """
 import json
 import os
+import threading
 
 import numpy as np
 import pytest
@@ -86,10 +87,11 @@ def test_traced_replay_registry_and_reconciliation(tiny_pipe, tmp_path):
         assert (kind, str(key)) in registered, key
     for r in rows:
         assert r["compile_ms"] and r["compile_ms"] > 0
-        assert r["flops_jaxpr"] and r["flops_jaxpr"] > 0
+        # a request's init and noise programs hold no model evaluation
+        assert r["kind"] in ("init", "noise") or r["flops_jaxpr"] > 0
         assert r["fingerprint"]["platform"]
-    # both program kinds this workload compiles are present
-    assert {r["kind"] for r in rows} == {"chunk", "terminal"}
+    # every program kind this workload compiles is present
+    assert {r["kind"] for r in rows} == {"init", "noise", "chunk", "terminal"}
 
     # -- per-request rows reconcile with the histograms ---------------------
     recs = [json.loads(line) for line in
@@ -131,7 +133,11 @@ def test_tracing_adds_no_host_syncs_and_warm_zero_retrace(
     real_get = sched_mod._device_get
 
     def count_block(x):
-        counts["blocks"] += 1
+        # a result's syncs (completion thread). `serve.pace`, on the
+        # dispatch thread, takes the same seam as often as the device
+        # is behind, which is timing and not tracing
+        if threading.current_thread().name == "serving-complete":
+            counts["blocks"] += 1
         return real_block(x)
 
     def count_get(x):

@@ -7,6 +7,7 @@ batched == solo bit-identity under padding/masking/chunking, and a
 warm program cache that never re-traces — run against a real tiny
 pipeline.
 """
+import contextlib
 import threading
 import time
 
@@ -352,6 +353,182 @@ def test_thread_safe_submit():
 
 
 # ---------------------------------------------------------------------------
+# Run-ahead: one round launched behind the one the device is running
+# ---------------------------------------------------------------------------
+
+class Carry:
+    """A round's output as the scheduler's seams see it: ready on
+    command, like a device array behind a running program."""
+
+    def __init__(self, fail: bool = False):
+        self.done = threading.Event()
+        self.fail = fail
+
+    def is_ready(self):
+        return self.done.is_set()
+
+    def block_until_ready(self):
+        assert self.done.wait(20), "the test never released this round"
+        if self.fail:
+            raise RuntimeError("the round failed on the device")
+        return self
+
+
+class GatedEngine(FakeEngine):
+    """FakeEngine whose rounds return at once (a launch) and finish
+    when the test says so: `carries[i]` is round i's output."""
+
+    def __init__(self, failing=()):
+        super().__init__()
+        self.carries = []
+        self.failing = set(failing)
+
+    def advance(self, rows, bucket, round_steps):
+        carry = Carry(fail=len(self.carries) in self.failing)
+        self.carries.append(carry)
+        for r in rows:
+            r.x = carry
+        return super().advance(rows, bucket, round_steps)
+
+
+def _wait_for(cond, timeout=10.0):
+    t_end = time.perf_counter() + timeout
+    while not cond():
+        assert time.perf_counter() < t_end, "timed out"
+        time.sleep(0.002)
+
+
+def _gated_scheduler(tel=None, failing=(), buckets=(1,)):
+    eng = GatedEngine(failing)
+    sched = ServingScheduler(
+        engine=eng, telemetry=tel or Telemetry(enabled=False),
+        autostart=False,
+        config=SchedulerConfig(round_steps=1, batch_buckets=buckets))
+    return eng, sched
+
+
+def test_dispatch_thread_is_never_more_than_one_round_ahead():
+    """With every round unfinished until released, the thread launches
+    the running round and ONE behind it, then waits in `serve.pace`;
+    each release lets exactly one more round out. The wait holds no
+    lock."""
+    tel = Telemetry(enabled=False)
+    eng, sched = _gated_scheduler(tel)
+    fut = sched.submit(SampleRequest(resolution=8, diffusion_steps=6,
+                                     sampler="ddim", seed=4))
+    sched.start()
+    for released in range(5):
+        _wait_for(lambda: len(eng.carries) == released + 2)
+        time.sleep(0.05)                  # it would have raced by now
+        assert len(eng.carries) == released + 2
+        assert sched.queue_depth() == 0   # takes the scheduler's lock
+        eng.carries[released].done.set()
+    eng.carries[5].done.set()
+    assert np.all(fut.result(timeout=10).samples == 4.0)
+    sched.close()
+    snap = tel.registry.snapshot()
+    # every round but the first left while the round before still ran
+    assert snap["serving/rounds"] == 6
+    assert snap["serving/rounds_overlapped"] == 5
+
+
+def test_rounds_overlapped_is_zero_when_the_device_keeps_up():
+    """A round whose output is ready before the next turn (the plain
+    FakeEngine: the host is the slower side) overlaps nothing and never
+    waits in `serve.pace`."""
+    tel = Telemetry(enabled=False)
+    eng, sched = _fake_scheduler(tel, round_steps=1)
+    fut = sched.submit(SampleRequest(resolution=8, diffusion_steps=6,
+                                     sampler="ddim", seed=2))
+    sched.start()
+    fut.result(timeout=10)
+    sched.close()
+    snap = tel.registry.snapshot()
+    assert snap["serving/rounds"] == 6
+    assert snap.get("serving/rounds_overlapped", 0) == 0
+
+
+def test_pace_is_one_block_on_the_older_round(monkeypatch):
+    """`serve.pace` goes through the `_block_until_ready` seam, once a
+    turn at most, on the OLDER of the two unfinished rounds."""
+    blocked = []
+    real_block = sched_mod._block_until_ready
+
+    def recording(x):
+        if threading.current_thread().name == "serving-dispatch":
+            blocked.append(x)
+        return real_block(x)
+
+    monkeypatch.setattr(sched_mod, "_block_until_ready", recording)
+    eng, sched = _gated_scheduler()
+    fut = sched.submit(SampleRequest(resolution=8, diffusion_steps=4,
+                                     sampler="ddim", seed=1))
+    sched.start()
+    for i in range(3):
+        _wait_for(lambda: len(blocked) == i + 1)
+        assert len(eng.carries) == min(i + 2, 4)
+        eng.carries[i].done.set()
+    fut.result(timeout=10)
+    sched.close()
+    # with rounds i+1 and i+2 unfinished, the turn waited for round i+1
+    assert blocked == eng.carries[:3]
+
+
+def test_midflight_deadline_shed_at_round_boundary_under_run_ahead():
+    """A deadline that passes while the thread is a round ahead is
+    still acted on at the next round boundary: `serve.pace` stands
+    before admission, so the turn after the wait sheds the request
+    before launching anything more for it."""
+    tel = Telemetry(enabled=False)
+    eng, sched = _gated_scheduler(tel, buckets=(1, 2))
+    doomed = sched.submit(SampleRequest(resolution=8, diffusion_steps=8,
+                                        sampler="ddim", deadline_s=0.05))
+    ok = sched.submit(SampleRequest(resolution=8, diffusion_steps=4,
+                                    sampler="ddim", seed=9))
+    sched.start()
+    _wait_for(lambda: len(eng.carries) == 2)
+    time.sleep(0.08)                      # the deadline passes in pace
+    for c in eng.carries:
+        c.done.set()
+    _wait_for(lambda: len(eng.advance_calls) >= 3)
+    with pytest.raises(DeadlineExceeded, match="after 2 round"):
+        doomed.result(timeout=10)
+    while not ok.done():
+        for c in list(eng.carries):
+            c.done.set()
+        time.sleep(0.002)
+    assert np.all(ok.result(timeout=10).samples == 9.0)
+    for c in eng.carries:
+        c.done.set()
+    sched.close()
+    # rounds 1 and 2 carried both rows; from round 3 on the survivor
+    assert [n for n, _, _ in eng.advance_calls] == [2, 2, 1, 1]
+    assert tel.registry.snapshot()["serving/shed_midflight"] == 1
+
+
+def test_a_round_that_failed_on_the_device_does_not_kill_the_loop():
+    """The pace wait is not a fault barrier of its own: a round whose
+    output raises when waited for is recorded and the loop goes on (the
+    round and fetch barriers own the fault)."""
+    from flaxdiff_tpu import resilience as R
+    ev = R.EventLog("pace")
+    eng, sched = _gated_scheduler(failing={0})
+    with R.use_event_log(ev):
+        fut = sched.submit(SampleRequest(resolution=8, diffusion_steps=4,
+                                         sampler="ddim", seed=6))
+        sched.start()
+        while not fut.done():
+            for c in list(eng.carries):
+                c.done.set()
+            time.sleep(0.002)
+        assert np.all(fut.result(timeout=10).samples == 6.0)
+        for c in eng.carries:
+            c.done.set()
+        sched.close()
+    assert ev.count(site="serving.pace") == 1
+
+
+# ---------------------------------------------------------------------------
 # Real-engine acceptance: bit-identity + warm cache
 # ---------------------------------------------------------------------------
 
@@ -411,6 +588,34 @@ def test_batched_bit_identity_with_padding_and_chunking(tiny_pipe):
     assert snap["serving/requests_ok"] == 3
 
 
+def test_samples_do_not_depend_on_round_mates(tiny_pipe):
+    """Rows never interact: one request's samples are the same bits
+    whatever shares its rounds (other seeds, other NFEs, other slots of
+    the bucket), so stack and unstack inside the round program hand
+    every row its own carry. On a v-predicting model, whose samples do
+    not saturate at the clip (every step shows in them)."""
+    from flaxdiff_tpu.inference import DiffusionInferencePipeline
+    pipe = DiffusionInferencePipeline.from_config(
+        dict(tiny_pipe.config, predictor="v"), params=tiny_pipe.params)
+
+    def serve(mates):
+        sched = ServingScheduler(
+            pipeline=pipe, telemetry=Telemetry(enabled=False),
+            autostart=False,
+            config=SchedulerConfig(round_steps=2, batch_buckets=(4,)))
+        futs = [sched.submit(_tiny_request(n, seed, "euler_ancestral"))
+                for n, seed in mates]
+        sched.start()
+        outs = [f.result(timeout=300).samples for f in futs]
+        sched.close()
+        return outs
+
+    first = serve([(5, 7), (3, 11), (4, 3)])
+    second = serve([(2, 1), (7, 2), (5, 7)])        # another slot, too
+    np.testing.assert_array_equal(first[0], second[2])
+    assert (np.abs(first[0]) < 1.0).mean() > 0.5
+
+
 def test_multistep_state_carry_bit_identity(tiny_pipe):
     """Multistep DPM is the hardest carry: its scan state (denoised
     history + lambda trail, keyed on the global step index) must
@@ -437,6 +642,58 @@ def test_multistep_state_carry_bit_identity(tiny_pipe):
         np.testing.assert_array_equal(o.samples, solo)
 
 
+@contextlib.contextmanager
+def _count_compiles():
+    """The seconds of every backend compile inside the block (what the
+    benchmark's `correct` holds at zero inside its window)."""
+    import jax.monitoring
+    seen, on = [], [True]
+
+    def listen(event, secs, **_):
+        if on[0] and event == "/jax/core/compile/backend_compile_duration":
+            seen.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        yield seen
+    finally:
+        on[0] = False       # jax has no public way to take it off again
+
+
+def _tiny_request(nfe, seed, sampler="ddim"):
+    return SampleRequest(resolution=8, channels=1, diffusion_steps=nfe,
+                         sampler=sampler, seed=seed, use_ema=False)
+
+
+def _drive(engine, rows, buckets, round_steps):
+    """The scheduler's own engine calls for `rows`, to the end."""
+    live = rows
+    while live:
+        finished, _ = engine.advance(live, bucket_up(len(live), buckets),
+                                     round_steps)
+        live = [r for r in live if r.remaining > 0]
+        if finished:
+            out, _ = engine.finalize(finished,
+                                     bucket_up(len(finished), buckets))
+            sched_mod._block_until_ready(out)
+
+
+def _warm_walk(engine, buckets, round_steps, nfes):
+    """Every shape traffic can meet, as the benchmark's `warm_engine`
+    walks them: a round and a terminal for every bucket and every count
+    of rows that finish together, and one request of every NFE."""
+    from flaxdiff_tpu.serving import ServingFuture
+
+    def rows_of(ns):
+        return [engine.prepare(_tiny_request(n, 10 ** 6 + i),
+                               ServingFuture(), 0.0, 0.0)
+                for i, n in enumerate(ns)]
+
+    for n in range(1, max(buckets) + 1):
+        _drive(engine, rows_of([round_steps] * n), buckets, round_steps)
+    _drive(engine, rows_of(nfes), buckets, round_steps)
+
+
 def test_warm_cache_never_retraces(tiny_pipe):
     """Repeat traffic of identical request shapes must be served
     entirely from the compiled-program cache: zero misses on the
@@ -458,14 +715,152 @@ def test_warm_cache_never_retraces(tiny_pipe):
     misses_cold = tel.registry.counter(
         "serving/program_cache_misses").value
     assert misses_cold > 0
-    second = pass_once()
+    with _count_compiles() as compiled:
+        second = pass_once()
     sched.close()
+    # neither the engine's own programs nor anything eager beside them
+    assert compiled == []
     assert tel.registry.counter(
         "serving/program_cache_misses").value == misses_cold
     assert tel.registry.counter("serving/program_cache_hits").value > 0
     # same request, same seed -> same samples on both passes
     for a, b in zip(first, second):
         np.testing.assert_array_equal(a.samples, b.samples)
+
+
+def test_warm_walk_then_traffic_compiles_nothing(tiny_pipe):
+    """After a `warm_engine`-style walk, traffic that arrives while
+    rounds run (any mix of fresh and continued rows, any bucket)
+    compiles nothing, and a turn stays a handful of launches."""
+    tel = Telemetry(enabled=False)
+    cfg = SchedulerConfig(round_steps=2, batch_buckets=(1, 2, 4))
+    sched = ServingScheduler(pipeline=tiny_pipe, telemetry=tel,
+                             autostart=False, config=cfg)
+    _warm_walk(sched.engine, cfg.batch_buckets, cfg.round_steps, (3, 4, 5))
+    count = tel.registry.counter
+    misses = count("serving/program_cache_misses").value
+    launches, rounds = (count("serving/launches").value,
+                        count("serving/rounds").value)
+    sched.start()
+    with _count_compiles() as compiled:
+        futs = []
+        for i in range(12):
+            futs.append(sched.submit(_tiny_request((3, 4, 5)[i % 3], i)))
+            time.sleep(0.003 * (i % 4))
+        outs = [f.result(timeout=300) for f in futs]
+    sched.close()
+    assert len(outs) == 12 and compiled == []
+    assert count("serving/program_cache_misses").value == misses
+    per_round = ((count("serving/launches").value - launches)
+                 / (count("serving/rounds").value - rounds))
+    assert 2.0 <= per_round <= 16.0
+
+
+@pytest.fixture
+def dispatches(monkeypatch):
+    """Names of every compiled program dispatched from Python while the
+    test runs, an eager operation's one-operation program included: the
+    C++ fast path is switched off, so each call comes through
+    `_run_python_pjit`. (A call nested in a trace comes through it too:
+    count warm calls only.)"""
+    import jax
+    from jax._src import pjit
+    names = []
+    real = pjit._run_python_pjit
+
+    def counting(p, args_flat, fun, *args, **kwargs):
+        names.append(getattr(fun, "__name__", str(fun)))
+        return real(p, args_flat, fun, *args, **kwargs)
+
+    monkeypatch.setattr(pjit, "_run_python_pjit", counting)
+    monkeypatch.setattr(pjit, "_get_fastpath_data", lambda *a, **k: None)
+    jax.clear_caches()          # fast-path entries of earlier tests
+    yield names
+    jax.clear_caches()          # and ours, which have no fast path
+
+
+def test_a_warm_turn_is_a_handful_of_counted_launches(tiny_pipe,
+                                                      dispatches):
+    """One warm loop turn with 8 rows, 2 of them just admitted and 2
+    finishing: every program dispatched is one of the engine's own,
+    through its counting helper — an init and a noise program for each
+    admitted request, 1 round, 1 terminal — and no eager one-operation
+    program beside them."""
+    from flaxdiff_tpu.serving import ServingFuture
+    from flaxdiff_tpu.serving.engine import SamplerProgramEngine
+    tel = Telemetry(enabled=False)
+    engine = SamplerProgramEngine(tiny_pipe, telemetry=tel)
+    buckets, rs = (1, 2, 4, 8), 2
+
+    def admit(nfe, seed):
+        return engine.prepare(_tiny_request(nfe, seed), ServingFuture(),
+                              0.0, 0.0)
+
+    _drive(engine, [admit(2, s) for s in range(2)], buckets, rs)  # b2
+    rows = [admit(4, 10 + i) for i in range(2)] \
+        + [admit(8, 20 + i) for i in range(4)]
+    rows += [admit(6, 30), admit(6, 31)]
+    finished, _ = engine.advance(rows, 8, rs)       # warms bucket 8
+    assert not finished
+    rows = rows[:6]                                 # two slots free
+
+    launches = tel.registry.counter("serving/launches")
+    l0, n0 = launches.value, len(dispatches)
+    rows += [admit(6, 40), admit(6, 41)]
+    finished, compile_s = engine.advance(rows, 8, rs)
+    out, _ = engine.finalize(finished, bucket_up(len(finished), buckets))
+    turn = dispatches[n0:]
+    assert len(finished) == 2 and compile_s == 0.0
+    assert sorted(turn) == ["sampler_chunk", "sampler_init",
+                            "sampler_init", "sampler_noise",
+                            "sampler_noise", "sampler_terminal"]
+    assert launches.value - l0 == len(turn) <= 16
+    assert out.shape[0] == 2
+
+
+def test_second_prepare_of_a_seen_nfe_computes_and_reads_nothing(
+        tiny_pipe, monkeypatch):
+    """What depends only on (sampler, NFE, schedule) is made once and
+    kept as host values: a later request of that NFE calls no
+    `get_timestep_spacing` and reads no device value back (the first
+    read goes through the `_device_get` seam)."""
+    from jax._src.array import ArrayImpl
+
+    from flaxdiff_tpu.samplers import common
+    from flaxdiff_tpu.serving import ServingFuture
+    from flaxdiff_tpu.serving.engine import SamplerProgramEngine
+    spacings, gets, reads = [], [], []
+    real_spacing, real_get = common.get_timestep_spacing, \
+        sched_mod._device_get
+    real_value = ArrayImpl._value
+    monkeypatch.setattr(
+        common, "get_timestep_spacing",
+        lambda *a, **k: (spacings.append(1), real_spacing(*a, **k))[1])
+    monkeypatch.setattr(
+        sched_mod, "_device_get",
+        lambda x: (gets.append(1), real_get(x))[1])
+    engine = SamplerProgramEngine(tiny_pipe,
+                                  telemetry=Telemetry(enabled=False))
+    first = engine.prepare(_tiny_request(5, 1), ServingFuture(), 0.0, 0.0)
+    assert len(spacings) == 1 and len(gets) == 2
+    assert isinstance(first.pairs, np.ndarray) \
+        and first.pairs.shape == (5, 2) \
+        and isinstance(first.terminal_t, float)
+
+    monkeypatch.setattr(ArrayImpl, "_value", property(
+        lambda self: (reads.append(1), real_value.fget(self))[1]))
+    again = engine.prepare(_tiny_request(5, 2), ServingFuture(), 0.0, 0.0)
+    monkeypatch.setattr(ArrayImpl, "_value", real_value)
+    assert len(spacings) == 1 and len(gets) == 2 and reads == []
+    assert again.pairs is first.pairs
+    # and the carry is the solo path's, to the bit: its keys, its noise
+    from flaxdiff_tpu.utils import RngSeq
+    rng, noise_key = RngSeq.create(2).next_key()
+    _, loop_key = rng.next_key()
+    solo = tiny_pipe.get_sampler("ddim", 0.0)
+    np.testing.assert_array_equal(
+        again.x, solo.make_noise_program((1, 8, 8, 1))(noise_key))
+    np.testing.assert_array_equal(again.rng, loop_key)
 
 
 def test_prompted_cfg_bit_identity():

@@ -312,8 +312,9 @@ def test_round_is_the_join_key_and_each_sink_holds_it_once(
 
 
 def test_serving_programs_carry_stable_names(serving_capture):
-    assert serving_capture["programs"] == {"sampler_chunk",
-                                           "sampler_terminal"}
+    assert serving_capture["programs"] == {
+        "sampler_init", "sampler_noise", "sampler_chunk",
+        "sampler_terminal"}
 
 
 # -- fit ----------------------------------------------------------------------
@@ -487,8 +488,9 @@ def test_every_sampler_program_is_jitted_under_its_own_name():
             assert isinstance(arg, ast.Name), node.lineno
             jitted.append(arg.id)
     assert sorted(jitted) == ["sampler_chunk", "sampler_chunk_cached",
-                              "sampler_chunk_spatial", "sampler_scan",
-                              "sampler_terminal"]
+                              "sampler_chunk_spatial", "sampler_init",
+                              "sampler_model", "sampler_noise",
+                              "sampler_scan", "sampler_terminal"]
     assert set(jitted) <= defs
 
 
