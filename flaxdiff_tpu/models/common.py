@@ -238,7 +238,9 @@ def _norm_factory(norm_groups: int, dtype) -> Callable[[], nn.Module]:
 
 
 class FusedGroupNormSiLU(nn.Module):
-    """GroupNorm + SiLU through the fused Pallas kernel (ops/fused_norm.py).
+    """GroupNorm + SiLU through ops/fused_norm.py: the fused Pallas
+    kernels at sampling batches, the XLA composition at training batches
+    (multiples of 16), picked there from the input's shape.
 
     Param names match nn.GroupNorm ('scale'/'bias'), so checkpoints are
     interchangeable with the unfused (norm, swish) pair.

@@ -16,6 +16,15 @@ per-group stats — one stats pass over (x, g) producing the dx correction
 terms and dscale/dbias partials, an O(B*G + C) XLA finalize, then the dx
 pass (FLAXDIFF_FUSED_NORM_BWD=xla restores the recompute-through-XLA
 backward for A/B). Falls back to XLA off-TPU.
+
+The kernels are for small batches only (`_batch_fills_sublanes`): once
+the batch fills a sublane tile, XLA's TPU convolutions keep a
+`[B, H, W, C]` activation with the batch in the sublanes (physically
+`[H, W, B, C]`), a `pallas_call` over `[B, HW, C]` is reached only
+through a layout copy in and a copy out, and the custom call is a wall
+that XLA cannot fuse the normalize into its neighbours across. There the
+XLA composition is the faster program by a fifth of the UNet's train
+step (docs/KERNELS.md has the chip readings), so the shape picks it.
 """
 from __future__ import annotations
 
@@ -61,6 +70,14 @@ def _use_pallas(interpret: bool, force_pallas: bool):
     if os.environ.get("FLAXDIFF_FUSED_NORM") == "xla":
         return False, interpret
     return (jax.devices()[0].platform == "tpu" or interpret), interpret
+
+
+def _batch_fills_sublanes(shape) -> bool:
+    """A batch that fills the bf16 sublane tile (a multiple of 16:
+    training, per device under `shard_map`) takes the XLA composition,
+    whatever the platform or `force_pallas`; solo and small-batch
+    sampling keep the kernels. Nothing but the shape selects."""
+    return shape[0] % 16 == 0
 
 
 def _member_mask(c: int, groups: int) -> jnp.ndarray:
@@ -280,7 +297,7 @@ def _impl_stats(x: jax.Array, scale: jax.Array, bias: jax.Array,
     b = x.shape[0]
 
     run_pallas, interpret = _use_pallas(interpret, force_pallas)
-    if not run_pallas:
+    if not run_pallas or _batch_fills_sublanes(x.shape):
         return (_xla_groupnorm_silu(x, scale, bias, groups, eps,
                                     apply_silu), None, None)
 

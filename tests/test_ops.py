@@ -249,6 +249,96 @@ def test_fused_groupnorm_pallas_backward_multiblock(monkeypatch):
                                rtol=2e-3, atol=2e-3)
 
 
+def _gn_case(b, side, c, mean=0.0):
+    key = jax.random.PRNGKey(11)
+    x = mean + jax.random.normal(key, (b, side, side, c))
+    scale = jax.random.normal(jax.random.fold_in(key, 1), (c,)) * 0.1 + 1.0
+    bias = jax.random.normal(jax.random.fold_in(key, 2), (c,)) * 0.1
+    return x, scale, bias
+
+
+def _gn_forced(x, s, b, apply_silu=True):
+    return fused_groupnorm_silu(x, s, b, groups=8, apply_silu=apply_silu,
+                                interpret=True, force_pallas=True)
+
+
+def _gn_kernel_names(*args):
+    """Names of the `fdt_gn_silu_*` kernels in the jaxpr of the forward
+    and its gradient, Pallas forced as far as the program lets it be."""
+    import re
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(_gn_forced(*a)), argnums=(0, 1, 2)))(*args))
+    return set(re.findall(r"fdt_gn_silu_\w+", jaxpr))
+
+
+def _gn_grads(fn_, x, scale, bias):
+    return jax.grad(lambda *a: jnp.sum(fn_(*a) ** 2),
+                    argnums=(0, 1, 2))(x, scale, bias)
+
+
+@pytest.mark.parametrize("apply_silu", [True, False])
+@pytest.mark.parametrize("c", [64, 128, 1024])
+@pytest.mark.parametrize("b", [16, 32])
+def test_fused_groupnorm_sublane_batch_takes_the_xla_composition(
+        b, c, apply_silu):
+    """Selection is by shape: a batch that fills the bf16 sublane tile
+    runs no kernel at all, forward or backward, even with Pallas forced
+    (on the chip the composition is the faster program there:
+    docs/KERNELS.md), and is the composition to the last bit."""
+    x, scale, bias = _gn_case(b, 4, c)
+    assert _gn_kernel_names(x, scale, bias) == set()
+
+    def forced(x, s, z):
+        return _gn_forced(x, s, z, apply_silu)
+
+    def ref(x, s, z):
+        return _xla_groupnorm_silu(x, s, z, 8, 1e-6, apply_silu)
+
+    np.testing.assert_array_equal(forced(x, scale, bias),
+                                  ref(x, scale, bias))
+    for a, r, name in zip(_gn_grads(forced, x, scale, bias),
+                          _gn_grads(ref, x, scale, bias),
+                          ("dx", "dscale", "dbias")):
+        np.testing.assert_allclose(a, r, rtol=1e-6, atol=1e-6, err_msg=name)
+
+
+@pytest.mark.parametrize("shape", [(16, 16, 16, 16), (16, 256, 16)])
+def test_fused_groupnorm_sublane_batch_large_mean_stable(shape):
+    """The large-mean case of `test_fused_groupnorm_large_mean_stable` on
+    the side of the choice that runs the composition, 4-D and 3-D."""
+    x = 1000.0 + jax.random.normal(jax.random.PRNGKey(8), shape) * 0.1
+    scale, bias = jnp.ones((16,)), jnp.zeros((16,))
+    out = fused_groupnorm_silu(x, scale, bias, groups=4, interpret=True,
+                               force_pallas=True)
+    xf = np.asarray(x, np.float64).reshape(16, -1, 4, 4)
+    want = (xf - xf.mean((1, 3), keepdims=True)) \
+        / np.sqrt(xf.var((1, 3), keepdims=True) + 1e-6)
+    want = want.reshape(shape)
+    want = want / (1.0 + np.exp(-want))
+    np.testing.assert_allclose(out, want, rtol=5e-2, atol=5e-2)
+
+
+@pytest.mark.parametrize("b", [1, 8])
+def test_fused_groupnorm_small_batch_keeps_the_kernels(b):
+    """The other side of the choice: a batch that is no multiple of 16
+    (solo and small-batch sampling) runs today's four kernels, forward
+    and backward, and they agree with the composition."""
+    x, scale, bias = _gn_case(b, 4, 64)
+    assert _gn_kernel_names(x, scale, bias) == {
+        "fdt_gn_silu_stats", "fdt_gn_silu_apply", "fdt_gn_silu_bwd_sums",
+        "fdt_gn_silu_bwd_dx"}
+
+    def ref(x, s, z):
+        return _xla_groupnorm_silu(x, s, z, 8, 1e-6, True)
+
+    np.testing.assert_allclose(_gn_forced(x, scale, bias),
+                               ref(x, scale, bias), rtol=1e-4, atol=1e-4)
+    for a, r, name in zip(_gn_grads(_gn_forced, x, scale, bias),
+                          _gn_grads(ref, x, scale, bias),
+                          ("dx", "dscale", "dbias")):
+        np.testing.assert_allclose(a, r, rtol=2e-3, atol=2e-3, err_msg=name)
+
+
 def test_full_train_step_with_interpreted_kernels(monkeypatch):
     """BOTH kernel families' REAL code paths (flash fwd+bwd, fused-norm
     fwd + the r5 Pallas backward) inside one complete train step on CPU

@@ -1,0 +1,137 @@
+"""No layout copy between a convolution and a GroupNorm+SiLU at a
+training batch: a `ConvLayer -> FusedGroupNormSiLU -> ConvLayer` sandwich
+with its gradient, compiled for a described v5e chip (nothing attached,
+nothing runs). At batch 32 the shape picks the XLA composition: no
+`fdt_gn_silu_*` custom call, and the one activation-sized `copy` left is
+the program's own result. At batch 8 the four kernels run, each fed by
+a `copy` out of the convolutions' layout: the cost the rule avoids. The
+one file of `tests/` that loads the TPU's compiler: the topology is
+described inside a fixture, never at import.
+"""
+from __future__ import annotations
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+_INSTR = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) "
+                    r"([\w\-]+)\((.*)")
+_PASS_THROUGH = ("bitcast", "get-tuple-element", "reshape")
+
+
+def _elements(shape: str) -> int:
+    m = re.match(r"\w+\[([\d,]*)\]", shape)
+    n = 1
+    for d in (m.group(1).split(",") if m and m.group(1) else []):
+        n *= int(d)
+    return n
+
+
+def entry_instructions(hlo_text: str):
+    """{name: (opcode, [operand names], elements)} of the ENTRY
+    computation (elements of a tuple-shaped result read 1)."""
+    start = hlo_text.index("ENTRY ")
+    body = hlo_text[start:hlo_text.index("\n}", start)]
+    out = {}
+    for line in body.split("\n"):
+        m = _INSTR.match(line)
+        if m:
+            name, shape, op, rest = m.groups()
+            out[name] = (op, re.findall(r"%([\w.\-]+)",
+                                        rest.split("), ")[0]),
+                         _elements(shape))
+    return out
+
+
+def copies_feeding(instrs, prefix: str, min_elements: int):
+    """(kernel call, copy) pairs: operands of custom calls named
+    `prefix*` that a `copy` of at least `min_elements` produces, seen
+    through bitcasts (the per-sample statistics reach the kernels
+    through copies of a few KB, which do not count)."""
+    found = []
+    for name, (op, operands, _) in instrs.items():
+        if op != "custom-call" or not name.startswith(prefix):
+            continue
+        for o in operands:
+            while o in instrs and instrs[o][0] in _PASS_THROUGH \
+                    and instrs[o][1]:
+                o = instrs[o][1][0]
+            if o in instrs and instrs[o][0] == "copy" \
+                    and instrs[o][2] >= min_elements:
+                found.append((name, o))
+    return found
+
+
+def _compiled_sandwich(one_chip, monkeypatch, batch):
+    """(ENTRY instructions, names of its `fdt_gn_silu_*` calls, elements
+    of one activation) of the sandwich's gradient compiled for a v5e."""
+    import flax.linen as nn
+
+    from flaxdiff_tpu.models.common import ConvLayer, FusedGroupNormSiLU
+    from flaxdiff_tpu.ops import fused_norm
+
+    # the program asks jax for its first device (here the CPU) before it
+    # picks the kernels; the test steers it to the TPU's path
+    monkeypatch.setattr(fused_norm, "_use_pallas",
+                        lambda interpret, force_pallas: (True, False))
+
+    class Sandwich(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            x = ConvLayer("conv", 128, dtype=jnp.bfloat16)(x)
+            x = FusedGroupNormSiLU(groups=8)(x)
+            return ConvLayer("conv", 128, dtype=jnp.bfloat16)(x)
+
+    model = Sandwich()
+
+    def loss(params, x):
+        return jnp.sum(model.apply(params, x).astype(jnp.float32) ** 2)
+
+    x = jax.ShapeDtypeStruct((batch, 64, 64, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                       jnp.zeros(x.shape, x.dtype)))
+    instrs = entry_instructions(
+        jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x)
+        .compile().as_text())
+    kernels = sorted(n.rsplit(".", 1)[0] for n, (op, _, _) in instrs.items()
+                     if op == "custom-call" and n.startswith("fdt_gn_silu_"))
+    return instrs, kernels, batch * 64 * 64 * 128
+
+
+def test_training_batch_has_no_kernel_and_no_copy_beside_the_norm(
+        one_chip, monkeypatch):
+    instrs, kernels, activation = _compiled_sandwich(one_chip, monkeypatch, 32)
+    assert kernels == []
+    copies = [n for n, (op, _, size) in instrs.items()
+              if op == "copy" and size >= activation]
+    assert len(copies) <= 1, copies     # dx, in the caller's layout
+
+
+def test_small_batch_runs_the_kernels_behind_layout_copies(
+        one_chip, monkeypatch):
+    instrs, kernels, activation = _compiled_sandwich(one_chip, monkeypatch, 8)
+    assert kernels == sorted(f"fdt_gn_silu_{k}" for k in
+                             ("stats", "apply", "bwd_sums", "bwd_dx"))
+    fed = {call for call, _ in
+           copies_feeding(instrs, "fdt_gn_silu_", activation)}
+    assert len(fed) == 4, fed
