@@ -10,7 +10,7 @@ UNet (depth and step counts cut, weights random from a seed):
              an exact entry in both peak tables
   1 kernels  every Pallas entry point on a default TPU path, compiled by
              Mosaic, forward and backward, bf16, at the flagship's and
-             the bench DiT's shapes, against its XLA composition
+             two DiT widths' shapes, against its XLA composition
   2 train    train.main: DiffusionTrainer.fit, a checkpoint save and a
              --val_every sampling pass; losses finite, MFU a number
   3 sample   DiffusionInferencePipeline.from_checkpoint, DDIM, guidance
@@ -55,7 +55,7 @@ KERNEL_TOL = 8 * BF16_EPS
 _ATTN = {"heads": 8, "dim_head": 64, "backend": "auto",
          "force_fp32_for_softmax": True}
 
-# The flagship: the config bench.py's build_trainer measures (text-
+# The flagship: the benchmark's `unet-flaxdiff-128` configuration (text-
 # conditional UNet, 128^2, attention on the last two levels).
 FLAGSHIP = {
     "model_config": {
@@ -79,7 +79,7 @@ FLAGSHIP = {
     # (q tokens, kv tokens): self at both levels, cross against text
     "flash": [(1024, 1024), (256, 256), (1024, 77), (256, 77)],
     "heads": 8, "dim_head": 64,
-    # (tokens, channels): the bench's DiT and a DiT-XL-width block
+    # (tokens, channels): a 384-wide DiT and a DiT-XL-width block
     "adaln": [(256, 384), (1024, 1152)],
 }
 

@@ -225,8 +225,7 @@ class SilentExceptRule(AstRule):
            "pass`) — record a resilience event or log before "
            "swallowing")
     docs = "docs/OBSERVABILITY.md"
-    roots = ("flaxdiff_tpu", "scripts", "train.py", "bench.py",
-             "chip_smoke.py")
+    roots = ("flaxdiff_tpu", "scripts", "train.py", "chip_smoke.py")
 
     @staticmethod
     def _catches_everything(handler: ast.ExceptHandler) -> bool:

@@ -111,7 +111,7 @@ class AstRule(Rule):
     """
 
     roots: Tuple[str, ...] = ("flaxdiff_tpu", "scripts", "train.py",
-                              "bench.py", "chip_smoke.py")
+                              "chip_smoke.py")
     dirs: Tuple[str, ...] = ()
 
     def applies(self, relpath: str, scoped: bool = True) -> bool:

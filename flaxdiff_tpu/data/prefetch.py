@@ -111,7 +111,7 @@ class prefetch_to_device:
     loop. `state_dict()` exposes the in-flight window (submitted vs
     delivered vs screened) so the data plane can account for every
     batch the pipeline ever touched — the "zero stranded batches"
-    acceptance in `bench.py --data_chaos`."""
+    acceptance of tests/test_data_chaos.py."""
 
     def __init__(self, put_fn: Callable[[T], U], it: Iterator[T],
                  depth: int = 2, join_timeout: float = 5.0,

@@ -133,13 +133,13 @@ class AttentionLayer(nn.Module):
     bhld: project q/k/v straight into the flash kernel's native
     [B, H, L, D] layout — the head permutation is folded into the
     projection matmuls, so the per-operand transposes (and XLA's
-    materialized copies around the pallas custom call — ~750 copy
-    ops/step in the r3 trace) disappear. None (default) reads
-    FLAXDIFF_ATTN_BHLD at trace time so the bench can A/B without a
-    model rebuild — in MULTI-HOST runs that env var must be identical
-    on every host or the hosts compile divergent programs and hang at
-    the first collective; set it from a shared launcher (train.py
-    --attn_bhld) or pass bhld explicitly.
+    materialized copies around the pallas custom call) disappear;
+    whether the step is faster for it is not measured (ROADMAP D2).
+    None (default) reads FLAXDIFF_ATTN_BHLD at trace time, an A/B
+    without a model rebuild — in MULTI-HOST runs that env var must be
+    identical on every host or the hosts compile divergent programs and
+    hang at the first collective; set it from a shared launcher
+    (train.py --attn_bhld) or pass bhld explicitly.
     Parameters are layout-independent (same names and shapes).
     """
 

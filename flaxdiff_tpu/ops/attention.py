@@ -46,12 +46,9 @@ def attention_backend_available(backend: str = "flash") -> bool:
 def _flash_impl() -> str:
     """Which flash implementation backend="auto" uses on TPU:
     "firstparty" (ops/flash_attention.py, default) or "prebuilt" (JAX's
-    tuned TPU kernel — the one the reference calls). The flashtune bench
-    stage measures both and RECORDS the winner (best["impl"]); routing
-    production runs to it is a deliberate operator choice via this env
-    var (the bench never exports it — see export_winner_env). Read at
-    trace time, so multi-host runs must set it identically on every
-    host."""
+    tuned TPU kernel — the one the reference calls). Which is faster in
+    a step: not measured (ROADMAP D2). Read at trace time, so
+    multi-host runs must set it identically on every host."""
     import os
     return os.environ.get("FLAXDIFF_FLASH_IMPL", "firstparty")
 

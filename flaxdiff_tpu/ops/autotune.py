@@ -1,17 +1,16 @@
 """Per-shape flash-attention autotuner with a persistent JSON cache.
 
-The flash kernel's block sizes were one GLOBAL env pair
-(FLAXDIFF_FLASH_BLOCK_Q/K) chosen by the bench's flashtune stage at a
-single flagship shape — every other (seq, head_dim) the model runs
-inherited that choice, and the native-vs-padded head-dim decision was a
-second global toggle. This module makes both per-shape:
+The flash kernel's block sizes are one GLOBAL env pair
+(FLAXDIFF_FLASH_BLOCK_Q/K) — every (seq, head_dim) the model runs
+shares it — and the native-vs-padded head-dim decision is a second
+global toggle. This module makes both per-shape. Whether a probed plan
+beats the defaults in a step: not measured (ROADMAP D2, D15).
 
 - A registry keyed on ``(seq_q, seq_kv, head_dim, dtype, platform)``.
 - On first encounter (and ONLY outside jit — dispatch never probes at
   trace time), measured probes over a block-size ladder pick the
-  winner, using the same chained fwd+bwd grad harness the bench's
-  flashtune/attnpad stages time with (``chained_grad_ms``, factored out
-  of bench.py so bench and autotuner cannot drift).
+  winner, timed by the chained fwd+bwd grad harness below
+  (``chained_grad_ms``).
 - Winners persist to a JSON cache dir (the PR-5
   ``--compilation_cache_dir`` pattern): a warm cache re-measures
   NOTHING — the next process loads plans and compiles directly.
@@ -24,15 +23,15 @@ second global toggle. This module makes both per-shape:
   true head dim vs 128-padded and record which is faster.
 
 Activation: ``activate(cache_dir)`` in-process, or the
-``FLAXDIFF_FLASH_TUNE_CACHE`` env var (how bench stage subprocesses
-inherit the tuned cache). When inactive, dispatch behavior is exactly
+``FLAXDIFF_FLASH_TUNE_CACHE`` env var (a child process inherits the
+tuned cache through it). When inactive, dispatch behavior is exactly
 the pre-autotuner env/default path.
 
 Trace-time contract: ``ops/attention.py`` calls ``dispatch_plan`` while
 TRACING a jitted model. That call is a pure dict lookup (plus an
 observed-shape set add) — probing runs only from ``probe_pending()``,
 which callers invoke eagerly (trainer ``autotune_flash`` via a
-``jax.eval_shape`` scouting pass; the bench's flashtune stage).
+``jax.eval_shape`` scouting pass).
 """
 from __future__ import annotations
 
@@ -47,14 +46,15 @@ log = logging.getLogger("flaxdiff_tpu.autotune")
 
 LANES = 128
 
-# the flashtune ladder (bench.py): small blocks lose to per-program
-# overhead, 512x1024 is jax's own TPU kernel default
+# the candidate ladder, smallest to largest; 512x1024 is jax's own TPU
+# kernel default and this kernel's (DEFAULT_BLOCK_Q/K). Not measured
+# against each other on the chip (ROADMAP D2).
 DEFAULT_LADDER = ((128, 128), (256, 512), (512, 512), (512, 1024),
                   (1024, 1024))
 
 # probe operand sizing: batch*heads large enough that the grid's
 # parallel dimensions hide per-program latency differences the real
-# models would also hide (the flagship attnpad shape is 8x1024x8x64)
+# models would also hide
 PROBE_BATCH = 4
 PROBE_HEADS = 8
 PROBE_ITERS = 20
@@ -81,11 +81,8 @@ def chained_grad_ms(grad_fn: Callable, q0, k, v,
     """Time one attention fwd+bwd via jit(grad): compile+sync first,
     then `iters` steps with each iteration's dq fed into the next q (so
     no execution can be elided), synced by a scalar readback of the
-    last dq — a completion barrier by data dependence, the same one
-    bench.py's run() uses (measured equal to block_until_ready on a
-    v5e; see run()). `grad_fn(q, k, v) -> dq`. Shared by the bench's
-    flashtune/attnpad stages and the autotuner probes so the harness
-    cannot drift between them."""
+    last dq — a completion barrier by data dependence.
+    `grad_fn(q, k, v) -> dq`. The autotuner's probe."""
     import jax
     qi = q0
     float(jax.device_get(grad_fn(qi, k, v).sum()))   # compile + sync
@@ -321,22 +318,6 @@ class FlashAutotuner:
             out[key] = self.probe(sq, skv, d, dt)
         return out
 
-    def record(self, seq_q: int, seq_kv: int, head_dim: int, dtype: str,
-               block_q: int, block_k: int, native_d: int,
-               ms: Optional[float] = None,
-               probed_ms: Optional[Dict[str, float]] = None) -> None:
-        """Insert an externally-measured winner (the bench's flashtune
-        stage feeds its ladder results here so the cache and the
-        BENCH json stay one source of truth)."""
-        key = shape_key(seq_q, seq_kv, head_dim, dtype, self.platform)
-        self._plans[key] = {
-            "seq_q": seq_q, "seq_kv": seq_kv, "head_dim": head_dim,
-            "dtype": dtype, "block_q": int(block_q),
-            "block_k": int(block_k), "native_d": int(native_d),
-            "ms": ms, "probed_ms": probed_ms or {},
-        }
-        self._observed.pop(key, None)
-
     def plans(self) -> Dict[str, Dict]:
         return dict(self._plans)
 
@@ -368,8 +349,8 @@ def deactivate() -> None:
 
 def active() -> Optional[FlashAutotuner]:
     """The installed autotuner, auto-activating from
-    FLAXDIFF_FLASH_TUNE_CACHE on first use (bench stage subprocesses
-    inherit the cache through the env)."""
+    FLAXDIFF_FLASH_TUNE_CACHE on first use (a child process inherits
+    the cache through the env)."""
     global _ACTIVE, _ENV_CHECKED
     if _ACTIVE is None and not _ENV_CHECKED:
         _ENV_CHECKED = True

@@ -101,7 +101,7 @@ class CachePlan:
 
 
 # the serving layer's per-request default when a request asks for
-# caching without a specific plan; also the bench stage's headline plan
+# caching without a specific plan
 DEFAULT_CACHE_PLAN = CachePlan()
 
 

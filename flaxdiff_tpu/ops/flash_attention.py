@@ -40,8 +40,8 @@ NEG_INF = -1e30
 LANES = 128
 
 # Test hook: interpret mode normally shrinks the lane-replicated scratch
-# to width 1, which skips the lane resize paths real TPU hits (the d<128
-# native-head-dim bug the r3 bench's attnpad stage caught lived there).
+# to width 1, which skips the lane resize paths real TPU hits (a d<128
+# native-head-dim bug lived there).
 # Tests set this to LANES to run interpret with the hardware layout.
 _FORCE_LANES: Optional[int] = None
 

@@ -25,13 +25,14 @@ same lever ops/fused_norm.py pulled for the resblock prologue:
   never round-trips through a split.
 
 All three share the fused_norm dispatch conventions:
-``FLAXDIFF_FUSED_ADALN=xla`` forces the XLA composition (the ablate
-A/B), ``=interpret`` runs the real kernels through the Pallas
-interpreter on CPU, ``FLAXDIFF_FUSED_ADALN_BWD=xla`` swaps only the
-backward for recompute-through-autodiff. Off-TPU with no env set the
-wrappers return the exact XLA composition (and the model layers don't
-even call them — see ``fused_adaln_active``), so CPU outputs are
-bit-identical to the unfused code path.
+``FLAXDIFF_FUSED_ADALN=xla`` forces the XLA composition,
+``=interpret`` runs the real kernels through the Pallas interpreter on
+CPU, ``FLAXDIFF_FUSED_ADALN_BWD=xla`` swaps only the backward for
+recompute-through-autodiff; neither A/B is measured on the chip
+(ROADMAP D2). Off-TPU with no env set the wrappers return the exact XLA
+composition (and the model layers don't even call them — see
+``fused_adaln_active``), so CPU outputs are bit-identical to the
+unfused code path.
 
 Numerics: all norm/softening math is f32 regardless of input dtype;
 modulated outputs follow jnp promotion (f32 norm x bf16 scale -> f32),

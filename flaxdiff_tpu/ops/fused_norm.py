@@ -14,8 +14,7 @@ of any spatial size stream through VMEM in blocks:
 Backward (r5): dedicated Pallas kernels reusing the forward's saved
 per-group stats — one stats pass over (x, g) producing the dx correction
 terms and dscale/dbias partials, an O(B*G + C) XLA finalize, then the dx
-pass (FLAXDIFF_FUSED_NORM_BWD=xla restores the recompute-through-XLA
-backward for A/B). Falls back to XLA off-TPU.
+pass. Falls back to XLA off-TPU.
 
 The kernels are for small batches only (`_batch_fills_sublanes`): once
 the batch fills a sublane tile, XLA's TPU convolutions keep a
@@ -59,10 +58,10 @@ def _fused_norm_interpret() -> bool:
 
 def _use_pallas(interpret: bool, force_pallas: bool):
     """(run_pallas, interpret): Pallas on TPU or under the interpreter,
-    the XLA composition elsewhere. FLAXDIFF_FUSED_NORM=xla is the A/B
-    escape hatch the bench's ablate stage uses to measure whether the
-    fused kernel pays for the layout copies around its custom calls
-    in-context on real hardware."""
+    the XLA composition elsewhere. FLAXDIFF_FUSED_NORM=xla takes the
+    composition at every shape: the A/B hatch PR 28 read on the chip
+    (docs/KERNELS.md), which is why `_batch_fills_sublanes` now picks
+    the composition at a training batch."""
     if _fused_norm_interpret():
         interpret = True
     if force_pallas:
@@ -379,12 +378,10 @@ def _gn_bwd(groups, eps, apply_silu, interpret, force_pallas, res, g):
     # Pallas-path backward: dedicated tiled kernels reusing the saved
     # per-group stats (VERDICT r4 #3) — two passes over (x, g) instead
     # of XLA re-deriving the whole forward chain (which recomputes the
-    # statistics reduction as well). FLAXDIFF_FUSED_NORM_BWD=xla is the
-    # A/B escape hatch mirroring FLAXDIFF_FUSED_NORM. XLA-path forwards
-    # (no saved stats) keep the recompute-through-autodiff backward.
+    # statistics reduction as well). XLA-path forwards (no saved stats)
+    # keep the recompute-through-autodiff backward.
     x, scale, bias, mean_c, rstd_c = res
-    if (mean_c is not None
-            and os.environ.get("FLAXDIFF_FUSED_NORM_BWD") != "xla"):
+    if mean_c is not None:
         # the env interpret hook must reach the backward too — a fwd
         # that ran interpreted would otherwise hand Mosaic a CPU build
         if _fused_norm_interpret():

@@ -2,11 +2,11 @@
 
 The reference calls this exact kernel
 (reference flaxdiff/models/attention.py:14-17,100-102); our first-party
-kernel (ops/flash_attention.py) replaces it. VERDICT r4 #2 requires the
-head-to-head comparison on record — this wrapper makes the prebuilt
-kernel a dispatchable backend ("prebuilt") so the flashtune harness can
-time both through an identical code path, and so dispatch can route to
-whichever kernel measures faster (FLAXDIFF_FLASH_IMPL=prebuilt).
+kernel (ops/flash_attention.py) replaces it. This wrapper makes the
+prebuilt kernel a dispatchable backend ("prebuilt") so both run through
+an identical code path and dispatch can route to either
+(FLAXDIFF_FLASH_IMPL=prebuilt). Which is faster in a step: not measured
+(ROADMAP D2).
 
 Layout: the prebuilt kernel grids over [batch, heads, seq, head_dim]
 (BHLD). Sequence lengths must divide the block sizes, so both are padded

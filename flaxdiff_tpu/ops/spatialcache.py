@@ -158,14 +158,13 @@ class ComposedPlan:
 
 
 # the serving layer's default when a request asks for composed caching
-# without a specific plan; also the bench diffcache stage's headline
-# composed plan. The spatial axis buys a much sparser full-refresh
-# cadence than the pure-timestep default can afford: between full
-# refreshes, every other cached step re-runs the deep trunk on the
-# top-1/8 highest-change tokens, the rest reuse. Measured on the
-# bench stage (DDIM-50, 12-layer DiT, 32², CPU): 2.72x device speedup
-# at 76.5 dB trajectory PSNR vs the pure-timestep default's 1.99x at
-# 83.6 dB (docs/CACHING.md trade-off table).
+# without a specific plan. The spatial axis buys a much sparser
+# full-refresh cadence than the pure-timestep default can afford:
+# between full refreshes, every other cached step re-runs the deep
+# trunk on the top-1/8 highest-change tokens, the rest reuse
+# (trajectory PSNR against the uncached run: docs/CACHING.md trade-off
+# table). Speed: not measured on a chip (cell
+# `dit-xl-2.generate-cached`, PERF.md section 7).
 DEFAULT_SPATIAL_PLAN = SpatialPlan(keep_fraction=0.125, every=2)
 DEFAULT_COMPOSED_PLAN = ComposedPlan(
     cache=CachePlan(refresh_every=16, depth_fraction=0.2,

@@ -25,9 +25,10 @@ The closed loop the static analyzer (PR 14) and device-time attribution
      (`comm_achieved_bytes_per_s`) when such rows are supplied — the
      ranking then trusts measured bandwidth, not raw byte counts.
   3. PROBES the top-k shortlist with short measured runs through an
-     injectable `probe_fn` (the bench `plan` stage feeds the real
-     `DiffusionTrainer` dispatch harness; tests feed counting mocks —
-     the PR-7 autotuner mold), persisting the decision in an
+     injectable `probe_fn` (tests feed counting mocks — the PR-7
+     autotuner mold; no caller in the repo feeds a measured one, and
+     a probed plan against the hand-written mesh is not measured on a
+     chip: ROADMAP queue 1 item 7), persisting the decision in an
      atomic-JSON cache keyed on model-shape-signature x topology x
      hardware fingerprint. A warm cache performs ZERO probes.
   4. COMMITS the decision to the program evidence registry
@@ -636,8 +637,7 @@ class ParallelPlanner:
 
     `probe_fn(evaluated: EvaluatedPlan) -> ms` is injectable so unit
     tests can count probes with a mock (the autotuner mold —
-    `self.probe_count` is the counting contract); the bench `plan`
-    stage feeds the real `DiffusionTrainer` dispatch harness. A probe
+    `self.probe_count` is the counting contract). A probe
     that raises simply loses (its candidate keeps only its static
     rank); when NO probe succeeds the static rank-1 survivor wins."""
 
@@ -863,8 +863,9 @@ def resolve_plan(plan: Union[str, PlanDecision], tree, *,
                  planner: Optional[ParallelPlanner] = None,
                  **plan_kwargs) -> PlanDecision:
     """The consumer seam: `"auto"` runs a static search (cache dir from
-    $FLAXDIFF_PLAN_CACHE; no probes — measured probing is the bench
-    `plan` stage's job), a `PlanDecision` passes through. Either way
+    $FLAXDIFF_PLAN_CACHE; no probes: a caller that wants measured
+    probing builds its own `ParallelPlanner(probe_fn=...)`), a
+    `PlanDecision` passes through. Either way
     the decision is committed to `telemetry.programs` when the hub
     carries a registry."""
     if isinstance(plan, PlanDecision):

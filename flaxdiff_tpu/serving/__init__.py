@@ -19,8 +19,8 @@
                  cross-replica attempt budget, hedged retries, pool-
                  wide admission + brownout — docs/SERVING.md "Front
                  door"
-    loadgen      seeded Poisson workload build + replay (bench.py
-                 serve), plus the multi-tenant open-loop harness for
+    loadgen      seeded Poisson workload build + replay, plus the
+                 multi-tenant open-loop harness for
                  the front door (diurnal-ramp/burst shapes, per-tenant
                  SLO attainment)
 

@@ -49,7 +49,7 @@ burning its delivery objective ranks behind peers in its health class).
 The chaos site `serving.replica_lost` (resilience/faults.py) is polled
 once per replica per submission with key="replica:<name>:"; a firing
 kills that replica mid-traffic — the deterministic lever the pool
-chaos suite and `bench.py serve --serve_pool` pull.
+chaos suite (tests/test_frontdoor_chaos.py) pulls.
 
 Sync-free contract: this file performs NO host synchronization and
 never imports jax — routing, failover, and hedging are pure host
@@ -770,7 +770,7 @@ def build_pool(pipelines: Sequence[Any], scheduler_config=None,
     each with its own scheduler (and its own telemetry hub when
     `telemetries` is given — per-replica hubs keep program-cache and
     retrace counters attributable per replica, which the pool chaos
-    bench relies on)."""
+    suite relies on)."""
     from .scheduler import ServingScheduler
     replicas = []
     for i, pipe in enumerate(pipelines):

@@ -2,9 +2,9 @@
 
 One seeded `numpy` Generator drives everything — inter-arrival gaps
 (exponential), template choice, and per-request seeds — so a spec
-builds the *identical* workload every time: the `bench.py serve` stage
-replays the same list twice to prove the warm program cache re-traces
-nothing, and tests assert replay determinism outright.
+builds the *identical* workload every time: tests/test_serving.py
+replays it against the scheduler and asserts replay determinism
+outright.
 
 Two harnesses share that determinism contract:
 
@@ -125,9 +125,8 @@ def replay(scheduler, workload: List[Tuple[float, SampleRequest]],
         if results else None,
         "device_ms_mean": float(np.mean([r.device_ms for r in results]))
         if results else None,
-        # NFE-normalized device cost: the serving-side analogue of the
-        # bench diffcache stage's per-step number — a cached replay of
-        # the same workload should drop this, same stage that guards it
+        # NFE-normalized device cost: a cached replay of the same
+        # workload should drop this
         "device_ms_per_step_mean": float(np.mean(
             [r.device_ms / max(1, r.request.diffusion_steps)
              for r in results])) if results else None,

@@ -44,10 +44,10 @@ without one, XLA op events carrying an `hlo_op` arg (the CPU backend's
 (`host_xla`); neither means the window closed before any compiled work
 ran (`host_only`).
 
-No module-level jax import: readers (`compare_runs`, the bench
-orchestrator) must be able to load rows without a backend. Profiler
-start/stop imports jax lazily and degrades with a `trace_failed`
-resilience event, same contract as `profiling.trace`.
+No module-level jax import: readers (`scripts/compare_runs.py`,
+`scripts/diagnose_run.py`) must be able to load rows without a
+backend. Profiler start/stop imports jax lazily and degrades with a
+`trace_failed` resilience event, same contract as `profiling.trace`.
 """
 from __future__ import annotations
 

@@ -65,8 +65,8 @@ PROGRAMS_FILENAME = "programs.jsonl"
 def hardware_fingerprint() -> Dict[str, Any]:
     """Platform identity for evidence comparability: two runs whose
     fingerprints differ are different experiments, not a regression
-    (`scripts/compare_runs.py` enforces this). Lazy jax import so the
-    bench orchestrator can stamp results without a backend."""
+    (`scripts/compare_runs.py` enforces this). Lazy jax import: a
+    process with no backend still gets a fingerprint ("unknown")."""
     out: Dict[str, Any] = {}
     try:
         import jax

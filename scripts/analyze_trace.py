@@ -13,9 +13,9 @@ old script silently lacked: truncated/corrupt captures are REPORTED
 instead of being conflated with "no data".
 
 Usage:
-    python scripts/analyze_trace.py bench_trace
+    python scripts/analyze_trace.py path/to/trace_dir
     python scripts/analyze_trace.py path/to/vm.trace.json.gz --steps 5
-    python scripts/analyze_trace.py bench_trace --top 30 --raw
+    python scripts/analyze_trace.py path/to/trace_dir --top 30 --raw
 
 `--steps N` divides totals by N (pass the number of steps captured in
 the trace window) so numbers read as ms/step. `--raw` lists individual

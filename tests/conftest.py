@@ -16,8 +16,8 @@ if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
         flags + " --xla_force_host_platform_device_count=8").strip()
 
-# No persistent compile cache inside the test process: train.main and the
-# bench CLIs turn it on (<checkout>/.jax_cache), and entries surviving
+# No persistent compile cache inside the test process: train.main and
+# chip_smoke.py turn it on (<checkout>/.jax_cache), and entries surviving
 # from an earlier run would make every "cold compile" a test times warm.
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 

@@ -99,7 +99,7 @@ def test_flash_bh_interpret_parity():
 
 
 def test_bhld_env_toggle(monkeypatch):
-    """bhld=None reads FLAXDIFF_ATTN_BHLD (the bench A/B knob)."""
+    """bhld=None reads FLAXDIFF_ATTN_BHLD (the A/B knob)."""
     x = jnp.ones((1, 16, 8))
     layer = AttentionLayer(heads=2, dim_head=4, backend="xla")
     params = layer.init(jax.random.PRNGKey(0), x)["params"]

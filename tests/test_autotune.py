@@ -3,8 +3,7 @@ warm-cache zero-probe contract, env-override precedence, persistence
 robustness, and the trainer's eval-shape scouting pass.
 
 Probes use counting mocks throughout — no kernel is ever measured here
-(CPU CI); the measured probe path is exercised on hardware by the
-bench's flashtune stage."""
+(CPU CI); the measured probe path has not run on a chip (ROADMAP D15)."""
 import json
 import os
 
@@ -147,7 +146,7 @@ def test_dispatch_plan_precedence(tmp_path):
 
 
 def test_env_cache_dir_auto_activates(tmp_path, monkeypatch):
-    """Bench stage subprocesses inherit the tuned cache through
+    """A child process inherits the tuned cache through
     FLAXDIFF_FLASH_TUNE_CACHE."""
     calls = []
     # platform must match what the env-activated registry detects on
@@ -165,19 +164,6 @@ def test_env_cache_dir_auto_activates(tmp_path, monkeypatch):
         assert plan.source == "cache" and plan.block_q == 512
     finally:
         at.deactivate()
-
-
-def test_record_roundtrips_through_cache(tmp_path):
-    """The bench's flashtune stage feeds externally-measured winners in
-    through record(); a fresh registry must read them back."""
-    aut = at.FlashAutotuner(cache_dir=str(tmp_path), platform="tpu")
-    aut.record(1024, 1024, 64, "bfloat16", block_q=512, block_k=1024,
-               native_d=1, ms=5.43, probed_ms={"512x1024": 5.59})
-    aut.save()
-    warm = at.FlashAutotuner(cache_dir=str(tmp_path), platform="tpu")
-    plan = warm.get_plan(1024, 1024, 64, "bfloat16")
-    assert (plan.block_q, plan.block_k, plan.native_d) == (512, 1024, 1)
-    assert plan.ms == 5.43
 
 
 def test_trainer_autotune_flash_scouts_and_probes(tmp_path, mesh,

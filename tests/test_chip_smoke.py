@@ -1,7 +1,7 @@
 """chip_smoke.py (the on-chip bring-up proof) as far as a CPU can check
 it: the rehearsal mode end to end, the refusal to run without a chip,
-and the compile-cache placement rule the smoke, train.py and every
-bench stage child share (flaxdiff_tpu/utils.py)."""
+and the compile-cache placement rule the smoke and train.py share
+(flaxdiff_tpu/utils.py)."""
 import json
 import os
 import subprocess

@@ -149,14 +149,16 @@ def test_rollback_seek_replays_bit_identical(tmp_path):
     assert plane.rewinds == 1
 
 
-def test_trainer_rollback_rewinds_data_plane_bit_identical(
-        mesh, tmp_path, rng):
-    """The end-to-end acceptance scenario (tests what `bench.py
-    --data_chaos` measures): a step.nan fault mid-fit triggers an
-    anomaly rollback; with a DataPlane wired into fit(), the upload
-    pipeline is torn down, the stream rewound, and every re-served
-    batch is bit-identical to the uninterrupted reference — while the
-    quarantine journal accounts for the injected corruption."""
+SEAMS = ("_block_until_ready", "_fetch_losses", "_fetch_ring",
+         "_fetch_gate_events")
+
+
+def _rollback_fit(mesh, tmp_path, shard, with_plane):
+    """A 10-step fit of a tiny model over `shard` with a step.nan fault
+    at step 5 forcing an anomaly rollback, the trainer's four sync
+    seams counted. With `with_plane` the batches come through a
+    recording DataPlane; without, straight from the same generator (the
+    control the plane's sync count is held against)."""
     import flax.linen as nn
     import jax.numpy as jnp
     import optax
@@ -165,13 +167,7 @@ def test_trainer_rollback_rewinds_data_plane_bit_identical(
     from flaxdiff_tpu.schedulers import CosineNoiseSchedule
     from flaxdiff_tpu.trainer import (Checkpointer, DiffusionTrainer,
                                       TrainerConfig)
-
-    corrupt = {3, 12}
-    shard = _write_shard(tmp_path / "t.pr", corrupt=corrupt)
-    # batch=8: the mesh fixture shards batch dim over data*fsdp = 8 ways
-    reference = [batch_digest(b) for _, b in
-                 zip(range(32),
-                     _factory(shard, QuarantineJournal(), batch=8)(0))]
+    from flaxdiff_tpu.trainer import trainer as trainer_mod
 
     served = []
     journal = QuarantineJournal()
@@ -183,8 +179,10 @@ def test_trainer_rollback_rewinds_data_plane_bit_identical(
             served.append((idx, self._digests[idx]))
             return b
 
-    plane = RecordingPlane(_factory(shard, journal, batch=8), seed=0,
-                           journal=journal)
+    # batch=8: the mesh fixture shards batch dim over data*fsdp = 8 ways
+    factory = _factory(shard, journal, batch=8)
+    plane = (RecordingPlane(factory, seed=0, journal=journal)
+             if with_plane else None)
 
     class Tiny(nn.Module):
         @nn.compact
@@ -201,24 +199,78 @@ def test_trainer_rollback_rewinds_data_plane_bit_identical(
         return model.init(key, jnp.zeros((1, SIZE, SIZE, 3)),
                           jnp.zeros((1,)))["params"]
 
+    counts = dict.fromkeys(SEAMS, 0)
+    real = {s: getattr(trainer_mod, s) for s in SEAMS}
+
+    def counted(name):
+        def inner(*a, **k):
+            counts[name] += 1
+            return real[name](*a, **k)
+        return inner
+
     ev = R.EventLog("chaos")
     plan = R.FaultPlan(
         [R.FaultSpec("step.nan", at=(5,), error="flag", times=1)])
-    with R.use_event_log(ev), plan.installed():
-        trainer = DiffusionTrainer(
-            apply_fn=apply_fn, init_fn=init_fn, tx=optax.adam(1e-3),
-            schedule=CosineNoiseSchedule(timesteps=100),
-            transform=EpsilonPredictionTransform(), mesh=mesh,
-            config=TrainerConfig(normalize=False, log_every=2),
-            checkpointer=Checkpointer(str(tmp_path / "ck"), event_log=ev,
-                                      use_ledger=True))
-        hist = trainer.fit(None, total_steps=10, save_every=4,
-                           data_plane=plane)
-        trainer.checkpointer.wait_until_finished()
-        ledger = trainer.checkpointer.ledger
-        trainer.checkpointer.close()
+    with pytest.MonkeyPatch.context() as mp:
+        for s in SEAMS:
+            mp.setattr(trainer_mod, s, counted(s))
+        with R.use_event_log(ev), plan.installed():
+            trainer = DiffusionTrainer(
+                apply_fn=apply_fn, init_fn=init_fn, tx=optax.adam(1e-3),
+                schedule=CosineNoiseSchedule(timesteps=100),
+                transform=EpsilonPredictionTransform(), mesh=mesh,
+                config=TrainerConfig(normalize=False, log_every=2),
+                checkpointer=Checkpointer(
+                    str(tmp_path / ("ck_plane" if with_plane
+                                    else "ck_ctrl")),
+                    event_log=ev, use_ledger=True))
+            hist = trainer.fit(None if with_plane else factory(0),
+                               total_steps=10, save_every=4,
+                               data_plane=plane)
+            trainer.checkpointer.wait_until_finished()
+            ledger = trainer.checkpointer.ledger
+            trainer.checkpointer.close()
+    return {"hist": hist, "served": served, "journal": journal,
+            "plane": plane, "ledger": ledger, "counts": counts,
+            "rollbacks": ev.count("rollback", "train.step")}
 
-    assert ev.count("rollback", "train.step") == 1
+
+CORRUPT = {3, 12}
+
+
+@pytest.fixture(scope="module")
+def chaos_shard(tmp_path_factory):
+    return _write_shard(tmp_path_factory.mktemp("shard") / "t.pr",
+                        corrupt=CORRUPT)
+
+
+@pytest.fixture(scope="module")
+def chaos_run(mesh, chaos_shard, tmp_path_factory):
+    """The fit through the DataPlane, once for both tests below."""
+    return _rollback_fit(mesh, tmp_path_factory.mktemp("plane"),
+                         chaos_shard, with_plane=True)
+
+
+def test_trainer_rollback_rewinds_data_plane_bit_identical(
+        chaos_run, chaos_shard):
+    """The end-to-end acceptance scenario: a step.nan fault mid-fit
+    triggers an anomaly rollback; with a DataPlane wired into fit(),
+    the upload pipeline is torn down, the stream rewound, and every
+    re-served batch is bit-identical to the uninterrupted reference —
+    while the quarantine journal accounts for the injected corruption
+    and no prefetch worker outlives the fit."""
+    import threading
+
+    reference = [batch_digest(b) for _, b in
+                 zip(range(32),
+                     _factory(chaos_shard, QuarantineJournal(),
+                              batch=8)(0))]
+
+    run = chaos_run
+    hist, served, journal = run["hist"], run["served"], run["journal"]
+    plane, ledger = run["plane"], run["ledger"]
+
+    assert run["rollbacks"] == 1
     assert np.isfinite(hist["final_loss"])
     # every served batch — including re-served post-rollback ones —
     # matches the uninterrupted reference at its index
@@ -232,9 +284,13 @@ def test_trainer_rollback_rewinds_data_plane_bit_identical(
     # prefetcher teardown/rebuild
     idxs = sorted(counts)
     assert idxs == list(range(len(idxs)))
+    # ...and neither the torn-down prefetcher's worker nor its
+    # replacement's is still alive
+    assert not [t.name for t in threading.enumerate()
+                if t.is_alive() and "flaxdiff-put-batch" in t.name]
     # quarantine accounts for every injected corruption
     assert sorted(int(e["key"].split(":")[1])
-                  for e in journal.entries()) == sorted(corrupt)
+                  for e in journal.entries()) == sorted(CORRUPT)
     # data-plane state was committed beside the model checkpoints, and
     # the committed cursor equals a committed MODEL step (the state step
     # counter rewinds with the restore, so the post-rollback save lands
@@ -244,3 +300,17 @@ def test_trainer_rollback_rewinds_data_plane_bit_identical(
     assert state is not None and state["cursor"] in (4, 6, 8)
     assert {e["key"] for e in state["journal"]["entries"]} == \
         {e["key"] for e in journal.entries()}
+
+
+def test_data_plane_adds_no_host_sync_over_a_control_fit(
+        mesh, tmp_path, chaos_run, chaos_shard):
+    """docs/DATA.md "Zero host syncs": through the same fault and the
+    same rollback, a fit fed by the DataPlane calls each of the
+    trainer's four sync seams exactly as often as a fit fed by the bare
+    generator."""
+    chaos = chaos_run
+    control = _rollback_fit(mesh, tmp_path, chaos_shard, with_plane=False)
+    assert chaos["rollbacks"] == control["rollbacks"] == 1
+    assert chaos["plane"].rewinds >= 1
+    assert chaos["counts"] == control["counts"]
+    assert chaos["counts"]["_fetch_losses"] > 0     # the seams counted

@@ -467,6 +467,31 @@ def test_door_traces_and_health_timeline(tmp_path):
                for h in health)
 
 
+def test_replica_kill_dumps_an_incident_bundle_on_an_enabled_hub(tmp_path):
+    """A door hub built by `Telemetry.create` has its flight recorder
+    on the resilience event log: the fault site killing r0 mid-traffic
+    leaves an `incident-*replica_lost*.json` bundle in the hub's
+    directory, and every request still resolves."""
+    from flaxdiff_tpu.telemetry import list_incidents
+    ev = R.EventLog("pool-chaos")
+    with R.use_event_log(ev):
+        tel = Telemetry.create(str(tmp_path))
+        (r0, _), (r1, _) = (_replica("r0", tel, delay=0.1),
+                            _replica("r1", tel, delay=0.1))
+        door = _door([r0, r1], tel)
+        plan = R.FaultPlan([R.FaultSpec("serving.replica_lost",
+                                        per_key=True, match="replica:r0:",
+                                        at=(2,), error="flag")], seed=0)
+        with plan.installed():
+            outs = [f.result(timeout=60)
+                    for f in [door.submit(r) for r in _reqs(4)]]
+        door.close()
+        snap = tel.registry.snapshot()
+        tel.close()
+    assert len(outs) == 4 and snap["frontdoor/replica_lost"] == 1
+    assert any("replica_lost" in p for p in list_incidents(str(tmp_path)))
+
+
 # ---------------------------------------------------------------------------
 # real-engine acceptance: failover bit-identity + survivor zero-retrace
 # ---------------------------------------------------------------------------

@@ -77,8 +77,8 @@ def test_flash_attention_native_head_dim_hw_lanes(monkeypatch):
     """Native sub-128 head_dim with the HARDWARE 128-lane scratch layout
     (interpret mode normally shrinks lanes to 1, which is why the
     (128, 64)x(128, 0) broadcast bug in _bcast only surfaced on a real
-    chip — the r3 bench attnpad stage caught it). Forward and all grads
-    vs XLA at d=64 with full-width lane-replicated scratch."""
+    chip). Forward and all grads vs XLA at d=64 with full-width
+    lane-replicated scratch."""
     from flaxdiff_tpu.ops import flash_attention as fa
     monkeypatch.setattr(fa, "_FORCE_LANES", fa.LANES)
     key = jax.random.PRNGKey(7)
@@ -103,10 +103,10 @@ def test_flash_attention_native_head_dim_hw_lanes(monkeypatch):
     # sublane-minimum head dim, default sequence-capped blocks
     (8, 256, 256, "float32", None, None),
     # the flagship native shape (d=64) as CROSS-attention with a masked
-    # kv tail, bf16 — the exact dtype the bench's attnpad stage times
+    # kv tail, bf16 — the dtype the models run
     (64, 256, 77, "bfloat16", None, None),
-    # d=64 self-attention at the DEFAULT 512x1024 blocks the r3 attnpad
-    # failure ran with (multi-block q at a padded tail)
+    # d=64 self-attention at the DEFAULT 512x1024 blocks that
+    # lowering failure ran with (multi-block q at a padded tail)
     (64, 300, 300, "float32", 128, 256),
 ])
 def test_flash_attention_native_d_matrix(monkeypatch, d, lq, lk, dtype,
@@ -233,18 +233,17 @@ def test_fused_groupnorm_pallas_backward_multiblock(monkeypatch):
     scale = jnp.ones((16,)) * 1.3
     bias = jnp.ones((16,)) * 0.2
 
-    def loss(impl_env, x):
-        import os
-        os.environ["FLAXDIFF_FUSED_NORM_BWD"] = impl_env
-        try:
-            return jnp.sum(fn.fused_groupnorm_silu(
-                x, scale, bias, groups=4, interpret=True,
-                force_pallas=True) ** 3)
-        finally:
-            os.environ.pop("FLAXDIFF_FUSED_NORM_BWD", None)
+    def loss_pallas(x):
+        return jnp.sum(fn.fused_groupnorm_silu(
+            x, scale, bias, groups=4, interpret=True,
+            force_pallas=True) ** 3)
 
-    g_pallas = jax.grad(lambda x: loss("pallas", x))(x)
-    g_xla = jax.grad(lambda x: loss("xla", x))(x)
+    def loss_xla(x):
+        return jnp.sum(fn._xla_groupnorm_silu(
+            x, scale, bias, 4, 1e-6, True) ** 3)
+
+    g_pallas = jax.grad(loss_pallas)(x)
+    g_xla = jax.grad(loss_xla)(x)
     np.testing.assert_allclose(np.asarray(g_pallas), np.asarray(g_xla),
                                rtol=2e-3, atol=2e-3)
 

@@ -222,16 +222,54 @@ def test_backpressure_bounds_inflight_dispatch(mesh, rng, monkeypatch):
     assert hub.counter("pipeline/backpressure_waits").value == 8
 
 
-def test_healthy_cpu_pipeline_never_backpressures(mesh, rng):
-    """On the (near-synchronous) CPU backend the non-blocking readiness
-    check finds the oldest step settled — the bound costs a host query,
-    not a wait."""
+def test_healthy_cpu_pipeline_never_backpressures(mesh, rng, monkeypatch):
+    """When the non-blocking readiness check finds the oldest step
+    settled (forced via the _is_ready seam, so a loaded host cannot
+    say otherwise), the bound costs a host query, not a wait: no
+    back-pressure is counted and nothing blocks."""
+    block = _Counting(trainer_mod._block_until_ready)
+    monkeypatch.setattr(trainer_mod, "_block_until_ready", block)
+    monkeypatch.setattr(trainer_mod, "_is_ready", lambda x: True)
     hub = T.Telemetry(enabled=False)
     with T.use_telemetry(hub):
         trainer = _make_trainer(mesh, log_every=5, pipeline_depth=2)
         hist = trainer.fit(_data(rng), total_steps=10)
     assert np.isfinite(hist["final_loss"])
     assert hub.counter("pipeline/backpressure_waits").value == 0
+    assert block.calls == 0
+
+
+@pytest.mark.parametrize("depth", [1, 2, 4])
+@pytest.mark.parametrize("policy,sample_every,blocks", [
+    ("tel_off", 1, 0),          # nothing timed: no step closes dispatch
+    ("tel_on_s1", 1, 16),       # exact phases: every step does
+    ("tel_on_s8", 8, 3),        # sampled: steps 1 (compile), 8 and 16
+])
+def test_sync_schedule_at_every_depth_and_sampling_policy(
+        mesh, rng, tmp_path, monkeypatch, depth, policy, sample_every,
+        blocks):
+    """What makes sampled telemetry nearly free in `fit`, as counts: at
+    pipeline depth 1, 2 and 4 a step closes dispatch only where the
+    telemetry policy samples it, one loss fetch a log window, and with
+    the device keeping up (the _is_ready seam) the depth bound itself
+    never waits."""
+    block = _Counting(trainer_mod._block_until_ready)
+    fetch = _Counting(trainer_mod._fetch_losses)
+    monkeypatch.setattr(trainer_mod, "_block_until_ready", block)
+    monkeypatch.setattr(trainer_mod, "_fetch_losses", fetch)
+    monkeypatch.setattr(trainer_mod, "_is_ready", lambda x: True)
+    tel = (T.Telemetry(enabled=False) if policy == "tel_off"
+           else T.Telemetry.create(str(tmp_path / "tel")))
+    with T.use_telemetry(tel):
+        trainer = _make_trainer(mesh, telemetry=tel, log_every=8,
+                                telemetry_sample_every=sample_every,
+                                pipeline_depth=depth)
+        hist = trainer.fit(_data(rng), total_steps=16)
+    tel.close()
+    assert np.isfinite(hist["final_loss"])
+    assert block.calls == blocks
+    assert fetch.calls == 2
+    assert tel.counter("pipeline/backpressure_waits").value == 0
 
 
 # -- sampled timer + goodput window semantics ---------------------------------

@@ -697,7 +697,7 @@ def _warm_walk(engine, buckets, round_steps, nfes):
 def test_warm_cache_never_retraces(tiny_pipe):
     """Repeat traffic of identical request shapes must be served
     entirely from the compiled-program cache: zero misses on the
-    second pass (the bench stage asserts the same end to end)."""
+    second pass."""
     tel = Telemetry(enabled=False)
     sched = ServingScheduler(
         pipeline=tiny_pipe, telemetry=tel, autostart=False,

@@ -1,8 +1,7 @@
-"""Dev-tooling coverage: trace analyzer + bench stage CPU guards."""
-import argparse
+"""Dev-tooling coverage: trace analyzer, the SFC demo, the graph-hygiene
+analyzer and the run-comparison and diagnosis scripts."""
 import gzip
 import json
-import time
 
 import pytest
 
@@ -75,85 +74,13 @@ def test_analyze_trace_reports_host_only(tmp_path):
         main([str(d)])
 
 
-def test_tpu_only_bench_stages_skip_on_cpu():
-    """flashtune/attnpad/ablate must refuse to fake numbers off-TPU."""
-    import bench
-    args = argparse.Namespace(trace="bench_trace", quick=False)
-    for stage in (bench.stage_flashtune, bench.stage_attnpad,
-                  bench.stage_ablate, bench.stage_longseq):
-        out = stage(args)
-        assert out["platform"] == "cpu" and "skipped" in out
-
-
-def test_chained_grad_ms_runs_on_cpu():
-    """The shared timing harness itself is backend-agnostic."""
-    import jax
-    import jax.numpy as jnp
-
-    import bench
-    q = jax.random.normal(jax.random.PRNGKey(0), (1, 128, 2, 16),
-                          jnp.float32)
-    t0 = time.perf_counter()
-    ms = bench.chained_grad_ms("xla", q, q, q, iters=2)
-    assert 0 < ms < (time.perf_counter() - t0) * 1e3
-
-
-def test_bench_budget_exhaustion_still_emits_final_line(tmp_path):
-    """The orchestrator must produce a parseable final (non-partial)
-    JSON line within its budget even when no stage fits — and with no
-    stage run there is no device, so no value."""
-    import os
-    import subprocess
-    import sys
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "bench.py"),
-         "--quick", "--budget", "8"],
-        capture_output=True, text=True, timeout=240, env=env,
-        cwd=tmp_path)
-    lines = proc.stdout.strip().splitlines()
-    final = json.loads(lines[-1])
-    assert "partial" not in final
-    assert all("skipped: budget" in v["status"]
-               for v in final["stages"].values())
-    assert final["value"] is None and final["platform"] is None
-    assert proc.returncode == 1
-
-
-def test_bench_sigterm_emits_final_line(tmp_path):
-    """The driver kills with SIGTERM at ITS wall clock; the handler
-    must flush the cumulative result first. The run is an explicit
-    JAX_PLATFORMS=cpu one: the first (TPU-only, so instant) stage child
-    reports what jax gave it, the run is labelled `cpu`, and nothing is
-    published under the device metric's name."""
-    import os
-    import signal
-    import subprocess
-    import sys
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.Popen(
-        [sys.executable, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "bench.py"),
-         "--budget", "900", "--stages", "flashtune,sweep"],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
-        env=env, cwd=tmp_path)
-    # wait for the flashtune child's cumulative line, so the signal
-    # lands inside the sweep stage
-    first = proc.stdout.readline()
-    while "flashtune" not in first:
-        first = proc.stdout.readline()
-        assert first, "bench exited before the first stage reported"
-    proc.send_signal(signal.SIGTERM)
-    out, _ = proc.communicate(timeout=60)
-    final = json.loads(out.strip().splitlines()[-1])
-    assert final.get("terminated", "").startswith("signal")
-    assert "partial" not in final
-    assert final["platform"] == "cpu" and final["device_kind"] == "cpu"
-    assert final["device_count"] >= 1
-    assert final["value"] is None
-    assert final["metric"] == \
-        "train_imgs_per_sec_per_chip_unet128_text_cond"
+def test_sfc_demo_renders(tmp_path):
+    """The SFC visualization demo (reference demo_hilbert_curve.py
+    analogue) renders and its round-trip check passes."""
+    from scripts.demo_sfc import main
+    out = tmp_path / "sfc.png"
+    assert main(["--grid", "8", "--out", str(out)]) == 0
+    assert out.stat().st_size > 10_000
 
 
 # -- graph-hygiene analyzer (scripts/lint.py; ISSUE 9) ------------------------
