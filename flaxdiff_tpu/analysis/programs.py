@@ -193,7 +193,7 @@ def chunk_program_jaxpr(sampler_name: str, rows: int = 2,
     state = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
                                    *row_states)
     return jax.make_jaxpr(prog)(params, x, keys, pairs, n_act, offsets,
-                                None, None, state)
+                                jnp.int32(round_steps), None, None, state)
 
 
 def terminal_program_jaxpr(sampler_name: str, rows: int = 2):
@@ -241,7 +241,8 @@ def cached_chunk_program_jaxpr(sampler_name: str = "ddim",
     flags = jnp.zeros((round_steps,), bool)
     taps = jnp.zeros((rows, 1, 8, 8, 8), jnp.float32)
     return jax.make_jaxpr(prog)(params, x, keys, pairs, n_act, offsets,
-                                None, None, state, flags, taps)
+                                jnp.int32(round_steps), None, None, state,
+                                flags, taps)
 
 
 def spatial_chunk_program_jaxpr(sampler_name: str = "ddim",
@@ -266,7 +267,8 @@ def spatial_chunk_program_jaxpr(sampler_name: str = "ddim",
     taps = jnp.zeros((rows, 1, 8, 8, 8), jnp.float32)
     refs = jnp.zeros((rows, 1, 8, 8, 8), jnp.float32)
     return jax.make_jaxpr(prog)(params, x, keys, pairs, n_act, offsets,
-                                None, None, state, codes, taps, refs)
+                                jnp.int32(round_steps), None, None, state,
+                                codes, taps, refs)
 
 
 # ---------------------------------------------------------------------------
@@ -451,11 +453,11 @@ def meshed_chunk_program_jaxpr(sampler_name: str = "ddim",
     ds, params = _sampler_pieces(sampler_name)
     prog = ds.make_chunk_program(round_steps)
 
-    def sharded_prog(params, x, keys, pairs, n_act, offsets, state):
+    def sharded_prog(params, x, keys, pairs, n_act, offsets, steps, state):
         x = with_named_constraint(x, P("data"), mesh)
         keys = with_named_constraint(keys, P("data"), mesh)
-        return prog(params, x, keys, pairs, n_act, offsets, None, None,
-                    state)
+        return prog(params, x, keys, pairs, n_act, offsets, steps, None,
+                    None, state)
 
     x = jnp.zeros((rows, 1, 8, 8, 1), jnp.float32)
     keys = jnp.stack([jax.random.PRNGKey(i) for i in range(rows)])
@@ -467,7 +469,8 @@ def meshed_chunk_program_jaxpr(sampler_name: str = "ddim",
     state = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
                                    *row_states)
     closed = jax.make_jaxpr(sharded_prog)(params, x, keys, pairs,
-                                          n_act, offsets, state)
+                                          n_act, offsets,
+                                          jnp.int32(round_steps), state)
     return TracedProgram(closed, {"data": 2})
 
 

@@ -117,6 +117,27 @@ class Sampler(flax.struct.PyTreeNode):
 # The engine
 # --------------------------------------------------------------------------
 
+def _run_steps(step, carry, xs, steps):
+    """`carry = step(carry, xs[i], i)` for i in [0, steps): the serving
+    chunk programs' loop. `steps` is a traced int32 scalar, so how many
+    steps run is DATA and one compiled program runs rounds of every
+    length up to the leading size of `xs`: a scan of that size whose
+    step is a scalar `lax.cond`, so a step past the bound costs a loop
+    turn and no model evaluation. Under a `vmap` over rows `steps` is
+    shared by all of them, so the `cond` stays ONE real branch (a
+    batched predicate would lower to a `select` that runs both sides).
+    (A `fori_loop` bounded by `steps` costs the same a live step on a
+    v5e, but its first call lowers 2-4 s slower per program there:
+    PERF.md, PR 31.)"""
+    def scan_step(c, inp):
+        x_i, i = inp
+        return jax.lax.cond(i < steps, lambda c: step(c, x_i, i),
+                            lambda c: c, c), ()
+
+    size = jax.tree_util.tree_leaves(xs)[0].shape[0]
+    return jax.lax.scan(scan_step, carry, (xs, jnp.arange(size)))[0]
+
+
 class DiffusionSampler:
     """Builds and caches jitted scan programs for trajectory generation.
 
@@ -706,32 +727,45 @@ class DiffusionSampler:
         return jax.jit(sampler_init)
 
     def make_chunk_program(self, round_steps: int):
-        """One continuous-batching round: advance every row by up to
-        `round_steps` of ITS OWN trajectory.
+        """One continuous-batching round: advance every row by `steps`
+        of ITS OWN trajectory, `steps` <= `round_steps`.
 
-        program(params, x, keys, pairs, n_act, offsets, cond, uncond)
+        `round_steps` is the size the program is compiled for (the
+        `pairs` operand's width) and nothing else: how many steps a
+        round runs is DATA, the scalar `steps`, so one program serves
+        every length and no row spends a model evaluation on a step it
+        throws away (`_run_steps`). The serving engine ends a round
+        where its first row ends (`serving/engine.py` `round_length`).
+
+        program(params, x, keys, pairs, n_act, offsets, steps, cond,
+                uncond, state)
           x        [R, *block]            row carries (trajectory state)
           keys     [R, 2] uint32          per-row scan RNG carries
           pairs    [R, round_steps, 2]    this round's (t_cur, t_next)
                                           pairs, inert-padded past n_act
-          n_act    [R] int32              live steps this round (0 for
-                                          padding rows: carry unchanged)
+          n_act    [R] int32              live steps this round, at most
+                                          `steps`: a row with fewer keeps
+                                          its carry for the rest (rows
+                                          of different lengths run to
+                                          completion together; padding)
           offsets  [R] int32              global step index of the row's
                                           first step this round (multistep
                                           samplers key history on it)
+          steps    [] int32               steps this round runs, shared
+                                          by all rows; never a Python int
           state    [R, ...] pytree        per-row sampler state carry
                                           (init_state at admission)
         Returns (x, keys, state) carries. Rows never interact, so a
-        padded round is output-invariant for the real rows.
+        padded round is output-invariant for the real rows, and a row's
+        samples do not depend on where its rounds were cut (tested).
         """
-        def sampler_chunk(params, x, keys, pairs, n_act, offsets, cond,
-                          uncond, state):
+        def sampler_chunk(params, x, keys, pairs, n_act, offsets, steps,
+                          cond, uncond, state):
             def row(x_r, key, row_pairs, n, off, c, u, st):
                 denoise = self._denoise_fn(params, c, u)
 
-                def scan_step(carry, inp):
+                def step(carry, pair, i):
                     x_c, rng, s = carry
-                    pair, i = inp
                     rng, sub = jax.random.split(rng)
                     x_n, s_n = self.sampler.step(
                         denoise, x_c, pair[0], pair[1], sub, s,
@@ -740,12 +774,9 @@ class DiffusionSampler:
                     x_n = jnp.where(active, x_n, x_c)
                     s_n = jax.tree_util.tree_map(
                         lambda a, b: jnp.where(active, a, b), s_n, s)
-                    return (x_n, rng, s_n), ()
+                    return x_n, rng, s_n
 
-                (x_out, rng_out, s_out), _ = jax.lax.scan(
-                    scan_step, (x_r, key, st),
-                    (row_pairs, jnp.arange(round_steps)))
-                return x_out, rng_out, s_out
+                return _run_steps(step, (x_r, key, st), row_pairs, steps)
 
             return jax.vmap(row)(x, keys, pairs, n_act, offsets,
                                  cond, uncond, state)
@@ -762,7 +793,7 @@ class DiffusionSampler:
 
         and `(x, keys, state, taps)` carries out.
 
-        Structure flips to scan-outside / vmap-inside: the refresh
+        Structure flips to loop-outside / vmap-inside: the refresh
         decision must be a SCALAR `lax.cond` — vmapping a cond over
         per-row predicates lowers to `select`, which executes both
         branches and erases the speedup. The round flags are therefore
@@ -775,7 +806,7 @@ class DiffusionSampler:
         plan is bit-identical to the uncached chunk path (tested).
         """
         def sampler_chunk_cached(params, x, keys, pairs, n_act, offsets,
-                                 cond, uncond, state, flags, taps):
+                                 steps, cond, uncond, state, flags, taps):
             def make_step(mode):
                 def step_all(x_c, subs, st, tp, pair_i, i):
                     def row(x_r, sub, s_r, tp_r, pr, off, c, u):
@@ -800,10 +831,10 @@ class DiffusionSampler:
             record_step = make_step("record")
             reuse_step = make_step("reuse")
 
-            def scan_step(carry, inp):
+            def step(carry, inp, i):
                 x_c, rngs, st, tp = carry
-                pair_i, i, refresh = inp
-                # per-row split, same lineage as the uncached row scan:
+                pair_i, refresh = inp
+                # per-row split, same lineage as the uncached row loop:
                 # rng, sub = split(rng) at every step
                 both = jax.vmap(jax.random.split)(rngs)
                 rngs_n, subs = both[:, 0], both[:, 1]
@@ -818,13 +849,10 @@ class DiffusionSampler:
                 x_n = sel(x_n, x_c)
                 s_n = jax.tree_util.tree_map(sel, s_n, st)
                 tp_n = jax.tree_util.tree_map(sel, tp_n, tp)
-                return (x_n, rngs_n, s_n, tp_n), ()
+                return x_n, rngs_n, s_n, tp_n
 
-            (x_o, keys_o, state_o, taps_o), _ = jax.lax.scan(
-                scan_step, (x, keys, state, taps),
-                (jnp.swapaxes(pairs, 0, 1), jnp.arange(round_steps),
-                 flags))
-            return x_o, keys_o, state_o, taps_o
+            return _run_steps(step, (x, keys, state, taps),
+                              (jnp.swapaxes(pairs, 0, 1), flags), steps)
 
         return jax.jit(sampler_chunk_cached)
 
@@ -841,7 +869,7 @@ class DiffusionSampler:
 
         and `(x, keys, state, taps, refs)` carries out.
 
-        Same scan-outside / vmap-inside shape as the cached chunk
+        Same loop-outside / vmap-inside shape as the cached chunk
         program — the per-step decision must be a SCALAR `lax.switch`
         (a vmapped switch lowers to select: every branch executes and
         the speedup is gone). The engine builds the round codes as the
@@ -851,7 +879,8 @@ class DiffusionSampler:
         extra fidelity. Token selection runs per-row inside the vmap
         (each row picks its own top-k from its own carries)."""
         def sampler_chunk_spatial(params, x, keys, pairs, n_act, offsets,
-                                  cond, uncond, state, codes, taps, refs):
+                                  steps, cond, uncond, state, codes, taps,
+                                  refs):
             def make_step(mode):
                 def step_all(x_c, subs, st, tp, rf, pair_i, i):
                     def row(x_r, sub, s_r, tp_r, rf_r, pr, off, c, u):
@@ -878,10 +907,10 @@ class DiffusionSampler:
             steps_by_code = (make_step("reuse"), make_step("spatial"),
                              make_step("record"))
 
-            def scan_step(carry, inp):
+            def step(carry, inp, i):
                 x_c, rngs, st, tp, rf = carry
-                pair_i, i, code = inp
-                # per-row split, same lineage as the uncached row scan
+                pair_i, code = inp
+                # per-row split, same lineage as the uncached row loop
                 both = jax.vmap(jax.random.split)(rngs)
                 rngs_n, subs = both[:, 0], both[:, 1]
                 x_n, s_n, tp_n, rf_n = jax.lax.switch(
@@ -896,13 +925,10 @@ class DiffusionSampler:
                 s_n = jax.tree_util.tree_map(sel, s_n, st)
                 tp_n = jax.tree_util.tree_map(sel, tp_n, tp)
                 rf_n = jax.tree_util.tree_map(sel, rf_n, rf)
-                return (x_n, rngs_n, s_n, tp_n, rf_n), ()
+                return x_n, rngs_n, s_n, tp_n, rf_n
 
-            (x_o, keys_o, state_o, taps_o, refs_o), _ = jax.lax.scan(
-                scan_step, (x, keys, state, taps, refs),
-                (jnp.swapaxes(pairs, 0, 1), jnp.arange(round_steps),
-                 codes))
-            return x_o, keys_o, state_o, taps_o, refs_o
+            return _run_steps(step, (x, keys, state, taps, refs),
+                              (jnp.swapaxes(pairs, 0, 1), codes), steps)
 
         return jax.jit(sampler_chunk_spatial)
 

@@ -7,11 +7,13 @@ existing single-`lax.scan` `DiffusionSampler`, keyed on
      sampler, guidance, use_ema, num_samples, channels,
      has_cond, has_uncond, cache_plan)
 
-so repeat traffic never re-traces. `scan_steps` is the program's scan
-trip count — the whole (bucketed) NFE in run-to-completion mode, the
-round length in continuous mode; either way NFE-heterogeneous rows
-share one program because each row's timestep pairs and live-step
-count are *inputs*, not trace constants. Cache hits/misses are counted
+so repeat traffic never re-traces. `scan_steps` is the size the round
+program is compiled for — the (power-of-two-bucketed) longest NFE in
+run-to-completion mode, `SchedulerConfig.round_steps` in continuous
+mode — and NOT the number of steps a round runs: that is an operand
+(`round_length`: a round ends where its first row ends), as each row's
+timestep pairs and live-step count are, so rounds of every length and
+NFE-heterogeneous rows share one program. Cache hits/misses are counted
 at `serving/program_cache_hits` / `serving/program_cache_misses`.
 Program kinds: "init" and "noise" (a request's starting carry from its
 seed), "chunk" (uncached), "chunk_cached" (timestep diffusion cache),
@@ -68,12 +70,34 @@ def bucket_up(n: int, buckets: Tuple[int, ...]) -> int:
 
 
 def nfe_bucket(n: int) -> int:
-    """Next power of two >= n: the run-to-completion scan length, so
-    nearby NFEs share one program (rows mask their own tail)."""
+    """Next power of two >= n: the size a run-to-completion round
+    program is compiled for, so nearby NFEs share one program (the
+    round runs the exact longest length; shorter rows mask their own
+    tail)."""
     b = 1
     while b < n:
         b *= 2
     return b
+
+
+def round_length(rows, round_steps: int) -> Tuple[int, int]:
+    """(size the round's program is compiled for, steps the round runs)
+    for `rows` under `SchedulerConfig.round_steps`, from the rows'
+    remaining steps and nothing else (host integers: `done` advances at
+    launch, so the dispatch thread one round ahead knows them without a
+    read-back).
+
+    Continuous mode (`round_steps` > 0): the round ends where its first
+    row ends, after at most `round_steps`. Every row is live on every
+    step, so no model evaluation is thrown away, and a finished row's
+    slot is refilled at the next round. Run-to-completion
+    (`round_steps` 0): the exact longest remaining length, in the
+    program of its power-of-two bucket; shorter rows keep their carry
+    past their own end."""
+    left = [r.remaining for r in rows]
+    if round_steps:
+        return round_steps, min(left + [round_steps])
+    return nfe_bucket(max(left)), max(left)
 
 
 def _stacked(rows):
@@ -406,33 +430,40 @@ class SamplerProgramEngine:
 
     def advance(self, rows: List[RequestState], bucket: int,
                 round_steps: int) -> Tuple[List[RequestState], float]:
-        """Run one round: every row advances min(remaining, round_steps)
-        steps of its own trajectory. Returns (rows that completed their
-        trajectory this round, compile seconds spent — 0 on a cache
-        hit). One launch; `serve.stack` is host arithmetic, and
-        `serve.unstack` hands each row its own outputs of the program."""
+        """Run one round of `round_length(rows, round_steps)` steps:
+        `round_steps` is `SchedulerConfig.round_steps`, the longest
+        round and the size of the compiled program (0 = run to
+        completion); every row advances min(remaining, the round's
+        length) steps of its own trajectory. Returns (rows that
+        completed their trajectory this round, compile seconds spent —
+        0 on a cache hit). One launch; `serve.stack` is host arithmetic,
+        and `serve.unstack` hands each row its own outputs of the
+        program."""
         group = rows[0].group
         ds = self._sampler_for(rows[0].req)
         plan = rows[0].plan             # group-uniform (plan is in the key)
         span = self._span
         sched_row = None        # cache-plan step codes this round ran
+        size, steps = round_length(rows, round_steps)
         with span("serve.stack"):
             # padding slots replicate row 0 (their output is discarded)
             srcs = rows + [rows[0]] * (bucket - len(rows))
-            pairs = np.empty((bucket, round_steps, 2), np.float32)
+            pairs = np.empty((bucket, size, 2), np.float32)
             n_act = np.empty((bucket,), np.int32)
             offsets = np.empty((bucket,), np.int32)
             for i, r in enumerate(srcs):
-                sl = r.pairs[r.done:r.done + round_steps]
+                sl = r.pairs[r.done:r.done + steps]
                 if len(sl) == 0:            # exhausted padding row
                     sl = r.pairs[-1:]
                 pairs[i, :len(sl)] = sl     # inert past n_act: the last
                 pairs[i, len(sl):] = sl[-1]     # pair again
-                n_act[i] = max(0, min(r.remaining, round_steps))
+                n_act[i] = max(0, min(r.remaining, steps))
                 offsets[i] = r.done
             carries = [{"x": r.x, "keys": r.rng, "state": r.state,
                         "cond": r.cond, "uncond": r.uncond} for r in srcs]
-            batch = {"pairs": pairs, "n_act": n_act, "offsets": offsets}
+            # the round's length is data: one program for every length
+            batch = {"pairs": pairs, "n_act": n_act, "offsets": offsets,
+                     "steps": np.int32(steps)}
             if plan is None:
                 kind_used, build = "chunk", ds.make_chunk_program
             elif rows[0].ref is not None:
@@ -441,9 +472,9 @@ class SamplerProgramEngine:
                 # schedule (host-side numpy, zero syncs) — refresh beats
                 # spatial beats reuse, so no row gets LESS refresh than
                 # ITS plan scheduled; round-mates can only add fidelity
-                want = np.zeros((round_steps,), np.int32)
+                want = np.zeros((size,), np.int32)
                 for r in rows:
-                    w = r.codes[r.done:r.done + round_steps]
+                    w = r.codes[r.done:r.done + steps]
                     want[:len(w)] = np.maximum(want[:len(w)], w)
                 sched_row = want.tolist()
                 kind_used = "chunk_spatial"
@@ -457,9 +488,9 @@ class SamplerProgramEngine:
                 # no row ever misses ITS scheduled refresh; round-mates
                 # may grant extra free refreshes (fidelity can only
                 # improve)
-                want = np.zeros((round_steps,), bool)
+                want = np.zeros((size,), bool)
                 for r in rows:
-                    w = r.flags[r.done:r.done + round_steps]
+                    w = r.flags[r.done:r.done + steps]
                     want[:len(w)] |= w
                 sched_row = want.astype(int).tolist()
                 kind_used = "chunk_cached"
@@ -472,20 +503,24 @@ class SamplerProgramEngine:
         with span("serve.launch", kind=kind_used):
             t0 = time.perf_counter()
             program, miss = self._get_program(
-                kind_used, group, bucket, round_steps,
-                lambda: _round_program(build(round_steps)))
+                kind_used, group, bucket, size,
+                lambda: _round_program(build(size)))
             # per row (x, key, state), then the taps and the
             # score-reference carries of the cached programs
             outs = self._launch(program, *prog_args)
             compile_s = (time.perf_counter() - t0) if miss else 0.0
         n_live = [int(n) for n in n_act[:len(rows)]]
+        # step occupancy: live / run is 1.0 when every row is live on
+        # every step of its rounds (continuous mode)
+        count = self.telemetry.counter
+        count("serving/row_steps_run").inc(len(rows) * steps)
+        count("serving/row_steps_live").inc(sum(n_live))
         if sched_row is not None:
             # codes of a composed plan: 2 refresh, 1 spatial, 0 reuse;
             # flags of a timestep plan: 1 refresh, 0 reuse
             spatial = kind_used == "chunk_spatial"
             top = 2 if spatial else 1
             ran = [c for n in n_live for c in sched_row[:n]]
-            count = self.telemetry.counter
             count("serving/cache_rows").inc(len(rows))
             count("serving/cache_refresh_steps").inc(ran.count(top))
             count("serving/cache_reused_steps").inc(ran.count(0))
@@ -498,15 +533,14 @@ class SamplerProgramEngine:
             # dispatch key with measured compile ms. No-op without a
             # registry (the disabled default hub), so the warm path and
             # the zero-retrace acceptance see no change.
-            self._register_evidence(kind_used, group, bucket,
-                                    round_steps, program, prog_args,
-                                    compile_s)
+            self._register_evidence(kind_used, group, bucket, size,
+                                    program, prog_args, compile_s)
         self.last_round_info = {
             "kind": kind_used,
             "key": str(self._program_key(kind_used, group, bucket,
-                                         round_steps)),
+                                         size)),
             "bucket": int(bucket), "rows": len(rows),
-            "steps": int(round_steps), "miss": bool(miss),
+            "steps": int(steps), "miss": bool(miss),
             "n_act": n_live,
         }
         if sched_row is not None:
@@ -575,11 +609,10 @@ class SamplerProgramEngine:
         t0 = time.perf_counter()
         before = self.program_cache_size
         for req in reqs:
-            rs = round_steps or nfe_bucket(int(req.diffusion_steps))
             for bucket in sorted(set(batch_buckets)):
                 rows = [self.prepare(req, ServingFuture(), t0, t0)]
                 while rows[0].remaining > 0:
-                    finished, _ = self.advance(rows, bucket, rs)
+                    finished, _ = self.advance(rows, bucket, round_steps)
                 out, _ = self.finalize(finished, bucket)
                 # settle before admission opens: the compile itself is
                 # synchronous, this only keeps the warmup device work

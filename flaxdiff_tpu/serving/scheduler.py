@@ -11,11 +11,15 @@ Architecture (docs/SERVING.md):
 - A single **dispatch loop** drains the queue in rounds. Each round
   serves one compatibility group (least-recently-served for fairness),
   admits queued requests into the group's free capacity, pads the
-  batch to a bucket, and advances every row by up to
-  `round_steps` of its OWN trajectory through the engine's compiled
-  program. Rows that complete exit mid-group ("continuous admission"):
-  a 10-NFE request batched with a 50-NFE one returns after its own
-  rounds, and its slot is refilled from the queue.
+  batch to a bucket, and advances every row of its OWN trajectory
+  through the engine's compiled program. **A round ends where its
+  first row ends**, after at most `round_steps` steps (`round_length`,
+  serving/engine.py): its length is an operand of the one compiled
+  program, decided from the rows' remaining steps (host integers), so
+  no row spends a step on a model evaluation it throws away. Rows that
+  complete exit mid-group ("continuous admission"): a 10-NFE request
+  batched with a 50-NFE one returns after its own 10 steps, and its
+  slot is refilled from the queue at the next round.
 - Completed rows are handed (still device-resident, dispatch still
   async) to a **completion thread** that performs the host syncs of a
   result — `_block_until_ready` + `_device_get`, module-level seams so
@@ -62,7 +66,7 @@ from ..resilience.events import record_event
 from ..resilience.retry import RetryPolicy
 from ..telemetry.reqtrace import RequestTracer
 from .engine import (DEFAULT_BATCH_BUCKETS, RequestState,
-                     SamplerProgramEngine, bucket_up, nfe_bucket)
+                     SamplerProgramEngine, bucket_up, round_length)
 from .request import (DeadlineExceeded, SampleRequest, SampleResult,
                       SchedulerClosed, ServingFuture)
 from .supervision import (BrownoutConfig, BrownoutPolicy, DeviceLost,
@@ -114,10 +118,14 @@ def _now() -> float:
 class SchedulerConfig:
     """Knobs for the dispatch loop.
 
-    round_steps: trajectory steps advanced per round (the compiled
-      program's scan length). 0 = run-to-completion: one round runs a
-      group's whole (power-of-two-bucketed) max NFE — lowest overhead,
-      but a short request then waits for the longest row in its round.
+    round_steps: the LONGEST round, in trajectory steps, and the size
+      the round program is compiled for. A round runs to where its
+      first row ends, so it is shorter whenever a row has fewer steps
+      left (`round_length`, serving/engine.py); one program serves
+      every length. 0 = run-to-completion: one round runs a group's
+      longest remaining NFE exactly (in the program of its
+      power-of-two bucket) — lowest overhead, but a short request then
+      waits for the longest row in its round.
     batch_buckets: padded batch sizes; max(batch_buckets) caps rows
       per round.
     max_queue: admission cap; submits past it are shed at the door.
@@ -468,8 +476,7 @@ class ServingScheduler:
         return admitted
 
     # -- fault isolation ------------------------------------------------------
-    def _checked_advance(self, rows: List[RequestState], bucket: int,
-                         round_steps: int):
+    def _checked_advance(self, rows: List[RequestState], bucket: int):
         """One engine round behind the serving fault barriers
         (resilience/faults.py): `serving.device_lost` (flag -> raises
         `DeviceLost`) models a dead chip, `serving.round` is polled
@@ -477,10 +484,13 @@ class ServingScheduler:
         deterministically poison ONE request no matter what it is
         batched with. One dict lookup each with no plan armed. The
         whole of it is the span `serve.round`; `round` is the join key
-        to the request tracer's `round_detail` rows."""
+        to the request tracer's `round_detail` rows, `steps` the
+        round's own length (not the compiled size)."""
+        round_steps = self.config.round_steps
         with self.telemetry.span("serve.round", cat="serving", args={
                 "round": self._round_no, "bucket": bucket,
-                "rows": len(rows), "steps": round_steps}):
+                "rows": len(rows),
+                "steps": round_length(rows, round_steps)[1]}):
             if _faults.check("serving.device_lost"):
                 raise DeviceLost("injected fault at serving.device_lost")
             for r in rows:
@@ -542,8 +552,8 @@ class ServingScheduler:
                 not_before=now + delay, degraded=r.degraded))
         self.telemetry.gauge("serving/queue_depth").set(len(self._queue))
 
-    def _convict(self, rows: List[RequestState], buckets: Tuple[int, ...],
-                 round_steps: int):
+    def _convict(self, rows: List[RequestState],
+                 buckets: Tuple[int, ...]):
         """Binary-search eviction after a batch fault: requests are
         deterministic given their seed, so any suspect row can be
         re-run solo from scratch to convict. Probes re-prepare fresh
@@ -560,8 +570,7 @@ class ServingScheduler:
                 sts = [self.engine.prepare(r.req, ServingFuture(),
                                            r.submit_t, _now())
                        for r in subset]
-                self._checked_advance(
-                    sts, bucket_up(len(sts), buckets), round_steps)
+                self._checked_advance(sts, bucket_up(len(sts), buckets))
                 return None
             except (KeyboardInterrupt, SystemExit, DeviceLost):
                 raise
@@ -594,8 +603,8 @@ class ServingScheduler:
         return g1 + g2, i1 + i2
 
     def _on_round_failure(self, gk: tuple, rows: List[RequestState],
-                          exc: BaseException, buckets: Tuple[int, ...],
-                          round_steps: int) -> None:
+                          exc: BaseException,
+                          buckets: Tuple[int, ...]) -> None:
         """Fault-isolate one failed round: classify, convict or
         rebuild, requeue the innocent. The failing round poisons only
         its own group — other groups' active rows are untouched (except
@@ -616,7 +625,7 @@ class ServingScheduler:
             self._supervised_rebuild(exc, rows)
             return
         try:
-            guilty, innocent = self._convict(rows, buckets, round_steps)
+            guilty, innocent = self._convict(rows, buckets)
         except DeviceLost as e2:
             self._supervised_rebuild(e2, rows)
             return
@@ -817,8 +826,6 @@ class ServingScheduler:
                 # admission)
                 self.profiler.poll_round(self._round_no)
             bucket = bucket_up(len(rows), buckets)
-            round_steps = cfg.round_steps or nfe_bucket(
-                max(r.remaining for r in rows))
             tel.gauge("serving/batch_occupancy").set(len(rows) / bucket)
             tel.counter("serving/rows_real").inc(len(rows))
             tel.counter("serving/rows_padded").inc(bucket - len(rows))
@@ -832,8 +839,7 @@ class ServingScheduler:
                 if self._unfinished \
                         and not _is_ready(self._unfinished[-1]):
                     tel.counter("serving/rounds_overlapped").inc()
-                finished, _ = self._checked_advance(rows, bucket,
-                                                    round_steps)
+                finished, _ = self._checked_advance(rows, bucket)
                 self._unfinished.append(rows[0].x)
                 if self.tracer.enabled:
                     # host timestamps + host-side dicts only: tracing
@@ -855,8 +861,7 @@ class ServingScheduler:
             except BaseException as e:  # noqa: BLE001 — fault barrier
                 # the failing round poisons only its group: convict /
                 # requeue / rebuild, then keep serving everyone else
-                self._on_round_failure(gk, rows, e, buckets,
-                                       round_steps)
+                self._on_round_failure(gk, rows, e, buckets)
                 continue
             with self._cv:
                 if live:

@@ -6,12 +6,15 @@ its result line does not carry.
       --seed <n> --trace <0|1>        (benchmark/run.py's own arguments)
 
 `benchmark/harness/serving.py` snapshots a closed tuple of counters
-(`COUNTERS`); this wrapper adds `serving/launches` and
-`serving/rounds_overlapped` to it for the run, lets `benchmark/run.py`
-do everything else, and prints after its result line one `counters` line
+(`COUNTERS`); this wrapper adds `serving/launches`,
+`serving/rounds_overlapped`, `serving/row_steps_run` and
+`serving/row_steps_live` to it for the run, lets `benchmark/run.py` do
+everything else, and prints after its result line one `counters` line
 per pair of snapshots and a `counters_window` line with
-`launches_per_round` and `overlapped_per_round` (docs/OBSERVABILITY.md;
-PERF.md section 3). A builder's tool: no run of the benchmark calls it.
+`launches_per_round`, `overlapped_per_round`, `step_occupancy` (live
+row-steps over row-steps run) and `steps_per_round` (the mean round
+length; docs/OBSERVABILITY.md; PERF.md section 3). A builder's tool: no
+run of the benchmark calls it.
 """
 import json
 import os
@@ -25,8 +28,9 @@ def main() -> int:
     import run as bench_run
     from harness import serving
 
-    serving.COUNTERS = serving.COUNTERS + ("serving/launches",
-                                           "serving/rounds_overlapped")
+    serving.COUNTERS = serving.COUNTERS + (
+        "serving/launches", "serving/rounds_overlapped",
+        "serving/row_steps_run", "serving/row_steps_live")
     snaps = []
     real = serving._counters
 
@@ -41,9 +45,12 @@ def main() -> int:
     if snaps:
         d = {k: snaps[-1][k] - snaps[0][k] for k in snaps[0]}
         rounds = d["serving/rounds"] or 1.0
+        run = d["serving/row_steps_run"]
         print("counters_window " + json.dumps(dict(
             d, launches_per_round=d["serving/launches"] / rounds,
-            overlapped_per_round=d["serving/rounds_overlapped"] / rounds)))
+            overlapped_per_round=d["serving/rounds_overlapped"] / rounds,
+            step_occupancy=d["serving/row_steps_live"] / (run or 1.0),
+            steps_per_round=run / (d["serving/rows_real"] or 1.0))))
     return rc
 
 

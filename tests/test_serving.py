@@ -642,6 +642,235 @@ def test_multistep_state_carry_bit_identity(tiny_pipe):
         np.testing.assert_array_equal(o.samples, solo)
 
 
+# ---------------------------------------------------------------------------
+# A round ends where its first row ends (ISSUE 31): its length is an
+# operand of the one compiled chunk program
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def deep_pipe():
+    """Three perturbed blocks (the cache plans split the depth, and an
+    AdaLN-Zero block is an identity at init), v-prediction (samples do
+    not saturate at the clip: every step shows in them)."""
+    import jax
+    import jax.numpy as jnp
+
+    from flaxdiff_tpu.inference import (DiffusionInferencePipeline,
+                                        build_model)
+    kw = {"emb_features": 32, "num_heads": 4, "num_layers": 3,
+          "patch_size": 4, "output_channels": 1}
+    params = build_model("simple_dit", **kw).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 1)), jnp.zeros((1,)),
+        None)
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(7), len(leaves))
+    params = jax.tree_util.tree_unflatten(
+        treedef, [l + 0.05 * jax.random.normal(k, l.shape, l.dtype)
+                  for l, k in zip(leaves, keys)])
+    return DiffusionInferencePipeline.from_config(
+        {"model": dict(kw, name="simple_dit"),
+         "schedule": {"name": "cosine", "timesteps": 100},
+         "predictor": "v"}, params=params)
+
+
+def _plan_of(kind):
+    from flaxdiff_tpu.ops.diffcache import CachePlan
+    from flaxdiff_tpu.ops.spatialcache import ComposedPlan, SpatialPlan
+    cache = CachePlan(refresh_every=3, refresh_head=1, refresh_tail=1)
+    return {"chunk": None, "chunk_cached": cache,
+            "chunk_spatial": ComposedPlan(
+                cache=cache, spatial=SpatialPlan(keep_fraction=0.5))}[kind]
+
+
+def _advance_cut(engine, row, steps, round_steps=8):
+    """One solo round of exactly `steps` in the program compiled for
+    `round_steps`: a round ends where its first row ends, so the row is
+    made to look `steps` from its end for the length of the call."""
+    nfe, row.nfe = row.nfe, row.done + steps
+    try:
+        engine.advance([row], 1, round_steps)
+    finally:
+        row.nfe = nfe
+    return engine.last_round_info
+
+
+@pytest.fixture(scope="module")
+def cut_engines():
+    """One engine a (pipeline, kind), kept over the cut cases so that
+    they share its one compiled chunk program."""
+    return {}
+
+
+def _samples_by_cuts(engines, pipe, kind, cuts):
+    """A 20-step request's samples with its rounds cut as `cuts`, every
+    round through the ONE size-8 program of its kind."""
+    from flaxdiff_tpu.serving import ServingFuture
+    from flaxdiff_tpu.serving.engine import SamplerProgramEngine
+    engine = engines.get((id(pipe), kind))
+    if engine is None:
+        engine = engines[id(pipe), kind] = SamplerProgramEngine(
+            pipe, telemetry=Telemetry(enabled=False))
+    row = engine.prepare(SampleRequest(
+        resolution=8, channels=1, diffusion_steps=20,
+        sampler="euler_ancestral", seed=5, use_ema=False,
+        cache_plan=_plan_of(kind)), ServingFuture(), 0.0, 0.0)
+    for c in cuts:
+        info = _advance_cut(engine, row, c)
+        assert info["kind"] == kind and info["steps"] == c
+    assert row.remaining == 0 and row.rounds == len(cuts)
+    out, _ = engine.finalize([row], 1)
+    # every cut of every case went through one chunk program
+    assert sum(k[0] == kind for k in engine._programs) == 1
+    return np.asarray(out[0])
+
+
+@pytest.mark.parametrize("cuts", [(5, 5, 5, 5), (1,) * 20, (3, 8, 2, 7)],
+                         ids=["5x4", "1x20", "3+8+2+7"])
+@pytest.mark.parametrize("kind", ["chunk", "chunk_cached", "chunk_spatial"])
+def test_samples_do_not_depend_on_where_rounds_are_cut(
+        deep_pipe, cut_engines, kind, cuts):
+    """A request's samples are equal to the last bit whether its 20
+    steps run as 8+8+4 (what it gets alone), 5+5+5+5, 20 x 1 or an
+    uneven cut: its steps, its RNG lineage (one split a step that runs,
+    none for a step that does not) and its cache schedule are its own."""
+    assert sum(cuts) == 20
+    base = _samples_by_cuts(cut_engines, deep_pipe, kind, (8, 8, 4))
+    np.testing.assert_array_equal(
+        _samples_by_cuts(cut_engines, deep_pipe, kind, cuts), base)
+    assert (np.abs(base) < 1.0).mean() > 0.5        # not saturated
+
+
+@pytest.mark.parametrize("cuts", [(8, 8, 4), (5, 5, 5, 5), (1,) * 20],
+                         ids=["8+8+4", "5x4", "1x20"])
+def test_cut_rounds_equal_the_solo_scan(tiny_pipe, cut_engines, cuts):
+    """The anchor of the cut cases, on the model whose batched-equals-
+    solo bar holds to the bit (ROADMAP D9): however the chunk program's
+    rounds are cut, the samples are those of `generate_samples` and its
+    single scan."""
+    solo = tiny_pipe.generate_samples(
+        num_samples=1, resolution=8, channels=1, diffusion_steps=20,
+        sampler="euler_ancestral", seed=5, use_ema=False)
+    np.testing.assert_array_equal(
+        _samples_by_cuts(cut_engines, tiny_pipe, "chunk", cuts), solo)
+
+
+def _rounds_by_rule(nfes, cap, round_steps):
+    """What `round_length` and FIFO admission give `nfes` submitted
+    together: ({request: its rounds}, [every round's length])."""
+    queue, active = list(enumerate(nfes)), []
+    rounds, lengths = {i: 0 for i in range(len(nfes))}, []
+    while queue or active:
+        while queue and len(active) < cap:
+            active.append(list(queue.pop(0)))
+        steps = min([round_steps] + [left for _, left in active])
+        lengths.append(steps)
+        for a in active:
+            a[1] -= steps
+            rounds[a[0]] += 1
+        active = [a for a in active if a[1] > 0]
+    return rounds, lengths
+
+
+@pytest.mark.parametrize("nfes,buckets,round_steps", [
+    ((3, 5, 7, 6, 9, 4), (2,), 4),
+    ((20, 30, 50, 20, 20, 30, 20, 20, 30, 20), (1, 2, 4), 8),
+    ((5, 5, 5, 1, 13), (1, 2), 8),
+], ids=["cap2-rs4", "deal-6-3-1", "ones-and-long"])
+def test_no_row_step_is_dead_and_rounds_follow_the_rule(
+        tiny_pipe, nfes, buckets, round_steps):
+    """Mixed step counts over fewer rows than requests (the queue is
+    never empty until the tail): every row is live on every step its
+    rounds run (`serving/row_steps_run == serving/row_steps_live`), a
+    request's `rounds` and the count of rounds are what the rule gives,
+    and every request ran its own NFE, no more."""
+    tel = Telemetry(enabled=False)
+    sched = ServingScheduler(
+        pipeline=tiny_pipe, telemetry=tel, autostart=False,
+        config=SchedulerConfig(round_steps=round_steps,
+                               batch_buckets=buckets))
+    futs = [sched.submit(_tiny_request(n, 50 + i))
+            for i, n in enumerate(nfes)]
+    sched.start()
+    outs = [f.result(timeout=300) for f in futs]
+    sched.close()
+    want, lengths = _rounds_by_rule(nfes, max(buckets), round_steps)
+    assert [o.rounds for o in outs] == [want[i] for i in range(len(nfes))]
+    snap = tel.registry.snapshot()
+    assert snap["serving/rounds"] == len(lengths)
+    assert snap["serving/row_steps_run"] == snap["serving/row_steps_live"] \
+        == sum(nfes)
+    assert max(lengths) <= round_steps and min(lengths) >= 1
+    assert len(set(lengths)) > 1            # the rule did cut rounds short
+
+
+@pytest.fixture(scope="module")
+def warm_round8(tiny_pipe):
+    """An engine whose size-8 bucket-1 chunk program has run once, and
+    the telemetry that counted it."""
+    from flaxdiff_tpu.serving import ServingFuture
+    from flaxdiff_tpu.serving.engine import SamplerProgramEngine
+    tel = Telemetry(enabled=False)
+    engine = SamplerProgramEngine(tiny_pipe, telemetry=tel)
+    row = engine.prepare(_tiny_request(40, 1), ServingFuture(), 0.0, 0.0)
+    engine.advance([row], 1, 8)
+    sched_mod._block_until_ready(row.x)
+    return engine, tel, row
+
+
+@pytest.mark.parametrize("steps", range(1, 9))
+def test_round_lengths_share_one_program_and_one_compilation(
+        warm_round8, steps):
+    """Round lengths 1..8 at one bucket are one entry of the program
+    cache and one compilation: the length is an operand, never a trace
+    constant."""
+    engine, tel, row = warm_round8
+    misses = tel.registry.counter("serving/program_cache_misses")
+    m0, size0, done0 = misses.value, engine.program_cache_size, row.done
+    if row.remaining < steps:
+        row.done = done0 = 0            # its trajectory, again
+    with _count_compiles() as compiled:
+        info = _advance_cut(engine, row, steps)
+        sched_mod._block_until_ready(row.x)
+    assert info["steps"] == steps and info["n_act"] == [steps]
+    assert not info["miss"] and row.done == done0 + steps
+    assert compiled == [] and misses.value == m0
+    assert engine.program_cache_size == size0
+    assert sum(k[0] == "chunk" for k in engine._programs) == 1
+
+
+@pytest.mark.parametrize("nfes", [(3, 5, 4), (6,), (9, 2, 16, 11)],
+                         ids=["3-5-4", "6", "9-2-16-11"])
+def test_run_to_completion_is_one_round_of_the_exact_longest_length(
+        tiny_pipe, nfes):
+    """`round_steps=0`: every row finishes in ONE round, which runs the
+    longest row's steps exactly (in the program of their power-of-two
+    bucket); shorter rows keep their carry past their own end, and the
+    samples are the solo scan's to the last bit."""
+    tel = Telemetry(enabled=False)
+    sched = ServingScheduler(
+        pipeline=tiny_pipe, telemetry=tel, autostart=False,
+        config=SchedulerConfig(round_steps=0, batch_buckets=(4,)))
+    futs = [sched.submit(_tiny_request(n, 70 + i, "euler_ancestral"))
+            for i, n in enumerate(nfes)]
+    sched.start()
+    outs = [f.result(timeout=300) for f in futs]
+    sched.close()
+    assert [o.rounds for o in outs] == [1] * len(nfes)
+    snap = tel.registry.snapshot()
+    assert snap["serving/rounds"] == 1
+    assert snap["serving/row_steps_run"] == len(nfes) * max(nfes)
+    assert snap["serving/row_steps_live"] == sum(nfes)
+    info = sched.engine.last_round_info
+    assert info["steps"] == max(nfes) and info["n_act"] == list(nfes)
+    chunk_keys = [k for k in sched.engine._programs if k[0] == "chunk"]
+    assert [k[2] for k in chunk_keys] == [nfe_bucket(max(nfes))]
+    for i, (n, o) in enumerate(zip(nfes, outs)):
+        solo = tiny_pipe.generate_samples(
+            num_samples=1, resolution=8, channels=1, diffusion_steps=n,
+            sampler="euler_ancestral", seed=70 + i, use_ema=False)
+        np.testing.assert_array_equal(o.samples, solo)
+
+
 @contextlib.contextmanager
 def _count_compiles():
     """The seconds of every backend compile inside the block (what the
