@@ -92,12 +92,22 @@ def worst_leaf_difference(program_tree, reference_tree) -> Tuple[float, str]:
     return float(worst), where
 
 
-def verdict(compared: List[Tuple[str, float, float]]) -> bool:
-    """Print each (name, value, limit) and return whether all hold."""
+Row = Tuple[str, str, float, float]      # short name, what, value, limit
+
+
+def verdict(compared: List[Row]) -> bool:
+    """Print each number compared beside its limit and return whether
+    all hold."""
     ok = True
-    for name, value, limit in compared:
+    for _, what, value, limit in compared:
         good = bool(math.isfinite(value) and value <= limit)
         ok = ok and good
-        print(f"check: {name} = {value:.6g}  limit {limit:.6g}  "
+        print(f"check: {what} = {value:.6g}  limit {limit:.6g}  "
               f"{'ok' if good else 'FAIL'}", flush=True)
     return ok
+
+
+def as_result(compared: List[Row]) -> Dict[str, Dict[str, float]]:
+    """The result line's last key: {short name: {"value", "limit"}}."""
+    return {short: {"value": float(value), "limit": float(limit)}
+            for short, _, value, limit in compared}

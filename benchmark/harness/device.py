@@ -80,6 +80,22 @@ def memory_peak_bytes(devices) -> int:
     return peak
 
 
+def live_bytes(devices) -> int:
+    """Bytes alive now on the fullest device: the allocator's
+    `bytes_in_use`, or, where the backend reports none (the CPU), the
+    sum of the live arrays'."""
+    import jax
+    best = 0
+    for d in devices:
+        stats = d.memory_stats() or {}
+        if "bytes_in_use" in stats:
+            best = max(best, int(stats["bytes_in_use"]))
+        else:
+            best = max(best, sum(int(a.nbytes) for a in jax.live_arrays()
+                                 if d in a.devices()))
+    return best
+
+
 def configure_cache(bench_dir: str) -> str:
     """jax's persistent compilation cache at a fixed path inside the
     checkout (`benchmark/out/jax_cache`), without a size cap, whatever

@@ -20,3 +20,11 @@ def forward_flops(cfg: Dict[str, Any]) -> float:
 
 def train_flops_per_image(cfg: Dict[str, Any]) -> float:
     return 3.0 * forward_flops(cfg)
+
+
+def kernel_costs(cfg: Dict[str, Any]) -> Dict[str, Dict[str, float]]:
+    """{kernel name: {"flops", "bytes"}} required for one model
+    evaluation of one row, from the function beside the family's
+    reference."""
+    return importlib.import_module(
+        f"reference.{cfg['family']}").kernel_costs(cfg)

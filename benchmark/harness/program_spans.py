@@ -15,10 +15,10 @@ started as `python3`, `python` in PR 23's probe), whatever
 of their own or none. So a thread is known here by its line's position
 in the plane (`python3#16`), and the dispatching thread by the spans it
 holds: the line with `fdt.serve.round` (the scheduler's
-`serving-dispatch`) or `fdt.fit.step` (`fit`'s caller). `harness/trace.py` keeps `bench.*`
-host events only and names a gap by them; it is not edited by a PR
-that is not a `benchmark` PR, so this reader stands beside it and
-`benchmark/spans.py` prints what it reads (PERF.md, section 7).
+`serving-dispatch`) or `fdt.fit.step` (`fit`'s caller). `harness/trace.py`
+keeps these rows since PR 34; `benchmark/spans.py` prints what this
+module reads from them, and `device_rounds` is what the serving cells'
+device-clock metrics are reduced from.
 
 A metric over spans is a `read` object, as the other kinds' are:
 
@@ -40,8 +40,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from . import trace as tr
 
-PREFIX = "fdt."
-MODULES_LINE = "XLA Modules"
+PREFIX = tr.PROGRAM_PREFIX
+MODULES_LINE = tr.MODULES_LINE
 # the spans that open a loop turn of a dispatching thread, and the
 # program each launching span starts on the device
 DISPATCH_MARKS = ("serve.round", "fit.step", "fit.host")
@@ -63,32 +63,7 @@ class Span:
 
 # -- reading ----------------------------------------------------------------
 
-def rows_of(pb_path: str) -> List[Dict]:
-    """`trace.to_rows` and, besides: the `fdt.*` host events with their
-    thread and stats, and the devices' `XLA Modules` lines."""
-    from jax.profiler import ProfileData
-    rows: List[Dict] = []
-    for plane in ProfileData.from_file(pb_path).planes:
-        on_device = bool(tr.DEVICE_PLANE.match(plane.name))
-        for k, line in enumerate(plane.lines):
-            if on_device and line.name not in (
-                    tr.OPS_LINE, tr.ASYNC_LINE, MODULES_LINE):
-                continue
-            for e in line.events:
-                ours = e.name.startswith(PREFIX)
-                if not on_device and not ours \
-                        and not e.name.startswith(tr.SPAN_PREFIX):
-                    continue
-                row = {"plane": plane.name, "line": line.name,
-                       "name": e.name, "start_ns": e.start_ns,
-                       "dur_ns": e.duration_ns}
-                if ours:
-                    row["thread"] = f"{line.name}#{k}"
-                    row["stats"] = {
-                        str(a): (b if isinstance(b, (int, float, str))
-                                 else str(b)) for a, b in e.stats}
-                rows.append(row)
-    return rows
+rows_of = tr.to_rows
 
 
 def from_rows(rows: List[Dict]) -> List[Span]:
@@ -125,6 +100,156 @@ def split(rows: List[Dict]
     """(the harness's trace, the program's spans, the first device's
     executed programs) of one capture's rows."""
     return tr.from_events(rows), from_rows(rows), modules_of(rows)
+
+
+# -- rounds on the device's clock --------------------------------------------
+
+ROUND_SPAN, FINALIZE_SPAN = "serve.round", "serve.finalize"
+
+
+@dataclasses.dataclass
+class Round:
+    """One serving round as the device ran it."""
+    start: float              # its program's start on the device, ns
+    end: float
+    steps: int                # the round's own length (`serve.round`)
+    rows: int                 # real rows in it
+    finished: int             # rows finalised after it: a terminal
+    #                           evaluation each
+    whole: bool = True        # False for the capture's first or last
+    #                           module, which it may have cut short
+
+
+class PairingError(RuntimeError):
+    """A capture's `serve.round` spans cannot be paired with the round
+    programs they launched, or can be in two ways that differ."""
+
+
+# two runs of one round program at the same bucket and steps take the
+# same time on the device to this share (62.35 / 62.34 and 230.57 /
+# 230.57 ms on `dit-xl-2.generate`, my chip run, PR 34); runs of
+# different steps differ by more
+ROUND_REPEATS = 0.02
+
+
+def _unlike(kept: List[Tuple[int, int, float]]) -> str:
+    """Why the (bucket, steps, device ns) of some pairing's rounds cannot
+    all be true, or "": at one bucket, rounds of the same steps take the
+    same time and a round of more steps takes longer."""
+    for i, (bucket, steps, took) in enumerate(kept):
+        for bucket2, steps2, took2 in kept[i + 1:]:
+            if bucket2 != bucket:
+                continue
+            alike = abs(took - took2) <= ROUND_REPEATS * max(took, took2)
+            if (steps == steps2) != alike \
+                    or (not alike and (steps < steps2) != (took < took2)):
+                return (f"rounds of {steps} and {steps2} steps at bucket "
+                        f"{bucket} against programs of {took / 1e6:.2f} "
+                        f"and {took2 / 1e6:.2f} ms")
+    return ""
+
+
+def device_rounds(spans: List[Span], modules: List[tr.Event], program: str,
+                  rounds_ahead: int, slack_ns: float = 5e6) -> List[Round]:
+    """The capture's `serve.round` spans, each paired with the round
+    program (a module named like `program`) it launched.
+
+    Spans and programs are both in launch order, so a pairing is one
+    shift between the two lists; the capture cuts both at arbitrary
+    points, so the shift has to be found. A shift is possible where, for
+    every pair it makes (each bound with `slack_ns` for the difference
+    between the two clocks): the program starts no earlier than its span
+    opens; the device is never idle between the span's end and the
+    program's start (a launched program starts as soon as the device is
+    free); and, the dispatch thread running at most `rounds_ahead`
+    rounds ahead of the device (the metric's file states it: the
+    benchmark reads no constant of the program's), the program has ended
+    when the span `rounds_ahead + 1` rounds later opens. With the device
+    saturated round k's span opens just as round k-1's program starts,
+    so times alone leave two shifts. The device's own time chooses: at
+    one bucket, whole programs of the same steps take the same time to
+    `ROUND_REPEATS` and one of more steps takes longer (`_unlike`); the
+    capture's first and last module may be cut short, are not held to
+    that, and come back with `whole` false (the first's end and the
+    last's start are still right). Raises `PairingError` where spans and
+    programs are there and no shift is possible, or where two are and
+    give some whole program different steps or rows: a metric read from
+    a guess would be wrong by up to the longest round over the
+    shortest. A capture with fewer
+    than two round spans or programs has nothing to read: []. `finished`
+    counts the rows of the `serve.finalize` spans the thread opened
+    before its next round."""
+    import re
+    rx = re.compile(program)
+    progs = [(i, m) for i, m in enumerate(modules) if rx.search(m[0])]
+    rounds = [s for s in spans if s.name == ROUND_SPAN]
+    if len(progs) < 2 or len(rounds) < 2:
+        return []
+    busy = tr.union(tr.as_intervals(modules))
+
+    def pairs(shift):
+        return [(k, k + shift) for k in range(len(rounds))
+                if 0 <= k + shift < len(progs)]
+
+    def whole(j) -> bool:
+        return 0 < progs[j][0] < len(modules) - 1
+
+    def refused(shift) -> str:
+        kept = []
+        for k, j in pairs(shift):
+            _, start, dur = progs[j][1]
+            r, later = rounds[k], k + rounds_ahead + 1
+            if start < r.start - slack_ns:
+                return "a program would start before its span opens"
+            if later < len(rounds) \
+                    and start + dur > rounds[later].start + slack_ns:
+                return ("a program would still run when the span "
+                        f"{rounds_ahead + 1} rounds later opens")
+            since = max(r.end + slack_ns, busy[0][0])
+            if tr.measure(tr.gaps(busy, (since, start))) > slack_ns:
+                return ("the device would idle between a round's launch "
+                        "and its program")
+            if whole(j):
+                kept.append((int(r.stats.get("bucket", 1)),
+                             int(r.stats["steps"]), dur))
+        return _unlike(kept)
+
+    why: Dict[str, List[int]] = {}
+    for sh in range(1 - len(rounds), len(progs)):
+        why.setdefault(refused(sh), []).append(sh)
+    left = why.pop("", [])
+    if not left:
+        raise PairingError(
+            f"{len(rounds)} {ROUND_SPAN} spans and {len(progs)} programs "
+            f"like {program!r}: no pairing in launch order is possible ("
+            + "; ".join(f"shifts {shs}: {no}" for no, shs in why.items())
+            + ")")
+    said: Dict[int, Tuple[int, int, int]] = {}
+    for sh in left:
+        for k, j in pairs(sh):
+            if not whole(j):
+                continue
+            what = (sh, int(rounds[k].stats["steps"]),
+                    int(rounds[k].stats["rows"]))
+            if said.setdefault(j, what)[1:] != what[1:]:
+                raise PairingError(
+                    f"shifts {said[j][0]} and {sh} both pair the "
+                    f"{ROUND_SPAN} spans with the programs like "
+                    f"{program!r}, and give the program at "
+                    f"{progs[j][1][1] / 1e6:.2f} ms (steps, rows) "
+                    f"{said[j][1:]} and {what[1:]}")
+    shift = max(left, key=lambda sh: (len(pairs(sh)), -sh))
+    out: List[Round] = []
+    for k, j in pairs(shift):
+        r = rounds[k]
+        until = rounds[k + 1].start if k + 1 < len(rounds) else float("inf")
+        finished = sum(int(s.stats.get("rows", 0)) for s in spans
+                       if s.name == FINALIZE_SPAN and s.thread == r.thread
+                       and r.start <= s.start < until)
+        _, start, dur = progs[j][1]
+        out.append(Round(start, start + dur, int(r.stats["steps"]),
+                         int(r.stats["rows"]), finished, whole(j)))
+    return out
 
 
 # -- spans ------------------------------------------------------------------

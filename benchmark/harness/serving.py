@@ -36,18 +36,18 @@ class SeededContextEncoder:
 
 
 def build_pipeline(cfg: Dict[str, Any], seed: int, null_ctx: np.ndarray):
-    import jax
-
     from flaxdiff_tpu.inference import DiffusionInferencePipeline
     from flaxdiff_tpu.inputs import (ConditionalInputConfig,
                                      DiffusionInputConfig)
 
-    _, _, init_fn, shapes = models.build(cfg)
+    _, _, _, shapes = models.build(cfg)
     raw_key, ema_key = serve_keys(seed)
     hold_ema = bool(cfg.get("serve", {}).get("hold_ema", True))
-    make = jax.jit(init_fn)
-    params = {"params": make(raw_key)}
-    ema = {"params": make(ema_key)} if hold_ema else None
+    # a top-level subtree at a time: the peak of set-up is the finished
+    # trees plus one subtree's float32 normals, not every leaf's
+    maker = weights.Maker(shapes)
+    params = {"params": maker.make(raw_key)}
+    ema = {"params": maker.make(ema_key)} if hold_ema else None
     pipe = DiffusionInferencePipeline.from_config(
         {"model": dict(cfg["model"], name=cfg["registry_name"]),
          "schedule": dict(cfg["schedule"]), "predictor": cfg["predictor"]},
@@ -57,7 +57,7 @@ def build_pipeline(cfg: Dict[str, Any], seed: int, null_ctx: np.ndarray):
         sample_data_key="sample", sample_data_shape=(res, res, ch),
         conditions=[ConditionalInputConfig(
             encoder=SeededContextEncoder(null_ctx))])
-    return pipe, init_fn, shapes, (ema_key if hold_ema else raw_key)
+    return pipe, shapes, (ema_key if hold_ema else raw_key)
 
 
 def serve_keys(seed: int):
@@ -129,6 +129,8 @@ def _counters(tel, names) -> Dict[str, float]:
     return {n: float(tel.counter(n).value) for n in names}
 
 
+# the counters the harness itself needs; a cell's per-layer files name
+# the others they read (`layer_metrics.counters_named`)
 COUNTERS = ("serving/rows_real", "serving/rounds", "serving/rows_padded")
 
 
@@ -149,8 +151,9 @@ def run(cell, args, found, meter, t_start) -> Dict[str, Any]:
 
     if args.control:
         return _control_only(cfg, traffic, null_ctx, devices, args, t_start)
-    pipe, init_fn, shapes, served_key = build_pipeline(cfg, args.seed,
-                                                       null_ctx)
+    pipe, shapes, served_key = build_pipeline(cfg, args.seed, null_ctx)
+    counters = tuple(dict.fromkeys(
+        COUNTERS + layer_metrics.counters_named(cell.per_layer)))
     print(f"config: {cfg['name']} {models.count_params(shapes) / 1e6:.1f} M "
           "parameters per tree", flush=True)
     tel = Telemetry(enabled=False)
@@ -201,14 +204,14 @@ def run(cell, args, found, meter, t_start) -> Dict[str, Any]:
                                     int(traffic.get("submit_workers", 2)))
     setup_s = t0 - t_start
     before = meter.snapshot()
-    c0 = _counters(tel, COUNTERS)
+    c0 = _counters(tel, counters)
 
     window = None
     if args.trace:
         # a short traced window inside the run: a few scheduler rounds
         rounds = int(traffic["trace_rounds"])
         time.sleep(0.2)
-        c_tr0, t_tr0 = _counters(tel, COUNTERS), time.perf_counter()
+        c_tr0, t_tr0 = _counters(tel, counters), time.perf_counter()
         n_done0 = len(rec.snapshot())
         with trace.capture(trace_dir):
             with trace.span("window"):
@@ -217,7 +220,7 @@ def run(cell, args, found, meter, t_start) -> Dict[str, Any]:
                         and time.perf_counter() - t_tr0 < 30:
                     time.sleep(0.002)
                 t_tr1 = time.perf_counter()
-                c_tr1 = _counters(tel, COUNTERS)
+                c_tr1 = _counters(tel, counters)
         done_tr = rec.snapshot()[n_done0:]
         seconds = 0.0       # the traced run's end-to-end numbers are not
         #                     the cell's: no further window
@@ -226,7 +229,7 @@ def run(cell, args, found, meter, t_start) -> Dict[str, Any]:
     t1 = time.perf_counter()
     stop.set()
     after = meter.snapshot()
-    c1 = _counters(tel, COUNTERS)
+    c1 = _counters(tel, counters)
     for t in threads:
         t.join(600)
     gc.unfreeze()
@@ -271,16 +274,17 @@ def run(cell, args, found, meter, t_start) -> Dict[str, Any]:
         tr = trace.read(trace_dir)
         if not tr.devices and not rehearse:
             raise RuntimeError("the trace holds no device operation")
-        rounds_n = c_tr1["serving/rounds"] - c_tr0["serving/rounds"]
         window = layer_metrics.Window(
             trace=tr, interval=tr.window(), wall_s=t_tr1 - t_tr0,
-            steps=int(rounds_n * (sconf.round_steps or 1)),
+            steps=0,        # rounds run a length of their own: the
+            #                 readers take it from the `serve.round` spans
             images=sum(int(d.fields["images"]) for d in done_tr
                        if d.result is not None),
             chips=cell.chips,
             results=[d.result for d in done if d.result is not None],
-            counters={k: c_tr1[k] - c_tr0[k] for k in COUNTERS},
-            memory=device.fullest_memory_stats(devices), peaks=peaks, cfg=cfg)
+            counters={k: c_tr1[k] - c_tr0[k] for k in counters},
+            memory=device.fullest_memory_stats(devices), peaks=peaks, cfg=cfg,
+            evals_per_row_step=2 if float(traffic["guidance_scale"]) else 1)
         out["window"] = window
 
     # -- correct: one request served twice returns equal samples; then,
@@ -298,24 +302,26 @@ def run(cell, args, found, meter, t_start) -> Dict[str, Any]:
     twice = [np.asarray(sched.submit(make_request(
         cfg, pool[0].fields, args.seed)).result(timeout=600).samples,
         np.float64) for _ in range(2)]
-    compared.append(("one request served twice, alone: largest gap",
+    compared.append(("repeat_gap",
+                     "one request served twice, alone: largest gap",
                      float(np.abs(twice[0] - twice[1]).max()),
                      limits["repeat_max_abs"]))
     sched.close(drain=True)
     served = pick_served(pool, int(traffic["check_requests"]), args.seed)
     del sched, pipe, pool, done, inside, good, rec, twice
     gc.collect()
-    gaps = reference_gaps(cfg, served, init_fn, served_key, null_ctx,
-                          args.seed, "")
+    gaps = reference_gaps(cfg, served, shapes, served_key, null_ctx,
+                          args.seed, "", devices)
     compared += gap_rows(gaps, len(served), limits, "served")
     ok = check.verdict(compared)
+    out["compared"] = compared
     if compiled:
         print(f"check: {compiled} compilation(s) inside the timed window  "
               "FAIL", flush=True)
     out["correct"] = bool(ok and ok_shapes and not compiled
                           and not out["failed"])
     out["compiled_in_window"] = compiled
-    out["readings"] = dict(gaps, repeat_gap=compared[0][1])
+    out["readings"] = dict(gaps, repeat_gap=compared[0][2])
     return out
 
 
@@ -331,10 +337,12 @@ def pick_served(pool, n_check: int, seed: int):
 
 
 def gap_rows(gaps, n, limits, what):
-    return [(f"mean abs gap of {n} {what} requests' samples to the "
-             "reference's", gaps["sample_gap"], limits["sample_mean_abs"]),
-            (f"the same, the worst request (nfe {gaps['worst_nfe']})",
-             gaps["worst_request_gap"], limits["sample_worst_request"])]
+    return [("sample_gap", f"mean abs gap of {n} {what} requests' samples "
+             "to the reference's", gaps["sample_gap"],
+             limits["sample_mean_abs"]),
+            ("worst_request_gap", "the same, the worst request (nfe "
+             f"{gaps['worst_nfe']})", gaps["worst_request_gap"],
+             limits["sample_worst_request"])]
 
 
 def _control_only(cfg, traffic, null_ctx, devices, args, t_start):
@@ -342,7 +350,7 @@ def _control_only(cfg, traffic, null_ctx, devices, args, t_start):
     first 50-step one among them), the reference's trajectory with its
     products in the next precision down, put in the served samples'
     place. The program is not built; `correct` has to come out false."""
-    _, _, init_fn, _ = models.build(cfg)
+    _, _, _, shapes = models.build(cfg)
     keys = serve_keys(args.seed)
     served_key = keys[1] if cfg.get("serve", {}).get("hold_ema", True) \
         else keys[0]
@@ -354,64 +362,96 @@ def _control_only(cfg, traffic, null_ctx, devices, args, t_start):
     served = [(request_fields(cfg, traffic, args.seed, i, nfes[i]),
                np.zeros((int(traffic["images_per_request"]), res, res, ch)))
               for i in picks]
-    gaps = reference_gaps(cfg, served, init_fn, served_key, null_ctx,
-                          args.seed, args.control)
-    ok = check.verdict(gap_rows(gaps, len(served),
-                                check.load_limits(cfg, "serve"),
-                                f"{args.control}-reference"))
+    gaps = reference_gaps(cfg, served, shapes, served_key, null_ctx,
+                          args.seed, args.control, devices)
+    compared = gap_rows(gaps, len(served), check.load_limits(cfg, "serve"),
+                        f"{args.control}-reference")
+    ok = check.verdict(compared)
     return {"metrics": {"setup_s": time.perf_counter() - t_start},
             "attempted": len(served), "failed": 0, "correct": ok,
+            "compared": compared,
             "memory_peak_bytes": device.memory_peak_bytes(devices),
             "readings": dict(gaps, repeat_gap=None), "window": None}
 
 
-def reference_gaps(cfg, served, init_fn, served_key, null_ctx, bench_seed,
-                   control: str) -> Dict[str, Any]:
+def reference_stages(cfg, maker, key):
+    """(stages_of, make) for `reference.sample.serve_staged`: the
+    forward pass of the configuration's family as ordered stages, and
+    the maker of a stage's weights, rounded to the program's type and
+    widened to float32. A family whose reference states no `stages` is
+    one stage, the whole tree, made once."""
+    import importlib
+    family = importlib.import_module(f"reference.{cfg['family']}")
+    names = tuple(maker.names())
+    held: Dict[Any, Any] = {}
+    if hasattr(family, "stages"):
+        def stages_of(shape):
+            return family.stages(cfg["model"], shape)
+    else:
+        def whole(parts, carry):
+            return family.forward(dict(zip(names, parts)), cfg["model"],
+                                  carry["x"], carry["t"], carry["text"])
+        stages_of = lambda shape: [("all", names, whole)]   # noqa: E731
+
+    def make(needs):
+        if needs == names:          # one stage: nothing to make room for
+            if needs not in held:
+                held[needs] = maker.make(key, needs, widen=True)
+            made = held[needs]
+        else:
+            made = maker.make(key, needs, widen=True)
+        return tuple(made[n] for n in needs)
+
+    return stages_of, make
+
+
+def reference_gaps(cfg, served, shapes, served_key, null_ctx, bench_seed,
+                   control: str, devices) -> Dict[str, Any]:
     """Mean absolute gap between the served samples and the plain
     reference's for the same requests: over all of them, and of the
-    worst request. With `control`, the reference in that lower precision
-    stands in the program's place."""
-    import importlib
-
-    import jax
-    import jax.numpy as jnp
-
+    worst request. The reference walks every request's trajectory with
+    one stage of float32 weights on the device at a time
+    (`reference.sample.serve_staged`). With `control`, the reference in
+    that lower precision stands in the program's place."""
     from reference import nn as ref_nn, sample as ref_sample
-    forward = importlib.import_module(f"reference.{cfg['family']}").forward
     t0 = time.perf_counter()
-    params = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32),
-                                    jax.jit(init_fn)(served_key))
+    maker = weights.Maker(shapes)
+    stages_of, make = reference_stages(cfg, maker, served_key)
     tok, feat = (cfg["conditioning"]["tokens"],
                  cfg["conditioning"]["features"])
     res, ch = cfg["input"]["resolution"], cfg["input"]["channels"]
-    per_request = []
-    eps_fns: Dict[Any, Any] = {}
-    for fields, samples in served:
-        req = {"seed": fields["seed"], "nfe": fields["nfe"],
-               "guidance": fields["guidance"],
-               "shape": (fields["images"], res, res, ch),
-               "cond": np.repeat(weights.request_context(
-                   bench_seed, fields["index"], tok, feat),
-                   fields["images"], axis=0),
-               "uncond": np.repeat(null_ctx, fields["images"], axis=0)}
+    requests = [{"seed": fields["seed"], "nfe": fields["nfe"],
+                 "guidance": fields["guidance"],
+                 "shape": (fields["images"], res, res, ch),
+                 "cond": np.repeat(weights.request_context(
+                     bench_seed, fields["index"], tok, feat),
+                     fields["images"], axis=0),
+                 "uncond": np.repeat(null_ctx, fields["images"], axis=0)}
+                for fields, _ in served]
+    stage_names = [(n, needs) for n, needs, _ in
+                   stages_of((2 * requests[0]["shape"][0], res, res, ch))]
+    largest = max(maker.nbytes(needs, widen=True) for _, needs in stage_names)
+    peak = [0]
 
-        def run(prec):
-            key = (prec, req["guidance"])
-            with ref_nn.precision(prec):
-                if key not in eps_fns:
-                    eps_fns[key] = ref_sample.make_eps(
-                        forward, cfg["model"], req["guidance"])
-                return np.asarray(ref_sample.serve(
-                    forward, cfg["model"], params, req,
-                    cfg["schedule"]["timesteps"], eps_fns[key],
-                    cfg["predictor"]))
+    def probe(_name):
+        peak[0] = max(peak[0], device.live_bytes(devices))
 
-        want = run("f32")
-        got = run(control) if control else samples
-        per_request.append(float(np.abs(got.astype(np.float64)
-                                        - want).mean()))
-    print(f"reference: {len(served)} requests in "
-          f"{time.perf_counter() - t0:.1f} s"
+    def run(prec):
+        with ref_nn.precision(prec):
+            return [np.asarray(x, np.float64) for x in
+                    ref_sample.serve_staged(
+                        stages_of, make, requests,
+                        cfg["schedule"]["timesteps"], cfg["predictor"],
+                        probe)]
+
+    want = run("f32")
+    got = run(control) if control else [np.asarray(s, np.float64)
+                                        for _, s in served]
+    per_request = [float(np.abs(g - w).mean()) for g, w in zip(got, want)]
+    print(f"reference: {len(served)} requests through {len(stage_names)} "
+          f"stage(s) in {time.perf_counter() - t0:.1f} s; most bytes alive "
+          f"on a device with a stage loaded {peak[0]}, the largest stage's "
+          f"float32 weights {largest}"
           + (f" (control: products in {control})" if control else ""),
           flush=True)
     worst = int(np.argmax(per_request))

@@ -11,7 +11,7 @@ import dataclasses
 import json
 import os
 import re
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -28,8 +28,18 @@ SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 CONFIG_FILE_KEYS = {
     "name", "source", "family", "registry_name", "model", "input",
     "conditioning", "schedule", "predictor", "train", "serve", "reduced",
-    "assumed", "departures", "deployment", "required_gflop_per_image_fwd",
-    "rehearse", "limits"}
+    "assumed", "departures", "deployment", "published",
+    "required_gflop_per_image_fwd", "rehearse", "limits"}
+# a catalog row's own sizes outside its `config`: accepted at the top
+# level of the file under their names, not required
+ROW_SIZE_KEYS = {"layers", "expert_width", "dense_width", "context_length"}
+# what `reduced` may name: a count of layers, of experts held, of heads
+# held or of vocabulary rows, or a list with one entry a layer whose name
+# is not a width's (the contract's widths)
+WIDTH_RE = re.compile(
+    r"hidden|intermediate|latent|state|proj|width|window|expan|ratio|"
+    r"per_tok|top_k|head_dim|head_size|_dim$|_rank$")
+COUNT_RE = re.compile(r"(^|_)(layers?|experts|heads|vocab_size)$")
 TRAFFIC_KEYS = {
     "train_steady": {"kind", "why", "mesh", "trace_steps",
                      "check_steps"},
@@ -51,7 +61,13 @@ READ_KEYS = {
     "counter_ratio": {"from", "numerator", "denominator"},
     "memory_stats": {"from", "keys", "of"},
     "required_ops": {"from", "of"},
+    "device_rounds": {"from", "round", "rounds_ahead", "match", "reduce"},
+    "served_ops": {"from", "round", "rounds_ahead", "live", "run", "of"},
+    "kernel_roofline": {"from", "match", "kernel", "round", "rounds_ahead",
+                        "live", "run", "of"},
 }
+# the keys of a `read` object whose values name counters of the program
+COUNTER_KEYS = ("numerator", "denominator", "live", "run")
 
 
 class SpecError(ValueError):
@@ -112,7 +128,8 @@ class Benchmark:
         if cfg_entry is None:
             raise SpecError(f"workload {name!r} names configuration "
                             f"{w['config']!r}, which `configs` lacks")
-        config = load_config(os.path.join(self.root, cfg_entry["file"]))
+        config = load_config(os.path.join(self.root, cfg_entry["file"]),
+                             entry=cfg_entry)
         traffic = load_traffic(os.path.join(
             self.bench_dir, "traffic", w["traffic"] + ".json"))
         e2e = [m for m in self.raw["end_to_end"]
@@ -141,12 +158,178 @@ class Benchmark:
                     end_to_end=e2e, per_layer=layer)
 
 
-def load_config(path: str) -> Dict[str, Any]:
+def source_keys(cfg: Dict[str, Any]) -> List[str]:
+    """The top-level keys of a loaded configuration that are its
+    source's own (`load_config` admits no others beside the harness's)."""
+    return [k for k in cfg if k not in CONFIG_FILE_KEYS
+            and k not in ROW_SIZE_KEYS]
+
+
+def _same(a: Any, b: Any) -> bool:
+    """Equal as JSON: a number never equals a boolean or a null."""
+    if isinstance(a, bool) or isinstance(b, bool) or a is None or b is None:
+        return type(a) is type(b) and a == b
+    if isinstance(a, dict) and isinstance(b, dict):
+        return set(a) == set(b) and all(_same(a[k], b[k]) for k in a)
+    if isinstance(a, list) and isinstance(b, list):
+        return len(a) == len(b) and all(map(_same, a, b))
+    return a == b
+
+
+def _within(short: list, whole: list) -> bool:
+    """Whether `short` is a run of consecutive entries of `whole`."""
+    n = len(short)
+    return any(_same(short, whole[i:i + n])
+               for i in range(len(whole) - n + 1))
+
+
+def _pattern(kinds: list) -> Tuple[int, int]:
+    """(leading, period) of a published per-layer list: the fewest
+    layers in all for which, after `leading` of them, the rest repeats
+    every `period` (a list that never repeats is one period)."""
+    for total in range(1, len(kinds) + 1):
+        for lead in range(total):
+            rest, p = kinds[lead:], total - lead
+            if all(_same(a, b) for a, b in zip(rest, rest[p:])):
+                return lead, p
+    return 0, 0
+
+
+def _under_floor(k: str, got: Any, want: Any) -> str:
+    """Why a reduced key leaves less than the model (the `model-configs`
+    guide's floors: a whole period and at least four of the layers that
+    follow the leading dense ones, at least 8 routed experts in each
+    layer that has them, at least an eighth of the vocabulary), or ""."""
+    if isinstance(want, list):
+        lead, period = _pattern(want)
+        need, n = max(period, 4), len(got)
+        held = max((n - max(0, lead - i) for i in range(len(want) - n + 1)
+                    if _same(got, want[i:i + n])), default=0)
+        if held < need:
+            return (f"the published list repeats every {period} layer(s) "
+                    f"after {lead} leading: a cut keeps a whole period and "
+                    f"at least four of the layers that follow the leading "
+                    f"ones, {need} of them here, and this keeps {held}")
+    elif re.search(r"(^|_)layers?$", k):
+        if got < 4:
+            return "a cut keeps at least four layers"
+    elif k.endswith("experts"):
+        if "shared" in k:
+            return ("shared experts are computed alike on every chip: "
+                    "each chip holds them all")
+        if got < 8:
+            return "a chip holds at least 8 routed experts of a layer"
+    elif k.endswith("vocab_size") and got * 8 < want:
+        return (f"a chip holds at least an eighth of the vocabulary, "
+                f"{-(-want // 8)} rows")
+    return ""
+
+
+def lint_against_source(what: str, cfg: Dict[str, Any], row: Dict[str, Any],
+                        entry: Optional[Dict[str, Any]] = None) -> None:
+    """Hold a configuration file to its catalog row, in the driver's
+    words for `config_differs`: the file holds every number and every
+    nested group of the source's entry under the same key, at its top
+    level; a key not listed in `reduced` equals the source's value
+    exactly; a key listed there is a count of layers, of experts held,
+    of heads held or of vocabulary rows (or the list that goes with
+    depth), never a width, and only smaller; the file states the
+    published count beside each (`published`) and the deployment."""
+    src = row["config"]
+    clash = sorted(set(src) & CONFIG_FILE_KEYS)
+    if clash:
+        raise SpecError(
+            f"{what}: the source's key(s) {clash} collide with the "
+            "harness's own keys of a configuration file: the file cannot "
+            "hold both under one name")
+    missing = sorted(set(src) - set(cfg))
+    if missing:
+        raise SpecError(
+            f"{what}: the source's key(s) {missing} are missing: the file "
+            "holds EVERY key of the row's `config` at its top level, "
+            "whether the model uses it or not")
+    reduced = list(cfg["reduced"])
+    for where, other in [("the file", cfg)] + (
+            [("BENCHMARK.json `configs`", entry)] if entry else []):
+        if other["source"] != row["source_url"]:
+            raise SpecError(
+                f"{what}: `source` in {where} is {other['source']!r}, the "
+                f"catalog row's `source_url` is {row['source_url']!r}")
+        if list(other["reduced"]) != reduced:
+            raise SpecError(
+                f"{what}: `reduced` is {reduced} in the file and "
+                f"{list(other['reduced'])} in {where}")
+    unknown = sorted(set(reduced) - set(src))
+    if unknown:
+        raise SpecError(f"{what}: `reduced` names {unknown}, which the "
+                        "source's `config` lacks")
+    for k, want in src.items():
+        got = cfg[k]
+        if k not in reduced:
+            if not _same(got, want):
+                raise SpecError(
+                    f"{what}: key {k!r} is {got!r} and its source gives "
+                    f"{want!r}; it is not listed in `reduced`")
+            continue
+        depth_list = (isinstance(want, list) and not WIDTH_RE.search(k)
+                      and len(want) == row.get("layers", len(want)))
+        if not (COUNT_RE.search(k) or depth_list):
+            raise SpecError(
+                f"{what}: `reduced` names {k!r}: only a count of layers, "
+                "of experts held, of heads held or of vocabulary rows, or "
+                "the list that goes with depth, may be reduced, never a "
+                "width")
+        smaller = (isinstance(got, list) and len(got) < len(want)
+                   and _within(got, want)) if isinstance(want, list) else (
+            isinstance(got, int) and not isinstance(got, bool)
+            and isinstance(want, int) and 0 < got < want)
+        if not smaller:
+            raise SpecError(
+                f"{what}: reduced key {k!r} is {got!r}; it may only be "
+                f"smaller than the source's {want!r}")
+        low = _under_floor(k, got, want)
+        if low:
+            raise SpecError(f"{what}: reduced key {k!r} is {got!r}: {low}")
+        if not _same(cfg.get("published", {}).get(k), want):
+            raise SpecError(
+                f"{what}: `published` has to state the source's {k!r} "
+                f"({want!r}) beside the reduced value")
+    for k, want in src.items():
+        # a per-layer list and the count of layers say one depth
+        if not (isinstance(want, list) and len(want) == row.get("layers")):
+            continue
+        for n, depth in src.items():
+            if re.search(r"(^|_)layers?$", n) and _same(depth, len(want)) \
+                    and cfg[n] != len(cfg[k]):
+                raise SpecError(
+                    f"{what}: {k!r} lists {len(cfg[k])} layers and {n!r} "
+                    f"is {cfg[n]!r}: reduce both, to the same depth")
+    if reduced and not cfg.get("deployment"):
+        raise SpecError(f"{what}: `deployment` has to say over how many "
+                        "chips each layer is divided, and how")
+
+
+def load_config(path: str, entry: Optional[Dict[str, Any]] = None
+                ) -> Dict[str, Any]:
+    """A configuration file. Where `sources/<name>.json` beside it holds
+    a copy of the configuration's catalog row, the row's `config` keys
+    are accepted at the top level, all of them are required, and the
+    file is held to the row (`lint_against_source`); `entry` is the
+    configuration's entry in BENCHMARK.json `configs`."""
     cfg = _load_json(path)
-    _check_keys(f"configuration {path}", cfg, CONFIG_FILE_KEYS,
+    allowed = CONFIG_FILE_KEYS
+    row = None
+    row_path = os.path.join(os.path.dirname(path), "sources",
+                            os.path.basename(path))
+    if os.path.exists(row_path):
+        row = _load_json(row_path)
+        allowed = CONFIG_FILE_KEYS | set(row["config"]) | ROW_SIZE_KEYS
+    _check_keys(f"configuration {path}", cfg, allowed,
                 {"name", "source", "family", "registry_name", "model",
                  "input", "conditioning", "schedule", "predictor",
                  "reduced", "assumed"})
+    if row is not None:
+        lint_against_source(f"configuration {path}", cfg, row, entry)
     return cfg
 
 
@@ -168,7 +351,10 @@ def load_layer_metric(path: str) -> Dict[str, Any]:
     if how not in READ_KEYS:
         raise SpecError(f"layer metric {path}: read.from {how!r} is not "
                         f"one of {sorted(READ_KEYS)}")
-    _check_keys(f"layer metric {path} read", read, READ_KEYS[how])
+    # a reader that pairs rounds with their programs is told how far the
+    # dispatch thread runs ahead: the benchmark assumes no such constant
+    _check_keys(f"layer metric {path} read", read, READ_KEYS[how],
+                {"rounds_ahead"} if "round" in read else None)
     return m
 
 

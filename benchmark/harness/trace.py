@@ -27,7 +27,9 @@ Event = Tuple[str, float, float]         # (name, start_ns, dur_ns)
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 ASYNC_LINE = "Async XLA Ops"
+MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "fdt."      # the program's own spans (telemetry/tracing.py)
 
 
 @dataclasses.dataclass
@@ -119,21 +121,33 @@ def from_events(rows: List[Dict]) -> Trace:
 
 
 def to_rows(pb_path: str) -> List[Dict]:
-    """Every device-op, async-op and benchmark-span event of an
-    `.xplane.pb`, as plain rows."""
+    """The events of an `.xplane.pb` the readers use, as plain rows:
+    every device operation, asynchronous operation and executed program
+    (`XLA Modules`), the benchmark's host spans, and the program's own
+    `fdt.*` spans with their thread (`<line name>#<position in the
+    plane>`) and stats."""
     from jax.profiler import ProfileData
     rows: List[Dict] = []
     for plane in ProfileData.from_file(pb_path).planes:
         on_device = bool(DEVICE_PLANE.match(plane.name))
-        for line in plane.lines:
-            if on_device and line.name not in (OPS_LINE, ASYNC_LINE):
+        for k, line in enumerate(plane.lines):
+            if on_device and line.name not in (OPS_LINE, ASYNC_LINE,
+                                               MODULES_LINE):
                 continue
             for e in line.events:
-                if not on_device and not e.name.startswith(SPAN_PREFIX):
+                ours = e.name.startswith(PROGRAM_PREFIX)
+                if not on_device and not ours \
+                        and not e.name.startswith(SPAN_PREFIX):
                     continue
-                rows.append({"plane": plane.name, "line": line.name,
-                             "name": e.name, "start_ns": e.start_ns,
-                             "dur_ns": e.duration_ns})
+                row = {"plane": plane.name, "line": line.name,
+                       "name": e.name, "start_ns": e.start_ns,
+                       "dur_ns": e.duration_ns}
+                if ours:
+                    row["thread"] = f"{line.name}#{k}"
+                    row["stats"] = {
+                        str(a): (b if isinstance(b, (int, float, str))
+                                 else str(b)) for a, b in e.stats}
+                rows.append(row)
     return rows
 
 
@@ -231,9 +245,15 @@ def breakdown(trace: Trace, window: Interval, top: int = 10,
 def dump_rows(trace: Trace, out_path: str, limit: int = 150000) -> None:
     """Keep the trace's rows beside the run's other output (gzip JSON);
     long HLO texts are cut to their head and tail, which hold the name
-    and the custom-call target."""
+    and the custom-call target. Where the device's operations outnumber
+    `limit`, the first and the last half of them are kept, with every
+    host span and executed program."""
+    ops = [i for i, r in enumerate(trace.rows)
+           if r["line"] in (OPS_LINE, ASYNC_LINE)]
+    drop = set(ops[limit // 2:len(ops) - limit // 2]) \
+        if len(ops) > limit else set()
     rows = [dict(r, name=(r["name"] if len(r["name"]) <= 300 else
                           r["name"][:120] + " ... " + r["name"][-170:]))
-            for r in trace.rows[:limit]]
+            for i, r in enumerate(trace.rows) if i not in drop]
     with gzip.open(out_path, "wt") as f:
         json.dump(rows, f)

@@ -113,7 +113,7 @@ def run(cell, args, found, meter, t_start) -> Dict[str, Any]:
     if args.control:
         return _control_only(cfg, traffic, batches, null_ctx, devices,
                              args, t_start)
-    (trainer, init_fn, init_key, train_key, program_losses, first_grad,
+    (trainer, shapes, init_key, train_key, program_losses, first_grad,
      grad_norms, delta) = _first_steps(cfg, traffic, chips, args.seed,
                                        null_ctx, batches)
     n_check = int(traffic["check_steps"])
@@ -218,10 +218,11 @@ def run(cell, args, found, meter, t_start) -> Dict[str, Any]:
     mesh_devices = list(trainer.mesh.devices.flat)
     del trainer
     gc.collect()
-    ref = _reference(cfg, batches, init_fn, init_key, train_key, null_ctx,
+    ref = _reference(cfg, batches, shapes, init_key, train_key, null_ctx,
                      n_check, mesh_devices, "")
-    ok, readings = _compare(cfg, n_check, program_losses, first_grad,
-                            grad_norms, delta, ref)
+    ok, readings, compared = _compare(cfg, n_check, program_losses,
+                                      first_grad, grad_norms, delta, ref)
+    out["compared"] = compared
     if not finite:
         print("check: a loss in the window is not finite  FAIL", flush=True)
     if compiled:
@@ -239,7 +240,8 @@ def _first_steps(cfg, traffic, chips, seed, null_ctx, batches):
     import jax
     from reference import train as ref_train
     tc = cfg["train"]
-    trainer, init_fn, _ = build_trainer(cfg, traffic, chips, seed, null_ctx)
+    trainer, init_fn, shapes = build_trainer(cfg, traffic, chips, seed,
+                                             null_ctx)
     _seed_step_flops(trainer, batches[0],
                      flops.train_flops_per_image(cfg) * tc["batch_per_chip"])
     n_check = int(traffic["check_steps"])
@@ -257,7 +259,7 @@ def _first_steps(cfg, traffic, chips, seed, null_ctx, batches):
     grad_norms = jax.tree_util.tree_map(
         lambda g: float(np.linalg.norm(np.asarray(g, np.float64))),
         first_grad)
-    return (trainer, init_fn, init_key, train_key,
+    return (trainer, shapes, init_key, train_key,
             list(h1["loss"]) + list(h2["loss"]), first_grad, grad_norms,
             delta)
 
@@ -268,17 +270,19 @@ def _control_only(cfg, traffic, batches, null_ctx, devices, args, t_start):
     limits. The program is not built and no window is measured;
     `correct` has to come out false."""
     from reference import train as ref_train
-    _, _, init_fn, _ = models.build(cfg)
+    shapes = models.build(cfg)[3]
     init_key, train_key = ref_train.run_keys(weights.seed32(args.seed))
     n_check = int(traffic["check_steps"])
-    ref = _reference(cfg, batches, init_fn, init_key, train_key, null_ctx,
+    ref = _reference(cfg, batches, shapes, init_key, train_key, null_ctx,
                      n_check, list(devices), "")
-    ctl = _reference(cfg, batches, init_fn, init_key, train_key, null_ctx,
+    ctl = _reference(cfg, batches, shapes, init_key, train_key, null_ctx,
                      n_check, list(devices), args.control)
-    ok, readings = _compare(cfg, n_check, ctl["losses"], ctl["first_grad"],
-                            ctl["grad_norms"], ctl["delta_norms"], ref)
+    ok, readings, compared = _compare(
+        cfg, n_check, ctl["losses"], ctl["first_grad"], ctl["grad_norms"],
+        ctl["delta_norms"], ref)
     return {"metrics": {"setup_s": time.perf_counter() - t_start},
             "attempted": n_check, "failed": 0, "correct": ok,
+            "compared": compared,
             "memory_peak_bytes": device.memory_peak_bytes(devices),
             "readings": readings, "window": None}
 
@@ -288,27 +292,32 @@ def _compare(cfg, n_check, program_losses, first_grad, grad_norms, delta,
     limits = check.load_limits(cfg, "train")
     compared = []
     for i, (p, r) in enumerate(zip(program_losses, ref["losses"])):
-        compared.append((f"loss[{i}] rel gap (program {p:.6f} reference "
-                         f"{r:.6f})", abs(p - r) / abs(r), limits["loss_rel"]))
+        compared.append((f"loss_rel_{i}", f"loss[{i}] rel gap (program "
+                         f"{p:.6f} reference {r:.6f})", abs(p - r) / abs(r),
+                         limits["loss_rel"]))
     g, where = check.worst_leaf_gap(grad_norms, ref["grad_norms"])
-    compared.append((f"first-gradient norm, worst leaf {where}", g,
+    compared.append(("grad", f"first-gradient norm, worst leaf {where}", g,
                      limits["grad_norm_worst_leaf"]))
     gd, where = check.worst_leaf_difference(first_grad, ref["first_grad"])
-    compared.append((f"first gradient, norm of the difference, worst leaf "
-                     f"{where}", gd, limits["grad_diff_worst_leaf"]))
+    compared.append(("grad_diff", "first gradient, norm of the difference, "
+                     f"worst leaf {where}", gd,
+                     limits["grad_diff_worst_leaf"]))
     d, where = check.worst_leaf_gap(delta, ref["delta_norms"],
                                      ref["grad_norms"])
-    compared.append((f"parameter-change norm after {n_check} steps, worst "
-                     f"leaf {where}", d, limits["delta_norm_worst_leaf"]))
+    compared.append(("delta", f"parameter-change norm after {n_check} "
+                     f"steps, worst leaf {where}", d,
+                     limits["delta_norm_worst_leaf"]))
     ok = check.verdict(compared)
-    return ok, {"loss_rel": max(c[1] for c in compared[:-3]),
-                "grad": g, "grad_diff": gd, "delta": d}
+    return ok, {"loss_rel": max(c[2] for c in compared[:-3]),
+                "grad": g, "grad_diff": gd, "delta": d}, compared
 
 
-def _reference(cfg, batches, init_fn, init_key, train_key, null_ctx,
+def _reference(cfg, batches, shapes, init_key, train_key, null_ctx,
                steps, mesh_devices, control: str):
     """Follow the first steps in float32 (or, for the control, with the
-    reference's products in a lower precision)."""
+    reference's products in a lower precision). Its float32 tree is made
+    a subtree at a time, widened as it is made: never a tree in the
+    program's type beside the float32 one."""
     import importlib
 
     import jax
@@ -316,16 +325,14 @@ def _reference(cfg, batches, init_fn, init_key, train_key, null_ctx,
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from reference import nn as ref_nn, train as ref_train
-    forward = importlib.import_module(f"reference.{cfg['family']}").forward
+    family = importlib.import_module(f"reference.{cfg['family']}")
+    forward = family.forward
     n_dev = len(mesh_devices)
     mesh = Mesh(np.asarray(mesh_devices), ("rows",)) if n_dev > 1 else None
     t0 = time.perf_counter()
+    params0 = weights.Maker(shapes).make(init_key, widen=True)
     if mesh is not None:
-        params0 = jax.jit(init_fn, out_shardings=NamedSharding(mesh, P()))(
-            init_key)
-    else:
-        params0 = jax.jit(init_fn)(init_key)
-    params0 = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), params0)
+        params0 = jax.device_put(params0, NamedSharding(mesh, P()))
     block = int(cfg["train"].get("reference_block_rows",
                                  cfg["train"]["batch_per_chip"])) * n_dev
     block = min(block, batches[0]["sample"].shape[0])

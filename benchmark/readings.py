@@ -54,16 +54,16 @@ def train(cell, cfg, args):
     sound, control = [], []
     for seed in args.seeds:
         batches = weights.train_batches(seed, tc["host_batches"], *shape)
-        (trainer, init_fn, init_key, train_key, losses, first_grad,
+        (trainer, shapes, init_key, train_key, losses, first_grad,
          grad_norms, delta) = ts._first_steps(cfg, traffic, cell.chips,
                                               seed, null_ctx, batches)
         devices = list(trainer.mesh.devices.flat)
         del trainer
         gc.collect()
-        ref = ts._reference(cfg, batches, init_fn, init_key, train_key,
+        ref = ts._reference(cfg, batches, shapes, init_key, train_key,
                             null_ctx, n_check, devices, "")
-        ok, got = ts._compare(cfg, n_check, losses, first_grad, grad_norms,
-                              delta, ref)
+        ok, got, _ = ts._compare(cfg, n_check, losses, first_grad,
+                                 grad_norms, delta, ref)
         sound.append(dict(got, seed=seed, correct=ok))
         del ref, first_grad
     for seed in args.control_seeds:
@@ -89,7 +89,7 @@ def serve(cell, cfg, args):
     limits = check.load_limits(cfg, "serve")
     sound, control = [], []
     if args.seeds:
-        pipe, init_fn, _, _ = sv.build_pipeline(cfg, args.seeds[0], null_ctx)
+        pipe, shapes, _ = sv.build_pipeline(cfg, args.seeds[0], null_ctx)
         sconf = SchedulerConfig()
         sched = ServingScheduler(pipeline=pipe,
                                  telemetry=Telemetry(enabled=False),
@@ -97,7 +97,7 @@ def serve(cell, cfg, args):
         sv.warm_engine(sched.engine, cfg, traffic, args.seeds[0],
                        sconf.batch_buckets, sconf.round_steps)
         sched.start()
-        make = jax.jit(init_fn)
+        make = weights.Maker(shapes).make
     for seed in args.seeds:
         raw_key, ema_key = sv.serve_keys(seed)
         pipe.params = pipe.ema_params = None
@@ -116,8 +116,8 @@ def serve(cell, cfg, args):
             t.join(600)
         pool = [d for d in rec.snapshot() if d.result is not None]
         served = sv.pick_served(pool, int(traffic["check_requests"]), seed)
-        gaps = sv.reference_gaps(cfg, served, init_fn, ema_key, null_ctx,
-                                 seed, "")
+        gaps = sv.reference_gaps(cfg, served, shapes, ema_key, null_ctx,
+                                 seed, "", jax.devices()[:cell.chips])
         ok = check.verdict(sv.gap_rows(gaps, len(served), limits, "served"))
         sound.append(dict(gaps, seed=seed, finished=len(pool), correct=ok))
     if args.seeds:

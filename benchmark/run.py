@@ -100,6 +100,7 @@ def main(argv=None) -> int:
     out = driver.run(cell, args, found, meter, T_START)
 
     dev = dict(found, memory_peak_bytes=int(out["memory_peak_bytes"]))
+    notes: dict = {}    # what a reader says besides its value
     line = {"correct": bool(out["correct"]),
             "attempted": int(out["attempted"]),
             "failed": int(out["failed"])}
@@ -109,7 +110,7 @@ def main(argv=None) -> int:
         if w.trace is not None:
             trace.dump_rows(w.trace, os.path.join(
                 args.out_dir, f"trace_rows_seed{args.seed}.json.gz"))
-        line["metrics"] = layer_metrics.read_all(cell.per_layer, w)
+        line["metrics"] = layer_metrics.read_all(cell.per_layer, w, notes)
         if w.trace is not None and w.trace.devices and w.interval:
             dev["busy_s"] = trace.busy_seconds(w.trace, w.interval)
             dev["window_s"] = (w.interval[1] - w.interval[0]) / 1e9
@@ -121,6 +122,12 @@ def main(argv=None) -> int:
         line["metrics"] = {k: {"value": float(v), "unit": units[k]}
                            for k, v in out["metrics"].items() if k in units}
     line["device"] = dev
+    if notes:
+        line["notes"] = notes       # which bound a roofline share stands on
+    # each number compared beside its limit: the line's last key, and the
+    # last lines on standard error
+    from harness import check
+    line["compared"] = check.as_result(out.get("compared", []))
     report = {"workload": cell.name, "seed": args.seed,
               "seconds": args.seconds, "trace": args.trace,
               "rehearsal": bool(args.rehearse), "control": args.control,
@@ -133,6 +140,10 @@ def main(argv=None) -> int:
     print("report " + json.dumps({k: report[k] for k in
                                   ("compile", "readings")}), flush=True)
     sys.stdout.flush()
+    for name, c in line["compared"].items():
+        print(f"compared {name} {c['value']:.6g} limit {c['limit']:.6g}",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

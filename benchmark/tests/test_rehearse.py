@@ -42,7 +42,13 @@ def test_cell_rehearses_end_to_end(cell, traced):
               "--seconds", "2", "--trace", str(traced), "--rehearse"])
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     line = _last_json(r.stdout)
-    assert LINE_KEYS <= set(line) <= LINE_KEYS | {"breakdown"}
+    assert LINE_KEYS <= set(line) <= LINE_KEYS | {"breakdown", "notes",
+                                                  "compared"}
+    # each number compared beside its limit: the line's last key, and the
+    # last lines on standard error
+    assert list(line)[-1] == "compared" and line["compared"]
+    assert all(c["value"] <= c["limit"] for c in line["compared"].values())
+    assert r.stderr.strip().splitlines()[-1].startswith("compared ")
     assert line["correct"] is True, r.stdout[-3000:]
     assert line["failed"] == 0 and line["attempted"] > 0
     assert line["device"]["platform"] == "cpu"       # labelled, not a chip
@@ -314,3 +320,43 @@ def test_the_four_chip_fsdp_cell_rehearses_on_four_virtual_devices(
         assert {"fit.step_wall_ms", "train.mfu_pct"} <= set(line["metrics"])
     else:
         assert set(line["metrics"]) == {"train_img_per_s_chip", "setup_s"}
+
+
+@pytest.mark.skipif(not SERVE, reason="no serving cell")
+def test_a_new_counter_is_read_by_a_metric_file_alone(tmp_path):
+    """The counters a cell reads are the ones its files name: the step
+    occupancy (live row-steps over row-steps run; 1.0 since rounds end
+    where their first row ends) added to a copy as a file and an entry,
+    with no edit to the harness."""
+    import shutil
+    shutil.copytree(BENCH, tmp_path / "benchmark",
+                    ignore=shutil.ignore_patterns("out", "__pycache__"))
+    os.symlink(os.path.join(ROOT, "flaxdiff_tpu"), tmp_path / "flaxdiff_tpu")
+    metric = {"name": "serve.step_occupancy", "unit": "share",
+              "better": "higher", "moves": "gen_img_per_s",
+              "layer": "serving (serving/scheduler.py, engine.py)",
+              "source": "program_counter",
+              "kinds": ["closed_loop", "open_loop"],
+              "read": {"from": "counter_ratio",
+                       "numerator": "serving/row_steps_live",
+                       "denominator": "serving/row_steps_run"}}
+    (tmp_path / "benchmark" / "layer_metrics" / "serve.step_occupancy.json"
+     ).write_text(json.dumps(metric))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        raw = json.load(f)
+    raw["per_layer"].append(
+        {k: metric[k] for k in ("name", "unit", "better", "moves", "layer",
+                                "source")} | {"workloads": [SERVE[0]]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(raw))
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run(
+        [sys.executable, str(tmp_path / "benchmark" / "run.py"),
+         "--workload", SERVE[0], "--seed", "17", "--seconds", "2",
+         "--trace", "1", "--rehearse"], cwd=tmp_path, env=env,
+        capture_output=True, text=True, timeout=900)
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
+    line = _last_json(r.stdout)
+    assert line["correct"] is True
+    assert line["metrics"]["serve.step_occupancy"] == {"value": 1.0,
+                                                       "unit": "share"}

@@ -159,3 +159,211 @@ def test_a_configuration_without_limits_stops_the_run():
     assert check.load_limits(cfg, "serve")["repeat_max_abs"] == 0
     with pytest.raises(KeyError, match="limits.train"):
         check.load_limits(cfg, "train")     # its training cell: unread
+
+
+# -- a configuration drawn from the catalog ---------------------------------
+
+TOY_DIR = os.path.join(BENCH, "tests", "data", "configs")
+
+
+def _toy(tmp_path):
+    """A copy of the toy catalog configuration (file, row, and its entry
+    in BENCHMARK.json `configs`), to break."""
+    shutil.copytree(TOY_DIR, tmp_path / "configs")
+    path = tmp_path / "configs" / "toy-catalog.json"
+    row_path = tmp_path / "configs" / "sources" / "toy-catalog.json"
+    cfg, row = json.loads(path.read_text()), json.loads(row_path.read_text())
+    entry = {"name": cfg["name"], "source": row["source_url"],
+             "file": "configs/toy-catalog.json",
+             "reduced": list(cfg["reduced"]), "why": "a toy"}
+    return path, row_path, cfg, row, entry
+
+
+@pytest.fixture
+def toy_dit(monkeypatch):
+    """A model that reads the toy row's keys under the source's names,
+    as the model a `model_config` PR brings reads its row's: SimpleDiT
+    with fields for the per-layer list, the head size, the experts held
+    and of a token, and the vocabulary (it does nothing with them)."""
+    import dataclasses
+
+    from flaxdiff_tpu.inference import registry
+    from flaxdiff_tpu.models.dit import SimpleDiT
+
+    @dataclasses.dataclass
+    class ToyDiT(SimpleDiT):
+        layer_types: tuple = ()
+        head_dim: int = 0
+        num_experts: int = 0
+        num_experts_per_tok: int = 0
+        vocab_size: int = 0
+        __hash__ = SimpleDiT.__hash__
+
+    monkeypatch.setitem(registry.MODEL_REGISTRY, "toy_dit", ToyDiT)
+
+
+def test_a_catalog_configuration_holds_its_sources_keys_at_the_top(
+        tmp_path, toy_dit, capsys):
+    from harness import models
+    path, _, _, row, entry = _toy(tmp_path)
+    cfg = spec.load_config(str(path), entry)
+    assert set(row["config"]) <= set(cfg)
+    assert set(spec.source_keys(cfg)) == set(row["config"])
+    assert cfg["rms_norm_eps"] is None and cfg["use_qk_norm"] is False
+    assert cfg["rope_parameters"] == row["config"]["rope_parameters"]
+    # the model is built from the source's keys as they stand after
+    # `reduced`, with the program's own `model` group over them
+    eff = models.effective_config(cfg, False)
+    assert eff["model"]["num_layers"] == 4 and eff["model"]["patch_size"] == 2
+    assert eff["model"]["layer_types"] == ["local", "local", "local", "full"]
+    model, _, _, shapes = models.build(eff)
+    assert (model.emb_features, model.head_dim, model.num_experts) == (
+        32, 8, 8)
+    assert model.layer_types == ("local", "local", "local", "full")
+    assert sorted(k for k in shapes if k.startswith("block_")) == [
+        "block_0", "block_1", "block_2", "block_3"]
+    # what the model has no field for is said, every run
+    said = capsys.readouterr().out
+    assert "does not read: ['model_type', 'rms_norm_eps', " \
+        "'rope_parameters', 'use_qk_norm']" in said
+    # a rehearsal may shrink a width, at the top level, that a run may not
+    assert models.effective_config(cfg, True)["model"]["emb_features"] == 16
+    assert models.effective_config(cfg, True)["model"]["patch_size"] == 4
+
+
+@pytest.mark.parametrize("registry_name,change,names", [
+    ("simple_dit", {}, "head_dim.*layer_types.*num_experts"),
+    ("toy_dit", {"model": {"patch": 2}}, "patch"),
+], ids=["a-width-and-a-reduced-key", "a-key-of-the-model-group"])
+def test_a_key_the_model_would_drop_is_refused(tmp_path, toy_dit,
+                                               registry_name, change, names):
+    """`build_model` drops a key its class has no field for. A width or
+    a reduced key dropped would pass the lint as equal to the source
+    while the model runs at its own default."""
+    from harness import models
+    path, _, _, _, entry = _toy(tmp_path)
+    eff = models.effective_config(spec.load_config(str(path), entry), False)
+    eff["registry_name"] = registry_name
+    for group, values in change.items():
+        eff[group].update(values)
+    with pytest.raises(spec.SpecError, match=names):
+        models.build(eff)
+
+
+def _missing(cfg, row, entry):
+    del cfg["use_qk_norm"]
+
+
+def _number_as_null(cfg, row, entry):
+    cfg["mlp_ratio"] = None
+
+
+def _width_changed(cfg, row, entry):
+    cfg["emb_features"] = 16
+
+
+def _width_listed(cfg, row, entry):
+    cfg["emb_features"] = 16
+    cfg["reduced"].append("emb_features")
+    entry["reduced"].append("emb_features")
+    cfg["published"]["emb_features"] = 32
+
+
+def _reduced_differs(cfg, row, entry):
+    entry["reduced"] = ["num_layers"]
+
+
+def _collision(cfg, row, entry):
+    row["config"]["family"] = "toy"
+
+
+def _grown(cfg, row, entry):
+    cfg["num_layers"] = 8
+
+
+def _head_size_listed(cfg, row, entry):
+    cfg["head_dim"] = 4
+    cfg["reduced"].append("head_dim")
+    entry["reduced"].append("head_dim")
+
+
+def _published_missing(cfg, row, entry):
+    del cfg["published"]["num_layers"]
+
+
+def _stray_key(cfg, row, entry):
+    cfg["hidden_size"] = 32         # the row's own size, not in `config`
+
+
+def _half_a_period(cfg, row, entry):
+    cfg["layer_types"], cfg["num_layers"] = ["local", "full"], 2
+
+
+def _list_and_count_disagree(cfg, row, entry):
+    cfg["num_layers"] = 5
+
+
+def _few_experts(cfg, row, entry):
+    cfg["num_experts"] = 4
+
+
+def _an_experts_share_of_a_token(cfg, row, entry):
+    cfg["num_experts_per_tok"] = 1
+    cfg["reduced"].append("num_experts_per_tok")
+    entry["reduced"].append("num_experts_per_tok")
+
+
+def _thin_vocabulary(cfg, row, entry):
+    cfg["vocab_size"] = 7
+
+
+@pytest.mark.parametrize("breaks,names", [
+    (_missing, "use_qk_norm"), (_number_as_null, "mlp_ratio"),
+    (_width_changed, "emb_features"), (_width_listed, "emb_features"),
+    (_reduced_differs, "reduced"), (_collision, "family"),
+    (_grown, "num_layers"), (_head_size_listed, "head_dim"),
+    (_published_missing, "published"), (_stray_key, "hidden_size"),
+    (_half_a_period, "layer_types.*whole period"),
+    (_list_and_count_disagree, "layer_types.*num_layers"),
+    (_few_experts, "num_experts.*8 routed"),
+    (_an_experts_share_of_a_token, "num_experts_per_tok.*never a width"),
+    (_thin_vocabulary, "vocab_size.*eighth")],
+    ids=lambda v: getattr(v, "__name__", None))
+def test_a_file_that_differs_from_its_source_is_refused(tmp_path, breaks,
+                                                        names):
+    """Where the driver would say `config_differs`, `load_config` fails
+    first, naming the key."""
+    path, row_path, cfg, row, entry = _toy(tmp_path)
+    breaks(cfg, row, entry)
+    path.write_text(json.dumps(cfg))
+    row_path.write_text(json.dumps(row))
+    with pytest.raises(spec.SpecError, match=names):
+        spec.load_config(str(path), entry)
+
+
+def test_the_configurations_without_a_source_copy_load_as_before():
+    raw = _raw()
+    for c in raw["configs"]:
+        cfg = spec.load_config(os.path.join(ROOT, c["file"]), c)
+        assert not spec.source_keys(cfg)
+        assert cfg["reduced"] == c["reduced"]
+        assert not os.path.exists(os.path.join(
+            BENCH, "configs", "sources", c["name"] + ".json"))
+
+
+@pytest.mark.parametrize("kinds,want", [
+    (["local", "local", "local", "full"] * 8, (0, 4)),
+    (["dense"] + ["moe"] * 9, (1, 1)),
+    (["dense", "dense"] + ["a", "b"] * 4 + ["a"], (2, 2)),
+    (["a", "b", "c"], (0, 3))])
+def test_the_period_of_a_published_per_layer_list(kinds, want):
+    assert spec._pattern(kinds) == want
+
+
+@pytest.mark.parametrize("got,low", [
+    (["dense", "moe", "moe", "moe"], True),        # three after the dense one
+    (["dense", "moe", "moe", "moe", "moe"], False),
+    (["moe"] * 4, False), (["moe"] * 3, True)])
+def test_leading_layers_count_once_towards_the_floor(got, low):
+    said = spec._under_floor("layer_types", got, ["dense"] + ["moe"] * 9)
+    assert bool(said) == low and ("whole period" in said) == low

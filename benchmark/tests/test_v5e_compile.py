@@ -131,6 +131,75 @@ def test_train_step_fits_a_v5e_chip(topo, config_name, chips):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def stand_in_shapes(layers: int = 4, experts: int = 16):
+    """Shapes only, bfloat16: the serving cut ISSUE 34 sizes the harness
+    for. Hidden 4096, 128 query and 8 key-value heads of 128, 4 shared
+    and `experts` routed experts of width 4096 with three matrices each,
+    a router over 128: 1,149.7 M parameters a layer, 4.60 B in four."""
+    import jax
+    import jax.numpy as jnp
+
+    def k(*shape):
+        return {"kernel": jax.ShapeDtypeStruct(shape, jnp.bfloat16)}
+
+    def mlp(n):
+        return {"gate": k(n, 4096, 4096), "up": k(n, 4096, 4096),
+                "down": k(n, 4096, 4096)}
+
+    return {f"layer_{i}": {
+        "attn": {"q": k(4096, 16384), "k": k(4096, 1024),
+                 "v": k(4096, 1024), "out": k(16384, 4096)},
+        "router": k(4096, 128), "shared_experts": mlp(4),
+        "experts": mlp(experts)} for i in range(layers)}
+
+
+HBM = 15.75e9        # what the v5e compiler allows a program
+
+
+def test_a_4_6_b_parameter_tree_is_made_a_stage_at_a_time(topo):
+    """The stage-wise init of a bfloat16 tree that fills most of a chip,
+    and one float32 stage of it for the reference, compiled for one v5e
+    chip: set-up peaks at the finished tree plus one stage's program,
+    the reference at one float32 stage plus its carries. A compile, not
+    a run."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import SingleDeviceSharding
+
+    from harness import models, weights
+
+    shapes = stand_in_shapes()
+    assert models.count_params(shapes) == pytest.approx(4.6e9, rel=0.01)
+    maker = weights.Maker(shapes)
+    one = SingleDeviceSharding(topo.devices[0])
+    total = {}
+    for widen in (False, True):
+        fn, folds = maker.program("layer_0", widen)
+        assert fn is maker.program("layer_3", widen)[0]     # one program
+        ma = fn.lower(
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one),
+            jax.ShapeDtypeStruct(folds.shape, folds.dtype, sharding=one)
+        ).compile().memory_analysis()
+        total[widen] = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+                        + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+        print("stage program", "float32" if widen else "bfloat16",
+              {"output": ma.output_size_in_bytes,
+               "temp": ma.temp_size_in_bytes, "total": total[widen]})
+    stage = maker.nbytes(["layer_0"])
+    assert stage == pytest.approx(2.3e9, rel=0.01)
+    assert maker.nbytes(["layer_0"], widen=True) == 2 * stage
+    # set-up: three stages made, the fourth's program running
+    assert 3 * stage + total[False] < HBM
+    # the reference: the program's tree freed; one stage in float32 and
+    # the carries of 8 guided requests of 4,096 tokens (2 x 4096 x 4096
+    # float32 each, a few times over for a block's intermediates)
+    carries = 8 * 6 * 2 * 4096 * 4096 * 4
+    assert total[True] + carries < HBM
+    assert np.dtype(np.float32).itemsize * models.count_params(
+        shapes) > HBM      # the whole tree in float32 would not fit
+
+
 if __name__ == "__main__":
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
