@@ -232,8 +232,19 @@ class DiffusionInferencePipeline:
                 cache_fns = resolve_composed_fns(self.model, plan)
             else:
                 cache_fns = resolve_cache_fns(self.model, plan)
+            # a model with routed experts also returns its held picks by
+            # layer and expert: summed over the batch, they ride the
+            # serving programs as each row's tally (`moe/picks_*`)
+            picks_shape = getattr(self.model, "picks_shape", None)
+            if picks_shape is None:
+                model_fn = lambda p, x, t, c: self.model.apply(p, x, t, c)
+            else:
+                def model_fn(p, x, t, c):
+                    raw, picks = self.model.apply(p, x, t, c,
+                                                  return_picks=True)
+                    return raw, picks.sum(axis=0)
             self._sampler_cache[key] = DiffusionSampler(
-                model_fn=lambda p, x, t, c: self.model.apply(p, x, t, c),
+                model_fn=model_fn, tally_shape=picks_shape,
                 schedule=self.schedule, transform=self.transform,
                 autoencoder=self.autoencoder,
                 guidance_scale=guidance_scale,

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Tuple
 
+from ..models.cohere2_moe import Cohere2MoEDenoiser
 from ..models.dit import SimpleDiT
 from ..models.mmdit import HierarchicalMMDiT, SimpleMMDiT
 from ..models.ssm import HybridSSMAttentionDiT
@@ -24,6 +25,7 @@ MODEL_REGISTRY: Dict[str, Any] = {
     "hierarchical_mmdit": HierarchicalMMDiT,
     "hybrid_ssm": HybridSSMAttentionDiT,
     "unet_3d": UNet3D,
+    "cohere2_moe_dn": Cohere2MoEDenoiser,
 }
 
 # Suffix -> constructor kwarg toggles (reference inference/utils.py:168-180).

@@ -15,7 +15,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import dot_product_attention, dot_product_attention_bhld
+from ..ops.attention import attend
 from ..typing import Dtype
 from .common import kernel_init
 
@@ -170,9 +170,7 @@ class AttentionLayer(nn.Module):
         q = proj("to_q")(x)
         k = proj("to_k")(context)
         v = proj("to_v")(context)
-        attend = (dot_product_attention_bhld if bhld
-                  else dot_product_attention)
-        out = attend(q, k, v, backend=self.backend,
+        out = attend(q, k, v, bhld=bhld, backend=self.backend,
                      force_fp32_for_softmax=self.force_fp32_for_softmax)
         out = head_out_projection(
             bhld, features=x.shape[-1], heads=self.heads,

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 import os
 
-from ..ops.attention import dot_product_attention, dot_product_attention_bhld
+from ..ops.attention import attend
 from ..typing import Dtype
 from .attention import head_out_projection, head_projection
 from .common import FourierEmbedding, TimeProjection
@@ -164,9 +164,7 @@ class RoPEAttention(nn.Module):
                        sin[: k.shape[seq_axis]], bhld=bhld)
         out_init = (self.out_kernel_init if self.out_kernel_init is not None
                     else nn.linear.default_kernel_init)
-        attend = (dot_product_attention_bhld if bhld
-                  else dot_product_attention)
-        out = attend(q, k, v, backend=self.backend,
+        out = attend(q, k, v, bhld=bhld, backend=self.backend,
                      force_fp32_for_softmax=self.force_fp32_for_softmax)
         out = head_out_projection(
             bhld, features=x.shape[-1], heads=self.heads,

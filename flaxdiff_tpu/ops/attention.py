@@ -53,21 +53,65 @@ def _flash_impl() -> str:
     return os.environ.get("FLAXDIFF_FLASH_IMPL", "firstparty")
 
 
+def _self_mask(lq: int, lk: int, causal: bool, window: Optional[int]):
+    """[lq, lk] boolean mask of a self-attention call, or None: query i
+    sees keys j with j <= i (`causal`) and i - window < j (`window`)."""
+    if not causal and window is None:
+        return None
+    assert lq == lk, "a causal / window mask is self-attention's"
+    i = jnp.arange(lq)[:, None]
+    j = jnp.arange(lk)[None, :]
+    keep = jnp.ones((lq, lk), bool)
+    if causal:
+        keep = keep & (j <= i)
+    if window is not None:
+        keep = keep & (j > i - window)
+    return keep
+
+
+def _masked_call(q_heads: int, kv_heads: int, seq_len: int, causal: bool,
+                 window: Optional[int]):
+    """The ONE place the second head count and the mask are settled for
+    both dispatchers: (special, window). A window the sequence never
+    reaches is no window (static, so the kernel compiles without it);
+    `special` says the call is grouped or masked, which only the flash
+    kernel's BLHD entry and the XLA compositions serve."""
+    if q_heads % kv_heads:
+        raise ValueError(f"{q_heads} query heads are not a multiple of "
+                         f"{kv_heads} key/value heads")
+    if window is not None and window >= seq_len:
+        window = None
+    return bool(causal or window is not None or q_heads != kv_heads), window
+
+
 def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    scale: Optional[float] = None,
-                   force_fp32_for_softmax: bool = True) -> jax.Array:
-    """Plain XLA attention; softmax in f32 for bf16 stability."""
+                   force_fp32_for_softmax: bool = True,
+                   causal: bool = False,
+                   window: Optional[int] = None) -> jax.Array:
+    """Plain XLA attention over [B, L, H, D]; softmax in f32 for bf16
+    stability. `k` / `v` may carry fewer heads (query head i reads
+    key/value head i // (H / KV)); `causal` / `window` as `_self_mask`."""
     orig_dtype = q.dtype
-    d = q.shape[-1]
+    b, lq, h, d = q.shape
+    kv = k.shape[2]
     scale = scale if scale is not None else 1.0 / jnp.sqrt(d).astype(jnp.float32)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+    keep = _self_mask(lq, k.shape[1], causal, window)
+    grouped = kv != h
+    if grouped:     # [B, L, KV, G, D] queries beside [B, L, KV, D] keys
+        q = q.reshape(b, lq, kv, h // kv, d)
+    logits = jnp.einsum("bqkgd,bskd->bkgqs" if grouped else
+                        "bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
+    if keep is not None:
+        logits = jnp.where(keep, logits, -1e30)
     if force_fp32_for_softmax:
         probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     else:
         probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(orig_dtype), v)
-    return out
+    out = jnp.einsum("bkgqs,bskd->bqkgd" if grouped else
+                     "bhqk,bkhd->bqhd", probs.astype(orig_dtype), v)
+    return out.reshape(b, lq, h, d) if grouped else out
 
 
 def _flash_specs(mesh, n_batch: int, n_heads: int):
@@ -102,7 +146,9 @@ def _shard_mapped_flash(q: jax.Array, k: jax.Array, v: jax.Array,
                         scale: float, mesh, batch_axes, head_axis,
                         interpret: bool = False,
                         block_q: Optional[int] = None,
-                        block_k: Optional[int] = None) -> jax.Array:
+                        block_k: Optional[int] = None,
+                        causal: bool = False,
+                        window: Optional[int] = None) -> jax.Array:
     """Run the Pallas kernel per-device under shard_map.
 
     A pallas_call is opaque to GSPMD — in a program compiled for more
@@ -118,10 +164,9 @@ def _shard_mapped_flash(q: jax.Array, k: jax.Array, v: jax.Array,
     from ..parallel.context import batch_partition_entry
     spec = jax.sharding.PartitionSpec(batch_partition_entry(batch_axes),
                                       None, head_axis, None)
-    body = lambda a, b, c: flash_attention(a, b, c, scale=scale,
-                                           block_q=block_q,
-                                           block_k=block_k,
-                                           interpret=interpret)
+    body = lambda a, b, c: flash_attention(a, b, c, scale, block_q,
+                                           block_k, interpret, causal,
+                                           window)
     return _shard_map_qkv(body, mesh, spec)(q, k, v)
 
 
@@ -178,8 +223,17 @@ def _seq_parallel_gate(q: jax.Array, k: jax.Array,
 def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                           backend: str = "auto",
                           scale: Optional[float] = None,
-                          force_fp32_for_softmax: bool = True) -> jax.Array:
+                          force_fp32_for_softmax: bool = True,
+                          causal: bool = False,
+                          window: Optional[int] = None) -> jax.Array:
     """Multi-head attention over BTNH tensors.
+
+    `k` / `v` may carry fewer heads than `q`, a divisor of them (query
+    head i reads key/value head i // (H / KV)); `causal` / `window`
+    (self-attention): query i sees keys j with j <= i, and with
+    i - window < j. A grouped or masked call runs the flash kernel or
+    the XLA composition; the other backends serve neither and give way
+    to "auto".
 
     backend: "flash" (Pallas TPU kernel), "xla", "ring" (sequence-parallel
     ring attention over the active mesh's seq axis — self-attention only),
@@ -189,6 +243,13 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (flash on TPU when shapes qualify, else xla).
     """
     assert q.ndim == 4 and k.ndim == 4 and v.ndim == 4
+    special, window = _masked_call(q.shape[2], k.shape[2], q.shape[1],
+                                   causal, window)
+    if special and backend in ("performer", "ring", "ulysses", "prebuilt"):
+        backend = "auto"
+    xla = functools.partial(
+        _xla_attention, scale=scale, causal=causal, window=window,
+        force_fp32_for_softmax=force_fp32_for_softmax)
     if backend == "performer":
         # softmax is implicit in the kernel estimator (always f32), so
         # force_fp32_for_softmax has no meaning here; scale is honored.
@@ -240,27 +301,26 @@ def dot_product_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         from ..parallel.context import get_active_mesh
         mesh = get_active_mesh()
         if mesh is not None and mesh.devices.size > 1:
-            sharded = _flash_specs(mesh, q.shape[0], q.shape[2])
+            # the key/value heads tile the tensor axis if the queries'
+            # do, never the other way round
+            sharded = _flash_specs(mesh, q.shape[0], k.shape[2])
             if sharded is None:
-                return _xla_attention(
-                    q, k, v, scale=scale,
-                    force_fp32_for_softmax=force_fp32_for_softmax)
+                return xla(q, k, v)
             q, k, v, pad = _maybe_pad_head_dim(q, k, v, native=native)
             out = _shard_mapped_flash(q, k, v, scale_eff, mesh, *sharded,
                                       interpret=_flash_interpret(),
-                                      block_q=bq, block_k=bk)
+                                      block_q=bq, block_k=bk,
+                                      causal=causal, window=window)
             return out[..., :d] if pad else out
-        if _route_auto_to_prebuilt(backend):
+        if not special and _route_auto_to_prebuilt(backend):
             return _prebuilt_btnh(q, k, v, scale)
         q, k, v, pad = _maybe_pad_head_dim(q, k, v, native=native)
-        out = flash_attention(q, k, v, scale=scale_eff,
-                              block_q=bq, block_k=bk,
-                              interpret=_flash_interpret())
+        out = flash_attention(q, k, v, scale_eff, bq, bk,
+                              _flash_interpret(), causal, window)
         return out[..., :d] if pad else out
     if backend == "flash" and not attention_backend_available("flash"):
         raise _flash_unavailable()
-    return _xla_attention(q, k, v, scale=scale,
-                          force_fp32_for_softmax=force_fp32_for_softmax)
+    return xla(q, k, v)
 
 
 def _flash_unavailable() -> RuntimeError:
@@ -378,7 +438,9 @@ def _xla_attention_bhld(q, k, v, scale=None,
 def dot_product_attention_bhld(q: jax.Array, k: jax.Array, v: jax.Array,
                                backend: str = "auto",
                                scale: Optional[float] = None,
-                               force_fp32_for_softmax: bool = True
+                               force_fp32_for_softmax: bool = True,
+                               causal: bool = False,
+                               window: Optional[int] = None
                                ) -> jax.Array:
     """Attention over [B, H, L, D] operands — the flash kernel's native
     grid layout, reached by FREE reshapes (B and H adjacent).
@@ -397,6 +459,16 @@ def dot_product_attention_bhld(q: jax.Array, k: jax.Array, v: jax.Array,
     from ..parallel.context import get_active_mesh
     mesh = get_active_mesh()
     multi = mesh is not None and mesh.devices.size > 1
+    special, window = _masked_call(h, k.shape[1], lq, causal, window)
+    if special:
+        # the kernel's [B*H, L, D] entry serves one head count and no
+        # mask: a grouped or masked call pays the BLHD entry's transposes
+        out = dot_product_attention(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), backend=backend, scale=scale,
+            force_fp32_for_softmax=force_fp32_for_softmax,
+            causal=causal, window=window)
+        return out.transpose(0, 2, 1, 3)
     if backend in ("ring", "ulysses", "performer") or multi:
         # batch/head-sharded flash keeps the BHLD-native shard_map path
         # (free reshapes into the kernel grid); everything else —
@@ -455,3 +527,16 @@ def dot_product_attention_bhld(q: jax.Array, k: jax.Array, v: jax.Array,
                              interpret=_flash_interpret())
     out = out.reshape(b, h, lq, out.shape[-1])
     return out[..., :d] if pad else out
+
+
+def attend(q: jax.Array, k: jax.Array, v: jax.Array, *, bhld: bool = False,
+           backend: str = "auto", scale: Optional[float] = None,
+           force_fp32_for_softmax: bool = True, causal: bool = False,
+           window: Optional[int] = None) -> jax.Array:
+    """What an attention module calls: the dispatcher of its layout
+    ([B, L, H, D], or [B, H, L, D] with `bhld`), with the second head
+    count read off `k` and the mask handed on."""
+    fn = dot_product_attention_bhld if bhld else dot_product_attention
+    return fn(q, k, v, backend=backend, scale=scale,
+              force_fp32_for_softmax=force_fp32_for_softmax,
+              causal=causal, window=window)

@@ -66,14 +66,33 @@ def _bcast(x: jax.Array, width: int) -> jax.Array:
 # Forward kernel
 # ---------------------------------------------------------------------------
 
+def _mask_block_range(qi, block_q: int, block_k: int, num_kb: int,
+                      causal: bool, window: Optional[int]):
+    """(first, last) kv block a q block needs under the causal / window
+    mask (query i sees keys j with i - window < j <= i). Traced on `qi`;
+    shared by the kernel's skip test and the k/v index maps, which clamp
+    to it so that a skipped block costs no copy either."""
+    last = num_kb - 1
+    if causal:
+        last = jnp.minimum(last, (qi * block_q + block_q - 1) // block_k)
+    first = 0
+    if window is not None:
+        first = jnp.maximum(0, (qi * block_q - window + 1) // block_k)
+    return first, last
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
-                scale: float, kv_len: int, block_k: int):
+                scale: float, kv_len: int, block_k: int,
+                group: int = 1, causal: bool = False,
+                window: Optional[int] = None):
     # rest = (lse_ref?, m_scr, l_scr, acc_scr); lse is only emitted on the
     # custom_vjp fwd path — the plain primal skips the residual write.
     if len(rest) == 4:
         lse_ref, m_scr, l_scr, acc_scr = rest
     else:
         lse_ref, (m_scr, l_scr, acc_scr) = None, rest
+    masked = causal or window is not None
+    qi = pl.program_id(1) if masked else None
     ki = pl.program_id(2)
     num_kb = pl.num_programs(2)
 
@@ -83,35 +102,69 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0]                                # [block_q, d] native dtype
-    k = k_ref[0]                                # [block_k, d]
-    v = v_ref[0]
-    d = q.shape[-1]
+    def _block():
+        q = q_ref[0]                            # [block_q, d] native dtype
+        k = k_ref[0]                            # [block_k, d]
+        v = v_ref[0]
+        d = q.shape[-1]
+        block_q = q.shape[-2]
+        if group > 1:
+            # the query heads that share this key/value head, stacked
+            # on the rows: [group, block_q, d] -> [group * block_q, d]
+            q = q.reshape(group * block_q, d)
 
-    # bf16 x bf16 -> f32 rides the MXU natively; only the softmax math is f32.
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    kv_idx = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(kv_idx < kv_len, s, NEG_INF)
+        # bf16 x bf16 -> f32 rides the MXU natively; only the softmax
+        # math is f32.
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        kv_idx = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        keep = kv_idx < kv_len
+        if masked:
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            if group > 1:       # the row's position inside its head
+                row = (row & (block_q - 1)
+                       if block_q & (block_q - 1) == 0
+                       else jax.lax.rem(row, block_q))
+            q_idx = qi * block_q + row
+            if causal:
+                keep = jnp.logical_and(keep, kv_idx <= q_idx)
+            if window is not None:
+                keep = jnp.logical_and(keep, kv_idx > q_idx - window)
+        s = jnp.where(keep, s, NEG_INF)
 
-    m_prev = m_scr[...]                          # [block_q, LANES]
-    l_prev = l_scr[...]
-    m_curr = jnp.max(s, axis=1, keepdims=True)   # [block_q, 1]
-    m_next = jnp.maximum(m_prev, m_curr)         # lane-replicated
-    p = jnp.exp(s - _bcast(m_next, block_k))
-    alpha = jnp.exp(m_prev - m_next)             # [block_q, LANES]
-    l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-    m_scr[...] = m_next
-    acc_scr[...] = (acc_scr[...] * _bcast(alpha, d)
-                    + jax.lax.dot_general(
-                        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32))
+        m_prev = m_scr[...]                          # [rows, LANES]
+        l_prev = l_scr[...]
+        m_curr = jnp.max(s, axis=1, keepdims=True)   # [rows, 1]
+        m_next = jnp.maximum(m_prev, m_curr)         # lane-replicated
+        p = jnp.exp(s - _bcast(m_next, block_k))
+        if masked:
+            # a row whose every key so far is masked has m = NEG_INF and
+            # exp(0) = 1 on each of them: they weigh nothing
+            p = jnp.where(keep, p, 0.0)
+        alpha = jnp.exp(m_prev - m_next)             # [rows, LANES]
+        l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[...] = m_next
+        acc_scr[...] = (acc_scr[...] * _bcast(alpha, d)
+                        + jax.lax.dot_general(
+                            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32))
+
+    if masked:
+        # blocks wholly outside the mask are skipped (their copies too:
+        # the index maps clamp to the same range)
+        first, last = _mask_block_range(qi, o_ref.shape[-2], block_k,
+                                        num_kb, causal, window)
+        pl.when(jnp.logical_and(ki >= first, ki <= last))(_block)
+    else:
+        _block()
 
     @pl.when(ki == num_kb - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] * _bcast(1.0 / l, d)
-                    ).astype(o_ref.dtype)
+        d = o_ref.shape[-1]
+        out = (acc_scr[...] * _bcast(1.0 / l, d)).astype(o_ref.dtype)
+        o_ref[0] = out.reshape(o_ref.shape[1:]) if group > 1 else out
         if lse_ref is not None:
             lse_ref[0] = m_scr[...] + jnp.log(l)
 
@@ -231,7 +284,8 @@ DEFAULT_BLOCK_K = 1024
 
 
 def _block_sizes(lq: int, lk: int, block_q: Optional[int],
-                 block_k: Optional[int], interpret: bool):
+                 block_k: Optional[int], interpret: bool,
+                 group: int = 1, masked: bool = False):
     """Effective block sizes. On TPU blocks stay lane-aligned (the caller
     pads head_dim; seq dims are padded here); in interpret mode small
     test shapes shrink the blocks instead.
@@ -249,10 +303,16 @@ def _block_sizes(lq: int, lk: int, block_q: Optional[int],
     # (tests, VMEM-bounded long-sequence callers) must win
     if block_q is None:
         env_q = os.environ.get("FLAXDIFF_FLASH_BLOCK_Q")
-        block_q = int(env_q) if env_q else min(DEFAULT_BLOCK_Q, rq)
+        # grouped queries: a block holds `group` heads' rows of it
+        block_q = int(env_q) if env_q else min(
+            max(LANES, DEFAULT_BLOCK_Q // group), rq)
     if block_k is None:
         env_k = os.environ.get("FLAXDIFF_FLASH_BLOCK_K")
-        block_k = int(env_k) if env_k else min(DEFAULT_BLOCK_K, rk)
+        # under a mask whole blocks are skipped, so smaller ones skip
+        # more: a lane's width up to 1024 keys, 512 beyond
+        default_k = (DEFAULT_BLOCK_K if not masked
+                     else LANES if rk <= 1024 else 512)
+        block_k = int(env_k) if env_k else min(default_k, rk)
     if interpret:
         bq = min(block_q, max(lq, 8))
         bk = min(block_k, max(lk, 8))
@@ -262,46 +322,77 @@ def _block_sizes(lq: int, lk: int, block_q: Optional[int],
 
 
 def _fwd_impl(q3, k3, v3, scale, block_q, block_k, interpret,
-              save_residuals: bool = False):
+              save_residuals: bool = False, causal: bool = False,
+              window: Optional[int] = None):
     """Forward over [B*H, L, D] operands (the layout the kernel grids
     over natively — BHLD callers reach here with FREE reshapes, BLHD
-    callers pay one transpose in _to_bh)."""
-    bh, lq, d = q3.shape
+    callers pay one transpose in _to_bh).
+
+    Grouped queries: `q3` is [B*KV, G, L, D] beside [B*KV, L, D] keys
+    and values, and a q block holds the G heads' rows, so a key/value
+    block is read once for the heads that share it. `causal` / `window`
+    (static): query i sees keys j with j <= i / i - window < j; blocks
+    wholly outside the mask are skipped. With none of the three the
+    kernel, its grid and its block maps are what they were before them
+    (static Python branches)."""
+    group = q3.shape[1] if q3.ndim == 4 else 1
+    masked = causal or window is not None
+    bh, lq, d = q3.shape[0], q3.shape[-2], q3.shape[-1]
     kv_len = k3.shape[1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    bq, bk = _block_sizes(lq, kv_len, block_q, block_k, interpret)
+    bq, bk = _block_sizes(lq, kv_len, block_q, block_k, interpret,
+                          group, masked)
     lanes = _FORCE_LANES or (1 if interpret else LANES)
 
-    qb = _pad_to(q3, 1, bq)
+    qb = _pad_to(q3, q3.ndim - 2, bq)
     kb = _pad_to(k3, 1, bk)
     vb = _pad_to(v3, 1, bk)
-    lq_pad, lk_pad = qb.shape[1], kb.shape[1]
+    lq_pad, lk_pad = qb.shape[-2], kb.shape[1]
+    num_kb = lk_pad // bk
 
-    grid = (bh, lq_pad // bq, lk_pad // bk)
-    out_specs = [pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0))]
-    out_shape = [jax.ShapeDtypeStruct((bh, lq_pad, d), q3.dtype)]
+    grid = (bh, lq_pad // bq, num_kb)
+    if group > 1:
+        q_block = (1, group, bq, d)
+        q_map = lambda bh, qi, ki: (bh, 0, qi, 0)
+    else:
+        q_block = (1, bq, d)
+        q_map = lambda bh, qi, ki: (bh, qi, 0)
+    if masked:
+        def kv_map(bh, qi, ki):
+            first, last = _mask_block_range(qi, bq, bk, num_kb, causal,
+                                            window)
+            return (bh, jnp.clip(ki, first, last), 0)
+    else:
+        kv_map = lambda bh, qi, ki: (bh, ki, 0)
+    out_specs = [pl.BlockSpec(q_block, q_map)]
+    out_shape = [jax.ShapeDtypeStruct(qb.shape, q3.dtype)]
     if save_residuals:
+        assert group == 1 and not masked, (
+            "the masked / grouped call's backward is the XLA "
+            "composition's: it keeps no residuals")
         out_specs.append(
             pl.BlockSpec((1, bq, lanes), lambda bh, qi, ki: (bh, qi, 0)))
         out_shape.append(
             jax.ShapeDtypeStruct((bh, lq_pad, lanes), jnp.float32))
+    kernel_kwargs = dict(scale=scale, kv_len=kv_len, block_k=bk)
+    if group > 1 or masked:
+        kernel_kwargs.update(group=group, causal=causal, window=window)
     res = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, kv_len=kv_len,
-                          block_k=bk),
+        functools.partial(_fwd_kernel, **kernel_kwargs),
         name="fdt_flash_fwd",
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (bh, ki, 0)),
+            pl.BlockSpec(q_block, q_map),
+            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bk, d), kv_map),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((bq, lanes), jnp.float32),   # running max
-            pltpu.VMEM((bq, lanes), jnp.float32),   # running sum
-            pltpu.VMEM((bq, d), jnp.float32),       # output accumulator
+            pltpu.VMEM((group * bq, lanes), jnp.float32),   # running max
+            pltpu.VMEM((group * bq, lanes), jnp.float32),   # running sum
+            pltpu.VMEM((group * bq, d), jnp.float32),       # accumulator
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -393,12 +484,35 @@ def _bwd_impl(q3, k3, v3, out_bh, lse, g3, scale, block_q, block_k,
     return dq[:, :lq], dk[:, :kv_len], dv[:, :kv_len]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _to_bkv(q: jax.Array, kv_heads: int) -> jax.Array:
+    """[B, L, H, D] -> [B*KV, H/KV, L, D]: query head i reads key/value
+    head i // (H/KV), so a key/value head's queries are adjacent."""
+    b, l, h, d = q.shape
+    g = h // kv_heads
+    return q.reshape(b, l, kv_heads, g, d).transpose(0, 2, 3, 1, 4).reshape(
+        b * kv_heads, g, l, d)
+
+
+def _from_bkv(x: jax.Array, b: int) -> jax.Array:
+    bkv, g, l, d = x.shape
+    return x.reshape(b, bkv // b, g, l, d).transpose(0, 3, 1, 2, 4).reshape(
+        b, l, (bkv // b) * g, d)
+
+
+def _plain(q, k, causal, window) -> bool:
+    """Whether the call is the one from before the mask and the second
+    head count: its forward and backward kernels are those."""
+    return q.shape[2] == k.shape[2] and not causal and window is None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     scale: Optional[float] = None,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: bool = False) -> jax.Array:
+                    interpret: bool = False,
+                    causal: bool = False,
+                    window: Optional[int] = None) -> jax.Array:
     """Flash attention over [B, L, H, D] tensors (full fwd+bwd in Pallas).
 
     head_dim must be a multiple of 8 on real TPU — multiples of 128 use
@@ -408,18 +522,44 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     Sequence dims are padded internally. block_q/block_k default to
     large sequence-capped blocks (see _block_sizes).
 
+    Grouped queries: `k` / `v` may carry fewer heads than `q`, a
+    divisor of them; query head i reads key/value head i // (H / KV).
+    `causal` / `window` (static; self-attention, equal lengths): query
+    i sees keys j with j <= i, and with i - window < j. The forward is
+    the same kernel (`fdt_flash_fwd`, static branches); the backward of
+    a grouped or masked call is the XLA composition's
+    (docs/KERNELS.md), not `fdt_flash_bwd_*`.
+
     The [B,L,H,D] layout pays a transpose into the kernel's native
     [B*H,L,D] grid layout on every operand — BHLD-projecting callers
     should use flash_attention_bh, whose reshapes are free (the r3
     trace counted ~750 layout-copy ops around these transposes).
     """
-    out, _ = _fwd_impl(_to_bh(q), _to_bh(k), _to_bh(v), scale,
-                       block_q, block_k, interpret)
+    return _forward(q, k, v, scale, block_q, block_k, interpret, causal,
+                    window)
+
+
+def _forward(q, k, v, scale, block_q, block_k, interpret, causal, window):
     b, lq, h, _ = q.shape
-    return _from_bh(out[:, :lq], b, h)
+    if _plain(q, k, causal, window):
+        out, _ = _fwd_impl(_to_bh(q), _to_bh(k), _to_bh(v), scale,
+                           block_q, block_k, interpret)
+        return _from_bh(out[:, :lq], b, h)
+    if causal or window is not None:
+        assert lq == k.shape[1], "a causal / window mask is self-attention's"
+    kv = k.shape[2]
+    assert h % kv == 0, f"{h} query heads over {kv} key/value heads"
+    q3 = _to_bkv(q, kv) if kv != h else _to_bh(q)
+    out, _ = _fwd_impl(q3, _to_bh(k), _to_bh(v), scale, block_q, block_k,
+                       interpret, causal=causal, window=window)
+    out = out[..., :lq, :]
+    return _from_bkv(out, b) if kv != h else _from_bh(out, b, h)
 
 
-def _fwd(q, k, v, scale, block_q, block_k, interpret):
+def _fwd(q, k, v, scale, block_q, block_k, interpret, causal, window):
+    if not _plain(q, k, causal, window):
+        return _forward(q, k, v, scale, block_q, block_k, interpret,
+                        causal, window), (q, k, v, None, None)
     out, lse = _fwd_impl(_to_bh(q), _to_bh(k), _to_bh(v), scale,
                          block_q, block_k, interpret,
                          save_residuals=True)
@@ -427,8 +567,13 @@ def _fwd(q, k, v, scale, block_q, block_k, interpret):
     return _from_bh(out[:, :lq], b, h), (q, k, v, out, lse)
 
 
-def _bwd(scale, block_q, block_k, interpret, res, g):
+def _bwd(scale, block_q, block_k, interpret, causal, window, res, g):
     q, k, v, out_bh, lse = res
+    if lse is None:
+        from .attention import _xla_attention
+        _, vjp = jax.vjp(lambda q, k, v: _xla_attention(
+            q, k, v, scale=scale, causal=causal, window=window), q, k, v)
+        return vjp(g)
     b, _, h, _ = q.shape
     dq, dk, dv = _bwd_impl(_to_bh(q), _to_bh(k), _to_bh(v), out_bh, lse,
                            _to_bh(g), scale, block_q, block_k, interpret)

@@ -153,7 +153,8 @@ class DiffusionSampler:
                  clip_denoised: bool = False,
                  timestep_spacing: str = "linear",
                  cache_plan: Optional[Any] = None,
-                 cache_fns: Optional[Tuple[Callable, Callable]] = None):
+                 cache_fns: Optional[Tuple[Callable, Callable]] = None,
+                 tally_shape: Optional[Tuple[int, ...]] = None):
         # ONE trace of the network for every program of this sampler:
         # the solo scan and each serving bucket's round and terminal
         # programs call it with the same per-row shapes (the batch axis
@@ -179,6 +180,13 @@ class DiffusionSampler:
         # program below is byte-for-byte the pre-cache one.
         self.cache_plan = cache_plan
         self.cache_fns = cache_fns
+        # a model that counts what it does (routed experts: the picks
+        # that landed on the experts held): `model_fn` then returns
+        # (raw, tally), an int32 array of this shape summed over the
+        # batch it was given, and the serving programs carry each row's
+        # sum over its evaluations (`make_chunk_program`). None: no
+        # program differs by an operand.
+        self.tally_shape = tally_shape
         self._compiled = {}
         self._taps_specs = {}
 
@@ -199,9 +207,20 @@ class DiffusionSampler:
                 and hasattr(self.cache_fns, "spatial"))
 
     # -- model evaluation with CFG ------------------------------------------
-    def _denoise_fn(self, params, cond, uncond):
+    def _denoise_fn(self, params, cond, uncond, tally=None):
+        """`tally`: a list that receives the tally of each evaluation
+        `denoise` makes while it is traced (a model with `tally_shape`);
+        without one the tallies are dropped."""
         schedule, transform = self.schedule, self.transform
         use_cfg = self.guidance_scale > 0.0 and uncond is not None
+
+        def model(*args):
+            raw = self.model_fn(params, *args)
+            if self.tally_shape is None:
+                return raw
+            if tally is not None:
+                tally.append(raw[1])
+            return raw[0]
 
         def denoise(x, t):
             t_b = jnp.broadcast_to(t, (x.shape[0],)).astype(jnp.float32)
@@ -212,11 +231,11 @@ class DiffusionSampler:
                 t2 = jnp.concatenate([t_in, t_in], axis=0)
                 c2 = jax.tree_util.tree_map(
                     lambda c, u: jnp.concatenate([c, u], axis=0), cond, uncond)
-                raw = self.model_fn(params, x2, t2, c2)
+                raw = model(x2, t2, c2)
                 raw_c, raw_u = jnp.split(raw, 2, axis=0)
                 raw = raw_u + self.guidance_scale * (raw_c - raw_u)
             else:
-                raw = self.model_fn(params, x_in, t_in, cond)
+                raw = model(x_in, t_in, cond)
             pred = transform.transform_output(x, t_b, raw.astype(jnp.float32),
                                               schedule)
             x0, eps = transform.to_x0_eps(x, t_b, pred, schedule)
@@ -755,31 +774,38 @@ class DiffusionSampler:
                                           by all rows; never a Python int
           state    [R, ...] pytree        per-row sampler state carry
                                           (init_state at admission)
-        Returns (x, keys, state) carries. Rows never interact, so a
+          tally    [R, *tally_shape] int32  a counting model's sums so
+                                          far (zeros at admission); None
+                                          and absent from the result
+                                          for any other model
+        Returns (x, keys, state) carries, with a `tally_shape` (x, keys,
+        state, tally). Rows never interact, so a
         padded round is output-invariant for the real rows, and a row's
         samples do not depend on where its rounds were cut (tested).
         """
         def sampler_chunk(params, x, keys, pairs, n_act, offsets, steps,
-                          cond, uncond, state):
-            def row(x_r, key, row_pairs, n, off, c, u, st):
-                denoise = self._denoise_fn(params, c, u)
-
+                          cond, uncond, state, tally=None):
+            def row(x_r, key, row_pairs, n, off, c, u, st, tl):
                 def step(carry, pair, i):
-                    x_c, rng, s = carry
+                    x_c, rng, s, tl_c = carry
                     rng, sub = jax.random.split(rng)
+                    seen = []       # the tallies of this step's evaluations
                     x_n, s_n = self.sampler.step(
-                        denoise, x_c, pair[0], pair[1], sub, s,
-                        self.schedule, off + i)
+                        self._denoise_fn(params, c, u, seen), x_c, pair[0],
+                        pair[1], sub, s, self.schedule, off + i)
                     active = i < n
                     x_n = jnp.where(active, x_n, x_c)
                     s_n = jax.tree_util.tree_map(
                         lambda a, b: jnp.where(active, a, b), s_n, s)
-                    return x_n, rng, s_n
+                    if seen:
+                        tl_c = jnp.where(active, tl_c + sum(seen), tl_c)
+                    return x_n, rng, s_n, tl_c
 
-                return _run_steps(step, (x_r, key, st), row_pairs, steps)
+                out = _run_steps(step, (x_r, key, st, tl), row_pairs, steps)
+                return out if tl is not None else out[:3]
 
             return jax.vmap(row)(x, keys, pairs, n_act, offsets,
-                                 cond, uncond, state)
+                                 cond, uncond, state, tally)
 
         return jax.jit(sampler_chunk)
 
@@ -936,14 +962,17 @@ class DiffusionSampler:
         """Terminal denoise for rows whose trajectory just completed:
         the solo program's final `denoise(x, steps[-1])` call, vmapped
         with each row's OWN terminal step value (spacings of different
-        NFE need not end at bit-identical values)."""
-        def sampler_terminal(params, x, t_term, cond, uncond):
-            def row(x_r, t_r, c, u):
-                denoise = self._denoise_fn(params, c, u)
+        NFE need not end at bit-identical values). With a `tally_shape`
+        the rows' `tally` carries go in and (x0, tally) comes out, this
+        evaluation's counted in."""
+        def sampler_terminal(params, x, t_term, cond, uncond, tally=None):
+            def row(x_r, t_r, c, u, tl):
+                seen = []
+                denoise = self._denoise_fn(params, c, u, seen)
                 x0, _ = denoise(x_r, jnp.full((x_r.shape[0],), t_r))
-                return x0
+                return x0 if tl is None else (x0, tl + sum(seen))
 
-            return jax.vmap(row)(x, t_term, cond, uncond)
+            return jax.vmap(row)(x, t_term, cond, uncond, tally)
 
         return jax.jit(sampler_terminal)
 

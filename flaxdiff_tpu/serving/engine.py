@@ -125,17 +125,20 @@ def _round_program(program):
     return jax.jit(run)
 
 
-def _terminal_program(program, autoencoder):
+def _terminal_program(program, autoencoder, tallied: bool = False):
     """`make_terminal_program` as the engine launches it: stack, the
     terminal denoise, decode and clip in one launch. Returns the whole
     bucket, `[bucket, num_samples, *sample_shape]` (a cut to the real
-    rows would be a program per row count)."""
+    rows would be a program per row count); `tallied` (a counting
+    model): (that, the rows' tallies `[bucket, *tally_shape]`)."""
     def run(params, rows, batch):
         x0 = program(params, **_stacked(rows), **batch)
+        if tallied:
+            x0, tally = x0
         if autoencoder is not None:
             flat = autoencoder.decode(x0.reshape((-1,) + x0.shape[2:]))
             x0 = flat.reshape(x0.shape[:2] + flat.shape[1:])
-        return clip_images(x0)
+        return (clip_images(x0), tally) if tallied else clip_images(x0)
 
     run.__name__ = program.__name__
     return jax.jit(run)
@@ -148,13 +151,14 @@ class RequestState:
                  "x", "rng", "state", "pairs", "terminal_t", "nfe",
                  "done", "cond", "uncond", "compile_ms", "rounds",
                  "first_dispatch_t", "plan", "flags", "taps", "codes",
-                 "ref", "trace", "attempts", "orig_req", "degraded")
+                 "ref", "trace", "attempts", "orig_req", "degraded",
+                 "tally", "tally_out")
 
     def __init__(self, req: SampleRequest, future: ServingFuture,
                  submit_t: float, admit_t: float, group: tuple,
                  x, rng, state, pairs, terminal_t: float,
                  cond, uncond, plan=None, flags=None, taps=None,
-                 codes=None, ref=None):
+                 codes=None, ref=None, tally=None):
         self.req = req
         self.future = future
         self.submit_t = submit_t
@@ -183,6 +187,13 @@ class RequestState:
         self.taps = taps
         self.codes = codes
         self.ref = ref
+        # a counting model's sums over this row's evaluations (routed
+        # experts: held picks by layer and expert): host zeros at
+        # admission, then a device carry like `x`; `tally_out` is
+        # (the finalised batch's tallies on the device, this row's
+        # index), which the completion thread fetches with the samples
+        self.tally = tally
+        self.tally_out = None
         # request-scoped trace accumulator (telemetry/reqtrace.py);
         # None on the disabled hub — the scheduler attaches it
         self.trace = None
@@ -412,7 +423,9 @@ class SamplerProgramEngine:
             req=req, future=future, submit_t=submit_t, admit_t=admit_t,
             group=group, x=x, rng=loop_key, state=state, pairs=pairs,
             terminal_t=terminal_t, cond=cond, uncond=uncond, plan=plan,
-            flags=flags, taps=taps, codes=codes, ref=ref)
+            flags=flags, taps=taps, codes=codes, ref=ref,
+            tally=(None if ds.tally_shape is None
+                   else np.zeros(ds.tally_shape, np.int32)))
         if miss:            # both: they share the group's key
             compile_s = time.perf_counter() - t0
             st.compile_ms = compile_s * 1e3
@@ -466,6 +479,9 @@ class SamplerProgramEngine:
                      "steps": np.int32(steps)}
             if plan is None:
                 kind_used, build = "chunk", ds.make_chunk_program
+                if ds.tally_shape is not None:
+                    for c, r in zip(carries, srcs):
+                        c["tally"] = r.tally
             elif rows[0].ref is not None:
                 # composed (timestep x spatial) plan: round-level step
                 # codes = per-step MAX over each row's own offset-aligned
@@ -549,6 +565,8 @@ class SamplerProgramEngine:
         finished: List[RequestState] = []
         with span("serve.unstack"):
             for r, out, n in zip(rows, outs, n_live):
+                if r.tally is not None:     # (x, key, state, tally)
+                    *out, r.tally = out
                 r.x, r.rng, r.state, r.taps, r.ref = \
                     (tuple(out) + (None, None))[:5]
                 r.done += n
@@ -563,29 +581,63 @@ class SamplerProgramEngine:
         """Terminal denoise + (optional) decode + clip for completed
         rows, in one launch. Returns (`[bucket, num_samples,
         *sample_shape]` device array whose first `len(rows)` entries
-        are the rows' samples in row order, compile seconds)."""
+        are the rows' samples in row order, compile seconds). A counting
+        model's tallies come out of the same launch and stay on the
+        device, on the rows (`tally_out`), for `count_picks`."""
         group = rows[0].group
         ds = self._sampler_for(rows[0].req)
+        tallied = ds.tally_shape is not None
         with self._span("serve.stack"):
             srcs = rows + [rows[0]] * (bucket - len(rows))
+            carries = [{"x": r.x, "cond": r.cond, "uncond": r.uncond}
+                       for r in srcs]
+            if tallied:
+                for c, r in zip(carries, srcs):
+                    c["tally"] = r.tally
             prog_args = (
-                self._params_for(group),
-                tuple({"x": r.x, "cond": r.cond, "uncond": r.uncond}
-                      for r in srcs),
+                self._params_for(group), tuple(carries),
                 {"t_term": np.float32([r.terminal_t for r in srcs])})
 
         with self._span("serve.launch", kind="terminal"):
             program, miss = self._get_program(
                 "terminal", group, bucket, 0,
                 lambda: _terminal_program(ds.make_terminal_program(),
-                                          ds.autoencoder))
+                                          ds.autoencoder, tallied))
             t0 = time.perf_counter()
             out = self._launch(program, *prog_args)
             compile_s = (time.perf_counter() - t0) if miss else 0.0
+        if tallied:
+            out, tallies = out
+            for i, r in enumerate(rows):
+                r.tally_out = (tallies, i)
         if miss:
             self._register_evidence("terminal", group, bucket, 0,
                                     program, prog_args, compile_s)
         return out, compile_s
+
+    def count_picks(self, rows: List[RequestState], fetch) -> None:
+        """Add a finalised batch's routed-expert picks to the telemetry
+        counters (docs/OBSERVABILITY.md): called by the completion
+        thread where it fetches the samples, with its `fetch`, for rows
+        that carry a `tally_out` (a model with routed experts).
+        `moe/picks_routed` is host
+        arithmetic: the token-picks the router made over the request's
+        evaluations, wherever the experts are; `moe/picks_held` the
+        picks that landed on the experts held here, `moe/picks_hottest`
+        the largest expert's of each layer."""
+        held = fetch(rows[0].tally_out[0])      # [bucket, layers, experts]
+        count = self.telemetry.counter
+        for r in rows:
+            n = held[r.tally_out[1]]
+            evals = (r.nfe + 1) * int(r.req.num_samples) * (
+                2 if r.uncond is not None and r.req.guidance_scale > 0
+                else 1)
+            count("moe/picks_routed").inc(
+                evals * self.pipeline.model.routed_picks(
+                    r.x.shape[1:],
+                    0 if r.cond is None else r.cond.shape[-2]))
+            count("moe/picks_held").inc(int(n.sum()))
+            count("moe/picks_hottest").inc(int(n.max(axis=-1).sum()))
 
     # -- program-cache pre-warming -------------------------------------------
     def prewarm(self, reqs: List[SampleRequest], round_steps: int,

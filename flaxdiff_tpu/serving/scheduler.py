@@ -931,6 +931,10 @@ class ServingScheduler:
                               args={"rows": len(rows)}):
                     _block_until_ready(out)
                     host = _device_get(out)
+                    # a routed-expert model's picks left the device in
+                    # the same launch: no read-back of the dispatch thread
+                    if getattr(rows[0], "tally_out", None) is not None:
+                        self.engine.count_picks(rows, _device_get)
             except (KeyboardInterrupt, SystemExit):
                 raise
             except BaseException as e:  # noqa: BLE001 — fault barrier

@@ -462,14 +462,15 @@ def _pallas_calls():
 
 def test_every_pallas_call_carries_a_distinct_name():
     calls = _pallas_calls()
-    assert len(calls) == 13
+    assert len(calls) == 15
     names = []
     for path, line, name in calls:
         assert isinstance(name, ast.Constant) \
             and isinstance(name.value, str), (path, line)
         prefix = {"flash_attention.py": "fdt_flash_",
                   "fused_norm.py": "fdt_gn_silu_",
-                  "fused_adaln.py": "fdt_adaln_"}[path]
+                  "fused_adaln.py": "fdt_adaln_",
+                  "moe.py": "fdt_moe_gmm_"}[path]
         assert name.value.startswith(prefix), (path, line, name.value)
         names.append(name.value)
     assert len(set(names)) == len(names)
