@@ -21,8 +21,10 @@ from typing import Any, Callable, Optional, Tuple
 import flax.struct
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Literal
 
 from ..predictors import PredictionTransform
+from ..profiling import _iter_subjaxprs
 from ..schedulers.common import NoiseSchedule, bcast_right
 from ..typing import PRNGKey
 from ..utils import RngSeq, clip_images
@@ -114,6 +116,100 @@ class Sampler(flax.struct.PyTreeNode):
 
 
 # --------------------------------------------------------------------------
+# How a program reads its parameters
+# --------------------------------------------------------------------------
+
+# equations that run ONE jaxpr on their own operands, in order (`remat2`
+# is `jax.checkpoint`; a name missing here is only a leaf not narrowed)
+_CALLS = frozenset({"jit", "closed_call", "custom_jvp_call",
+                    "custom_vjp_call", "remat2"})
+
+
+def _is_var(v) -> bool:
+    return not isinstance(v, Literal)
+
+
+def _call_jaxpr(eqn):
+    """The jaxpr a call-like equation runs with the equation's operands
+    as its inputs, one for one; None for anything else. A loop or a
+    branch slices, carries or selects its operands: the walker below
+    follows no leaf into one."""
+    if eqn.primitive.name not in _CALLS:
+        return None
+    subs = list(_iter_subjaxprs(eqn.params))
+    if len(subs) != 1 or len(subs[0].invars) != len(eqn.invars):
+        return None
+    return subs[0]
+
+
+def _collect_reads(jaxpr, leaf_of, reads) -> None:
+    """Add every read of the variables `leaf_of` maps to a leaf's index
+    to that leaf's set in `reads`: the dtype a `convert_element_type`
+    converts it to, or None for any other use (an equation that is
+    neither a convert nor a call, or the jaxpr's own result). A call
+    that takes the variable whole is descended into."""
+    for eqn in jaxpr.eqns:
+        sub, inner = None, {}
+        for pos, v in enumerate(eqn.invars):
+            leaf = leaf_of.get(v) if _is_var(v) else None
+            if leaf is None:
+                continue
+            if eqn.primitive.name == "convert_element_type":
+                reads[leaf].add(jnp.dtype(eqn.params["new_dtype"]))
+                continue
+            sub = sub or _call_jaxpr(eqn)
+            if sub is None:
+                reads[leaf].add(None)
+            else:
+                inner[sub.invars[pos]] = leaf
+        if inner:
+            _collect_reads(sub, inner, reads)
+    for v in jaxpr.outvars:
+        if _is_var(v) and v in leaf_of:
+            reads[leaf_of[v]].add(None)
+
+
+def _operations(jaxpr, skip=frozenset()):
+    """The program as a nested list of (primitive, result types), the
+    converts of the `skip` variables left out: two traces of one
+    function that are equal here run the same operations on the same
+    types in the same order."""
+    out = []
+    for eqn in jaxpr.eqns:
+        held = [_is_var(v) and v in skip for v in eqn.invars]
+        if eqn.primitive.name == "convert_element_type" and held[0]:
+            continue
+        out.append((eqn.primitive.name,
+                    tuple(str(v.aval) for v in eqn.outvars)))
+        sub = _call_jaxpr(eqn)
+        if sub is not None:
+            out.append(_operations(sub, frozenset(
+                s for s, h in zip(sub.invars, held) if h)))
+        else:
+            out.extend(_operations(j)
+                       for j in _iter_subjaxprs(eqn.params))
+    return out
+
+
+def _signature(tree) -> tuple:
+    """The (shape, dtype) of every leaf, in order: what a memo of an
+    abstract trace is keyed on."""
+    return tuple((tuple(a.shape), str(a.dtype))
+                 for a in jax.tree_util.tree_leaves(tree))
+
+
+def narrow_tree(params, dtypes):
+    """`params` with leaf i (flatten order) held at `dtypes[i]`; None
+    leaves the leaf as it is: the same array, not a copy. The very tree
+    when no leaf narrows."""
+    if all(d is None for d in dtypes):      # (a dtype is falsy)
+        return params
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    return treedef.unflatten([l if d is None else l.astype(d)
+                              for l, d in zip(leaves, dtypes)])
+
+
+# --------------------------------------------------------------------------
 # The engine
 # --------------------------------------------------------------------------
 
@@ -189,6 +285,7 @@ class DiffusionSampler:
         self.tally_shape = tally_shape
         self._compiled = {}
         self._taps_specs = {}
+        self._narrowings = {}
 
     @property
     def cache_active(self) -> bool:
@@ -381,12 +478,7 @@ class DiffusionSampler:
         per input-shape signature: the abstract model trace costs tens
         of ms, which must not recur on every serving admission (it
         would serialize the dispatch loop)."""
-        def sig(v):
-            return tuple(jax.tree_util.tree_flatten(
-                jax.tree_util.tree_map(
-                    lambda a: (tuple(a.shape), str(a.dtype)), v))[0])
-
-        spec_key = (sig(x), sig(cond), sig(uncond))
+        spec_key = (_signature(x), _signature(cond), _signature(uncond))
         spec = self._taps_specs.get(spec_key)
         if spec is not None:
             return jax.tree_util.tree_map(
@@ -422,12 +514,8 @@ class DiffusionSampler:
         input-shape signature (the abstract trace must not recur on
         every serving admission), and step 0 of every plan refreshes,
         so the zeros are never consumed."""
-        def sig(v):
-            return tuple(jax.tree_util.tree_flatten(
-                jax.tree_util.tree_map(
-                    lambda a: (tuple(a.shape), str(a.dtype)), v))[0])
-
-        spec_key = ("composed", sig(x), sig(cond), sig(uncond))
+        spec_key = ("composed", _signature(x), _signature(cond),
+                    _signature(uncond))
         spec = self._taps_specs.get(spec_key)
         if spec is None:
             fns = self.cache_fns
@@ -454,6 +542,83 @@ class DiffusionSampler:
             self._taps_specs[spec_key] = spec
         return jax.tree_util.tree_map(
             lambda s: jnp.zeros(s.shape, s.dtype), spec)
+
+    # -- the dtype each leaf is served at -----------------------------------
+    def _evaluations(self, params, x, cond, uncond):
+        """Every way this sampler's programs evaluate the network, once
+        each at a row's shapes: the plain evaluation (every terminal
+        program, the uncached rounds) and, with a cache plan, each mode
+        of the cached rounds."""
+        t = jnp.zeros((x.shape[0],), jnp.float32)
+        outs = [self._denoise_fn(params, cond, uncond)(x, t)]
+        if self.spatial_active:
+            carry = self.cache_carry_init(params, x, cond, uncond)
+            outs += [self._denoise_composed_mode_fn(
+                params, cond, uncond, m)(x, t, *carry)
+                for m in ("reuse", "spatial", "record")]
+        elif self.cache_active:
+            taps = self.cache_taps_init(params, x, cond, uncond)
+            outs += [self._denoise_taps_mode_fn(
+                params, cond, uncond, m)(x, t, taps)
+                for m in ("record", "reuse")]
+        return outs
+
+    def narrowing(self, params, x, cond, uncond) -> tuple:
+        """For each leaf of `params` (flatten order) the dtype a serving
+        engine may hold it at, or None for as it is stored.
+
+        Read from the program, not from the model: `_evaluations` is
+        traced abstractly (`jax.make_jaxpr` over shapes: nothing
+        compiles, nothing runs) and a leaf is narrowed to D iff EVERY
+        read of it is a `convert_element_type` to the one D and D is
+        narrower than the dtype stored. Two dtypes, a read at the
+        stored dtype (a `dot_general`, a `reshape`), a read inside a
+        loop or a branch, a widening convert: the leaf stays. Then the
+        programs are traced again at the narrowed dtypes, and unless
+        that trace is the first one less exactly those converts nothing
+        narrows (a model that asks a leaf's dtype in Python shows in no
+        equation). So what a round program computes from the narrowed
+        tree is what it computed from `params`, the converts made once
+        by whoever holds the tree and not once a launch. `x`, `cond`,
+        `uncond`: one row's carries, for their shapes. Memoised per
+        (tree structure, shapes, dtypes)."""
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        key = (treedef,) + tuple(
+            _signature(v) for v in (leaves, x, cond, uncond))
+        if key in self._narrowings:
+            return self._narrowings[key]
+
+        def spec(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+        def trace(specs):
+            return jax.make_jaxpr(
+                lambda ls, *row: self._evaluations(
+                    treedef.unflatten(ls), *row))(
+                specs, spec(x), spec(cond), spec(uncond)).jaxpr
+
+        first = trace(spec(leaves))
+        inputs = first.invars[:len(leaves)]
+        reads = [set() for _ in leaves]
+        _collect_reads(first, {v: i for i, v in enumerate(inputs)}, reads)
+
+        def narrower(read, stored):
+            (d,) = read if len(read) == 1 else (None,)
+            return d if d is not None \
+                and d.itemsize < jnp.dtype(stored).itemsize else None
+
+        dtypes = tuple(narrower(r, l.dtype) for r, l in zip(reads, leaves))
+        if any(d is not None for d in dtypes):
+            again = trace([jax.ShapeDtypeStruct(
+                l.shape, l.dtype if d is None else d)
+                for l, d in zip(leaves, dtypes)])
+            held = frozenset(
+                v for v, d in zip(inputs, dtypes) if d is not None)
+            if _operations(first, held) != _operations(again):
+                dtypes = (None,) * len(leaves)
+        self._narrowings[key] = dtypes
+        return dtypes
 
     # -- one compiled program per (steps, shape) ----------------------------
     def _get_program(self, num_steps: int, shape: Tuple[int, ...],

@@ -35,6 +35,12 @@ tuple, stacked and unstacked INSIDE the compiled program. `finalize`
 is one launch (stack, terminal denoise, decode, clip). Every launch
 goes through `_launch`, counted at `serving/launches`.
 
+**What the programs take is the served tree, not the pipeline's**
+(`_params_for`, docs/SERVING.md "What the engine holds"): a leaf the
+network does nothing with but convert it to a narrower dtype is held at
+that dtype, cast once before the first round and not once a launch;
+every other leaf is the pipeline's own array.
+
 Batching model (see `DiffusionSampler.make_chunk_program`): the batch
 axis is requests, each row an independent block of the request's
 `num_samples` samples with its own RNG carry. Rows never interact, so
@@ -226,6 +232,9 @@ class SamplerProgramEngine:
         # and the null context tiled to a request's num_samples
         self._trajectories: Dict[tuple, Tuple[np.ndarray, float]] = {}
         self._null_contexts: Dict[int, Any] = {}
+        # what `_params_for` holds: use_ema -> (the pipeline's tree,
+        # {group: served tree}, {narrowing: served tree})
+        self._served: Dict[bool, Tuple[Any, dict, dict]] = {}
         # last dispatched round's provenance (program kind/key, bucket,
         # live steps, cache-plan codes) — written by advance() on the
         # single dispatch thread, read by the
@@ -318,10 +327,44 @@ class SamplerProgramEngine:
         return self.pipeline.get_sampler(req.sampler, req.guidance_scale,
                                          cache_plan=self._plan_for(req))
 
-    def _params_for(self, group: tuple):
+    def _params_for(self, group: tuple, ds, x, cond, uncond):
+        """The tree every program of `group` takes: the pipeline's
+        (`ema_params` or `params`) with each leaf held at the dtype the
+        network converts it to, wherever that conversion is the leaf's
+        only use (`DiffusionSampler.narrowing`, read from the group's
+        own programs at one row's `x`, `cond`, `uncond`). Every other
+        leaf is the pipeline's own array and the pipeline's trees are
+        untouched; a tree with nothing to narrow is served as it is.
+        Made at first use, which warm-up reaches before admission
+        (`serving/served_trees`: 0 inside a steady window), and held by
+        the identity of the pipeline's tree: replace
+        `pipeline.ema_params` and the next round makes a new one and
+        drops the old. Groups whose programs read the tree alike share
+        one served tree."""
         use_ema = group[6]
-        return (self.pipeline.ema_params
+        tree = (self.pipeline.ema_params
                 if use_ema else self.pipeline.params)
+        held = self._served.get(use_ema)
+        if held is None or held[0] is not tree:
+            # first use, or the pipeline's tree was replaced: what was
+            # made from the old one is dropped here
+            held = self._served[use_ema] = (tree, {}, {})
+        _, by_group, by_narrowing = held
+        served = by_group.get(group)
+        if served is None:
+            from ..samplers.common import narrow_tree
+            dtypes = ds.narrowing(tree, x, cond, uncond)
+            if dtypes not in by_narrowing:
+                by_narrowing[dtypes] = narrow_tree(tree, dtypes)
+                nbytes = [l.nbytes for l in jax.tree_util.tree_leaves(tree)]
+                self.telemetry.counter("serving/served_trees").inc()
+                self.telemetry.gauge("serving/served_tree_bytes").set(
+                    sum(nbytes))
+                self.telemetry.gauge(
+                    "serving/served_tree_narrowed_bytes").set(
+                    sum(n for n, d in zip(nbytes, dtypes) if d is not None))
+            served = by_group[group] = by_narrowing[dtypes]
+        return served
 
     def _launch(self, program, *args):
         """Every device launch of the dispatch thread goes through
@@ -399,7 +442,9 @@ class SamplerProgramEngine:
         program, miss = self._get_program(
             "init", group, 0, 0, lambda: ds.make_init_program(
                 shape(),
-                self._params_for(group) if ds.cache_active else None,
+                self._params_for(
+                    group, ds, jax.ShapeDtypeStruct(shape(), jnp.float32),
+                    cond, uncond) if ds.cache_active else None,
                 uncond))
         args = (np.int64(req.seed), cond)
         noise_key, loop_key, state, cond, taps, ref = \
@@ -514,7 +559,9 @@ class SamplerProgramEngine:
                 batch["flags"] = want
                 for c, r in zip(carries, srcs):
                     c["taps"] = r.taps
-            prog_args = (self._params_for(group), tuple(carries), batch)
+            prog_args = (self._params_for(group, ds, srcs[0].x, srcs[0].cond,
+                                          srcs[0].uncond),
+                         tuple(carries), batch)
 
         with span("serve.launch", kind=kind_used):
             t0 = time.perf_counter()
@@ -595,7 +642,8 @@ class SamplerProgramEngine:
                 for c, r in zip(carries, srcs):
                     c["tally"] = r.tally
             prog_args = (
-                self._params_for(group), tuple(carries),
+                self._params_for(group, ds, srcs[0].x, srcs[0].cond,
+                                 srcs[0].uncond), tuple(carries),
                 {"t_term": np.float32([r.terminal_t for r in srcs])})
 
         with self._span("serve.launch", kind="terminal"):
