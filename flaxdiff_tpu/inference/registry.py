@@ -7,6 +7,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Tuple
 
+from ..models.brumby import BrumbyDenoiser
 from ..models.cohere2_moe import Cohere2MoEDenoiser
 from ..models.dit import SimpleDiT
 from ..models.mmdit import HierarchicalMMDiT, SimpleMMDiT
@@ -26,6 +27,7 @@ MODEL_REGISTRY: Dict[str, Any] = {
     "hybrid_ssm": HybridSSMAttentionDiT,
     "unet_3d": UNet3D,
     "cohere2_moe_dn": Cohere2MoEDenoiser,
+    "brumby_dn": BrumbyDenoiser,
 }
 
 # Suffix -> constructor kwarg toggles (reference inference/utils.py:168-180).

@@ -7,13 +7,11 @@ The published block (CohereLabs `command-a-plus-05-2026`, `model_type`
 that holds those keys builds the model with no translation; the fields
 below `# the program's own` are this repository's.
 
-Sequence: `[time token; text tokens; patch tokens]`. The time token is
-the sinusoidal timestep embedding (256 features) through the two-layer
-`TimeProjection` to `hidden_size`; text is `Dense(features ->
-hidden_size)`; patches are `PatchEmbedding` (patch `patch_size`, raster
-order). Positions are indices in this sequence. The conditioning comes
-first, so under the published causal mask every patch token sees all of
-it (the published block has no AdaLN: conditioning is in context, as in
+Sequence: `[time token; text tokens; patch tokens]`, embedded and read
+out as `models/trunk.py` sets out (shared with `models/brumby.py`).
+Positions are indices in this sequence. The conditioning comes first, so
+under the published causal mask every patch token sees all of it (the
+published block has no AdaLN: conditioning is in context, as in
 `models/uvit.py`).
 
 Block (`use_parallel_block`): `h = LayerNorm(x)` (mean-subtracting, a
@@ -57,10 +55,7 @@ import jax.numpy as jnp
 from ..ops import moe
 from ..ops.attention import attend
 from ..typing import Dtype
-from .common import TimeEmbedding, TimeProjection
-from .vit_common import PatchEmbedding
-
-TIME_FEATURES = 256     # sinusoidal features ahead of the time MLP
+from .trunk import Kernel, SequenceEmbed, patch_head, sequence_tokens
 
 
 def rope_interleaved(x: jax.Array, theta: float) -> jax.Array:
@@ -77,49 +72,11 @@ def rope_interleaved(x: jax.Array, theta: float) -> jax.Array:
     return out.reshape(x.shape).astype(x.dtype)
 
 
-class Kernel(nn.Module):
-    """A bare weight named `kernel` (no bias): a stack of experts'
-    matrices [experts, in, out], or the router's [in, experts]."""
-
-    shape: Tuple[int, ...]
-    param_dtype: Dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self) -> jax.Array:
-        init = nn.initializers.lecun_normal(
-            in_axis=-2, out_axis=-1,
-            batch_axis=tuple(range(len(self.shape) - 2)))
-        return self.param("kernel", init, self.shape, self.param_dtype)
-
-
 def _norm(eps: float, param_dtype, name: str) -> nn.Module:
     """The published LayerNorm: mean-subtracting, a weight, no bias;
     computed in float32."""
     return nn.LayerNorm(epsilon=eps, use_bias=False, dtype=jnp.float32,
                         param_dtype=param_dtype, name=name)
-
-
-class SequenceEmbed(nn.Module):
-    """[B, H, W, C], [B], [B, L, F] -> [B, 1 + L + patches, hidden]
-    float32: the time token, the text tokens, the patch tokens."""
-
-    hidden_size: int
-    patch_size: int
-    dtype: Optional[Dtype] = None
-
-    @nn.compact
-    def __call__(self, x, temb, textcontext=None):
-        d = self.hidden_size
-        t = TimeProjection(features=d, dtype=self.dtype, name="t_proj")(
-            TimeEmbedding(features=TIME_FEATURES)(temb))
-        seq = [t[:, None, :]]
-        if textcontext is not None:
-            seq.append(nn.Dense(d, dtype=self.dtype,
-                                name="text_proj")(textcontext))
-        seq.append(PatchEmbedding(patch_size=self.patch_size,
-                                  embedding_dim=d, dtype=self.dtype,
-                                  name="patch_embed")(x))
-        return jnp.concatenate([s.astype(jnp.float32) for s in seq], axis=1)
 
 
 class Cohere2MoEBlock(nn.Module):
@@ -276,19 +233,17 @@ class Cohere2MoEDenoiser(nn.Module):
     def routed_picks(self, sample_shape, context_tokens: int) -> int:
         """Token-picks the routers make in ONE evaluation of one sample
         of `sample_shape` [H, W, C], wherever the experts are."""
-        p = self.patch_size
-        tokens = 1 + context_tokens + (sample_shape[0] // p) * (
-            sample_shape[1] // p)
+        tokens = sequence_tokens(sample_shape, self.patch_size,
+                                 context_tokens)
         return tokens * self.num_experts_per_tok * self.num_hidden_layers
 
     @nn.compact
     def __call__(self, x: jax.Array, temb: jax.Array,
                  textcontext: Optional[jax.Array] = None,
                  return_picks: bool = False):
-        p = self.patch_size
-        b, hgt, wid, _ = x.shape
-        tokens = SequenceEmbed(self.hidden_size, p, self.dtype,
-                               name="embed")(x, temb, textcontext)
+        tokens = SequenceEmbed(self.hidden_size, self.patch_size,
+                               self.dtype, name="embed")(x, temb,
+                                                         textcontext)
         picks = []
         for i, kind in enumerate(self._kinds()):
             tokens, n = Cohere2MoEBlock(
@@ -310,14 +265,9 @@ class Cohere2MoEDenoiser(nn.Module):
                 dtype=self.dtype, backend=self.backend,
                 name=f"layer_{i}")(tokens)
             picks.append(n)
-        n_patch = (hgt // p) * (wid // p)
-        out = _norm(self.layer_norm_eps, jnp.float32, "final_norm")(
-            tokens[:, -n_patch:])
-        out = nn.Dense(p * p * self.output_channels, dtype=jnp.float32,
-                       name="final_proj")(out)
-        out = out.reshape(b, hgt // p, wid // p, p, p, self.output_channels)
-        out = out.transpose(0, 1, 3, 2, 4, 5).reshape(
-            b, hgt, wid, self.output_channels)
+        out = patch_head(
+            tokens, _norm(self.layer_norm_eps, jnp.float32, "final_norm"),
+            x.shape, self.patch_size, self.output_channels)
         if return_picks:
             return out, jnp.stack(picks, axis=1)
         return out
