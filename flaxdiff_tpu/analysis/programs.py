@@ -3,8 +3,8 @@
 Builds every program the framework actually dispatches on the hot
 paths — `make_train_step` (plain, gated), its monitored twin, a
 bf16-policy variant (the upcast audit's subject), and the serving
-layer's DDIM / Euler-ancestral chunk + terminal programs plus the solo
-single-scan program — around a deliberately tiny conv model. The model
+layer's DDIM / Euler-ancestral chunk programs (a row's terminal
+denoise is a turn of theirs) plus the solo single-scan program — around a deliberately tiny conv model. The model
 interior is irrelevant to the invariants being checked (RNG lineage,
 callbacks, upcast traffic live in the STEP/SAMPLER code, not the
 backbone); tiny keeps `jax.make_jaxpr` tracing sub-second per program.
@@ -192,16 +192,10 @@ def chunk_program_jaxpr(sampler_name: str, rows: int = 2,
         jnp.zeros((1, 8, 8, 1), jnp.float32)) for _ in range(rows)]
     state = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
                                    *row_states)
+    term = jnp.full((rows,), -1, jnp.int32)
     return jax.make_jaxpr(prog)(params, x, keys, pairs, n_act, offsets,
-                                jnp.int32(round_steps), None, None, state)
-
-
-def terminal_program_jaxpr(sampler_name: str, rows: int = 2):
-    ds, params = _sampler_pieces(sampler_name)
-    prog = ds.make_terminal_program()
-    x = jnp.zeros((rows, 1, 8, 8, 1), jnp.float32)
-    t_term = jnp.zeros((rows,), jnp.float32)
-    return jax.make_jaxpr(prog)(params, x, t_term, None, None)
+                                jnp.int32(round_steps), None, None, state,
+                                None, term)
 
 
 def solo_program_jaxpr(sampler_name: str = "ddim", steps: int = 4,
@@ -242,7 +236,7 @@ def cached_chunk_program_jaxpr(sampler_name: str = "ddim",
     taps = jnp.zeros((rows, 1, 8, 8, 8), jnp.float32)
     return jax.make_jaxpr(prog)(params, x, keys, pairs, n_act, offsets,
                                 jnp.int32(round_steps), None, None, state,
-                                flags, taps)
+                                flags, taps, jnp.full((rows,), -1, jnp.int32))
 
 
 def spatial_chunk_program_jaxpr(sampler_name: str = "ddim",
@@ -268,7 +262,8 @@ def spatial_chunk_program_jaxpr(sampler_name: str = "ddim",
     refs = jnp.zeros((rows, 1, 8, 8, 8), jnp.float32)
     return jax.make_jaxpr(prog)(params, x, keys, pairs, n_act, offsets,
                                 jnp.int32(round_steps), None, None, state,
-                                codes, taps, refs)
+                                codes, taps, refs,
+                                jnp.full((rows,), -1, jnp.int32))
 
 
 # ---------------------------------------------------------------------------
@@ -515,7 +510,6 @@ PROGRAM_BUILDERS = {
     "chunk_ddim_cached": lambda: cached_chunk_program_jaxpr("ddim"),
     "chunk_euler_ancestral_cached":
         lambda: cached_chunk_program_jaxpr("euler_ancestral"),
-    "terminal_ddim": lambda: terminal_program_jaxpr("ddim"),
     "solo_ddim": lambda: solo_program_jaxpr("ddim"),
     "solo_ddim_cached":
         lambda: solo_program_jaxpr("ddim", cached=True),

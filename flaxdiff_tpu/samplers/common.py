@@ -252,8 +252,8 @@ class DiffusionSampler:
                  cache_fns: Optional[Tuple[Callable, Callable]] = None,
                  tally_shape: Optional[Tuple[int, ...]] = None):
         # ONE trace of the network for every program of this sampler:
-        # the solo scan and each serving bucket's round and terminal
-        # programs call it with the same per-row shapes (the batch axis
+        # the solo scan and each serving bucket's round programs
+        # call it with the same per-row shapes (the batch axis
         # of requests is a vmap outside it), so jit's trace cache hands
         # every later program the first one's jaxpr. Tracing the network
         # is most of what a program costs before its first launch
@@ -304,91 +304,70 @@ class DiffusionSampler:
                 and hasattr(self.cache_fns, "spatial"))
 
     # -- model evaluation with CFG ------------------------------------------
-    def _denoise_fn(self, params, cond, uncond, tally=None):
-        """`tally`: a list that receives the tally of each evaluation
-        `denoise` makes while it is traced (a model with `tally_shape`);
-        without one the tallies are dropped."""
+    def _evaluate(self, cond, uncond, x, t, net):
+        """One evaluation at (x, t) around `net(x_net, t_net, c_net) ->
+        (raw, *carries)`: the input scaling, the CFG doubling and
+        recombination, the prediction transform and the clip. Returns
+        (x0, eps, *carries). Every way a program evaluates the network
+        (plain, and each mode of the diffusion caches) goes through
+        here, so a record-every-step plan is bit-identical to the
+        uncached path (tested)."""
         schedule, transform = self.schedule, self.transform
         use_cfg = self.guidance_scale > 0.0 and uncond is not None
+        t_b = jnp.broadcast_to(t, (x.shape[0],)).astype(jnp.float32)
+        c_in = bcast_right(transform.input_scale(schedule, t_b), x.ndim)
+        x_net, t_net = schedule.transform_inputs(x * c_in, t_b)
+        c_net = cond
+        if use_cfg:
+            x_net = jnp.concatenate([x_net, x_net], axis=0)
+            t_net = jnp.concatenate([t_net, t_net], axis=0)
+            c_net = jax.tree_util.tree_map(
+                lambda c, u: jnp.concatenate([c, u], axis=0), cond, uncond)
+        raw, *carries = net(x_net, t_net, c_net)
+        if use_cfg:
+            raw_c, raw_u = jnp.split(raw, 2, axis=0)
+            raw = raw_u + self.guidance_scale * (raw_c - raw_u)
+        pred = transform.transform_output(x, t_b, raw.astype(jnp.float32),
+                                          schedule)
+        x0, eps = transform.to_x0_eps(x, t_b, pred, schedule)
+        if self.clip_denoised:
+            x0 = clip_images(x0)
+            signal, sigma = schedule.rates(t_b)
+            eps = (x - bcast_right(signal, x.ndim) * x0) / jnp.maximum(
+                bcast_right(sigma, x.ndim), 1e-12)
+        return (x0, eps, *carries)
 
-        def model(*args):
+    def _denoise_fn(self, params, cond, uncond, tally=None):
+        """`denoise(x, t) -> (x0, eps)`. `tally`: a list that receives
+        the tally of each evaluation `denoise` makes while it is traced
+        (a model with `tally_shape`); without one the tallies are
+        dropped."""
+        def net(*args):
             raw = self.model_fn(params, *args)
             if self.tally_shape is None:
-                return raw
+                return (raw,)
             if tally is not None:
                 tally.append(raw[1])
-            return raw[0]
+            return (raw[0],)
 
-        def denoise(x, t):
-            t_b = jnp.broadcast_to(t, (x.shape[0],)).astype(jnp.float32)
-            c_in = bcast_right(transform.input_scale(schedule, t_b), x.ndim)
-            x_in, t_in = schedule.transform_inputs(x * c_in, t_b)
-            if use_cfg:
-                x2 = jnp.concatenate([x_in, x_in], axis=0)
-                t2 = jnp.concatenate([t_in, t_in], axis=0)
-                c2 = jax.tree_util.tree_map(
-                    lambda c, u: jnp.concatenate([c, u], axis=0), cond, uncond)
-                raw = model(x2, t2, c2)
-                raw_c, raw_u = jnp.split(raw, 2, axis=0)
-                raw = raw_u + self.guidance_scale * (raw_c - raw_u)
-            else:
-                raw = model(x_in, t_in, cond)
-            pred = transform.transform_output(x, t_b, raw.astype(jnp.float32),
-                                              schedule)
-            x0, eps = transform.to_x0_eps(x, t_b, pred, schedule)
-            if self.clip_denoised:
-                x0 = clip_images(x0)
-                _, sigma = schedule.rates(t_b)
-                signal, _ = schedule.rates(t_b)
-                eps = (x - bcast_right(signal, x.ndim) * x0) / jnp.maximum(
-                    bcast_right(sigma, x.ndim), 1e-12)
-            return x0, eps
-
-        return denoise
+        return lambda x, t: self._evaluate(cond, uncond, x, t, net)
 
     # -- cached model evaluation (training-free diffusion cache) ------------
     def _denoise_taps_mode_fn(self, params, cond, uncond, mode: str):
         """`denoise(x, t, taps) -> (x0, eps, taps_out)` for ONE cache
         mode — "record" (full evaluation, fresh taps) or "reuse"
-        (shallow-only, cached taps re-centered). The pre/post transform
-        math mirrors `_denoise_fn` exactly so a record-every-step plan
-        is bit-identical to the uncached path (tested)."""
-        schedule, transform = self.schedule, self.transform
+        (shallow-only, cached taps re-centered)."""
         # first two entries by position: works for both the plain
         # (record, reuse) pair and a ComposedCacheFns
         record_fn, reuse_fn = self.cache_fns[0], self.cache_fns[1]
-        use_cfg = self.guidance_scale > 0.0 and uncond is not None
 
         def denoise(x, t, taps):
-            t_b = jnp.broadcast_to(t, (x.shape[0],)).astype(jnp.float32)
-            c_in = bcast_right(transform.input_scale(schedule, t_b), x.ndim)
-            x_in, t_in = schedule.transform_inputs(x * c_in, t_b)
-            if use_cfg:
-                x_net = jnp.concatenate([x_in, x_in], axis=0)
-                t_net = jnp.concatenate([t_in, t_in], axis=0)
-                c_net = jax.tree_util.tree_map(
-                    lambda c, u: jnp.concatenate([c, u], axis=0),
-                    cond, uncond)
-            else:
-                x_net, t_net, c_net = x_in, t_in, cond
             if mode == "record":
-                raw, taps = record_fn(params, x_net, t_net, c_net)
-            else:
-                raw = reuse_fn(params, x_net, t_net, c_net, taps)
-            if use_cfg:
-                raw_c, raw_u = jnp.split(raw, 2, axis=0)
-                raw = raw_u + self.guidance_scale * (raw_c - raw_u)
-            pred = transform.transform_output(x, t_b,
-                                              raw.astype(jnp.float32),
-                                              schedule)
-            x0, eps = transform.to_x0_eps(x, t_b, pred, schedule)
-            if self.clip_denoised:
-                x0 = clip_images(x0)
-                _, sigma = schedule.rates(t_b)
-                signal, _ = schedule.rates(t_b)
-                eps = (x - bcast_right(signal, x.ndim) * x0) / jnp.maximum(
-                    bcast_right(sigma, x.ndim), 1e-12)
-            return x0, eps, taps
+                return self._evaluate(cond, uncond, x, t,
+                                      lambda *a: record_fn(params, *a))
+            return self._evaluate(
+                cond, uncond, x, t,
+                lambda *a: (reuse_fn(params, *a, taps), taps))
 
         return denoise
 
@@ -414,44 +393,16 @@ class DiffusionSampler:
         ops/spatialcache.py) or "reuse" (pure timestep reuse; taps and
         ref pass through). All three share one carry structure so they
         can be `lax.switch` branches."""
-        schedule, transform = self.schedule, self.transform
         fns = self.cache_fns
-        use_cfg = self.guidance_scale > 0.0 and uncond is not None
 
         def denoise(x, t, taps, ref):
-            t_b = jnp.broadcast_to(t, (x.shape[0],)).astype(jnp.float32)
-            c_in = bcast_right(transform.input_scale(schedule, t_b), x.ndim)
-            x_in, t_in = schedule.transform_inputs(x * c_in, t_b)
-            if use_cfg:
-                x_net = jnp.concatenate([x_in, x_in], axis=0)
-                t_net = jnp.concatenate([t_in, t_in], axis=0)
-                c_net = jax.tree_util.tree_map(
-                    lambda c, u: jnp.concatenate([c, u], axis=0),
-                    cond, uncond)
-            else:
-                x_net, t_net, c_net = x_in, t_in, cond
             if mode == "record":
-                raw, taps, ref = fns.record_ref(params, x_net, t_net,
-                                                c_net)
+                net = lambda *a: fns.record_ref(params, *a)
             elif mode == "spatial":
-                raw, taps, ref = fns.spatial(params, x_net, t_net,
-                                             c_net, taps, ref)
+                net = lambda *a: fns.spatial(params, *a, taps, ref)
             else:
-                raw = fns.reuse(params, x_net, t_net, c_net, taps)
-            if use_cfg:
-                raw_c, raw_u = jnp.split(raw, 2, axis=0)
-                raw = raw_u + self.guidance_scale * (raw_c - raw_u)
-            pred = transform.transform_output(x, t_b,
-                                              raw.astype(jnp.float32),
-                                              schedule)
-            x0, eps = transform.to_x0_eps(x, t_b, pred, schedule)
-            if self.clip_denoised:
-                x0 = clip_images(x0)
-                _, sigma = schedule.rates(t_b)
-                signal, _ = schedule.rates(t_b)
-                eps = (x - bcast_right(signal, x.ndim) * x0) / jnp.maximum(
-                    bcast_right(sigma, x.ndim), 1e-12)
-            return x0, eps, taps, ref
+                net = lambda *a: (fns.reuse(params, *a, taps), taps, ref)
+            return self._evaluate(cond, uncond, x, t, net)
 
         return denoise
 
@@ -471,84 +422,42 @@ class DiffusionSampler:
 
         return denoise
 
-    def cache_taps_init(self, params, x, cond, uncond):
-        """Zero-filled cache carry shaped like the record branch's taps
-        output (CFG doubles the batch the taps cover). `jax.eval_shape`
-        only — no device compute — and the resulting spec is memoized
-        per input-shape signature: the abstract model trace costs tens
-        of ms, which must not recur on every serving admission (it
-        would serialize the dispatch loop)."""
-        spec_key = (_signature(x), _signature(cond), _signature(uncond))
+    def _cache_carry_zeros(self, key, params, x, cond, uncond, record):
+        """Zero-filled cache carries shaped like what `record` (a
+        record branch: `(params, x_net, t_net, c_net) -> (raw,
+        *carries)`) returns after `raw`; CFG doubles the batch they
+        cover. `jax.eval_shape` only — no device compute — and the spec
+        is memoized per input-shape signature: the abstract model trace
+        costs tens of ms, which must not recur on every serving
+        admission (it would serialize the dispatch loop). Step 0 of
+        every plan refreshes, so the zeros are never consumed."""
+        spec_key = (key, _signature(x), _signature(cond), _signature(uncond))
         spec = self._taps_specs.get(spec_key)
-        if spec is not None:
-            return jax.tree_util.tree_map(
-                lambda s: jnp.zeros(s.shape, s.dtype), spec)
-        record_fn = self.cache_fns[0]
-        schedule, transform = self.schedule, self.transform
-        use_cfg = self.guidance_scale > 0.0 and uncond is not None
-
-        def probe(x):
-            t_b = jnp.zeros((x.shape[0],), jnp.float32)
-            c_in = bcast_right(transform.input_scale(schedule, t_b), x.ndim)
-            x_in, t_in = schedule.transform_inputs(x * c_in, t_b)
-            if use_cfg:
-                x_in = jnp.concatenate([x_in, x_in], axis=0)
-                t_in = jnp.concatenate([t_in, t_in], axis=0)
-                c = jax.tree_util.tree_map(
-                    lambda c_, u_: jnp.concatenate([c_, u_], axis=0),
-                    cond, uncond)
-            else:
-                c = cond
-            _, taps = record_fn(params, x_in, t_in, c)
-            return taps
-
-        spec = jax.eval_shape(probe, x)
-        self._taps_specs[spec_key] = spec
+        if spec is None:
+            spec = self._taps_specs[spec_key] = jax.eval_shape(
+                lambda x: self._evaluate(
+                    cond, uncond, x, 0.0,
+                    lambda *a: record(params, *a))[2:], x)
         return jax.tree_util.tree_map(
             lambda s: jnp.zeros(s.shape, s.dtype), spec)
+
+    def cache_taps_init(self, params, x, cond, uncond):
+        """The timestep cache's zero `taps` carry."""
+        return self._cache_carry_zeros("taps", params, x, cond, uncond,
+                                       self.cache_fns[0])[0]
 
     def cache_carry_init(self, params, x, cond, uncond):
         """(taps0, ref0) zero carries for the composed spatial cache —
-        the record_ref branch's taps AND score-reference outputs. Same
-        rules as `cache_taps_init`: `jax.eval_shape` only, memoized per
-        input-shape signature (the abstract trace must not recur on
-        every serving admission), and step 0 of every plan refreshes,
-        so the zeros are never consumed."""
-        spec_key = ("composed", _signature(x), _signature(cond),
-                    _signature(uncond))
-        spec = self._taps_specs.get(spec_key)
-        if spec is None:
-            fns = self.cache_fns
-            schedule, transform = self.schedule, self.transform
-            use_cfg = self.guidance_scale > 0.0 and uncond is not None
-
-            def probe(x):
-                t_b = jnp.zeros((x.shape[0],), jnp.float32)
-                c_in = bcast_right(transform.input_scale(schedule, t_b),
-                                   x.ndim)
-                x_in, t_in = schedule.transform_inputs(x * c_in, t_b)
-                if use_cfg:
-                    x_in = jnp.concatenate([x_in, x_in], axis=0)
-                    t_in = jnp.concatenate([t_in, t_in], axis=0)
-                    c = jax.tree_util.tree_map(
-                        lambda c_, u_: jnp.concatenate([c_, u_], axis=0),
-                        cond, uncond)
-                else:
-                    c = cond
-                _, taps, ref = fns.record_ref(params, x_in, t_in, c)
-                return taps, ref
-
-            spec = jax.eval_shape(probe, x)
-            self._taps_specs[spec_key] = spec
-        return jax.tree_util.tree_map(
-            lambda s: jnp.zeros(s.shape, s.dtype), spec)
+        the record_ref branch's taps AND score-reference outputs."""
+        return self._cache_carry_zeros("composed", params, x, cond, uncond,
+                                       self.cache_fns.record_ref)
 
     # -- the dtype each leaf is served at -----------------------------------
     def _evaluations(self, params, x, cond, uncond):
         """Every way this sampler's programs evaluate the network, once
-        each at a row's shapes: the plain evaluation (every terminal
-        program, the uncached rounds) and, with a cache plan, each mode
-        of the cached rounds."""
+        each at a row's shapes: the plain evaluation (the uncached
+        rounds, the solo scan's terminal denoise) and, with a cache
+        plan, each mode of the cached rounds."""
         t = jnp.zeros((x.shape[0],), jnp.float32)
         outs = [self._denoise_fn(params, cond, uncond)(x, t)]
         if self.spatial_active:
@@ -849,16 +758,19 @@ class DiffusionSampler:
     # (flaxdiff_tpu/serving/engine.py). All are UNCACHED — the serving
     # engine owns the compiled-program cache and its hit/miss counters;
     # a second cache here would hide misses from the SLO metrics. The
-    # engine launches the round and terminal programs with the rows'
-    # carries as a tuple and stacks them INSIDE the compiled program
+    # engine launches the round programs with the rows' carries as a
+    # tuple and stacks them INSIDE the compiled program
     # (engine.py `_round_program`); the layouts below are the stacked
     # ones.
     #
     # Row model: the batch axis is REQUESTS, each row a block of
     # `block_shape` samples (the request's own num_samples). Everything
-    # per-row — trajectory position, remaining NFE, timestep pairs, RNG
-    # — is vmapped, so one program serves rows at different points of
-    # different-length trajectories. vmap (not reshape-to-one-batch) is
+    # per-row — trajectory position, remaining turns, timestep pairs,
+    # which turn is the terminal denoise, RNG — is vmapped, so one
+    # program serves rows at different points of different-length
+    # trajectories, a row that ends among them (`make_chunk_program`:
+    # a trajectory is `nfe + 1` turns; no program but the round
+    # programs evaluates the network). vmap (not reshape-to-one-batch) is
     # what keeps per-row RNG exact: stochastic samplers draw
     # `normal(key, x.shape)` per row with the row's own key, the same
     # call a solo `generate_samples` makes, so a batched request is
@@ -910,32 +822,63 @@ class DiffusionSampler:
 
         return jax.jit(sampler_init)
 
+    def _turn(self, denoise, x, pair, key, state, index, terminal,
+              seen=()):
+        """One turn of a row in a round program: the sampler's step over
+        `pair`, or, where `terminal` (a traced bool; None: never), the
+        row's terminal denoise. Every sampler opens a step with
+        `denoise(x, t_cur)`, and a terminal turn's pair is `(t_term,
+        t_term)`, so the `x0` of the step's FIRST evaluation is the solo
+        program's closing `denoise(x, steps[-1])`; what the step makes
+        of a zero-length step is dropped by the select. Returns (x,
+        state, tally): `seen` is the list `denoise` adds its
+        evaluations' tallies to, and a terminal turn is charged one."""
+        first = []
+
+        def remembering(x_, t_):
+            out = denoise(x_, t_)
+            if not first:
+                first.append(out[0])
+            return out
+
+        x_n, s_n = self.sampler.step(remembering, x, pair[0], pair[1], key,
+                                     state, self.schedule, index)
+        counted = sum(seen)
+        if terminal is not None:
+            x_n = jnp.where(terminal, first[0], x_n)
+            if seen:
+                counted = jnp.where(terminal, seen[0], counted)
+        return x_n, s_n, counted
+
     def make_chunk_program(self, round_steps: int):
         """One continuous-batching round: advance every row by `steps`
-        of ITS OWN trajectory, `steps` <= `round_steps`.
+        turns of ITS OWN trajectory, `steps` <= `round_steps`.
+
+        A row's trajectory is `nfe + 1` turns: its `nfe` sampler steps
+        and then its terminal denoise (`_turn`).
 
         `round_steps` is the size the program is compiled for (the
-        `pairs` operand's width) and nothing else: how many steps a
+        `pairs` operand's width) and nothing else: how many turns a
         round runs is DATA, the scalar `steps`, so one program serves
-        every length and no row spends a model evaluation on a step it
+        every length and no row spends a model evaluation on a turn it
         throws away (`_run_steps`). The serving engine ends a round
         where its first row ends (`serving/engine.py` `round_length`).
 
         program(params, x, keys, pairs, n_act, offsets, steps, cond,
-                uncond, state)
+                uncond, state, tally, term)
           x        [R, *block]            row carries (trajectory state)
           keys     [R, 2] uint32          per-row scan RNG carries
           pairs    [R, round_steps, 2]    this round's (t_cur, t_next)
                                           pairs, inert-padded past n_act
-          n_act    [R] int32              live steps this round, at most
+          n_act    [R] int32              live turns this round, at most
                                           `steps`: a row with fewer keeps
                                           its carry for the rest (rows
                                           of different lengths run to
                                           completion together; padding)
           offsets  [R] int32              global step index of the row's
-                                          first step this round (multistep
+                                          first turn this round (multistep
                                           samplers key history on it)
-          steps    [] int32               steps this round runs, shared
+          steps    [] int32               turns this round runs, shared
                                           by all rows; never a Python int
           state    [R, ...] pytree        per-row sampler state carry
                                           (init_state at admission)
@@ -943,34 +886,40 @@ class DiffusionSampler:
                                           far (zeros at admission); None
                                           and absent from the result
                                           for any other model
+          term     [R] int32              the turn of this round that is
+                                          the row's terminal denoise, -1
+                                          for none; data like `n_act`,
+                                          never a Python int (None: no
+                                          row ends in this program)
         Returns (x, keys, state) carries, with a `tally_shape` (x, keys,
-        state, tally). Rows never interact, so a
+        state, tally); after its terminal turn a row's `x` is its
+        denoised sample. Rows never interact, so a
         padded round is output-invariant for the real rows, and a row's
         samples do not depend on where its rounds were cut (tested).
         """
         def sampler_chunk(params, x, keys, pairs, n_act, offsets, steps,
-                          cond, uncond, state, tally=None):
-            def row(x_r, key, row_pairs, n, off, c, u, st, tl):
+                          cond, uncond, state, tally=None, term=None):
+            def row(x_r, key, row_pairs, n, off, c, u, st, tl, tm):
                 def step(carry, pair, i):
                     x_c, rng, s, tl_c = carry
                     rng, sub = jax.random.split(rng)
-                    seen = []       # the tallies of this step's evaluations
-                    x_n, s_n = self.sampler.step(
-                        self._denoise_fn(params, c, u, seen), x_c, pair[0],
-                        pair[1], sub, s, self.schedule, off + i)
+                    seen = []       # the tallies of this turn's evaluations
+                    x_n, s_n, counted = self._turn(
+                        self._denoise_fn(params, c, u, seen), x_c, pair, sub,
+                        s, off + i, None if tm is None else i == tm, seen)
                     active = i < n
                     x_n = jnp.where(active, x_n, x_c)
                     s_n = jax.tree_util.tree_map(
                         lambda a, b: jnp.where(active, a, b), s_n, s)
                     if seen:
-                        tl_c = jnp.where(active, tl_c + sum(seen), tl_c)
+                        tl_c = jnp.where(active, tl_c + counted, tl_c)
                     return x_n, rng, s_n, tl_c
 
                 out = _run_steps(step, (x_r, key, st, tl), row_pairs, steps)
                 return out if tl is not None else out[:3]
 
             return jax.vmap(row)(x, keys, pairs, n_act, offsets,
-                                 cond, uncond, state, tally)
+                                 cond, uncond, state, tally, term)
 
         return jax.jit(sampler_chunk)
 
@@ -982,7 +931,10 @@ class DiffusionSampler:
           taps  [R, ...] pytree      per-row cache carry (rides the
                                      RequestState like x/rng/state)
 
-        and `(x, keys, state, taps)` carries out.
+        and `(x, keys, state, taps)` carries out. A row's terminal turn
+        (`term`, as in `make_chunk_program`) is the same per-row select
+        inside whichever branch the round's flag takes; the engine
+        schedules it as a refresh, so it is a full evaluation.
 
         Structure flips to loop-outside / vmap-inside: the refresh
         decision must be a SCALAR `lax.cond` — vmapping a cond over
@@ -997,10 +949,11 @@ class DiffusionSampler:
         plan is bit-identical to the uncached chunk path (tested).
         """
         def sampler_chunk_cached(params, x, keys, pairs, n_act, offsets,
-                                 steps, cond, uncond, state, flags, taps):
+                                 steps, cond, uncond, state, flags, taps,
+                                 term=None):
             def make_step(mode):
                 def step_all(x_c, subs, st, tp, pair_i, i):
-                    def row(x_r, sub, s_r, tp_r, pr, off, c, u):
+                    def row(x_r, sub, s_r, tp_r, pr, off, c, u, tm):
                         dn = self._denoise_taps_mode_fn(
                             params, c, u, mode)
                         taps_box = [tp_r]
@@ -1010,13 +963,13 @@ class DiffusionSampler:
                             taps_box[0] = tpn
                             return x0, eps
 
-                        x_n, s_n = self.sampler.step(
-                            step_denoise, x_r, pr[0], pr[1], sub, s_r,
-                            self.schedule, off + i)
+                        x_n, s_n, _ = self._turn(
+                            step_denoise, x_r, pr, sub, s_r, off + i,
+                            None if tm is None else i == tm)
                         return x_n, s_n, taps_box[0]
 
                     return jax.vmap(row)(x_c, subs, st, tp, pair_i,
-                                         offsets, cond, uncond)
+                                         offsets, cond, uncond, term)
                 return step_all
 
             record_step = make_step("record")
@@ -1068,13 +1021,15 @@ class DiffusionSampler:
         refresh beats spatial beats reuse, so no row ever gets LESS
         refresh than its plan scheduled — round-mates can only grant
         extra fidelity. Token selection runs per-row inside the vmap
-        (each row picks its own top-k from its own carries)."""
+        (each row picks its own top-k from its own carries). A row's
+        terminal turn (`term`) is the cached program's per-row select,
+        scheduled by the engine as a refresh."""
         def sampler_chunk_spatial(params, x, keys, pairs, n_act, offsets,
                                   steps, cond, uncond, state, codes, taps,
-                                  refs):
+                                  refs, term=None):
             def make_step(mode):
                 def step_all(x_c, subs, st, tp, rf, pair_i, i):
-                    def row(x_r, sub, s_r, tp_r, rf_r, pr, off, c, u):
+                    def row(x_r, sub, s_r, tp_r, rf_r, pr, off, c, u, tm):
                         dn = self._denoise_composed_mode_fn(
                             params, c, u, mode)
                         carry_box = [tp_r, rf_r]
@@ -1085,13 +1040,13 @@ class DiffusionSampler:
                             carry_box[0], carry_box[1] = tpn, rfn
                             return x0, eps
 
-                        x_n, s_n = self.sampler.step(
-                            step_denoise, x_r, pr[0], pr[1], sub, s_r,
-                            self.schedule, off + i)
+                        x_n, s_n, _ = self._turn(
+                            step_denoise, x_r, pr, sub, s_r, off + i,
+                            None if tm is None else i == tm)
                         return x_n, s_n, carry_box[0], carry_box[1]
 
                     return jax.vmap(row)(x_c, subs, st, tp, rf, pair_i,
-                                         offsets, cond, uncond)
+                                         offsets, cond, uncond, term)
                 return step_all
 
             # branch order == CODE_* values (ops/spatialcache.py)
@@ -1123,32 +1078,17 @@ class DiffusionSampler:
 
         return jax.jit(sampler_chunk_spatial)
 
-    def make_terminal_program(self):
-        """Terminal denoise for rows whose trajectory just completed:
-        the solo program's final `denoise(x, steps[-1])` call, vmapped
-        with each row's OWN terminal step value (spacings of different
-        NFE need not end at bit-identical values). With a `tally_shape`
-        the rows' `tally` carries go in and (x0, tally) comes out, this
-        evaluation's counted in."""
-        def sampler_terminal(params, x, t_term, cond, uncond, tally=None):
-            def row(x_r, t_r, c, u, tl):
-                seen = []
-                denoise = self._denoise_fn(params, c, u, seen)
-                x0, _ = denoise(x_r, jnp.full((x_r.shape[0],), t_r))
-                return x0 if tl is None else (x0, tl + sum(seen))
-
-            return jax.vmap(row)(x, t_term, cond, uncond, tally)
-
-        return jax.jit(sampler_terminal)
-
     def trajectory_inputs(self, num_steps: int,
                           start: Optional[float] = None,
                           end: float = 0.0):
-        """Host-side per-request trajectory constants for the serving
-        programs: ([num_steps, 2] step pairs, terminal step value) —
-        the same spacing the solo program closes over."""
+        """Host-side per-request trajectory constant for the serving
+        programs: the `[num_steps + 1, 2]` (t_cur, t_next) pairs of a
+        row's turns, from the same spacing the solo program closes
+        over. The last is the terminal turn's, `(t_term, t_term)`: the
+        row's OWN terminal value (spacings of different NFE need not
+        end at bit-identical values)."""
         steps = get_timestep_spacing(self.timestep_spacing, num_steps,
                                      self.schedule.timesteps, start, end,
                                      schedule=self.schedule)
-        pairs = jnp.stack([steps[:-1], steps[1:]], axis=1)
-        return pairs, steps[-1]
+        return jnp.stack(
+            [steps, jnp.concatenate([steps[1:], steps[-1:]])], axis=1)

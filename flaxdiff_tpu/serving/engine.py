@@ -8,18 +8,27 @@ existing single-`lax.scan` `DiffusionSampler`, keyed on
      has_cond, has_uncond, cache_plan)
 
 so repeat traffic never re-traces. `scan_steps` is the size the round
-program is compiled for — the (power-of-two-bucketed) longest NFE in
-run-to-completion mode, `SchedulerConfig.round_steps` in continuous
-mode — and NOT the number of steps a round runs: that is an operand
-(`round_length`: a round ends where its first row ends), as each row's
-timestep pairs and live-step count are, so rounds of every length and
-NFE-heterogeneous rows share one program. Cache hits/misses are counted
-at `serving/program_cache_hits` / `serving/program_cache_misses`.
-Program kinds: "init" and "noise" (a request's starting carry from its
-seed), "chunk" (uncached), "chunk_cached" (timestep diffusion cache),
-"chunk_spatial" (composed timestep x spatial cache,
-ops/spatialcache.py), "terminal". `prewarm` compiles the hot tuples
-before admission opens.
+program is compiled for — the (power-of-two-bucketed) longest
+trajectory in run-to-completion mode, `SchedulerConfig.round_steps` in
+continuous mode — and NOT the number of turns a round runs: that is an
+operand (`round_length`: a round ends where its first row ends), as
+each row's timestep pairs, live-turn count and terminal turn are, so
+rounds of every length and NFE-heterogeneous rows share one program.
+Cache hits/misses are counted at `serving/program_cache_hits` /
+`serving/program_cache_misses`. Program kinds: "init" and "noise" (a
+request's starting carry from its seed), "chunk" (uncached),
+"chunk_cached" (timestep diffusion cache), "chunk_spatial" (composed
+timestep x spatial cache, ops/spatialcache.py): the only ones that
+hold the network; and "handoff" (stack, decode, clip of finished rows:
+no evaluation). `prewarm` compiles the hot tuples before admission
+opens.
+
+**A row's trajectory is `nfe + 1` turns of the round programs, and the
+last is its terminal denoise** (`DiffusionSampler.make_chunk_program`):
+a row that ends rides the round its mates ride, every slot of every
+turn is a row somebody asked for, and no program is launched at a
+padded bucket for the rows that happened to finish together.
+`serving/terminal_turns` counts them.
 
 **A turn of the dispatch thread is a handful of launches and no
 read-back** (docs/SERVING.md "Run-ahead"): the runtime queues only so
@@ -27,13 +36,13 @@ many launches behind a running program and then blocks the caller, and
 a device-to-host read waits for everything queued before it, so either
 one would stop the thread from preparing the next round under the
 running one. `prepare` is two launches (the "init" and "noise"
-programs); what depends only on (sampler, NFE, schedule) — the trajectory's step pairs
-and terminal step, as HOST values — is computed once and kept here. A
-round is one launch: its pairs / live-step counts / offsets are built
-in numpy, and the rows' carries go in as a tuple and come back as a
-tuple, stacked and unstacked INSIDE the compiled program. `finalize`
-is one launch (stack, terminal denoise, decode, clip). Every launch
-goes through `_launch`, counted at `serving/launches`.
+programs); what depends only on (sampler, NFE, schedule) — the
+trajectory's turn pairs, as HOST values — is computed once and kept
+here. A round is one launch: its pairs / live-turn counts / offsets /
+terminal turns are built in numpy, and the rows' carries go in as a
+tuple and come back as a tuple, stacked and unstacked INSIDE the
+compiled program. `finalize` is one small launch (stack, decode, clip).
+Every launch goes through `_launch`, counted at `serving/launches`.
 
 **What the programs take is the served tree, not the pipeline's**
 (`_params_for`, docs/SERVING.md "What the engine holds"): a leaf the
@@ -87,16 +96,17 @@ def nfe_bucket(n: int) -> int:
 
 
 def round_length(rows, round_steps: int) -> Tuple[int, int]:
-    """(size the round's program is compiled for, steps the round runs)
+    """(size the round's program is compiled for, turns the round runs)
     for `rows` under `SchedulerConfig.round_steps`, from the rows'
-    remaining steps and nothing else (host integers: `done` advances at
+    remaining turns and nothing else (host integers: `done` advances at
     launch, so the dispatch thread one round ahead knows them without a
     read-back).
 
     Continuous mode (`round_steps` > 0): the round ends where its first
-    row ends, after at most `round_steps`. Every row is live on every
-    step, so no model evaluation is thrown away, and a finished row's
-    slot is refilled at the next round. Run-to-completion
+    row ends (at that row's terminal turn), after at most
+    `round_steps`. Every row is live on every turn, so no model
+    evaluation is thrown away, and a finished row's slot is refilled at
+    the next round. Run-to-completion
     (`round_steps` 0): the exact longest remaining length, in the
     program of its power-of-two bucket; shorter rows keep their carry
     past their own end."""
@@ -131,30 +141,30 @@ def _round_program(program):
     return jax.jit(run)
 
 
-def _terminal_program(program, autoencoder, tallied: bool = False):
-    """`make_terminal_program` as the engine launches it: stack, the
-    terminal denoise, decode and clip in one launch. Returns the whole
-    bucket, `[bucket, num_samples, *sample_shape]` (a cut to the real
-    rows would be a program per row count); `tallied` (a counting
-    model): (that, the rows' tallies `[bucket, *tally_shape]`)."""
-    def run(params, rows, batch):
-        x0 = program(params, **_stacked(rows), **batch)
-        if tallied:
-            x0, tally = x0
+def _handoff_program(autoencoder):
+    """What is left to do for rows whose terminal turn ran, in one
+    launch and with no model evaluation: stack their `x`, decode, clip.
+    Returns the whole bucket, `[bucket, num_samples, *sample_shape]` (a
+    cut to the real rows would be a program per row count); for rows
+    that carry a `tally` (a counting model): (that, the rows' tallies
+    `[bucket, *tally_shape]`)."""
+    def sampler_handoff(rows):
+        out = _stacked(rows)
+        x0 = out["x"]
         if autoencoder is not None:
             flat = autoencoder.decode(x0.reshape((-1,) + x0.shape[2:]))
             x0 = flat.reshape(x0.shape[:2] + flat.shape[1:])
-        return (clip_images(x0), tally) if tallied else clip_images(x0)
+        x0 = clip_images(x0)
+        return (x0, out["tally"]) if "tally" in out else x0
 
-    run.__name__ = program.__name__
-    return jax.jit(run)
+    return jax.jit(sampler_handoff)
 
 
 class RequestState:
     """One admitted request's device-resident trajectory carry."""
 
     __slots__ = ("req", "future", "submit_t", "admit_t", "group",
-                 "x", "rng", "state", "pairs", "terminal_t", "nfe",
+                 "x", "rng", "state", "pairs", "nfe",
                  "done", "cond", "uncond", "compile_ms", "rounds",
                  "first_dispatch_t", "plan", "flags", "taps", "codes",
                  "ref", "trace", "attempts", "orig_req", "degraded",
@@ -162,9 +172,8 @@ class RequestState:
 
     def __init__(self, req: SampleRequest, future: ServingFuture,
                  submit_t: float, admit_t: float, group: tuple,
-                 x, rng, state, pairs, terminal_t: float,
-                 cond, uncond, plan=None, flags=None, taps=None,
-                 codes=None, ref=None, tally=None):
+                 x, rng, state, pairs, cond, uncond, plan=None, flags=None,
+                 taps=None, codes=None, ref=None, tally=None):
         self.req = req
         self.future = future
         self.submit_t = submit_t
@@ -173,17 +182,17 @@ class RequestState:
         self.x = x                  # [num_samples, *sample_shape]
         self.rng = rng              # scan RNG carry (loop key lineage)
         self.state = state          # sampler state pytree
-        self.pairs = pairs          # [nfe, 2] trajectory pairs (numpy)
-        self.terminal_t = terminal_t    # terminal step (Python float)
+        self.pairs = pairs          # [nfe + 1, 2] turn pairs (numpy)
         self.nfe = int(req.diffusion_steps)
-        self.done = 0               # completed trajectory steps
+        self.done = 0               # completed turns of nfe + 1
         self.cond = cond
         self.uncond = uncond
         self.compile_ms = 0.0
         self.rounds = 0
         self.first_dispatch_t: Optional[float] = None
         # training-free diffusion cache (docs/CACHING.md): the
-        # request's plan, its host-side [nfe] refresh schedule, and the
+        # request's plan, its host-side [nfe + 1] refresh schedule (the
+        # terminal turn is a refresh), and the
         # device-resident activation-cache carry. A composed
         # (timestep x spatial, ops/spatialcache.py) plan carries a
         # three-way code row instead of boolean flags plus the
@@ -196,7 +205,7 @@ class RequestState:
         # a counting model's sums over this row's evaluations (routed
         # experts: held picks by layer and expert): host zeros at
         # admission, then a device carry like `x`; `tally_out` is
-        # (the finalised batch's tallies on the device, this row's
+        # (the handed-off batch's tallies on the device, this row's
         # index), which the completion thread fetches with the samples
         self.tally = tally
         self.tally_out = None
@@ -213,7 +222,8 @@ class RequestState:
 
     @property
     def remaining(self) -> int:
-        return self.nfe - self.done
+        """Turns left: the sampler's steps and the terminal denoise."""
+        return self.nfe + 1 - self.done
 
 
 class SamplerProgramEngine:
@@ -228,9 +238,9 @@ class SamplerProgramEngine:
         self.telemetry = telemetry
         self._programs: Dict[tuple, Any] = {}
         # constants of (sampler, NFE, schedule) and of num_samples,
-        # computed once: ([nfe, 2] pairs, terminal step) as host values,
-        # and the null context tiled to a request's num_samples
-        self._trajectories: Dict[tuple, Tuple[np.ndarray, float]] = {}
+        # computed once: the [nfe + 1, 2] turn pairs as host values, and
+        # the null context tiled to a request's num_samples
+        self._trajectories: Dict[tuple, np.ndarray] = {}
         self._null_contexts: Dict[int, Any] = {}
         # what `_params_for` holds: use_ema -> (the pipeline's tree,
         # {group: served tree}, {narrowing: served tree})
@@ -374,18 +384,18 @@ class SamplerProgramEngine:
         self.telemetry.counter("serving/launches").inc()
         return program(*args)
 
-    def _trajectory(self, ds, nfe: int) -> Tuple[np.ndarray, float]:
-        """(`[nfe, 2]` step pairs, terminal step) as HOST values:
-        `ds.trajectory_inputs` computes them, the same spacing the solo
-        program closes over, and they are read back ONCE per (sampler,
-        NFE) — at warm-up, or at the first sight of an NFE."""
-        traj = self._trajectories.get((ds, nfe))
-        if traj is None:
+    def _trajectory(self, ds, nfe: int) -> np.ndarray:
+        """The `[nfe + 1, 2]` turn pairs as HOST values, the last the
+        terminal turn's `(t_term, t_term)`: `ds.trajectory_inputs`
+        computes them, the same spacing the solo program closes over,
+        and they are read back ONCE per (sampler, NFE) — at warm-up, or
+        at the first sight of an NFE."""
+        pairs = self._trajectories.get((ds, nfe))
+        if pairs is None:
             from .scheduler import _device_get
-            pairs, terminal_t = ds.trajectory_inputs(nfe)
-            traj = (_device_get(pairs), float(_device_get(terminal_t)))
-            self._trajectories[(ds, nfe)] = traj
-        return traj
+            pairs = self._trajectories[(ds, nfe)] = _device_get(
+                ds.trajectory_inputs(nfe))
+        return pairs
 
     def _null_context(self, k: int):
         """The cached null tokens at `num_samples` k, exactly as
@@ -454,20 +464,22 @@ class SamplerProgramEngine:
         noise, _ = self._get_program(
             "noise", group, 0, 0, lambda: ds.make_noise_program(shape()))
         x = self._launch(noise, noise_key)
-        pairs, terminal_t = self._trajectory(ds, nfe)
+        pairs = self._trajectory(ds, nfe)
         # host-side numpy schedules of a cache plan (zero device work);
         # step 0 of every plan refreshes, so the zero carries the init
-        # program made are never consumed
+        # program made are never consumed, and so does the terminal
+        # turn (the solo scan's terminal denoise is a full evaluation)
         plan = ds.cache_plan if ds.cache_active else None
         flags = codes = None
         if ref is not None:
-            codes = plan.step_codes(nfe)
+            from ..ops.spatialcache import CODE_REFRESH
+            codes = np.append(plan.step_codes(nfe), np.int32(CODE_REFRESH))
         elif taps is not None:
-            flags = plan.flags(nfe)
+            flags = np.append(plan.flags(nfe), True)
         st = RequestState(
             req=req, future=future, submit_t=submit_t, admit_t=admit_t,
             group=group, x=x, rng=loop_key, state=state, pairs=pairs,
-            terminal_t=terminal_t, cond=cond, uncond=uncond, plan=plan,
+            cond=cond, uncond=uncond, plan=plan,
             flags=flags, taps=taps, codes=codes, ref=ref,
             tally=(None if ds.tally_shape is None
                    else np.zeros(ds.tally_shape, np.int32)))
@@ -488,12 +500,13 @@ class SamplerProgramEngine:
 
     def advance(self, rows: List[RequestState], bucket: int,
                 round_steps: int) -> Tuple[List[RequestState], float]:
-        """Run one round of `round_length(rows, round_steps)` steps:
+        """Run one round of `round_length(rows, round_steps)` turns:
         `round_steps` is `SchedulerConfig.round_steps`, the longest
         round and the size of the compiled program (0 = run to
         completion); every row advances min(remaining, the round's
-        length) steps of its own trajectory. Returns (rows that
-        completed their trajectory this round, compile seconds spent —
+        length) turns of its own trajectory, the last of them its
+        terminal denoise. Returns (rows whose terminal turn ran this
+        round: their `x` is the denoised sample, compile seconds spent —
         0 on a cache hit). One launch; `serve.stack` is host arithmetic,
         and `serve.unstack` hands each row its own outputs of the
         program."""
@@ -509,19 +522,21 @@ class SamplerProgramEngine:
             pairs = np.empty((bucket, size, 2), np.float32)
             n_act = np.empty((bucket,), np.int32)
             offsets = np.empty((bucket,), np.int32)
+            term = np.empty((bucket,), np.int32)
             for i, r in enumerate(srcs):
                 sl = r.pairs[r.done:r.done + steps]
-                if len(sl) == 0:            # exhausted padding row
-                    sl = r.pairs[-1:]
                 pairs[i, :len(sl)] = sl     # inert past n_act: the last
                 pairs[i, len(sl):] = sl[-1]     # pair again
-                n_act[i] = max(0, min(r.remaining, steps))
+                n_act[i] = min(r.remaining, steps)
                 offsets[i] = r.done
+                # the turn that is the row's terminal denoise, if this
+                # round reaches it
+                term[i] = r.nfe - r.done if r.remaining <= steps else -1
             carries = [{"x": r.x, "keys": r.rng, "state": r.state,
                         "cond": r.cond, "uncond": r.uncond} for r in srcs]
             # the round's length is data: one program for every length
             batch = {"pairs": pairs, "n_act": n_act, "offsets": offsets,
-                     "steps": np.int32(steps)}
+                     "steps": np.int32(steps), "term": term}
             if plan is None:
                 kind_used, build = "chunk", ds.make_chunk_program
                 if ds.tally_shape is not None:
@@ -573,11 +588,14 @@ class SamplerProgramEngine:
             outs = self._launch(program, *prog_args)
             compile_s = (time.perf_counter() - t0) if miss else 0.0
         n_live = [int(n) for n in n_act[:len(rows)]]
-        # step occupancy: live / run is 1.0 when every row is live on
-        # every step of its rounds (continuous mode)
+        # turn occupancy: live / run is 1.0 when every row is live on
+        # every turn of its rounds (continuous mode); and the real
+        # rows' terminal denoises that rode this round
         count = self.telemetry.counter
         count("serving/row_steps_run").inc(len(rows) * steps)
         count("serving/row_steps_live").inc(sum(n_live))
+        count("serving/terminal_turns").inc(
+            int((term[:len(rows)] >= 0).sum()))
         if sched_row is not None:
             # codes of a composed plan: 2 refresh, 1 spatial, 0 reuse;
             # flags of a timestep plan: 1 refresh, 0 reuse
@@ -625,8 +643,9 @@ class SamplerProgramEngine:
 
     def finalize(self, rows: List[RequestState],
                  bucket: int) -> Tuple[jax.Array, float]:
-        """Terminal denoise + (optional) decode + clip for completed
-        rows, in one launch. Returns (`[bucket, num_samples,
+        """Hand off rows whose terminal turn ran (`advance`'s
+        `finished`): stack + (optional) decode + clip in one launch that
+        evaluates no model. Returns (`[bucket, num_samples,
         *sample_shape]` device array whose first `len(rows)` entries
         are the rows' samples in row order, compile seconds). A counting
         model's tallies come out of the same launch and stay on the
@@ -636,35 +655,27 @@ class SamplerProgramEngine:
         tallied = ds.tally_shape is not None
         with self._span("serve.stack"):
             srcs = rows + [rows[0]] * (bucket - len(rows))
-            carries = [{"x": r.x, "cond": r.cond, "uncond": r.uncond}
-                       for r in srcs]
-            if tallied:
-                for c, r in zip(carries, srcs):
-                    c["tally"] = r.tally
-            prog_args = (
-                self._params_for(group, ds, srcs[0].x, srcs[0].cond,
-                                 srcs[0].uncond), tuple(carries),
-                {"t_term": np.float32([r.terminal_t for r in srcs])})
+            carries = tuple({"x": r.x, "tally": r.tally} if tallied
+                            else {"x": r.x} for r in srcs)
 
-        with self._span("serve.launch", kind="terminal"):
+        with self._span("serve.launch", kind="handoff"):
             program, miss = self._get_program(
-                "terminal", group, bucket, 0,
-                lambda: _terminal_program(ds.make_terminal_program(),
-                                          ds.autoencoder, tallied))
+                "handoff", group, bucket, 0,
+                lambda: _handoff_program(ds.autoencoder))
             t0 = time.perf_counter()
-            out = self._launch(program, *prog_args)
+            out = self._launch(program, carries)
             compile_s = (time.perf_counter() - t0) if miss else 0.0
         if tallied:
             out, tallies = out
             for i, r in enumerate(rows):
                 r.tally_out = (tallies, i)
         if miss:
-            self._register_evidence("terminal", group, bucket, 0,
-                                    program, prog_args, compile_s)
+            self._register_evidence("handoff", group, bucket, 0,
+                                    program, (carries,), compile_s)
         return out, compile_s
 
     def count_picks(self, rows: List[RequestState], fetch) -> None:
-        """Add a finalised batch's routed-expert picks to the telemetry
+        """Add a handed-off batch's routed-expert picks to the telemetry
         counters (docs/OBSERVABILITY.md): called by the completion
         thread where it fetches the samples, with its `fetch`, for rows
         that carry a `tally_out` (a model with routed experts).

@@ -13,13 +13,15 @@ Architecture (docs/SERVING.md):
   admits queued requests into the group's free capacity, pads the
   batch to a bucket, and advances every row of its OWN trajectory
   through the engine's compiled program. **A round ends where its
-  first row ends**, after at most `round_steps` steps (`round_length`,
+  first row ends**, after at most `round_steps` turns (`round_length`,
   serving/engine.py): its length is an operand of the one compiled
-  program, decided from the rows' remaining steps (host integers), so
-  no row spends a step on a model evaluation it throws away. Rows that
-  complete exit mid-group ("continuous admission"): a 10-NFE request
-  batched with a 50-NFE one returns after its own 10 steps, and its
-  slot is refilled from the queue at the next round.
+  program, decided from the rows' remaining turns (host integers), so
+  no row spends a turn on a model evaluation it throws away. A row's
+  last turn is its terminal denoise, so a row that ends rides the round
+  its mates ride. Rows that complete exit mid-group ("continuous
+  admission"): a 10-NFE request batched with a 50-NFE one returns after
+  its own 11 turns, and its slot is refilled from the queue at the
+  next round.
 - Completed rows are handed (still device-resident, dispatch still
   async) to a **completion thread** that performs the host syncs of a
   result — `_block_until_ready` + `_device_get`, module-level seams so
@@ -28,7 +30,7 @@ Architecture (docs/SERVING.md):
   beyond that it waits (genuine backpressure, counted at
   `serving/backpressure_waits`) instead of racing the device.
 - The dispatch loop runs **one round ahead of the device and no
-  further**: a turn (admit, round, finalize) is a handful of launches
+  further**: a turn (admit, round, hand-off) is a handful of launches
   and no device-to-host read (serving/engine.py), so it prepares round
   N+1 while round N runs. Its ONE wait on device work is `serve.pace`,
   at the top of a turn: with `_ROUNDS_AHEAD` rounds unfinished (the one
@@ -118,12 +120,13 @@ def _now() -> float:
 class SchedulerConfig:
     """Knobs for the dispatch loop.
 
-    round_steps: the LONGEST round, in trajectory steps, and the size
-      the round program is compiled for. A round runs to where its
-      first row ends, so it is shorter whenever a row has fewer steps
-      left (`round_length`, serving/engine.py); one program serves
-      every length. 0 = run-to-completion: one round runs a group's
-      longest remaining NFE exactly (in the program of its
+    round_steps: the LONGEST round, in turns of a trajectory (its
+      steps and then its terminal denoise), and the size the round
+      program is compiled for. A round runs to where its first row
+      ends, so it is shorter whenever a row has fewer turns left
+      (`round_length`, serving/engine.py); one program serves every
+      length. 0 = run-to-completion: one round runs a group's longest
+      remaining trajectory exactly (in the program of its
       power-of-two bucket) — lowest overhead, but a short request then
       waits for the longest row in its round.
     batch_buckets: padded batch sizes; max(batch_buckets) caps rows
@@ -833,7 +836,10 @@ class ServingScheduler:
             t_disp = _now()
             for r in rows:
                 if r.first_dispatch_t is None:
-                    r.first_dispatch_t = t_disp
+                    # what its admission compiled (`prepare`, on a cold
+                    # cache) is compile time and not queue time: counted
+                    # once, so queue + compile + device is the latency
+                    r.first_dispatch_t = t_disp - r.compile_ms / 1e3
 
             try:
                 if self._unfinished \
@@ -852,7 +858,9 @@ class ServingScheduler:
                 live = [r for r in rows if r.remaining > 0]
                 if finished:
                     fin_bucket = bucket_up(len(finished), buckets)
-                    with span("serve.finalize", rows=len(finished),
+                    # their terminal turn ran in the round: what is
+                    # left evaluates no model (stack, decode, clip)
+                    with span("serve.handoff", rows=len(finished),
                               bucket=fin_bucket):
                         out, _ = self.engine.finalize(finished,
                                                       fin_bucket)

@@ -2,11 +2,11 @@
 provenance (docs/OBSERVABILITY.md "Program evidence registry").
 
 Every compiled hot program — the train step, its monitored twin, every
-serving chunk/terminal program, solo sampler scans — registers ONE
+serving chunk and hand-off program, solo sampler scans — registers ONE
 record in `programs.jsonl` at trace/compile time:
 
     kind            train_step | chunk | chunk_cached | chunk_spatial |
-                    terminal | solo | ...
+                    handoff | solo | ...
     key             the program-cache key the owner compiled it under
                     (stringified; stable across runs of the same config)
     compile_ms      wall of the compiling call (first-call timing: on a

@@ -4,8 +4,9 @@ The serving histograms (`serving/{latency,queue,compile,device}_ms`)
 answer "how is the fleet doing" in aggregate; this module answers the
 attribution question they cannot: *follow one `SampleRequest`* from
 submit through admission, queue wait, every micro-batch round it rode
-(with the compiled program's cache key, batch bucket, live-step counts,
-and cache-plan step codes), terminal denoise, and completion — the
+(with the compiled program's cache key, batch bucket, live-turn counts,
+and cache-plan step codes; the last turn it rode is its terminal
+denoise), hand-off, and completion — the
 decomposition a multi-level split across chips (FastUSP-style) needs
 before any cross-chip placement decision is measurable.
 
@@ -22,7 +23,7 @@ Output, per traced request:
 - Chrome trace-event spans in the hub's `TraceRecorder` (`trace.json`,
   Perfetto-loadable): a `req.queue` span (submit -> first dispatch) and
   a `req.serve` span (first dispatch -> samples on host) on a per-trace
-  lane. The shared `serve.round` / `serve.finalize` spans are the
+  lane. The shared `serve.round` / `serve.handoff` spans are the
   scheduler's own (`Telemetry.span`, docs/OBSERVABILITY.md "Trace
   spans"), on the dispatch thread's lane, carrying round / bucket /
   rows / steps; program key and step codes are in the rows below,
@@ -43,7 +44,7 @@ import itertools
 import os
 from typing import Any, Dict, List, Optional
 
-# Chrome-trace lane ids: rounds/finalize on one fixed dispatch lane,
+# Chrome-trace lane ids: rounds/hand-offs on one fixed dispatch lane,
 # each request on its own small lane so Perfetto stacks them readably.
 DISPATCH_TID = 900_000
 _REQ_TID_BASE = 100_000

@@ -64,20 +64,23 @@ SPANS: Dict[str, SpanDoc] = {k: SpanDoc(*v) for k, v in {
                     "pick group, pop active, admit (`engine.prepare`: two "
                     "launches a request), brownout tier"),
     "serve.round": ("dispatch", None, ("round", "bucket", "rows", "steps"),
-                    "`_checked_advance`, first line to return"),
-    "serve.stack": ("dispatch", "serve.round | serve.finalize", (),
-                    "host arithmetic: the pairs / n_act / offsets in "
-                    "numpy and the tuple of row carries (no launch)"),
-    "serve.launch": ("dispatch", "serve.round | serve.finalize", ("kind",),
+                    "`_checked_advance`, first line to return; `steps` "
+                    "the turns it ran, the rows' terminal denoises "
+                    "among them: rows x steps is its model evaluations"),
+    "serve.stack": ("dispatch", "serve.round | serve.handoff", (),
+                    "host arithmetic: the pairs / n_act / offsets / term "
+                    "in numpy and the tuple of row carries (no launch)"),
+    "serve.launch": ("dispatch", "serve.round | serve.handoff", ("kind",),
                      "`_get_program` and the ONE jitted call: stack, "
                      "program, unstack (host side of the dispatch; a "
                      "compile on a miss is its length)"),
     "serve.unstack": ("dispatch", "serve.round", (), "each row takes its "
                       "own outputs of the program: x / rng / state / "
                       "taps / ref (no launch)"),
-    "serve.finalize": ("dispatch", None, ("rows", "bucket"),
-                       "`engine.finalize` whole: one launch for stack, "
-                       "terminal denoise, optional decode, clip"),
+    "serve.handoff": ("dispatch", None, ("rows", "bucket"),
+                      "`engine.finalize` whole, for rows whose terminal "
+                      "turn ran: one launch for stack, optional decode, "
+                      "clip, and no model evaluation"),
     "serve.backpressure": ("dispatch", None, (), "the wait while more "
                            "than `max_inflight` batches are in flight"),
     # -- serving: completion thread (`serving-complete`)

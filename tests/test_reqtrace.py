@@ -88,10 +88,15 @@ def test_traced_replay_registry_and_reconciliation(tiny_pipe, tmp_path):
     for r in rows:
         assert r["compile_ms"] and r["compile_ms"] > 0
         # a request's init and noise programs hold no model evaluation
-        assert r["kind"] in ("init", "noise") or r["flops_jaxpr"] > 0
+        assert r["kind"] in ("init", "noise", "handoff") \
+            or r["flops_jaxpr"] > 0
         assert r["fingerprint"]["platform"]
-    # every program kind this workload compiles is present
-    assert {r["kind"] for r in rows} == {"init", "noise", "chunk", "terminal"}
+    # every program kind this workload compiles is present, and only the
+    # round program holds the network: the hand-off of finished rows
+    # (stack, clip) is no evaluation
+    assert {r["kind"] for r in rows} == {"init", "noise", "chunk", "handoff"}
+    flops = {r["kind"]: r["flops_jaxpr"] for r in rows}
+    assert flops["handoff"] < 0.01 * flops["chunk"]
 
     # -- per-request rows reconcile with the histograms ---------------------
     recs = [json.loads(line) for line in
@@ -119,7 +124,8 @@ def test_traced_replay_registry_and_reconciliation(tiny_pipe, tmp_path):
     doc = json.load(open(tmp_path / "trace.json", encoding="utf-8"))
     names = {e.get("name") for e in doc["traceEvents"]}
     assert {"req.submit", "req.queue", "req.serve", "serve.round",
-            "serve.finalize"} <= names
+            "serve.handoff"} <= names
+    assert "serve.finalize" not in names
 
 
 # ---------------------------------------------------------------------------
@@ -219,7 +225,7 @@ class _FakeEngine:
         return self._rs(req=req, future=future, submit_t=submit_t,
                         admit_t=admit_t, group=self.group_key(req),
                         x=None, rng=None, state=None, pairs=None,
-                        terminal_t=0.0, cond=None, uncond=None)
+                        cond=None, uncond=None)
 
     def advance(self, rows, bucket, round_steps):
         finished = []

@@ -69,8 +69,9 @@ def test_poisson_workload_deterministic():
 
 class FakeEngine:
     """Deterministic jax-free engine: result rows are f(seed); advance
-    moves each row min(remaining, round_steps); per-call counters let
-    tests assert what compute was (not) spent."""
+    moves each row min(remaining, round_steps) of its `nfe + 1` turns
+    (its steps, then its terminal denoise); per-call counters let tests
+    assert what compute was (not) spent."""
 
     def __init__(self, step_delay_s: float = 0.0):
         self.prepared = []
@@ -86,7 +87,7 @@ class FakeEngine:
         st = RequestState(req=req, future=future, submit_t=submit_t,
                           admit_t=admit_t, group=self.group_key(req),
                           x=None, rng=None, state=None, pairs=None,
-                          terminal_t=0.0, cond=None, uncond=None)
+                          cond=None, uncond=None)
         self.prepared.append(req)
         return st
 
@@ -143,11 +144,12 @@ def test_scheduler_completes_all_and_routes_results():
 
 def test_heterogeneous_nfe_exits_early():
     """A short request grouped with a long one completes in fewer
-    rounds — continuous admission, not wait-for-longest."""
+    rounds — continuous admission, not wait-for-longest. (2 and 8
+    turns: a request's steps and its terminal denoise.)"""
     eng, sched = _fake_scheduler(round_steps=2)
-    short = sched.submit(SampleRequest(resolution=8, diffusion_steps=2,
+    short = sched.submit(SampleRequest(resolution=8, diffusion_steps=1,
                                        sampler="ddim", seed=1))
-    long = sched.submit(SampleRequest(resolution=8, diffusion_steps=8,
+    long = sched.submit(SampleRequest(resolution=8, diffusion_steps=7,
                                       sampler="ddim", seed=2))
     sched.start()
     r_short = short.result(timeout=10)
@@ -414,8 +416,8 @@ def test_dispatch_thread_is_never_more_than_one_round_ahead():
     lock."""
     tel = Telemetry(enabled=False)
     eng, sched = _gated_scheduler(tel)
-    fut = sched.submit(SampleRequest(resolution=8, diffusion_steps=6,
-                                     sampler="ddim", seed=4))
+    fut = sched.submit(SampleRequest(resolution=8, diffusion_steps=5,
+                                     sampler="ddim", seed=4))     # 6 turns
     sched.start()
     for released in range(5):
         _wait_for(lambda: len(eng.carries) == released + 2)
@@ -438,8 +440,8 @@ def test_rounds_overlapped_is_zero_when_the_device_keeps_up():
     waits in `serve.pace`."""
     tel = Telemetry(enabled=False)
     eng, sched = _fake_scheduler(tel, round_steps=1)
-    fut = sched.submit(SampleRequest(resolution=8, diffusion_steps=6,
-                                     sampler="ddim", seed=2))
+    fut = sched.submit(SampleRequest(resolution=8, diffusion_steps=5,
+                                     sampler="ddim", seed=2))     # 6 turns
     sched.start()
     fut.result(timeout=10)
     sched.close()
@@ -461,8 +463,8 @@ def test_pace_is_one_block_on_the_older_round(monkeypatch):
 
     monkeypatch.setattr(sched_mod, "_block_until_ready", recording)
     eng, sched = _gated_scheduler()
-    fut = sched.submit(SampleRequest(resolution=8, diffusion_steps=4,
-                                     sampler="ddim", seed=1))
+    fut = sched.submit(SampleRequest(resolution=8, diffusion_steps=3,
+                                     sampler="ddim", seed=1))     # 4 turns
     sched.start()
     for i in range(3):
         _wait_for(lambda: len(blocked) == i + 1)
@@ -481,10 +483,10 @@ def test_midflight_deadline_shed_at_round_boundary_under_run_ahead():
     before launching anything more for it."""
     tel = Telemetry(enabled=False)
     eng, sched = _gated_scheduler(tel, buckets=(1, 2))
-    doomed = sched.submit(SampleRequest(resolution=8, diffusion_steps=8,
+    doomed = sched.submit(SampleRequest(resolution=8, diffusion_steps=7,
                                         sampler="ddim", deadline_s=0.05))
-    ok = sched.submit(SampleRequest(resolution=8, diffusion_steps=4,
-                                    sampler="ddim", seed=9))
+    ok = sched.submit(SampleRequest(resolution=8, diffusion_steps=3,
+                                    sampler="ddim", seed=9))      # 4 turns
     sched.start()
     _wait_for(lambda: len(eng.carries) == 2)
     time.sleep(0.08)                      # the deadline passes in pace
@@ -683,14 +685,16 @@ def _plan_of(kind):
 
 
 def _advance_cut(engine, row, steps, round_steps=8):
-    """One solo round of exactly `steps` in the program compiled for
-    `round_steps`: a round ends where its first row ends, so the row is
-    made to look `steps` from its end for the length of the call."""
-    nfe, row.nfe = row.nfe, row.done + steps
-    try:
+    """One solo round of exactly `steps` turns in the program compiled
+    for `round_steps`: the rule that ends a round where its first row
+    ends is made to say `steps` for the length of the call (what a
+    round-mate `steps` turns from its end does to a round)."""
+    from unittest import mock
+
+    from flaxdiff_tpu.serving import engine as engine_mod
+    with mock.patch.object(engine_mod, "round_length",
+                           lambda rows, rs: (rs, steps)):
         engine.advance([row], 1, round_steps)
-    finally:
-        row.nfe = nfe
     return engine.last_round_info
 
 
@@ -702,8 +706,9 @@ def cut_engines():
 
 
 def _samples_by_cuts(engines, pipe, kind, cuts):
-    """A 20-step request's samples with its rounds cut as `cuts`, every
-    round through the ONE size-8 program of its kind."""
+    """A 20-step request's samples with its 21 turns (the last is the
+    terminal denoise) cut into rounds as `cuts`, every round through
+    the ONE size-8 program of its kind."""
     from flaxdiff_tpu.serving import ServingFuture
     from flaxdiff_tpu.serving.engine import SamplerProgramEngine
     engine = engines.get((id(pipe), kind))
@@ -719,29 +724,35 @@ def _samples_by_cuts(engines, pipe, kind, cuts):
         assert info["kind"] == kind and info["steps"] == c
     assert row.remaining == 0 and row.rounds == len(cuts)
     out, _ = engine.finalize([row], 1)
-    # every cut of every case went through one chunk program
+    # every cut of every case went through one chunk program, the only
+    # program of the engine that holds the network
     assert sum(k[0] == kind for k in engine._programs) == 1
+    assert {k[0] for k in engine._programs} \
+        == {"init", "noise", kind, "handoff"}
     return np.asarray(out[0])
 
 
-@pytest.mark.parametrize("cuts", [(5, 5, 5, 5), (1,) * 20, (3, 8, 2, 7)],
-                         ids=["5x4", "1x20", "3+8+2+7"])
+@pytest.mark.parametrize(
+    "cuts", [(5, 5, 5, 5, 1), (1,) * 21, (3, 8, 2, 8), (7, 7, 7)],
+    ids=["5x4+terminal-alone", "1x21", "3+8+2+8", "7x3"])
 @pytest.mark.parametrize("kind", ["chunk", "chunk_cached", "chunk_spatial"])
 def test_samples_do_not_depend_on_where_rounds_are_cut(
         deep_pipe, cut_engines, kind, cuts):
-    """A request's samples are equal to the last bit whether its 20
-    steps run as 8+8+4 (what it gets alone), 5+5+5+5, 20 x 1 or an
-    uneven cut: its steps, its RNG lineage (one split a step that runs,
-    none for a step that does not) and its cache schedule are its own."""
-    assert sum(cuts) == 20
-    base = _samples_by_cuts(cut_engines, deep_pipe, kind, (8, 8, 4))
+    """A request's samples are equal to the last bit whether its 21
+    turns run as 8+8+5 (what it gets alone), 5+5+5+5 and the terminal
+    denoise in a round of its own (the cut between the last step and
+    the terminal turn), 21 x 1 or an uneven cut: its steps, its RNG
+    lineage (one split a turn that runs, none for a turn that does not),
+    its cache schedule and its terminal value are its own."""
+    assert sum(cuts) == 21
+    base = _samples_by_cuts(cut_engines, deep_pipe, kind, (8, 8, 5))
     np.testing.assert_array_equal(
         _samples_by_cuts(cut_engines, deep_pipe, kind, cuts), base)
     assert (np.abs(base) < 1.0).mean() > 0.5        # not saturated
 
 
-@pytest.mark.parametrize("cuts", [(8, 8, 4), (5, 5, 5, 5), (1,) * 20],
-                         ids=["8+8+4", "5x4", "1x20"])
+@pytest.mark.parametrize("cuts", [(8, 8, 5), (5, 5, 5, 5, 1), (1,) * 21],
+                         ids=["8+8+5", "5x4+terminal-alone", "1x21"])
 def test_cut_rounds_equal_the_solo_scan(tiny_pipe, cut_engines, cuts):
     """The anchor of the cut cases, on the model whose batched-equals-
     solo bar holds to the bit (ROADMAP D9): however the chunk program's
@@ -756,8 +767,9 @@ def test_cut_rounds_equal_the_solo_scan(tiny_pipe, cut_engines, cuts):
 
 def _rounds_by_rule(nfes, cap, round_steps):
     """What `round_length` and FIFO admission give `nfes` submitted
-    together: ({request: its rounds}, [every round's length])."""
-    queue, active = list(enumerate(nfes)), []
+    together, each a trajectory of `nfe + 1` turns: ({request: its
+    rounds}, [every round's length])."""
+    queue, active = [(i, n + 1) for i, n in enumerate(nfes)], []
     rounds, lengths = {i: 0 for i in range(len(nfes))}, []
     while queue or active:
         while queue and len(active) < cap:
@@ -779,10 +791,12 @@ def _rounds_by_rule(nfes, cap, round_steps):
 def test_no_row_step_is_dead_and_rounds_follow_the_rule(
         tiny_pipe, nfes, buckets, round_steps):
     """Mixed step counts over fewer rows than requests (the queue is
-    never empty until the tail): every row is live on every step its
+    never empty until the tail): every row is live on every turn its
     rounds run (`serving/row_steps_run == serving/row_steps_live`), a
     request's `rounds` and the count of rounds are what the rule gives,
-    and every request ran its own NFE, no more."""
+    and every request ran its own `nfe + 1` turns, no more: its steps
+    and ONE terminal denoise, which rode a round
+    (`serving/terminal_turns`)."""
     tel = Telemetry(enabled=False)
     sched = ServingScheduler(
         pipeline=tiny_pipe, telemetry=tel, autostart=False,
@@ -798,7 +812,8 @@ def test_no_row_step_is_dead_and_rounds_follow_the_rule(
     snap = tel.registry.snapshot()
     assert snap["serving/rounds"] == len(lengths)
     assert snap["serving/row_steps_run"] == snap["serving/row_steps_live"] \
-        == sum(nfes)
+        == sum(nfes) + len(nfes)
+    assert snap["serving/terminal_turns"] == len(nfes)
     assert max(lengths) <= round_steps and min(lengths) >= 1
     assert len(set(lengths)) > 1            # the rule did cut rounds short
 
@@ -843,9 +858,10 @@ def test_round_lengths_share_one_program_and_one_compilation(
 def test_run_to_completion_is_one_round_of_the_exact_longest_length(
         tiny_pipe, nfes):
     """`round_steps=0`: every row finishes in ONE round, which runs the
-    longest row's steps exactly (in the program of their power-of-two
-    bucket); shorter rows keep their carry past their own end, and the
-    samples are the solo scan's to the last bit."""
+    longest row's turns exactly (in the program of their power-of-two
+    bucket); shorter rows take their terminal turn where their own
+    trajectory ends and keep their carry past it, and the samples are
+    the solo scan's to the last bit."""
     tel = Telemetry(enabled=False)
     sched = ServingScheduler(
         pipeline=tiny_pipe, telemetry=tel, autostart=False,
@@ -858,12 +874,14 @@ def test_run_to_completion_is_one_round_of_the_exact_longest_length(
     assert [o.rounds for o in outs] == [1] * len(nfes)
     snap = tel.registry.snapshot()
     assert snap["serving/rounds"] == 1
-    assert snap["serving/row_steps_run"] == len(nfes) * max(nfes)
-    assert snap["serving/row_steps_live"] == sum(nfes)
+    assert snap["serving/row_steps_run"] == len(nfes) * (max(nfes) + 1)
+    assert snap["serving/row_steps_live"] == sum(nfes) + len(nfes)
+    assert snap["serving/terminal_turns"] == len(nfes)
     info = sched.engine.last_round_info
-    assert info["steps"] == max(nfes) and info["n_act"] == list(nfes)
+    assert info["steps"] == max(nfes) + 1
+    assert info["n_act"] == [n + 1 for n in nfes]
     chunk_keys = [k for k in sched.engine._programs if k[0] == "chunk"]
-    assert [k[2] for k in chunk_keys] == [nfe_bucket(max(nfes))]
+    assert [k[2] for k in chunk_keys] == [nfe_bucket(max(nfes) + 1)]
     for i, (n, o) in enumerate(zip(nfes, outs)):
         solo = tiny_pipe.generate_samples(
             num_samples=1, resolution=8, channels=1, diffusion_steps=n,
@@ -909,7 +927,7 @@ def _drive(engine, rows, buckets, round_steps):
 
 def _warm_walk(engine, buckets, round_steps, nfes):
     """Every shape traffic can meet, as the benchmark's `warm_engine`
-    walks them: a round and a terminal for every bucket and every count
+    walks them: a round and a hand-off for every bucket and every count
     of rows that finish together, and one request of every NFE."""
     from flaxdiff_tpu.serving import ServingFuture
 
@@ -1013,8 +1031,9 @@ def test_a_warm_turn_is_a_handful_of_counted_launches(tiny_pipe,
     """One warm loop turn with 8 rows, 2 of them just admitted and 2
     finishing: every program dispatched is one of the engine's own,
     through its counting helper — an init and a noise program for each
-    admitted request, 1 round, 1 terminal — and no eager one-operation
-    program beside them."""
+    admitted request, 1 round (the two rows' terminal denoises are turns
+    of it), 1 hand-off — and no eager one-operation program beside
+    them."""
     from flaxdiff_tpu.serving import ServingFuture
     from flaxdiff_tpu.serving.engine import SamplerProgramEngine
     tel = Telemetry(enabled=False)
@@ -1026,7 +1045,7 @@ def test_a_warm_turn_is_a_handful_of_counted_launches(tiny_pipe,
                               0.0, 0.0)
 
     _drive(engine, [admit(2, s) for s in range(2)], buckets, rs)  # b2
-    rows = [admit(4, 10 + i) for i in range(2)] \
+    rows = [admit(3, 10 + i) for i in range(2)] \
         + [admit(8, 20 + i) for i in range(4)]
     rows += [admit(6, 30), admit(6, 31)]
     finished, _ = engine.advance(rows, 8, rs)       # warms bucket 8
@@ -1040,9 +1059,9 @@ def test_a_warm_turn_is_a_handful_of_counted_launches(tiny_pipe,
     out, _ = engine.finalize(finished, bucket_up(len(finished), buckets))
     turn = dispatches[n0:]
     assert len(finished) == 2 and compile_s == 0.0
-    assert sorted(turn) == ["sampler_chunk", "sampler_init",
-                            "sampler_init", "sampler_noise",
-                            "sampler_noise", "sampler_terminal"]
+    assert sorted(turn) == ["sampler_chunk", "sampler_handoff",
+                            "sampler_init", "sampler_init",
+                            "sampler_noise", "sampler_noise"]
     assert launches.value - l0 == len(turn) <= 16
     assert out.shape[0] == 2
 
@@ -1071,16 +1090,17 @@ def test_second_prepare_of_a_seen_nfe_computes_and_reads_nothing(
     engine = SamplerProgramEngine(tiny_pipe,
                                   telemetry=Telemetry(enabled=False))
     first = engine.prepare(_tiny_request(5, 1), ServingFuture(), 0.0, 0.0)
-    assert len(spacings) == 1 and len(gets) == 2
+    assert len(spacings) == 1 and len(gets) == 1
+    # 5 steps and the terminal turn, whose pair is (t_term, t_term)
     assert isinstance(first.pairs, np.ndarray) \
-        and first.pairs.shape == (5, 2) \
-        and isinstance(first.terminal_t, float)
+        and first.pairs.shape == (6, 2)
+    assert first.pairs[-1, 0] == first.pairs[-1, 1] == first.pairs[-2, 1]
 
     monkeypatch.setattr(ArrayImpl, "_value", property(
         lambda self: (reads.append(1), real_value.fget(self))[1]))
     again = engine.prepare(_tiny_request(5, 2), ServingFuture(), 0.0, 0.0)
     monkeypatch.setattr(ArrayImpl, "_value", real_value)
-    assert len(spacings) == 1 and len(gets) == 2 and reads == []
+    assert len(spacings) == 1 and len(gets) == 1 and reads == []
     assert again.pairs is first.pairs
     # and the carry is the solo path's, to the bit: its keys, its noise
     from flaxdiff_tpu.utils import RngSeq

@@ -233,7 +233,7 @@ def serving_capture(tiny_pipe, tmp_path_factory):
 
 SERVE_DISPATCH = ("serve.wait", "serve.admit", "serve.round",
                   "serve.stack", "serve.launch", "serve.unstack",
-                  "serve.finalize", "serve.backpressure")
+                  "serve.handoff", "serve.backpressure")
 SERVE_COMPLETE = ("serve.fetch", "serve.resolve")
 
 
@@ -258,12 +258,12 @@ def test_serving_spans_nest_as_stated(serving_capture):
             assert got is None, (s["name"], got and got["name"])
         else:
             assert got is not None and got["name"] in want, s["name"]
-    # a round is stack, launch, unstack in that order; a finalize is
+    # a round is stack, launch, unstack in that order; a hand-off is
     # stack then launch
     for r in _named(spans, "serve.round"):
         kids = [s["name"] for s in spans if _parent(spans, s) is r]
         assert kids == ["serve.stack", "serve.launch", "serve.unstack"]
-    for f in _named(spans, "serve.finalize"):
+    for f in _named(spans, "serve.handoff"):
         kids = [s["name"] for s in spans if _parent(spans, s) is f]
         assert kids == ["serve.stack", "serve.launch"]
 
@@ -278,14 +278,20 @@ def test_serving_spans_carry_their_attributes(serving_capture):
         assert r["stats"]["bucket"] == 2
         assert 1 <= r["stats"]["rows"] <= 2
     # `steps` is the round's own length, not the compiled size: a round
-    # ends where its first row ends (the 3-step request's last is 1)
+    # ends where its first row ends (the 4-step request's last is 1)
     assert {r["stats"]["steps"] for r in rounds} == {1, 2}
+    # rows x steps over the rounds is every turn the requests asked
+    # for, their terminal denoises among them: nfe + 1 each
+    assert sum(r["stats"]["rows"] * r["stats"]["steps"] for r in rounds) \
+        == sum(q.diffusion_steps + 1 for q in _requests())
     kinds = {s["stats"]["kind"] for s in _named(spans, "serve.launch")}
-    assert kinds == {"chunk", "terminal"}
-    for name in ("serve.finalize", "serve.fetch", "serve.resolve"):
+    assert kinds == {"chunk", "handoff"}
+    for name in ("serve.handoff", "serve.fetch", "serve.resolve"):
         assert all(s["stats"]["rows"] >= 1 for s in _named(spans, name))
     assert all(s["stats"]["bucket"] == 2
-               for s in _named(spans, "serve.finalize"))
+               for s in _named(spans, "serve.handoff"))
+    # the span that timed the terminal program went with the program
+    assert not _named(spans, "serve.finalize")
     # every request's result came back, three in all
     assert sum(s["stats"]["rows"]
                for s in _named(spans, "serve.resolve")) == 3
@@ -302,9 +308,9 @@ def test_round_is_the_join_key_and_each_sink_holds_it_once(
     assert len(mine) == n
     assert sorted(e["args"]["round"] for e in mine) \
         == list(range(1, n + 1))
-    fin = [e for e in events if e.get("name") == "serve.finalize"]
+    fin = [e for e in events if e.get("name") == "serve.handoff"]
     assert len(fin) == len(_named(serving_capture["spans"],
-                                  "serve.finalize"))
+                                  "serve.handoff"))
     traces = [r for r in serving_capture["rows"]
               if r.get("type") == "request_trace"]
     assert len(traces) == 3
@@ -317,7 +323,7 @@ def test_round_is_the_join_key_and_each_sink_holds_it_once(
 def test_serving_programs_carry_stable_names(serving_capture):
     assert serving_capture["programs"] == {
         "sampler_init", "sampler_noise", "sampler_chunk",
-        "sampler_terminal"}
+        "sampler_handoff"}
 
 
 # -- fit ----------------------------------------------------------------------
@@ -478,8 +484,17 @@ def test_every_pallas_call_carries_a_distinct_name():
             "fdt_flash_bwd_dkv"} <= set(names)
 
 
-def test_every_sampler_program_is_jitted_under_its_own_name():
-    path = os.path.join(PKG, "samplers", "common.py")
+@pytest.mark.parametrize("path,want", [
+    (("samplers", "common.py"),
+     ["sampler_chunk", "sampler_chunk_cached", "sampler_chunk_spatial",
+      "sampler_init", "sampler_model", "sampler_noise", "sampler_scan"]),
+    # the engine's own: the round wrapper takes its chunk program's
+    # name (`run`), the hand-off keeps the `sampler_` prefix so that
+    # `sampler.step_device_ms` sees every program of a turn
+    (("serving", "engine.py"), ["run", "sampler_handoff"]),
+], ids=["samplers", "engine"])
+def test_every_sampler_program_is_jitted_under_its_own_name(path, want):
+    path = os.path.join(PKG, *path)
     tree = ast.parse(open(path).read())
     defs = {n.name for n in ast.walk(tree)
             if isinstance(n, ast.FunctionDef)}
@@ -491,10 +506,7 @@ def test_every_sampler_program_is_jitted_under_its_own_name():
             arg = node.args[0]
             assert isinstance(arg, ast.Name), node.lineno
             jitted.append(arg.id)
-    assert sorted(jitted) == ["sampler_chunk", "sampler_chunk_cached",
-                              "sampler_chunk_spatial", "sampler_init",
-                              "sampler_model", "sampler_noise",
-                              "sampler_scan", "sampler_terminal"]
+    assert sorted(jitted) == want
     assert set(jitted) <= defs
 
 
