@@ -85,14 +85,32 @@ class SampleRequest:
 
 @dataclasses.dataclass
 class SampleResult:
-    """Samples plus the request's latency decomposition (milliseconds).
+    """Samples plus where the request's latency went (milliseconds).
 
-    queue_ms   submit -> first dispatch
-    compile_ms program trace+compile stalls in rounds this request
-               rode (0 on a warm program cache)
-    device_ms  residual: latency - queue - compile — dispatch plus
-               device execution of every round to result readiness
-    latency_ms submit -> samples ready on host
+    Every boundary is an instant of the HOST's clock, taken by the
+    scheduler's threads; none is read from the device. The dispatch
+    thread runs one round ahead of the device (serving/scheduler.py),
+    so a row's first turn starts on the device up to one round after
+    `queue_ms` ends, and its last round is still running there when
+    `service_ms` ends.
+
+    queue_ms   submit -> first dispatch (after a requeue: of the attempt
+               that delivered), less what its admission compiled
+    compile_ms program trace+compile stalls at its admission and in
+               rounds this request rode (0 on a warm program cache)
+    service_ms first dispatch -> the batch that holds its samples is
+               handed to the completion thread: the rounds it rode, as
+               the host paced them, less their compile stalls
+    tail_ms    that hand-off -> samples on the host: what was launched
+               ahead finishing on the device, the wait for the
+               completion thread, its `_block_until_ready` and
+               `_device_get`
+    device_ms  `service_ms + tail_ms`: everything after the first
+               dispatch that is not compiling. NOT a device's time: it
+               holds the dispatch thread's work and both waits above
+    latency_ms submit -> samples ready on host, formed as
+               `queue_ms + compile_ms + device_ms` (so the parts add up
+               to it to the last bit)
     rounds     scheduler rounds the request participated in
     attempts   failed dispatch attempts that were retried before this
                result (0 on the healthy path) — each retry replayed
@@ -100,6 +118,10 @@ class SampleResult:
     degraded   brownout flags ("nfe_capped", "plan_forced", ...) when
                admission degraded the request instead of shedding it
                (docs/SERVING.md "Failure semantics"); empty otherwise
+
+    A front door re-cuts `queue_ms`, `device_ms` and `latency_ms` on its
+    own clock (serving/frontdoor.py); `service_ms` and `tail_ms` stay
+    the replica's.
     """
     samples: np.ndarray
     request: SampleRequest
@@ -110,10 +132,13 @@ class SampleResult:
     rounds: int = 0
     attempts: int = 0
     degraded: tuple = ()
+    service_ms: float = 0.0
+    tail_ms: float = 0.0
 
     def timings(self) -> Dict[str, float]:
         return {"queue_ms": self.queue_ms, "compile_ms": self.compile_ms,
-                "device_ms": self.device_ms, "latency_ms": self.latency_ms}
+                "device_ms": self.device_ms, "latency_ms": self.latency_ms,
+                "service_ms": self.service_ms, "tail_ms": self.tail_ms}
 
 
 class ServingFuture:

@@ -41,6 +41,15 @@ Architecture (docs/SERVING.md):
   rounds early; bounded, they are decided at most one round before
   their round runs. `serving/rounds_overlapped` counts the rounds
   launched while the round before was still running (`_is_ready`).
+- The dispatch thread **keeps its own time account** (`_DispatchAccount`):
+  every turn of its loop is booked whole to `serving/dispatch_loop_ms`
+  and split into what it waited (`dispatch_pace_ms` for the device,
+  `dispatch_wait_ms` for work, `dispatch_backpressure_ms` for the
+  completion thread, timed at the call sites of the spans of those
+  names) and `dispatch_work_ms`, the rest; the five are written
+  together, at a turn's top. Counters of the hub, so they count with
+  no profiler and no recorder; `work / loop` near 1 less the wait share
+  means the host sets the pace and the device idles.
 - **close(drain=True)** stops admission, finishes queued + active
   work, and joins both threads.
 
@@ -57,6 +66,7 @@ even if a scheduler thread dies (chaos-tested).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -114,6 +124,52 @@ def _device_get(x):
 
 def _now() -> float:
     return time.perf_counter()
+
+
+class _DispatchAccount:
+    """Where the dispatch thread's wall time went, in milliseconds, as
+    counters of the hub (dispatch thread only; no device read).
+
+    `turn()` at the top of every loop turn books the turn that just
+    ended, top to top: its wall whole into `serving/dispatch_loop_ms`,
+    what it spent inside `waiting(...)` to that wait's counter, and the
+    rest of its wall, never a stopwatch of its own, to
+    `serving/dispatch_work_ms`. The five add up by construction, and
+    they move together (all at the turn's top), so a reader between two
+    turns sees whole turns in every one of them."""
+
+    def __init__(self, telemetry):
+        self._loop = telemetry.counter("serving/dispatch_loop_ms")
+        self._work = telemetry.counter("serving/dispatch_work_ms")
+        self._waits = {
+            "pace": telemetry.counter("serving/dispatch_pace_ms"),
+            "wait": telemetry.counter("serving/dispatch_wait_ms"),
+            "backpressure": telemetry.counter(
+                "serving/dispatch_backpressure_ms")}
+        self._top: Optional[float] = None
+        self._waited = dict.fromkeys(self._waits, 0.0)
+
+    def turn(self) -> None:
+        now = _now()
+        if self._top is not None:
+            wall = (now - self._top) * 1e3
+            work = wall
+            for wait, ms in self._waited.items():
+                if ms:
+                    self._waits[wait].inc(ms)
+                    self._waited[wait] = 0.0
+                    work -= ms
+            self._work.inc(max(0.0, work))
+            self._loop.inc(wall)
+        self._top = now
+
+    @contextlib.contextmanager
+    def waiting(self, wait: str):
+        t0 = _now()
+        try:
+            yield
+        finally:
+            self._waited[wait] += (_now() - t0) * 1e3
 
 
 @dataclasses.dataclass
@@ -221,12 +277,14 @@ class ServingScheduler:
         self._cv = threading.Condition(self._lock)
         self._queue: Deque[_Pending] = deque()
         self._active: Dict[tuple, List[RequestState]] = {}
+        # (rows, their samples on the device, the hand-off instant)
         self._completions: Deque[Tuple[List[RequestState], object, float]] \
             = deque()
         self._last_served: Dict[tuple, int] = {}
         # a carry out of each launched round, oldest first, until it is
         # seen ready: what `serve.pace` waits on (dispatch thread only)
         self._unfinished: Deque[Any] = deque()
+        self._account = _DispatchAccount(telemetry)
         self._round_no = 0
         self._closed = False
         self._draining = False
@@ -779,7 +837,8 @@ class ServingScheduler:
             while pending and _is_ready(pending[0]):
                 pending.popleft()
             if len(pending) >= _ROUNDS_AHEAD:
-                with self.telemetry.span("serve.pace", cat="serving"):
+                with self.telemetry.span("serve.pace", cat="serving"), \
+                        self._account.waiting("pace"):
                     _block_until_ready(pending[0])
                 pending.popleft()
         except (KeyboardInterrupt, SystemExit):
@@ -791,17 +850,19 @@ class ServingScheduler:
     def _dispatch_rounds(self) -> None:
         tel = self.telemetry
         cfg = self.config
+        account = self._account
 
         def span(name, **args):
             return tel.span(name, cat="serving", args=args)
 
         while True:
+            account.turn()
             # before the lock and before admission: what is admitted,
             # shed or degraded is decided as late as the bound allows
             self._pace()
             with self._cv:
                 if not (self._queue or self._active or self._closed):
-                    with span("serve.wait"):
+                    with span("serve.wait"), account.waiting("wait"):
                         while not (self._queue or self._active
                                    or self._closed):
                             self._cv.wait()
@@ -819,7 +880,7 @@ class ServingScheduler:
                 if not rows:
                     # nothing to dispatch yet: wait for the earliest
                     # retry (or the completion thread's requeue)
-                    with span("serve.wait"):
+                    with span("serve.wait"), account.waiting("wait"):
                         self._cv.wait(0.02)
                     continue
 
@@ -838,7 +899,8 @@ class ServingScheduler:
                 if r.first_dispatch_t is None:
                     # what its admission compiled (`prepare`, on a cold
                     # cache) is compile time and not queue time: counted
-                    # once, so queue + compile + device is the latency
+                    # once, so queue + compile + service + tail is the
+                    # latency
                     r.first_dispatch_t = t_disp - r.compile_ms / 1e3
 
             try:
@@ -881,12 +943,14 @@ class ServingScheduler:
                     # than max_inflight completed batches ahead of the
                     # completion thread's host sync
                     if len(self._completions) > cfg.max_inflight:
-                        with span("serve.backpressure"):
+                        with span("serve.backpressure"), \
+                                account.waiting("backpressure"):
                             while len(self._completions) \
                                     > cfg.max_inflight:
                                 tel.counter(
                                     "serving/backpressure_waits").inc()
                                 self._cv.wait()
+        account.turn()      # the turn that broke out is a turn too
         # non-draining close: rows popped mid-round missed close()'s
         # cancel sweep — resolve their futures before exiting
         with self._cv:
@@ -927,7 +991,7 @@ class ServingScheduler:
                     self._cv.wait()
                 if not self._completions and self._dispatch_done:
                     break
-                rows, out, _t_disp = self._completions.popleft()
+                rows, out, t_handoff = self._completions.popleft()
                 self._processing = True
                 self._cv.notify_all()     # free a backpressure slot
             try:
@@ -973,25 +1037,44 @@ class ServingScheduler:
             t_ready = _now()
             with tel.span("serve.resolve", cat="serving",
                           args={"rows": len(rows)}):
-                self._resolve(rows, host, t_ready, hist)
+                self._resolve(rows, host, t_handoff, t_ready, hist)
             with self._cv:
                 self._processing = False
                 self._cv.notify_all()
 
-    def _resolve(self, rows: List[RequestState], host, t_ready: float,
-                 hist) -> None:
+    def _resolve(self, rows: List[RequestState], host, t_handoff: float,
+                 t_ready: float, hist) -> None:
         """Per-row SLO histograms, trace row and `set_result` of one
-        fetched batch (completion thread; the span `serve.resolve`)."""
+        fetched batch (completion thread; the span `serve.resolve`).
+
+        A request's latency is cut at HOST instants (serving/request.py
+        `SampleResult`): submit, its first dispatch (less what its
+        admission compiled), the hand-off of the batch that holds its
+        samples (`t_handoff`, taken by the dispatch thread) and the
+        samples on the host (`t_ready`). The dispatch thread runs a
+        round ahead of the device, so the device takes up a row's first
+        turn up to one round after `queue_ms` ends, and is still
+        running its last round when `service_ms` ends: `tail_ms` holds
+        that. `latency_ms` is the sum the four are formed into, which
+        is `t_ready - submit` but for the rounding of the additions."""
         tel = self.telemetry
+        tail_ms = (t_ready - t_handoff) * 1e3
         for i, r in enumerate(rows):
-            latency_ms = (t_ready - r.submit_t) * 1e3
-            queue_ms = ((r.first_dispatch_t or r.submit_t)
-                        - r.submit_t) * 1e3
-            device_ms = max(0.0, latency_ms - queue_ms - r.compile_ms)
+            first_t = r.first_dispatch_t or r.submit_t
+            queue_ms = (first_t - r.submit_t) * 1e3
+            # `first_t` stands before the dispatch by what the
+            # admission compiled, and the stalls of later rounds are
+            # inside the rounds it rode: all of `compile_ms` comes out
+            service_ms = max(0.0, (t_handoff - first_t) * 1e3
+                             - r.compile_ms)
+            device_ms = service_ms + tail_ms
+            latency_ms = queue_ms + r.compile_ms + device_ms
             hist("serving/latency_ms").observe(latency_ms)
             hist("serving/queue_ms").observe(queue_ms)
             hist("serving/compile_ms").observe(r.compile_ms)
             hist("serving/device_ms").observe(device_ms)
+            hist("serving/service_ms").observe(service_ms)
+            hist("serving/tail_ms").observe(tail_ms)
             tel.counter("serving/requests_ok").inc()
             # the trace row carries the SAME decomposition the
             # histograms above observed — per-request span sums
@@ -1002,4 +1085,5 @@ class ServingScheduler:
                 samples=host[i], request=r.req, queue_ms=queue_ms,
                 compile_ms=r.compile_ms, device_ms=device_ms,
                 latency_ms=latency_ms, rounds=r.rounds,
-                attempts=r.attempts, degraded=r.degraded))
+                attempts=r.attempts, degraded=r.degraded,
+                service_ms=service_ms, tail_ms=tail_ms))

@@ -31,7 +31,8 @@ Output, per traced request:
 - One `request_trace` JSONL row in `telemetry.jsonl` with the same
   latency decomposition the result future carries — the row's
   `queue_ms + compile_ms + device_ms == latency_ms` identity is exact
-  by construction (all four derive from the same three timestamps), so
+  by construction (`scheduler._resolve` forms the latency as that sum,
+  `device_ms` as the result's `service_ms + tail_ms`), so
   per-request rows reconcile with the aggregate histograms to within
   timer resolution (tested).
 
