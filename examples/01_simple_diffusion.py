@@ -25,8 +25,10 @@ def main(argv=None):
     ap.add_argument("--out", default=None, help="PNG path for the grid")
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args(argv)
+    depths = (16, 32)
     if args.smoke:
-        args.steps, args.batch, args.sample_steps = 30, 8, 5
+        args.steps, args.batch, args.sample_steps = 6, 8, 2
+        depths = (16,)          # one level: less to compile
 
     import jax.numpy as jnp
     import numpy as np
@@ -48,7 +50,7 @@ def main(argv=None):
 
     # 2. model: a small UNet, no attention at this resolution
     model = Unet(output_channels=3, emb_features=64,
-                 feature_depths=(16, 32), attention_configs=None,
+                 feature_depths=depths, attention_configs=None,
                  num_res_blocks=1)
 
     def apply_fn(params, x, t, cond):

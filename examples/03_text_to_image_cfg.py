@@ -27,8 +27,10 @@ def main(argv=None):
     ap.add_argument("--sample_steps", type=int, default=50)
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args(argv)
+    depths = (16, 32)
     if args.smoke:
-        args.steps, args.batch, args.sample_steps = 30, 8, 5
+        args.steps, args.batch, args.sample_steps = 6, 8, 2
+        depths = (16,)          # one level: less to compile
 
     import jax.numpy as jnp
     import numpy as np
@@ -68,8 +70,9 @@ def main(argv=None):
     # model: cross-attention on the deepest level reads the text tokens
     attn = {"heads": 2, "dim_head": 16, "backend": "auto"}
     model = Unet(output_channels=3, emb_features=64,
-                 feature_depths=(16, 32),
-                 attention_configs=(None, attn), num_res_blocks=1)
+                 feature_depths=depths,
+                 attention_configs=(None,) * (len(depths) - 1) + (attn,),
+                 num_res_blocks=1)
 
     def apply_fn(params, x, t, cond):
         text = cond["text"] if cond is not None else None

@@ -26,8 +26,10 @@ def main(argv=None):
     ap.add_argument("--image_size", type=int, default=16)
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args(argv)
+    depths = (32, 64)
     if args.smoke:
-        args.vae_steps, args.steps, args.batch = 40, 25, 8
+        args.vae_steps, args.steps, args.batch = 8, 6, 8
+        depths = (32,)          # one level: less to compile
 
     import jax
     import jax.numpy as jnp
@@ -74,7 +76,7 @@ def main(argv=None):
     # encodes batches INSIDE the jitted step
     lat_res = args.image_size // vae.downscale_factor
     model = Unet(output_channels=vae.latent_channels, emb_features=64,
-                 feature_depths=(32, 64), attention_configs=None,
+                 feature_depths=depths, attention_configs=None,
                  num_res_blocks=1)
 
     def apply_fn(params, x, t, cond):

@@ -56,7 +56,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args(argv)
     if args.smoke:
-        args.steps, args.image_size = 10, 16
+        args.steps, args.image_size, args.num_frames = 4, 16, 2
 
     import jax.numpy as jnp
     import numpy as np

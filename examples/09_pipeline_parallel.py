@@ -40,7 +40,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args(argv)
     if args.smoke:
-        args.steps = 4
+        args.steps, args.pipe, args.microbatches = 4, 2, 2
 
     import jax
     import jax.numpy as jnp

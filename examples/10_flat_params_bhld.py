@@ -43,8 +43,10 @@ def main(argv=None):
     ap.add_argument("--image_size", type=int, default=16)
     ap.add_argument("--smoke", action="store_true")
     args = ap.parse_args(argv)
+    depths = (16, 32)
     if args.smoke:
         args.steps = 4
+        depths = (16,)          # one level: less to compile
 
     import jax
     import jax.numpy as jnp
@@ -62,8 +64,8 @@ def main(argv=None):
     size, ctx_len, ctx_dim = args.image_size, 8, 16
     attn = {"heads": 2, "dim_head": 8, "backend": "auto", "bhld": True}
     model = Unet(output_channels=3, emb_features=32,
-                 feature_depths=(16, 32),
-                 attention_configs=(None, dict(attn)),
+                 feature_depths=depths,
+                 attention_configs=(None,) * (len(depths) - 1) + (dict(attn),),
                  num_res_blocks=1, norm_groups=8)
 
     def apply_fn(params, x, t, cond):

@@ -121,7 +121,8 @@ def main():
         print("RESULT " + json.dumps({"proc": proc_id, "phase": phase,
                                       **result}), flush=True)
         return
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=4")
     import jax
     jax.config.update("jax_platforms", "cpu")
     # cross-process CPU collectives need an explicit implementation on

@@ -21,8 +21,8 @@ def _mk(bhld, heads=2, dim_head=8):
 
 def test_param_trees_are_layout_independent():
     x = jnp.ones((2, 16, 12))
-    p_ref = _mk(False).init(jax.random.PRNGKey(0), x)["params"]
-    p_bh = _mk(True).init(jax.random.PRNGKey(0), x)["params"]
+    p_ref = jax.jit(_mk(False).init)(jax.random.PRNGKey(0), x)["params"]
+    p_bh = jax.jit(_mk(True).init)(jax.random.PRNGKey(0), x)["params"]
     flat_ref = jax.tree_util.tree_leaves_with_path(p_ref)
     flat_bh = jax.tree_util.tree_leaves_with_path(p_bh)
     assert [(jax.tree_util.keystr(p), l.shape) for p, l in flat_ref] == \
@@ -37,9 +37,10 @@ def test_same_params_same_function(cross):
     x = jnp.asarray(rng.normal(size=(2, 4, 4, 12)), jnp.float32)
     ctx = (jnp.asarray(rng.normal(size=(2, 7, 12)), jnp.float32)
            if cross else None)
-    params = _mk(False).init(jax.random.PRNGKey(1), x, ctx)["params"]
-    out_ref = _mk(False).apply({"params": params}, x, ctx)
-    out_bh = _mk(True).apply({"params": params}, x, ctx)
+    params = jax.jit(_mk(False).init)(
+        jax.random.PRNGKey(1), x, ctx)["params"]
+    out_ref = jax.jit(_mk(False).apply)({"params": params}, x, ctx)
+    out_bh = jax.jit(_mk(True).apply)({"params": params}, x, ctx)
     np.testing.assert_allclose(np.asarray(out_ref), np.asarray(out_bh),
                                rtol=2e-5, atol=2e-6)
 
@@ -47,13 +48,14 @@ def test_same_params_same_function(cross):
 def test_same_params_same_gradients():
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.normal(size=(2, 16, 12)), jnp.float32)
-    params = _mk(False).init(jax.random.PRNGKey(2), x)["params"]
+    params = jax.jit(_mk(False).init)(jax.random.PRNGKey(2), x)["params"]
 
     def loss(p, bhld):
         return jnp.sum(_mk(bhld).apply({"params": p}, x) ** 2)
 
-    g_ref = jax.grad(loss)(params, False)
-    g_bh = jax.grad(loss)(params, True)
+    grad = jax.jit(jax.grad(loss), static_argnums=1)
+    g_ref = grad(params, False)
+    g_bh = grad(params, True)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5),
@@ -78,19 +80,20 @@ def test_flash_bh_interpret_parity():
             return fa.flash_attention_bh(q, k, v, None, None, None,
                                          True).sum()
 
-        out = fa.flash_attention_bh(q, k, v, None, None, None, True)
+        out = jax.jit(lambda *a: fa.flash_attention_bh(
+            *a, None, None, None, True))(q, k, v)
         ref = jax.nn.softmax(
             (q @ k.transpose(0, 2, 1)) / d ** 0.5, axis=-1) @ v
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
 
-        g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
         def oracle(q, k, v):
             return jnp.sum(jax.nn.softmax(
                 (q @ k.transpose(0, 2, 1)) / d ** 0.5, axis=-1) @ v)
 
-        g_ref = jax.grad(oracle, argnums=(0, 1, 2))(q, k, v)
+        g_ref = jax.jit(jax.grad(oracle, argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(g, g_ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-4)
@@ -102,7 +105,7 @@ def test_bhld_env_toggle(monkeypatch):
     """bhld=None reads FLAXDIFF_ATTN_BHLD (the A/B knob)."""
     x = jnp.ones((1, 16, 8))
     layer = AttentionLayer(heads=2, dim_head=4, backend="xla")
-    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)["params"]
     out_off = layer.apply({"params": params}, x)
     monkeypatch.setenv("FLAXDIFF_ATTN_BHLD", "1")
     out_on = layer.apply({"params": params}, x)
@@ -123,17 +126,19 @@ def test_rope_attention_layouts_agree(cross):
            if cross else None)
     mk = lambda bhld: RoPEAttention(heads=2, dim_head=8, backend="xla",
                                     bhld=bhld)
-    params = mk(False).init(jax.random.PRNGKey(0), x, ctx)["params"]
-    out_ref = mk(False).apply({"params": params}, x, ctx)
-    out_bh = mk(True).apply({"params": params}, x, ctx)
+    params = jax.jit(mk(False).init)(
+        jax.random.PRNGKey(0), x, ctx)["params"]
+    out_ref = jax.jit(mk(False).apply)({"params": params}, x, ctx)
+    out_bh = jax.jit(mk(True).apply)({"params": params}, x, ctx)
     np.testing.assert_allclose(np.asarray(out_ref), np.asarray(out_bh),
                                rtol=2e-5, atol=2e-6)
 
     def loss(p, bhld):
         return jnp.sum(mk(bhld).apply({"params": p}, x, ctx) ** 2)
 
-    g_ref = jax.grad(loss)(params, False)
-    g_bh = jax.grad(loss)(params, True)
+    grad = jax.jit(jax.grad(loss), static_argnums=1)
+    g_ref = grad(params, False)
+    g_bh = grad(params, True)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5),
@@ -152,8 +157,8 @@ def test_fresh_inits_are_layout_identical():
                                         backend="xla", bhld=b),
                lambda b: RoPEAttention(heads=2, dim_head=8,
                                        backend="xla", bhld=b)):
-        p_ref = mk(False).init(jax.random.PRNGKey(5), x)["params"]
-        p_bh = mk(True).init(jax.random.PRNGKey(5), x)["params"]
+        p_ref = jax.jit(mk(False).init)(jax.random.PRNGKey(5), x)["params"]
+        p_bh = jax.jit(mk(True).init)(jax.random.PRNGKey(5), x)["params"]
         jax.tree_util.tree_map(
             lambda a, b: np.testing.assert_array_equal(
                 np.asarray(a), np.asarray(b)),
@@ -182,12 +187,13 @@ def test_flash_interpret_dispatch_in_full_model(monkeypatch):
     for bhld in (False, True):
         block = TransformerBlock(heads=2, dim_head=8, backend="flash",
                                  bhld=bhld)
-        params = block.init(jax.random.PRNGKey(0), x, ctx)["params"]
+        params = jax.jit(block.init)(
+            jax.random.PRNGKey(0), x, ctx)["params"]
 
         def loss(p):
             return jnp.sum(block.apply({"params": p}, x, ctx) ** 2)
 
-        val, grads = jax.value_and_grad(loss)(params)
+        val, grads = jax.jit(jax.value_and_grad(loss))(params)
         assert np.isfinite(float(val))
         assert all(np.isfinite(np.asarray(g)).all()
                    for g in jax.tree_util.tree_leaves(grads))

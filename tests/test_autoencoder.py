@@ -84,11 +84,12 @@ def test_kl_vae_trains_one_step(vae, rng):
     tx = optax.adam(1e-3)
     params = vae.params
     opt_state = tx.init(params)
-    l0, g = jax.value_and_grad(loss_fn)(params)
+    loss_and_grad = jax.jit(jax.value_and_grad(loss_fn))
+    l0, g = loss_and_grad(params)
     for _ in range(5):
         updates, opt_state = tx.update(g, opt_state)
         params = optax.apply_updates(params, updates)
-        l1, g = jax.value_and_grad(loss_fn)(params)
+        l1, g = loss_and_grad(params)
     assert float(l1) < float(l0)
 
 

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 
 from flaxdiff_tpu.models.autoencoder import KLAutoEncoder
 from flaxdiff_tpu.parallel import create_mesh
@@ -31,19 +32,24 @@ def _build(kl_weight=1e-6):
         config=AutoEncoderTrainerConfig(kl_weight=kl_weight, log_every=20))
 
 
-def test_vae_trains_reconstruction_down():
+@pytest.fixture(scope="module")
+def fitted():
+    """ONE VAE trainer and its 120-step fit, (trainer, history): the
+    first test reads the history, the other two only read the trained
+    codec."""
     trainer = _build()
-    data = _toy_batches()
-    hist = trainer.fit(data, total_steps=120)
+    return trainer, trainer.fit(_toy_batches(), total_steps=120)
+
+
+def test_vae_trains_reconstruction_down(fitted):
+    _, hist = fitted
     assert np.isfinite(hist["final_loss"])
     assert hist["recon"][-1] < hist["recon"][0] * 0.8, hist["recon"]
     assert all(np.isfinite(v) for v in hist["kl"])
 
 
-def test_trained_vae_roundtrip_and_scale():
-    trainer = _build()
-    data = _toy_batches()
-    trainer.fit(data, total_steps=60)
+def test_trained_vae_roundtrip_and_scale(fitted):
+    trainer, _ = fitted
     scale = trainer.measure_latent_scale(_toy_batches(seed=1),
                                          num_batches=2)
     assert scale > 0
@@ -59,7 +65,7 @@ def test_trained_vae_roundtrip_and_scale():
     assert np.all(np.isfinite(np.asarray(recon)))
 
 
-def test_vae_feeds_latent_diffusion_step():
+def test_vae_feeds_latent_diffusion_step(fitted):
     """Latent diffusion end-to-end on first-party latents: the trained
     VAE plugs into DiffusionTrainer as the autoencoder."""
     import flax.linen as nn
@@ -69,9 +75,7 @@ def test_vae_feeds_latent_diffusion_step():
     from flaxdiff_tpu.schedulers import CosineNoiseSchedule
     from flaxdiff_tpu.trainer import DiffusionTrainer, TrainerConfig
 
-    trainer = _build()
-    trainer.fit(_toy_batches(), total_steps=20)
-    vae = trainer.trained_vae()
+    vae = fitted[0].trained_vae()
 
     class Tiny(nn.Module):
         @nn.compact

@@ -308,10 +308,11 @@ def test_forward_equals_the_plain_reference(gate):
     model = build_model("brumby_dn", **SMALL)
     params = _seeded(model, gate_bias=GATES[gate])
     x, t, text = _inputs()
-    got = model.apply({"params": params}, x, t, text)
+    got = jax.jit(model.apply)({"params": params}, x, t, text)
     with jax.default_matmul_precision("highest"):
-        want = ref.forward(params, dict(SMALL, rms_norm_eps=1e-6,
-                                        rope_theta=1000000), x, t, text)
+        want = jax.jit(lambda p: ref.forward(
+            p, dict(SMALL, rms_norm_eps=1e-6, rope_theta=1000000), x, t,
+            text))(params)
     assert got.shape == x.shape and float(jnp.abs(want).mean()) > 0.1
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
@@ -421,9 +422,12 @@ def test_a_served_request_equals_the_references_trajectory(gate):
     results = [f.result(timeout=600) for f in [sched.submit(r) for r in reqs]]
     sched.close(drain=True)
     cfg = dict(SMALL, rms_norm_eps=1e-6, rope_theta=1000000)
+    # the reference's forward as ONE program (traced inside `serve`'s
+    # own precision context), not a primitive at a time
+    forward = jax.jit(lambda p, *a: ref.forward(p, cfg, *a))
     for req, res in zip(reqs, results):
         want = sample.serve(
-            ref.forward, cfg, params,
+            lambda p, _, *a: forward(p, *a), cfg, params,
             {"seed": req.seed, "nfe": req.diffusion_steps, "guidance": 3.0,
              "shape": (1, RES, RES, CH), "cond": cond, "uncond": null_ctx},
             1000, predictor="v")

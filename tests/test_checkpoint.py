@@ -1,5 +1,6 @@
 """Tests: sharded checkpoint save/restore/resume, validation, logging."""
 import json
+import shutil
 
 import jax
 import jax.numpy as jnp
@@ -22,8 +23,10 @@ from flaxdiff_tpu.models.unet import Unet
 
 
 def _make_trainer(mesh, tmp_path=None):
-    model = Unet(output_channels=1, emb_features=16, feature_depths=(8, 12),
-                 num_res_blocks=1, norm_groups=4, attention_configs=(None, None))
+    # one resolution level: what is saved and restored is a state tree,
+    # whatever its depth
+    model = Unet(output_channels=1, emb_features=16, feature_depths=(8,),
+                 num_res_blocks=1, norm_groups=4, attention_configs=(None,))
     x0 = jnp.zeros((2, 8, 8, 1))
     t0 = jnp.zeros((2,))
 
@@ -47,17 +50,43 @@ def _batches(n, rng):
         yield {"sample": rng.normal(size=(8, 8, 8, 1)).astype(np.float32)}
 
 
-def test_checkpoint_roundtrip(mesh, tmp_path, rng):
-    trainer = _make_trainer(mesh, tmp_path / "ckpt")
-    data = _batches(4, rng)
-    trainer.fit(data, total_steps=4)
+@pytest.fixture(scope="module")
+def trained(mesh, tmp_path_factory):
+    """ONE training (4 steps, a save at 2 and at 4) that the restore tests
+    read: (trainer, its history, its checkpoint directory). Nobody trains
+    or restores this trainer again; a test that writes takes a copy of
+    the directory (`_copy`)."""
+    ckpt_dir = tmp_path_factory.mktemp("trained") / "ckpt"
+    trainer = _make_trainer(mesh, ckpt_dir)
+    hist = trainer.fit(_batches(4, np.random.default_rng(0)), total_steps=4,
+                       save_every=2)
     trainer.checkpointer.wait_until_finished()
+    yield trainer, hist, ckpt_dir
+    trainer.checkpointer.close()
+
+
+def _copy(ckpt_dir, tmp_path):
+    return shutil.copytree(ckpt_dir, tmp_path / "ckpt")
+
+
+@pytest.fixture(scope="module")
+def restored(trained, mesh, tmp_path_factory):
+    """A fresh trainer that restored a copy of `trained`'s checkpoint,
+    for the tests that only look at what a restore leaves behind."""
+    trainer2 = _make_trainer(
+        mesh, _copy(trained[2], tmp_path_factory.mktemp("restored")))
+    step = trainer2.restore_checkpoint()
+    yield trainer2, step
+    trainer2.checkpointer.close()
+
+
+def test_checkpoint_roundtrip(mesh, trained, restored):
+    trainer, _, _ = trained
     saved_step = trainer.checkpointer.latest_step()
     assert saved_step == 4
 
     # Fresh trainer restores the exact sharded state.
-    trainer2 = _make_trainer(mesh, tmp_path / "ckpt")
-    restored_step = trainer2.restore_checkpoint()
+    trainer2, restored_step = restored
     assert restored_step == 4
     p1 = jax.device_get(trainer.state.params)
     p2 = jax.device_get(trainer2.state.params)
@@ -67,45 +96,29 @@ def test_checkpoint_roundtrip(mesh, tmp_path, rng):
     # Restored state keeps its FSDP shardings.
     leaf = jax.tree_util.tree_leaves(trainer2.state.params)[0]
     assert leaf.sharding.mesh.axis_names == mesh.axis_names
-    trainer.checkpointer.close()
-    trainer2.checkpointer.close()
 
 
-def test_checkpoint_resume_continues_training(mesh, tmp_path, rng):
-    trainer = _make_trainer(mesh, tmp_path / "ckpt2")
-    trainer.fit(_batches(3, rng), total_steps=3)
-    trainer.checkpointer.wait_until_finished()
-
-    trainer2 = _make_trainer(mesh, tmp_path / "ckpt2")
-    trainer2.restore_checkpoint()
+def test_checkpoint_resume_continues_training(mesh, trained, tmp_path, rng):
+    trainer2 = _make_trainer(mesh, _copy(trained[2], tmp_path))
+    assert trainer2.restore_checkpoint() == 4
     trainer2.fit(_batches(2, rng), total_steps=2)
-    assert int(jax.device_get(trainer2.state.step)) == 5
+    assert int(jax.device_get(trainer2.state.step)) == 6
     trainer2.checkpointer.wait_until_finished()
-    assert trainer2.checkpointer.latest_step() == 5
-    trainer.checkpointer.close()
+    assert trainer2.checkpointer.latest_step() == 6
     trainer2.checkpointer.close()
 
 
-def test_fit_with_save_every_equal_total_steps(mesh, tmp_path, rng):
+def test_fit_with_save_every_equal_total_steps(trained):
     """Final forced save must not crash when save_every already wrote the
-    last step (orbax refuses duplicate steps)."""
-    trainer = _make_trainer(mesh, tmp_path / "ckpt3")
-    hist = trainer.fit(_batches(4, rng), total_steps=4, save_every=2)
+    last step (orbax refuses duplicate steps): `trained` is that fit."""
+    trainer, hist, _ = trained
     assert "final_loss" in hist
-    trainer.checkpointer.wait_until_finished()
     assert trainer.checkpointer.latest_step() == 4
-    trainer.checkpointer.close()
 
 
-def test_restore_arms_best_state(mesh, tmp_path, rng):
-    trainer = _make_trainer(mesh, tmp_path / "ckpt4")
-    trainer.fit(_batches(3, rng), total_steps=3)
-    trainer.checkpointer.wait_until_finished()
-    trainer2 = _make_trainer(mesh, tmp_path / "ckpt4")
-    trainer2.restore_checkpoint()
+def test_restore_arms_best_state(restored):
+    trainer2, _ = restored
     assert trainer2.best_state is not None  # NaN rollback armed after resume
-    trainer.checkpointer.close()
-    trainer2.checkpointer.close()
 
 
 def test_cross_mesh_restore(mesh, tmp_path, rng):
@@ -118,6 +131,8 @@ def test_cross_mesh_restore(mesh, tmp_path, rng):
     from flaxdiff_tpu.parallel import create_mesh
     from flaxdiff_tpu.trainer.checkpoints import abstract_state_like
 
+    # the writer is its own: bare `train_step`s and a forced save, with
+    # no `fit` around them
     trainer = _make_trainer(mesh, tmp_path)
     it = _batches(3, rng)
     for _ in range(2):

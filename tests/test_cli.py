@@ -11,8 +11,11 @@ import pytest
 
 sys.path.insert(0, ".")  # repo root (train.py lives there)
 
+# One resolution level: no test here is about the UNet's depth, and every
+# level is more to initialise, compile and checkpoint (tests/
+# test_inference.py `cli_run` is the suite's two-level `train.main`).
 TINY_MODEL = json.dumps({
-    "feature_depths": [8, 16], "attention_configs": [None, None],
+    "feature_depths": [8], "attention_configs": [None],
     "emb_features": 16, "num_res_blocks": 1,
 })
 
@@ -74,7 +77,7 @@ def test_cli_trains_video_with_audio_conditioning(tmp_path, make_av_file):
     vids = tmp_path / "vids"
     vids.mkdir()
     for i in range(8):   # >= one full batch after drop_remainder
-        make_av_file(vids / f"{i}.mp4", size=32, dur=2)
+        make_av_file(vids / f"{i}.mp4", size=32, dur=1)
     hist = _run(
         tmp_path, "--dataset", "av_folder",
         "--dataset_path", str(vids),
@@ -83,9 +86,9 @@ def test_cli_trains_video_with_audio_conditioning(tmp_path, make_av_file):
             "feature_depths": [8], "attention_levels": [True],
             "emb_features": 16, "num_res_blocks": 1, "norm_groups": 4,
             "heads": 2}),
-        "--num_frames", "4", "--audio_encoder", "mel",
+        "--num_frames", "2", "--audio_encoder", "mel",
         "--text_encoder", "none", "--batch_size", "8",
-        "--log_every", "1")
+        "--total_steps", "2", "--log_every", "1")
     assert np.isfinite(hist["final_loss"])
 
 

@@ -84,11 +84,11 @@ def test_forward_equals_the_plain_reference(window):
     model = build_model("cohere2_moe_dn", **dict(SMALL, sliding_window=window))
     params = _seeded(model)
     x, t, text = _inputs()
-    got, picks = model.apply({"params": params}, x, t, text,
-                             return_picks=True)
+    got, picks = jax.jit(lambda p: model.apply(
+        {"params": p}, x, t, text, return_picks=True))(params)
     with jax.default_matmul_precision("highest"):
-        want = ref.forward(params, _ref_cfg(sliding_window=window), x, t,
-                           text)
+        want = jax.jit(lambda p: ref.forward(
+            p, _ref_cfg(sliding_window=window), x, t, text))(params)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
     # every token makes 3 picks a layer over 16 experts; 4 are held here
     tokens = 1 + TOK + (RES // 2) ** 2
@@ -122,12 +122,13 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_reference():
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 22, 64))
     cfg = _ref_cfg(num_experts=16, first_expert=0)
     with jax.default_matmul_precision("highest"):
-        want = ref._layer(cfg, layer, x, jnp.asarray(True))
+        want = jax.jit(lambda p: ref._layer(cfg, p, x, jnp.asarray(True)))(
+            layer)
         # the same layer with no routed expert held: x + attention + shared
         none = dict(layer, **{k: {"kernel": layer[k]["kernel"][:0]} for k in
                               ("experts_gate", "experts_up", "experts_down")})
-        base = ref._layer(dict(cfg, num_experts=0), none, x,
-                          jnp.asarray(True))
+        base = jax.jit(lambda p: ref._layer(
+            dict(cfg, num_experts=0), p, x, jnp.asarray(True)))(none)
     total = 0.0
     picks = []
     for share in range(4):
@@ -140,7 +141,7 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_reference():
         held = dict(layer, **{
             k: {"kernel": layer[k]["kernel"][4 * share:4 * share + 4]}
             for k in ("experts_gate", "experts_up", "experts_down")})
-        y, n = block.apply({"params": held}, x)
+        y, n = jax.jit(block.apply)({"params": held}, x)
         total = total + (y - base)
         picks.append(n)
     np.testing.assert_allclose(total + base, want, atol=2e-5, rtol=2e-5)
@@ -204,10 +205,12 @@ def test_routed_experts_drop_no_token_and_pool_a_vmap_over_rows():
     x, wg, wu, wd = _experts()
     local = _imbalanced(40, 4)
     w = jax.random.uniform(jax.random.PRNGKey(9), (40, 2))
-    want = _dense(x, local, w, wg, wu, wd)
-    np.testing.assert_allclose(moe.routed_experts(x, local, w, wg, wu, wd),
-                               want, atol=2e-5, rtol=2e-5)
-    rows = jax.vmap(moe.routed_experts, in_axes=(0, 0, 0, None, None, None))(
+    want = jax.jit(_dense)(x, local, w, wg, wu, wd)
+    np.testing.assert_allclose(
+        jax.jit(moe.routed_experts)(x, local, w, wg, wu, wd), want,
+        atol=2e-5, rtol=2e-5)
+    rows = jax.jit(jax.vmap(moe.routed_experts,
+                            in_axes=(0, 0, 0, None, None, None)))(
         x.reshape(4, 10, -1), local.reshape(4, 10, 2), w.reshape(4, 10, 2),
         wg, wu, wd)
     np.testing.assert_allclose(rows.reshape(40, -1), want, atol=2e-5,
@@ -219,11 +222,12 @@ def test_routed_experts_drop_no_token_and_pool_a_vmap_over_rows():
         wg, wu, wd))
     alone = str(jax.make_jaxpr(moe.routed_experts)(x, local, w, wg, wu, wd))
     assert pooled.count("ragged_dot") == alone.count("ragged_dot") > 0
-    grads = jax.grad(lambda *a: (moe.routed_experts(
-        a[0], local, w, *a[1:]) ** 2).sum(), argnums=(0, 1, 2, 3))(
+    grads = jax.jit(jax.grad(lambda *a: (moe.routed_experts(
+        a[0], local, w, *a[1:]) ** 2).sum(), argnums=(0, 1, 2, 3)))(
         x, wg, wu, wd)
-    wants = jax.grad(lambda *a: (_dense(a[0], local, w, *a[1:]) ** 2).sum(),
-                     argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+    wants = jax.jit(jax.grad(
+        lambda *a: (_dense(a[0], local, w, *a[1:]) ** 2).sum(),
+        argnums=(0, 1, 2, 3)))(x, wg, wu, wd)
     for g, want_g in zip(grads, wants):
         np.testing.assert_allclose(g, want_g, atol=1e-4, rtol=1e-4)
 
@@ -270,12 +274,12 @@ def test_masked_grouped_flash_in_interpret_mode(case):
     def xla(q, k, v):
         return _xla_attention(q, k, v, causal=c["causal"], window=c["window"])
 
-    np.testing.assert_allclose(flash(q, k, v), xla(q, k, v), atol=2e-5,
-                               rtol=2e-5)
-    got = jax.grad(lambda *a: (flash(*a) * cot).sum(), argnums=(0, 1, 2))(
-        q, k, v)
-    want = jax.grad(lambda *a: (xla(*a) * cot).sum(), argnums=(0, 1, 2))(
-        q, k, v)
+    np.testing.assert_allclose(jax.jit(flash)(q, k, v),
+                               jax.jit(xla)(q, k, v), atol=2e-5, rtol=2e-5)
+    got = jax.jit(jax.grad(lambda *a: (flash(*a) * cot).sum(),
+                           argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(lambda *a: (xla(*a) * cot).sum(),
+                            argnums=(0, 1, 2)))(q, k, v)
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, atol=2e-5, rtol=2e-5)
 
@@ -356,9 +360,12 @@ def test_a_served_request_equals_the_references_trajectory_and_is_counted(
     launches0 = tel.counter("serving/launches").value
     results = [f.result(timeout=600) for f in [sched.submit(r) for r in reqs]]
     sched.close(drain=True)
+    # the reference's forward as ONE program (traced inside `serve`'s
+    # own precision context), not a primitive at a time
+    forward = jax.jit(lambda p, *a: ref.forward(p, _ref_cfg(), *a))
     for req, res in zip(reqs, results):
         want = sample.serve(
-            ref.forward, _ref_cfg(), params,
+            lambda p, cfg, *a: forward(p, *a), _ref_cfg(), params,
             {"seed": req.seed, "nfe": req.diffusion_steps, "guidance": 3.0,
              "shape": (1, RES, RES, CH), "cond": cond, "uncond": null_ctx},
             1000, predictor="v")

@@ -115,8 +115,8 @@ def test_combined_grain_to_train_step(corpus_root):
 
     enc = HashTextEncoder.create(features=16, max_length=8)
     model = Unet(output_channels=3, emb_features=16,
-                 feature_depths=(8, 16), attention_configs=(None, None),
-                 num_res_blocks=1)
+                 feature_depths=(8,), attention_configs=(None,),
+                 num_res_blocks=1)      # one level: the batch is the point
 
     def apply_fn(params, x, t, cond):
         ctx = (cond["text"] if cond is not None else

@@ -188,7 +188,9 @@ def test_av_batch_trains_unet3d_step(av_file):
     from flaxdiff_tpu.schedulers import CosineNoiseSchedule
     from flaxdiff_tpu.trainer import DiffusionTrainer, TrainerConfig
 
-    n_frames, size, feat = 4, 16, 32
+    # two frames, one resolution level: the claim is that an AV batch
+    # reaches the step with audio as the attention's context
+    n_frames, size, feat = 2, 16, 32
     enc = MelAudioEncoder.create(n_mels=16, features=feat,
                                  samples_per_frame=SR // FPS)
     aug = AudioVideoAugmenter(num_frames=n_frames, image_size=size)
@@ -201,8 +203,8 @@ def test_av_batch_trains_unet3d_step(av_file):
     batch = {"sample": video, "cond": {"audio": audio_ctx}}
 
     model = UNet3D(output_channels=3, emb_features=32,
-                   feature_depths=(8, 16), attention_levels=(False, True),
-                   heads=2, num_res_blocks=1)
+                   feature_depths=(8,), attention_levels=(True,),
+                   heads=2, num_res_blocks=1, norm_groups=4)
 
     def apply_fn(params, x, t, cond):
         ctx = cond["audio"] if cond is not None else None

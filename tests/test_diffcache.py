@@ -70,6 +70,7 @@ def test_plan_validation_and_keys():
 # Model cache_mode forward contract
 # ---------------------------------------------------------------------------
 
+@jax.jit
 def _perturb(params, scale=0.05, seed=7):
     # AdaLN-Zero blocks are exact identities at init (zero-init gates):
     # without this the deep delta is zero and reuse is trivially exact
@@ -108,23 +109,28 @@ def test_record_reuse_forward_contract(name, model, text):
     differences are expected); the param tree is mode-independent."""
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 8, 1))
     t = jnp.full((2,), 10.0)
-    params = _perturb(model.init(jax.random.PRNGKey(1), x, t, text))
+    params = _perturb(jax.jit(model.init)(jax.random.PRNGKey(1), x, t, text))
     split = model.cache_split_index(DEFAULT_CACHE_PLAN.depth_fraction)
-    plain = model.apply(params, x, t, text)
-    rec, taps = model.apply(params, x, t, text, cache_mode="record",
-                            cache_split=split)
+
+    def apply(x, mode=None, **carries):
+        # mode / split are Python values of the program, the taps its
+        # operand: one compiled program a call
+        static = {} if mode is None else dict(cache_mode=mode,
+                                              cache_split=split)
+        return jax.jit(lambda p, x, c: model.apply(p, x, t, text, **static,
+                                                   **c))(params, x, carries)
+
+    plain = apply(x)
+    rec, taps = apply(x, "record")
     np.testing.assert_array_equal(np.asarray(plain), np.asarray(rec))
-    reu = model.apply(params, x, t, text, cache_mode="reuse",
-                      cache_split=split, cache_taps=taps)
+    reu = apply(x, "reuse", cache_taps=taps)
     np.testing.assert_allclose(np.asarray(plain), np.asarray(reu),
                                rtol=1e-5, atol=1e-6)
     # stale taps (from a different input) give a DIFFERENT, finite
     # output — the reuse path is genuinely engaged
     x2 = jax.random.normal(jax.random.PRNGKey(2), (2, 8, 8, 1))
-    _, taps2 = model.apply(params, x2, t, text, cache_mode="record",
-                           cache_split=split)
-    approx = model.apply(params, x, t, text, cache_mode="reuse",
-                         cache_split=split, cache_taps=taps2)
+    _, taps2 = apply(x2, "record")
+    approx = apply(x, "reuse", cache_taps=taps2)
     assert np.isfinite(np.asarray(approx)).all()
     assert not np.array_equal(np.asarray(plain), np.asarray(approx))
 
@@ -165,8 +171,9 @@ def _pipe(num_layers=2, perturb=True):
     model = build_model("simple_dit", emb_features=32, num_heads=4,
                         num_layers=num_layers, patch_size=4,
                         output_channels=1)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 1)),
-                        jnp.zeros((1,)), None)
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 1)), jnp.zeros((1,)),
+        None)
     if perturb:
         params = _perturb(params)
     return DiffusionInferencePipeline.from_config(config, params=params)
@@ -227,7 +234,7 @@ def test_solo_cfg_prompted_refresh_every_step_identity():
     enc = HashTextEncoder.create(features=16, max_length=8)
     model = build_model("simple_dit", emb_features=32, num_heads=4,
                         num_layers=2, patch_size=4, output_channels=1)
-    params = _perturb(model.init(
+    params = _perturb(jax.jit(model.init)(
         jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 1)),
         jnp.zeros((1,)), jnp.asarray(enc([""]))))
     pipe = DiffusionInferencePipeline.from_config(
@@ -363,7 +370,7 @@ def test_chunked_cfg_prompted_refresh_every_step_identity():
     enc = HashTextEncoder.create(features=16, max_length=8)
     model = build_model("simple_dit", emb_features=32, num_heads=4,
                         num_layers=2, patch_size=4, output_channels=1)
-    params = _perturb(model.init(
+    params = _perturb(jax.jit(model.init)(
         jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 1)),
         jnp.zeros((1,)), jnp.asarray(enc([""]))))
     pipe = DiffusionInferencePipeline.from_config(

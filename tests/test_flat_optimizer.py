@@ -86,7 +86,7 @@ def test_trains_end_to_end_in_diffusion_trainer():
     from flaxdiff_tpu.trainer import DiffusionTrainer, TrainerConfig
 
     model = Unet(output_channels=3, emb_features=16,
-                 feature_depths=(8, 16), attention_configs=(None, None),
+                 feature_depths=(8,), attention_configs=(None,),
                  num_res_blocks=1, norm_groups=4)
 
     def apply_fn(params, x, t, cond):

@@ -25,7 +25,7 @@ from flaxdiff_tpu.trainer import DiffusionTrainer, TrainerConfig
 def _make_trainer(flat: bool, mesh_axes=None, seed=3):
     size = 8
     model = Unet(output_channels=1, emb_features=16,
-                 feature_depths=(8, 16), attention_configs=(None, None),
+                 feature_depths=(8,), attention_configs=(None,),
                  num_res_blocks=1, norm_groups=4)
 
     def apply_fn(params, x, t, cond):
@@ -52,17 +52,29 @@ def _batches(size, n=4, batch=8):
              .astype(np.uint8)} for _ in range(n)]
 
 
-def test_flat_params_matches_structured_path():
+@pytest.fixture(scope="module")
+def stepped_pair():
+    """The structured and the flat trainer of one seed, stepped side by
+    side over the same four batches: (t_ref, t_flat, size, losses).
+    The sampler test reads the flat one after it; nobody steps either
+    again."""
+    t_ref, size = _make_trainer(flat=False)
+    t_flat, _ = _make_trainer(flat=True)
+    losses = [(float(t_ref.train_step(t_ref.put_batch(b))),
+               float(t_flat.train_step(t_flat.put_batch(b))))
+              for b in _batches(size)]
+    return t_ref, t_flat, size, losses
+
+
+def test_flat_params_matches_structured_path(stepped_pair):
     """Same seeds, same batches: the flat-state trainer must follow the
     structured trainer's loss trajectory, params, and EMA. Tolerance is
     loose-float, not bitwise: clip_by_global_norm sums squares in a
     different order over one concatenated vector than over per-leaf
     partials, so the clip scale differs in the last ulp."""
-    t_ref, size = _make_trainer(flat=False)
-    t_flat, _ = _make_trainer(flat=True)
-    for b in _batches(size):
-        l_ref = float(t_ref.train_step(t_ref.put_batch(b)))
-        l_flat = float(t_flat.train_step(t_flat.put_batch(b)))
+    t_ref, t_flat, _, losses = stepped_pair
+    assert len(losses) == 4
+    for l_ref, l_flat in losses:
         assert np.isclose(l_ref, l_flat, rtol=1e-6), (l_ref, l_flat)
 
     p_ref = jax.device_get(t_ref.get_params(use_ema=False))
@@ -121,14 +133,12 @@ def test_flat_params_trains_under_fsdp_mesh():
     assert all(np.isfinite(losses))
 
 
-def test_flat_params_sampler_roundtrip():
+def test_flat_params_sampler_roundtrip(stepped_pair):
     """get_params returns the structured tree the samplers expect."""
     from flaxdiff_tpu.samplers import DDIMSampler, DiffusionSampler
     from flaxdiff_tpu.utils import RngSeq
 
-    t_flat, size = _make_trainer(flat=True)
-    for b in _batches(size, n=2):
-        t_flat.train_step(t_flat.put_batch(b))
+    _, t_flat, size, _ = stepped_pair
     engine = DiffusionSampler(
         model_fn=t_flat._apply_fn,
         schedule=CosineNoiseSchedule(timesteps=100),
@@ -147,7 +157,7 @@ def test_flat_params_with_grad_accum():
     state; k micro-steps per optimizer update must still train."""
     size = 8
     model = Unet(output_channels=1, emb_features=16,
-                 feature_depths=(8, 16), attention_configs=(None, None),
+                 feature_depths=(8,), attention_configs=(None,),
                  num_res_blocks=1, norm_groups=4)
 
     def apply_fn(params, x, t, cond):

@@ -196,22 +196,32 @@ def test_model_interpret_parity_and_cpu_bit_identity(model_kind,
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 8, 3))
     t = jnp.array([0.3, 0.7])
     txt = jax.random.normal(jax.random.PRNGKey(1), (2, 5, 12))
-    params = fused_m.init(jax.random.PRNGKey(2), x, t, txt)
-    leaves, treedef = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(3), len(leaves))
-    params = jax.tree_util.tree_unflatten(treedef, [
-        jax.random.normal(k, l.shape, l.dtype) * 0.05
-        for l, k in zip(leaves, keys)])
+    params = jax.jit(fused_m.init)(jax.random.PRNGKey(2), x, t, txt)
 
-    out_flag_on = fused_m.apply(params, x, t, txt)
-    out_flag_off = unfused_m.apply(params, x, t, txt)
+    @jax.jit
+    def randomized(params):
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        keys = jax.random.split(jax.random.PRNGKey(3), len(leaves))
+        return jax.tree_util.tree_unflatten(treedef, [
+            jax.random.normal(k, l.shape, l.dtype) * 0.05
+            for l, k in zip(leaves, keys)])
+
+    params = randomized(params)
+    out_flag_on = jax.jit(fused_m.apply)(params, x, t, txt)
+    out_flag_off = jax.jit(unfused_m.apply)(params, x, t, txt)
     assert float(jnp.max(jnp.abs(out_flag_off))) > 1e-4  # not vacuous
     # (a) same platform, no env: flag on == flag off BIT-IDENTICALLY
     np.testing.assert_array_equal(np.asarray(out_flag_on),
                                   np.asarray(out_flag_off))
     # (b) interpret hook: real kernels, numeric parity
     monkeypatch.setenv("FLAXDIFF_FUSED_ADALN", "interpret")
-    out_fused = fused_m.apply(params, x, t, txt)
+    # a NEW function object: the hook is read while tracing, and jit's
+    # cache of `fused_m.apply` holds the trace made without it
+    def hooked(*a):
+        return fused_m.apply(*a)
+
+    assert "pallas_call" in str(jax.make_jaxpr(hooked)(params, x, t, txt))
+    out_fused = jax.jit(hooked)(params, x, t, txt)
     np.testing.assert_allclose(np.asarray(out_fused),
                                np.asarray(out_flag_off),
                                rtol=1e-3, atol=1e-4)

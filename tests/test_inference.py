@@ -195,30 +195,44 @@ def test_from_registry_stale_step_warns_and_falls_back(tmp_path):
     assert out.shape == (1, 8, 8, 1)
 
 
-def test_cli_end_to_end(tmp_path):
-    """The CLI trains on the synthetic dataset and the inference pipeline
-    reloads from its checkpoint dir."""
+@pytest.fixture(scope="module")
+def cli_run(tmp_path_factory):
+    """ONE `train.main` run that the three checkpoint-to-pipeline tests
+    read: a CONDITIONAL UNet of two levels (hash text encoder,
+    cross-attention at the second level and in the mid block: the suite's
+    one `train.main` that down- and upsamples and reads a per-level
+    `attention_configs`) on a (data 2, fsdp 4) mesh, saved at steps 3
+    and 6 and entered in the registry as `regrun`. The tests only load
+    from it."""
     from train import main
-    ckpt_dir = str(tmp_path / "run")
+    root = tmp_path_factory.mktemp("cli_run")
+    ckpt_dir = root / "runs" / "regrun"
     hist = main([
         "--dataset", "synthetic", "--image_size", "8",
         "--batch_size", "16", "--architecture", "unet",
         "--model_config", json.dumps({
             "emb_features": 16, "feature_depths": [8, 12],
             "num_res_blocks": 1, "norm_groups": 4,
-            "attention_configs": [None, None]}),
+            "attention_configs": [None, {"heads": 2, "dim_head": 4}]}),
         "--dtype", "fp32",
         "--total_steps", "6", "--warmup_steps", "2",
         "--save_every", "3", "--log_every", "3",
         "--text_encoder", "hash",
-        "--checkpoint_dir", ckpt_dir,
+        "--checkpoint_dir", str(ckpt_dir), "--run_name", "regrun",
         "--mesh_data", "2", "--mesh_fsdp", "4",
     ])
+    return hist, ckpt_dir, str(root / "runs" / "registry.json")
+
+
+def test_cli_end_to_end(cli_run):
+    """The CLI trains on the synthetic dataset and the inference pipeline
+    reloads from its checkpoint dir."""
+    hist, ckpt_dir, _ = cli_run
     assert np.isfinite(hist["final_loss"])
-    log = (tmp_path / "run" / "train_log.jsonl").read_text().strip()
+    log = (ckpt_dir / "train_log.jsonl").read_text().strip()
     assert "loss" in log
 
-    pipe = DiffusionInferencePipeline.from_checkpoint(ckpt_dir)
+    pipe = DiffusionInferencePipeline.from_checkpoint(str(ckpt_dir))
     out = pipe.generate_samples(num_samples=2, resolution=8,
                                 diffusion_steps=3, sampler="ddim",
                                 guidance_scale=1.5,
@@ -228,62 +242,31 @@ def test_cli_end_to_end(tmp_path):
     assert np.all(np.isfinite(out))
 
 
-def test_pipeline_from_registry(tmp_path):
+def test_pipeline_from_registry(cli_run):
     """Registry -> best checkpoint -> pipeline (reference
     from_wandb_registry equivalent)."""
-    import json
-
     from flaxdiff_tpu.trainer import ModelRegistry
 
-    # reuse the CLI to produce a real checkpoint + config
-    import train
-    ckpt_dir = tmp_path / "runs" / "regrun"
-    train.main([
-        "--dataset", "synthetic", "--image_size", "16",
-        "--batch_size", "16", "--architecture", "unet",
-        "--model_config", json.dumps({
-            "feature_depths": [8, 16], "attention_configs": [None, None],
-            "emb_features": 16, "num_res_blocks": 1}),
-        "--total_steps", "4", "--log_every", "2", "--warmup_steps", "2",
-        "--save_every", "2", "--text_encoder", "none",
-        "--checkpoint_dir", str(ckpt_dir), "--run_name", "regrun"])
-
-    reg_path = str(tmp_path / "runs" / "registry.json")
+    # the CLI run is a real checkpoint + config + registry entry
+    reg_path = cli_run[2]
     assert ModelRegistry(reg_path).best_run("loss")["run"] == "regrun"
 
-    from flaxdiff_tpu.inference import DiffusionInferencePipeline
     pipe = DiffusionInferencePipeline.from_registry(reg_path, metric="loss")
-    out = pipe.generate_samples(num_samples=2, resolution=16,
+    out = pipe.generate_samples(num_samples=2, resolution=8,
                                 diffusion_steps=2, sampler="ddim")
-    assert out.shape == (2, 16, 16, 3)
+    assert out.shape == (2, 8, 8, 3)
 
-    import pytest
     with pytest.raises(FileNotFoundError, match="no best run"):
         DiffusionInferencePipeline.from_registry(reg_path, metric="fid")
 
 
-def test_promptless_sampling_from_conditional_checkpoint(tmp_path):
+def test_promptless_sampling_from_conditional_checkpoint(cli_run):
     """A CONDITIONAL checkpoint sampled without prompts must condition on
     the cached null tokens, not trace the model context-free: the param
     tree's branch structure depends on context presence (Unet's mid
     block forces use_self_and_cross=False, so attn1 is cross-attention
     when context exists) and a context-free trace fails param loading."""
-    from train import main
-    ckpt_dir = str(tmp_path / "run")
-    main([
-        "--dataset", "synthetic", "--image_size", "8",
-        "--batch_size", "8", "--architecture", "unet",
-        "--model_config", json.dumps({
-            "emb_features": 16, "feature_depths": [8, 12],
-            "num_res_blocks": 1, "norm_groups": 4,
-            "attention_configs": [None, {"heads": 2, "dim_head": 4}]}),
-        "--dtype", "fp32",
-        "--total_steps", "2", "--warmup_steps", "1",
-        "--save_every", "2", "--log_every", "2",
-        "--text_encoder", "hash",
-        "--checkpoint_dir", ckpt_dir,
-    ])
-    pipe = DiffusionInferencePipeline.from_checkpoint(ckpt_dir)
+    pipe = DiffusionInferencePipeline.from_checkpoint(str(cli_run[1]))
     out = pipe.generate_samples(num_samples=2, resolution=8,
                                 diffusion_steps=2, sampler="ddim",
                                 use_ema=False)

@@ -128,7 +128,10 @@ def test_inception_weight_conversion_roundtrip(tmp_path):
 
     model = InceptionV3Features()
     rng = np.random.default_rng(0)
-    variables = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 299, 299, 3)))
+    # only the tree's paths, shapes and dtypes are read: no InceptionV3
+    # is run to initialise leaves that are overwritten at once
+    variables = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                               jnp.zeros((1, 299, 299, 3)))
     # randomize so equal-shape leaves are distinguishable
     variables = jax.tree_util.tree_map(
         lambda x: jnp.asarray(rng.normal(size=x.shape), x.dtype), variables)
@@ -161,7 +164,11 @@ def test_inception_weight_load_rejects_bad_files(tmp_path):
                                       convert_torch_state_dict,
                                       load_inception_params)
     model = InceptionV3Features()
-    variables = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 299, 299, 3)))
+    # the tree's paths and shapes are all the checks below read
+    variables = jax.tree_util.tree_map(
+        lambda s: np.zeros(s.shape, s.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                       jnp.zeros((1, 299, 299, 3))))
     converted = convert_torch_state_dict(
         _fake_torch_state_from_variables(variables))
 

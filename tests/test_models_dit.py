@@ -106,8 +106,8 @@ def test_simple_dit_forward(scan, rng):
     x = jnp.asarray(rng.normal(size=(2, 16, 16, 3)), jnp.float32)
     t = jnp.asarray([0.1, 0.7], jnp.float32)
     ctx = jnp.asarray(rng.normal(size=(2, 7, 32)), jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), x, t, ctx)
-    out = model.apply(params, x, t, ctx)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x, t, ctx)
+    out = jax.jit(model.apply)(params, x, t, ctx)
     assert out.shape == x.shape
     # Zero-init final projection -> exact zeros at init.
     np.testing.assert_array_equal(np.asarray(out), 0.0)
@@ -117,8 +117,8 @@ def test_simple_dit_learn_sigma(rng):
     model = SimpleDiT(learn_sigma=True, **TINY)
     x = jnp.asarray(rng.normal(size=(1, 8, 8, 3)), jnp.float32)
     t = jnp.asarray([0.5], jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), x, t, None)
-    assert model.apply(params, x, t, None).shape == x.shape
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x, t, None)
+    assert jax.jit(model.apply)(params, x, t, None).shape == x.shape
 
 
 @pytest.mark.parametrize("hilbert", [False, True])
@@ -127,16 +127,16 @@ def test_uvit_forward(hilbert, rng):
     x = jnp.asarray(rng.normal(size=(2, 16, 16, 3)), jnp.float32)
     t = jnp.asarray([0.1, 0.9], jnp.float32)
     ctx = jnp.asarray(rng.normal(size=(2, 5, 32)), jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), x, t, ctx)
-    assert model.apply(params, x, t, ctx).shape == x.shape
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x, t, ctx)
+    assert jax.jit(model.apply)(params, x, t, ctx).shape == x.shape
 
 
 def test_uvit_no_text(rng):
     model = UViT(**TINY)
     x = jnp.asarray(rng.normal(size=(1, 8, 8, 3)), jnp.float32)
     t = jnp.asarray([0.3], jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), x, t, None)
-    assert model.apply(params, x, t, None).shape == x.shape
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x, t, None)
+    assert jax.jit(model.apply)(params, x, t, None).shape == x.shape
 
 
 @pytest.mark.parametrize("scan", ["raster", "hilbert"])
@@ -145,8 +145,8 @@ def test_simple_udit_forward(scan, rng):
     x = jnp.asarray(rng.normal(size=(2, 16, 16, 3)), jnp.float32)
     t = jnp.asarray([0.2, 0.8], jnp.float32)
     ctx = jnp.asarray(rng.normal(size=(2, 7, 32)), jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), x, t, ctx)
-    out = model.apply(params, x, t, ctx)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x, t, ctx)
+    out = jax.jit(model.apply)(params, x, t, ctx)
     assert out.shape == x.shape
     np.testing.assert_array_equal(np.asarray(out), 0.0)
 
@@ -155,7 +155,7 @@ def test_dit_jit_and_grad(rng):
     model = SimpleDiT(**TINY)
     x = jnp.asarray(rng.normal(size=(1, 8, 8, 3)), jnp.float32)
     t = jnp.asarray([0.5], jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), x, t, None)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x, t, None)
 
     @jax.jit
     def loss(p):

@@ -59,8 +59,8 @@ def test_simple_mmdit_forward(hilbert, rng):
     x = jnp.asarray(rng.normal(size=(2, 16, 16, 3)), jnp.float32)
     t = jnp.asarray([0.1, 0.9], jnp.float32)
     ctx = jnp.asarray(rng.normal(size=(2, 7, 32)), jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), x, t, ctx)
-    out = model.apply(params, x, t, ctx)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x, t, ctx)
+    out = jax.jit(model.apply)(params, x, t, ctx)
     assert out.shape == x.shape
     np.testing.assert_array_equal(np.asarray(out), 0.0)  # zero-init head
 
@@ -81,8 +81,8 @@ def test_hierarchical_mmdit_forward(hilbert, rng):
     x = jnp.asarray(rng.normal(size=(2, 16, 16, 3)), jnp.float32)
     t = jnp.asarray([0.2, 0.7], jnp.float32)
     ctx = jnp.asarray(rng.normal(size=(2, 5, 24)), jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), x, t, ctx)
-    out = model.apply(params, x, t, ctx)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x, t, ctx)
+    out = jax.jit(model.apply)(params, x, t, ctx)
     assert out.shape == x.shape
     np.testing.assert_array_equal(np.asarray(out), 0.0)
 
@@ -103,12 +103,11 @@ def test_hierarchical_mmdit_grad_flow(rng):
     x = jnp.asarray(rng.normal(size=(1, 8, 8, 1)), jnp.float32)
     t = jnp.asarray([0.5], jnp.float32)
     ctx = jnp.asarray(rng.normal(size=(1, 3, 8)), jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), x, t, ctx)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x, t, ctx)
 
-    @jax.jit
     def loss(p):
         return jnp.mean(model.apply(p, x, t, ctx) ** 2)
 
-    g = jax.grad(loss)(params)
+    g = jax.jit(jax.grad(loss))(params)
     assert all(np.all(np.isfinite(np.asarray(l)))
                for l in jax.tree_util.tree_leaves(g))

@@ -17,8 +17,8 @@ from flaxdiff_tpu.models.ssm import (
 def test_s5_forward_shape_and_finite(rng):
     layer = S5Layer(features=16, state_dim=8)
     u = jnp.asarray(rng.normal(size=(2, 32, 16)), jnp.float32)
-    params = layer.init(jax.random.PRNGKey(0), u)
-    y = layer.apply(params, u)
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), u)
+    y = jax.jit(layer.apply)(params, u)
     assert y.shape == u.shape
     assert np.all(np.isfinite(np.asarray(y)))
 
@@ -27,8 +27,8 @@ def test_s5_matches_sequential_recurrence(rng):
     """Parallel associative scan must equal the naive sequential recurrence."""
     layer = S5Layer(features=4, state_dim=6)
     u = jnp.asarray(rng.normal(size=(1, 10, 4)), jnp.float32)
-    params = layer.init(jax.random.PRNGKey(1), u)
-    y = np.asarray(layer.apply(params, u))
+    params = jax.jit(layer.init)(jax.random.PRNGKey(1), u)
+    y = np.asarray(jax.jit(layer.apply)(params, u))
 
     p = params["params"]
     a = -np.exp(np.asarray(p["log_A_real"])) + 1j * np.asarray(p["A_imag"])
@@ -52,10 +52,10 @@ def test_s5_causality(rng):
     """Output at step k must not depend on inputs after k."""
     layer = S5Layer(features=4, state_dim=4)
     u1 = jnp.asarray(rng.normal(size=(1, 12, 4)), jnp.float32)
-    params = layer.init(jax.random.PRNGKey(0), u1)
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), u1)
     u2 = u1.at[:, 8:].set(99.0)  # perturb the future
-    y1 = np.asarray(layer.apply(params, u1))
-    y2 = np.asarray(layer.apply(params, u2))
+    y1 = np.asarray(jax.jit(layer.apply)(params, u1))
+    y2 = np.asarray(jax.jit(layer.apply)(params, u2))
     np.testing.assert_allclose(y1[:, :8], y2[:, :8], rtol=1e-5)
     assert not np.allclose(y1[:, 8:], y2[:, 8:])
 
@@ -63,19 +63,19 @@ def test_s5_causality(rng):
 def test_bidirectional_s5_sees_both_directions(rng):
     layer = BidirectionalS5Layer(features=4, state_dim=4)
     u1 = jnp.asarray(rng.normal(size=(1, 12, 4)), jnp.float32)
-    params = layer.init(jax.random.PRNGKey(0), u1)
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), u1)
     # Perturbing the future changes early outputs (backward scan).
     u2 = u1.at[:, 10:].set(5.0)
-    y1 = np.asarray(layer.apply(params, u1))
-    y2 = np.asarray(layer.apply(params, u2))
+    y1 = np.asarray(jax.jit(layer.apply)(params, u1))
+    y2 = np.asarray(jax.jit(layer.apply)(params, u2))
     assert not np.allclose(y1[:, :5], y2[:, :5])
 
 
 def test_spatial_fusion_zero_init_is_identity(rng):
     fusion = SpatialFusionConv(features=8)
     y = jnp.asarray(rng.normal(size=(2, 4, 4, 8)), jnp.float32)
-    params = fusion.init(jax.random.PRNGKey(0), y)
-    out = fusion.apply(params, y)
+    params = jax.jit(fusion.init)(jax.random.PRNGKey(0), y)
+    out = jax.jit(fusion.apply)(params, y)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(y))
 
 
@@ -85,8 +85,8 @@ def test_ssm_dit_block_with_fusion(scan, rng):
                         scan_order=scan)
     x = jnp.asarray(rng.normal(size=(2, 16, 16)), jnp.float32)  # 4x4 grid
     cond = jnp.asarray(rng.normal(size=(2, 16)), jnp.float32)
-    params = block.init(jax.random.PRNGKey(0), x, cond)
-    out = block.apply(params, x, cond)
+    params = jax.jit(block.init)(jax.random.PRNGKey(0), x, cond)
+    out = jax.jit(block.apply)(params, x, cond)
     assert out.shape == x.shape
 
 
@@ -97,12 +97,12 @@ def test_ssm_dit_block_fusion_non_square_grid(rng):
                         scan_order="hilbert", grid_hw=(2, 8))
     x = jnp.asarray(rng.normal(size=(1, 16, 8)), jnp.float32)
     cond = jnp.asarray(rng.normal(size=(1, 8)), jnp.float32)
-    params = block.init(jax.random.PRNGKey(0), x, cond)
-    assert block.apply(params, x, cond).shape == x.shape
+    params = jax.jit(block.init)(jax.random.PRNGKey(0), x, cond)
+    assert jax.jit(block.apply)(params, x, cond).shape == x.shape
     with pytest.raises(ValueError):
         bad = SSMDiTBlock(features=8, state_dim=4, use_2d_fusion=True,
                           grid_hw=(3, 3))
-        bad.init(jax.random.PRNGKey(0), x, cond)
+        jax.eval_shape(bad.init, jax.random.PRNGKey(0), x, cond)
 
 
 def test_hybrid_non_square_image(rng):
@@ -111,8 +111,8 @@ def test_hybrid_non_square_image(rng):
         num_heads=2, ssm_state_dim=4, use_hilbert=True, use_2d_fusion=True)
     x = jnp.asarray(rng.normal(size=(1, 8, 32, 1)), jnp.float32)  # 2x8 grid
     t = jnp.asarray([0.5], jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), x, t, None)
-    assert model.apply(params, x, t, None).shape == x.shape
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x, t, None)
+    assert jax.jit(model.apply)(params, x, t, None).shape == x.shape
 
 
 def test_build_block_pattern():
@@ -138,8 +138,8 @@ def test_hybrid_ssm_dit_forward(scan, ratio, rng):
     x = jnp.asarray(rng.normal(size=(2, 16, 16, 3)), jnp.float32)
     t = jnp.asarray([0.1, 0.8], jnp.float32)
     ctx = jnp.asarray(rng.normal(size=(2, 7, 32)), jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), x, t, ctx)
-    out = model.apply(params, x, t, ctx)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x, t, ctx)
+    out = jax.jit(model.apply)(params, x, t, ctx)
     assert out.shape == x.shape
     np.testing.assert_array_equal(np.asarray(out), 0.0)
 
@@ -150,7 +150,7 @@ def test_hybrid_ssm_dit_grad(rng):
         num_heads=2, ssm_state_dim=4, ssm_attention_ratio="1:1")
     x = jnp.asarray(rng.normal(size=(1, 8, 8, 1)), jnp.float32)
     t = jnp.asarray([0.5], jnp.float32)
-    params = model.init(jax.random.PRNGKey(0), x, t, None)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x, t, None)
 
     @jax.jit
     def loss(p):

@@ -34,8 +34,8 @@ def test_residual_block_shapes():
     block = ResidualBlock(features=32, norm_groups=8)
     x = jnp.ones((2, 8, 8, 16))
     temb = jnp.ones((2, 64))
-    params = block.init(jax.random.PRNGKey(0), x, temb)
-    out = block.apply(params, x, temb)
+    params = jax.jit(block.init)(jax.random.PRNGKey(0), x, temb)
+    out = jax.jit(block.apply)(params, x, temb)
     assert out.shape == (2, 8, 8, 32)
 
 
@@ -43,21 +43,21 @@ def test_attention_self_and_cross():
     attn = AttentionLayer(heads=2, dim_head=8)
     x = jnp.ones((2, 16, 32))
     ctx = jnp.ones((2, 7, 32))
-    params = attn.init(jax.random.PRNGKey(0), x, ctx)
-    out = attn.apply(params, x, ctx)
+    params = jax.jit(attn.init)(jax.random.PRNGKey(0), x, ctx)
+    out = jax.jit(attn.apply)(params, x, ctx)
     assert out.shape == (2, 16, 32)
     # spatial input auto-flattens
     xs = jnp.ones((2, 4, 4, 32))
-    params = attn.init(jax.random.PRNGKey(0), xs)
-    assert attn.apply(params, xs).shape == (2, 4, 4, 32)
+    params = jax.jit(attn.init)(jax.random.PRNGKey(0), xs)
+    assert jax.jit(attn.apply)(params, xs).shape == (2, 4, 4, 32)
 
 
 def test_transformer_block_projection_residual():
     tb = TransformerBlock(heads=2, dim_head=16, use_projection=True)
     x = jnp.ones((2, 4, 4, 32))
     ctx = jnp.ones((2, 7, 32))
-    params = tb.init(jax.random.PRNGKey(0), x, ctx)
-    out = tb.apply(params, x, ctx)
+    params = jax.jit(tb.init)(jax.random.PRNGKey(0), x, ctx)
+    out = jax.jit(tb.apply)(params, x, ctx)
     assert out.shape == x.shape
     # zero-init proj_out => output == residual at init
     np.testing.assert_allclose(out, x, atol=1e-5)
@@ -74,8 +74,8 @@ def test_unet_forward(attn):
     x = jnp.ones((2, 16, 16, 3))
     temb = jnp.asarray([0.1, 0.7])
     ctx = jnp.ones((2, 7, 32)) if attn else None
-    params = model.init(jax.random.PRNGKey(0), x, temb, ctx)
-    out = model.apply(params, x, temb, ctx)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x, temb, ctx)
+    out = jax.jit(model.apply)(params, x, temb, ctx)
     assert out.shape == (2, 16, 16, 3)
     assert out.dtype == jnp.float32
     # zero-init output conv => exactly zero output at init
@@ -87,16 +87,18 @@ def test_unet_grad_flows():
                  num_res_blocks=1, norm_groups=4)
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 8, 8, 1))
     temb = jnp.asarray([0.5])
-    params = model.init(jax.random.PRNGKey(0), x, temb)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x, temb)
 
     target = jax.random.normal(jax.random.PRNGKey(2), x.shape)
 
     def loss(p):
         return jnp.mean((model.apply(p, x, temb) - target) ** 2)
 
+    grad = jax.jit(jax.grad(loss))  # one program for all four gradients
+
     # At exact init the zero-init output conv blocks upstream gradients (the
     # standard zero-init property: only the final conv trains on step 0).
-    g0 = jax.grad(loss)(params)
+    g0 = grad(params)
     norms0 = [float(jnp.abs(v).sum()) for v in jax.tree_util.tree_leaves(g0)]
     assert np.isfinite(norms0).all()
     assert sum(n > 0 for n in norms0) >= 2  # conv_out kernel + bias
@@ -105,9 +107,9 @@ def test_unet_grad_flows():
     # each resblock's conv2) is nonzero and gradient flows everywhere.
     p = params
     for _ in range(2):
-        g = jax.grad(loss)(p)
+        g = grad(p)
         p = jax.tree_util.tree_map(lambda w, gw: w - 0.1 * gw, p, g)
-    g1 = jax.grad(loss)(p)
+    g1 = grad(p)
     norms1 = [float(jnp.abs(v).sum()) for v in jax.tree_util.tree_leaves(g1)]
     assert np.isfinite(norms1).all()
     assert sum(n > 0 for n in norms1) > len(norms1) * 2 // 3
@@ -118,7 +120,7 @@ def test_unet_bf16_compute():
                  num_res_blocks=1, norm_groups=4, dtype=jnp.bfloat16)
     x = jnp.ones((1, 8, 8, 3))
     temb = jnp.asarray([0.5])
-    params = model.init(jax.random.PRNGKey(0), x, temb)
-    out = model.apply(params, x, temb)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), x, temb)
+    out = jax.jit(model.apply)(params, x, temb)
     assert out.shape == (1, 8, 8, 3)
     assert bool(jnp.all(jnp.isfinite(out)))

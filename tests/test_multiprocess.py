@@ -18,6 +18,7 @@ import subprocess
 import sys
 
 import pytest
+from conftest import CHEAP_COMPILE_FLAGS
 
 WORKER = os.path.join(os.path.dirname(__file__), "multiprocess_worker.py")
 
@@ -28,9 +29,16 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def _run_phase(phase: str, port: int, ckpt_dir: str, timeout: int = 420):
+# A phase ends in 40-65 s beside five busy workers; a wedged one may not
+# take a third of the suite's limit.
+PHASE_TIMEOUT_S = 180
+
+
+def _run_phase(phase: str, port: int, ckpt_dir: str,
+               timeout: int = PHASE_TIMEOUT_S):
     env = os.environ.copy()
-    env.pop("XLA_FLAGS", None)          # worker sets its own device count
+    # the worker adds its own device count to the suite's compile flags
+    env["XLA_FLAGS"] = CHEAP_COMPILE_FLAGS
     env["JAX_PLATFORMS"] = "cpu"
     procs = [
         subprocess.Popen(

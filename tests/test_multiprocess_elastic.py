@@ -20,23 +20,29 @@ import sys
 import time
 
 import pytest
+from conftest import CHEAP_COMPILE_FLAGS
 
 WORKER = os.path.join(os.path.dirname(__file__), "multiprocess_worker.py")
 
 pytestmark = [pytest.mark.chaos, pytest.mark.multiprocess]
 
 
+# A phase ends in 40-65 s beside five busy workers; a wedged one may not
+# take a third of the suite's limit.
+PHASE_TIMEOUT_S = 180
+
+
 def _launch(phase: str, proc_id: int, ckpt_root: str):
     env = os.environ.copy()
-    env.pop("XLA_FLAGS", None)
+    env["XLA_FLAGS"] = CHEAP_COMPILE_FLAGS      # and one device a host
     env["JAX_PLATFORMS"] = "cpu"
     return subprocess.Popen(
         [sys.executable, WORKER, phase, str(proc_id), "0", ckpt_root],
         stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True, env=env)
 
 
-def _finish(proc, phase, i, timeout, expect_rc=0, expect_result=True):
-    out, err = proc.communicate(timeout=timeout)
+def _finish(proc, phase, i, expect_rc=0, expect_result=True):
+    out, err = proc.communicate(timeout=PHASE_TIMEOUT_S)
     assert proc.returncode == expect_rc, (
         f"{phase} proc {i} rc={proc.returncode} (wanted {expect_rc})\n"
         f"stdout:{out[-2000:]}\nstderr:{err[-2000:]}")
@@ -58,9 +64,9 @@ def test_kill_one_mid_run_survivor_shrinks_and_trains(tmp_path):
     procs = [_launch("elastic_kill", i, root) for i in range(2)]
     try:
         # rank 1 self-destructs with rc 17 and never prints a RESULT
-        _finish(procs[1], "elastic_kill", 1, timeout=420, expect_rc=17,
+        _finish(procs[1], "elastic_kill", 1, expect_rc=17,
                 expect_result=False)
-        r0 = _finish(procs[0], "elastic_kill", 0, timeout=420)
+        r0 = _finish(procs[0], "elastic_kill", 0)
     finally:
         for p in procs:
             if p.poll() is None:
@@ -101,8 +107,8 @@ def test_late_joiner_readmitted_and_worlds_commit_in_lockstep(tmp_path):
         time.sleep(5.0)     # rank 1 is genuinely LATE
         p1 = _launch("elastic_join", 1, root)
         procs.append(p1)
-        r0 = _finish(p0, "elastic_join", 0, timeout=420)
-        r1 = _finish(p1, "elastic_join", 1, timeout=420)
+        r0 = _finish(p0, "elastic_join", 0)
+        r1 = _finish(p1, "elastic_join", 1)
     finally:
         for p in procs:
             if p.poll() is None:
@@ -142,8 +148,8 @@ def test_divergent_anomaly_quorum_evicts_outlier(tmp_path):
     root = str(tmp_path / "elastic")
     procs = [_launch("elastic_quorum", i, root) for i in range(2)]
     try:
-        r0 = _finish(procs[0], "elastic_quorum", 0, timeout=420)
-        r1 = _finish(procs[1], "elastic_quorum", 1, timeout=420)
+        r0 = _finish(procs[0], "elastic_quorum", 0)
+        r1 = _finish(procs[1], "elastic_quorum", 1)
     finally:
         for p in procs:
             if p.poll() is None:

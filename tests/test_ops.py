@@ -66,9 +66,10 @@ def test_flash_attention_long_sequence_grad():
 
     flash = lambda q_, k_, v_: flash_attention(q_, k_, v_, None, 1024, 1024,
                                                True)
-    got = jax.grad(lambda *a: jnp.sum(flash(*a) * g), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(_xla_attention(*a) * g),
-                    (0, 1, 2))(q, k, v)
+    got = jax.jit(jax.grad(lambda *a: jnp.sum(flash(*a) * g),
+                           (0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(lambda *a: jnp.sum(_xla_attention(*a) * g),
+                            (0, 1, 2)))(q, k, v)
     for name, a, b_ in zip(("dq", "dk", "dv"), got, want):
         np.testing.assert_allclose(a, b_, rtol=5e-3, atol=5e-3, err_msg=name)
 
@@ -158,7 +159,7 @@ def test_fused_groupnorm_matches_flax_groupnorm():
     import flax.linen as nn
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 4, 4, 16))
     gn = nn.GroupNorm(num_groups=4)
-    params = gn.init(jax.random.PRNGKey(1), x)
+    params = jax.jit(gn.init)(jax.random.PRNGKey(1), x)
     ref = jax.nn.silu(gn.apply(params, x))
     out = fused_groupnorm_silu(
         x, params["params"]["scale"], params["params"]["bias"], groups=4,
@@ -271,8 +272,8 @@ def _gn_kernel_names(*args):
 
 
 def _gn_grads(fn_, x, scale, bias):
-    return jax.grad(lambda *a: jnp.sum(fn_(*a) ** 2),
-                    argnums=(0, 1, 2))(x, scale, bias)
+    return jax.jit(jax.grad(lambda *a: jnp.sum(fn_(*a) ** 2),
+                            argnums=(0, 1, 2)))(x, scale, bias)
 
 
 @pytest.mark.parametrize("apply_silu", [True, False])
@@ -293,8 +294,8 @@ def test_fused_groupnorm_sublane_batch_takes_the_xla_composition(
     def ref(x, s, z):
         return _xla_groupnorm_silu(x, s, z, 8, 1e-6, apply_silu)
 
-    np.testing.assert_array_equal(forced(x, scale, bias),
-                                  ref(x, scale, bias))
+    np.testing.assert_array_equal(jax.jit(forced)(x, scale, bias),
+                                  jax.jit(ref)(x, scale, bias))
     for a, r, name in zip(_gn_grads(forced, x, scale, bias),
                           _gn_grads(ref, x, scale, bias),
                           ("dx", "dscale", "dbias")):
@@ -330,8 +331,9 @@ def test_fused_groupnorm_small_batch_keeps_the_kernels(b):
     def ref(x, s, z):
         return _xla_groupnorm_silu(x, s, z, 8, 1e-6, True)
 
-    np.testing.assert_allclose(_gn_forced(x, scale, bias),
-                               ref(x, scale, bias), rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(jax.jit(_gn_forced)(x, scale, bias),
+                               jax.jit(ref)(x, scale, bias),
+                               rtol=1e-4, atol=1e-4)
     for a, r, name in zip(_gn_grads(_gn_forced, x, scale, bias),
                           _gn_grads(ref, x, scale, bias),
                           ("dx", "dscale", "dbias")):
@@ -358,9 +360,9 @@ def test_full_train_step_with_interpreted_kernels(monkeypatch):
     monkeypatch.setattr(fa, "_FORCE_LANES", fa.LANES)
 
     model = Unet(output_channels=1, emb_features=16,
-                 feature_depths=(8, 12),
-                 attention_configs=(None, {"heads": 2, "dim_head": 8,
-                                           "backend": "flash"}),
+                 feature_depths=(8,),
+                 attention_configs=({"heads": 2, "dim_head": 8,
+                                     "backend": "flash"},),
                  num_res_blocks=1, norm_groups=4)
 
     def apply_fn(params, x, t, cond):

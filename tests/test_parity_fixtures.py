@@ -247,10 +247,10 @@ def test_fid_inception_torch_forward_parity(tmp_path):
     np.savez(npz, **flat)
 
     fmodel = InceptionV3Features(resize_input=False)
-    variables = fmodel.init(jax.random.PRNGKey(0),
-                            np.zeros((1, 299, 299, 3), np.float32))
+    variables = jax.jit(fmodel.init)(jax.random.PRNGKey(0),
+                                     np.zeros((1, 299, 299, 3), np.float32))
     variables = load_inception_params(variables, str(npz))
-    got = np.asarray(fmodel.apply(variables, x))
+    got = np.asarray(jax.jit(fmodel.apply)(variables, x))
 
     assert got.shape == want.shape == (2, 2048)
     np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
@@ -289,6 +289,7 @@ class _TinyProcessor:
 
 @pytest.fixture(scope="module")
 def tiny_clip():
+    import jax
     from transformers import CLIPConfig, FlaxCLIPModel
 
     cfg = CLIPConfig(
@@ -300,7 +301,14 @@ def tiny_clip():
                            num_hidden_layers=2, num_attention_heads=2,
                            image_size=30, patch_size=10),
         projection_dim=12)
-    model = FlaxCLIPModel(cfg, seed=0)
+    # transformers initialises a Flax model eagerly, every primitive
+    # compiled and launched alone: the same `init_weights` under jit
+    init = FlaxCLIPModel.init_weights
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(FlaxCLIPModel, "init_weights",
+                   lambda self, rng, shape, params=None: jax.jit(
+                       lambda key: init(self, key, shape))(rng))
+        model = FlaxCLIPModel(cfg, seed=0)
     return model, _TinyProcessor()
 
 

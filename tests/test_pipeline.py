@@ -26,7 +26,8 @@ def blocks():
     key = jax.random.PRNGKey(0)
     x0 = jnp.zeros((1, TOKENS, FEAT))
     c0 = jnp.zeros((1, FEAT))
-    params = [block.init(jax.random.fold_in(key, i), x0, c0)["params"]
+    init = jax.jit(block.init)
+    params = [init(jax.random.fold_in(key, i), x0, c0)["params"]
               for i in range(N_BLOCKS)]
     stacked = stack_block_params(params)
 
@@ -95,10 +96,12 @@ def test_pipeline_no_remat_matches(blocks):
     block_fn, stacked = blocks
     mesh = create_mesh(axes={"data": 2, "pipe": 4})
     x, cond = _data(batch=8, seed=3)
-    with_remat = pipeline_blocks(block_fn, stacked, x, cond, mesh,
-                                 remat=True)
-    without = pipeline_blocks(block_fn, stacked, x, cond, mesh,
-                              remat=False)
+    # each side one compiled program: remat changes what the backward
+    # keeps, and XLA may fuse the two forwards differently
+    with_remat = jax.jit(lambda *a: pipeline_blocks(
+        block_fn, *a, mesh, remat=True))(stacked, x, cond)
+    without = jax.jit(lambda *a: pipeline_blocks(
+        block_fn, *a, mesh, remat=False))(stacked, x, cond)
     np.testing.assert_allclose(with_remat, without, rtol=1e-6)
 
 
@@ -118,9 +121,10 @@ def test_pipelined_dit_matches_plain_apply(order):
     x = jax.random.normal(key, (8, 16, 16, 3))
     t = jax.random.uniform(jax.random.fold_in(key, 1), (8,))
     txt = jax.random.normal(jax.random.fold_in(key, 2), (8, 4, FEAT))
-    params = dit.init(jax.random.fold_in(key, 3), x, t, txt)["params"]
+    params = jax.jit(dit.init)(
+        jax.random.fold_in(key, 3), x, t, txt)["params"]
 
-    want = dit.apply({"params": params}, x, t, txt)
+    want = jax.jit(dit.apply)({"params": params}, x, t, txt)
     mesh = create_mesh(axes={"data": 2, "pipe": 4})
     got = jax.jit(lambda p, x_, t_, c_: pipelined_dit_apply(
         dit, p, x_, t_, c_, mesh))(params, x, t, txt)

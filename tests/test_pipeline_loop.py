@@ -309,7 +309,8 @@ def test_step_timer_mark_sampled_and_validation():
 def test_goodput_closes_under_sampling_and_pipelining(mesh, tmp_path, rng):
     """Satellite 6: window-granularity attribution still closes — with
     sample_every=4 and pipeline_depth=2 the productive+badput account
-    sums to fit wall-clock within 5% on CPU."""
+    holds every window's row, lies within fit's wall-clock, and leaves
+    out only fit's prologue and epilogue."""
     tel = T.Telemetry.create(str(tmp_path / "tel"))
     with T.use_telemetry(tel):
         trainer = _make_trainer(mesh, tmp_path / "ck", telemetry=tel,
@@ -323,19 +324,41 @@ def test_goodput_closes_under_sampling_and_pipelining(mesh, tmp_path, rng):
     trainer.checkpointer.close()
     g = json.load(open(tmp_path / "tel" / "goodput.json"))
     attributed = g["productive_s"] + sum(g["badput_s"].values())
-    assert abs(attributed - wall) / wall < 0.05, (attributed, wall)
+    # Against its own clocks, under any load: every second of a window's
+    # row is in the account (a dropped window or a sampling badput that
+    # went unbooked shows here, whatever the machine is doing).
+    rows = [json.loads(x) for x in open(tmp_path / "tel" / "telemetry.jsonl")]
+    rows = [r for r in rows if r.get("type") == "step_phases"]
+    assert max(r["step"] for r in rows) == 12
+    in_rows = sum(r["wall"] for r in rows)
+    assert in_rows <= attributed * (1 + 1e-3), (in_rows, attributed)
+    # Against the test's clock around `fit`: never more than it, and short
+    # of it by host work outside every step. That remainder was held to
+    # 5%, which one of 30 runs beside five copies of itself broke (5.24%:
+    # 2.3335 s of 2.4627 s, PR 42); 15% as in tests/test_telemetry.py.
+    assert attributed <= wall * (1 + 1e-3), (attributed, wall)
+    assert (wall - attributed) / wall < 0.15, (attributed, wall)
     assert hist["goodput"]["productive_s"] > 0
 
 
 # -- warm-compile reclassification (satellite 2) ------------------------------
 
-def test_cold_compile_stays_badput_warm_becomes_productive(mesh, rng):
+def test_cold_compile_stays_badput_warm_becomes_productive(
+        mesh, rng, monkeypatch):
     """The admitted heuristic bug, fixed: a COLD first step (real jit
     compile, much slower than steady state) stays compile badput; a
     WARM first step (second fit of the same program — the same shape a
     persistent compilation cache produces across processes) is
     re-attributed productive."""
     from flaxdiff_tpu import resilience as R
+
+    # The rule compares two readings of a clock: a warm first step of
+    # 3-16 ms against twice a steady median of 2 ms failed 4 of 30 runs
+    # beside five copies of itself (PR 42; the parent alike). The claim is
+    # which way each first step goes, and a cold one here is a thousand
+    # steady steps: at 50 the rule still tells them apart and a
+    # descheduled thread does not.
+    monkeypatch.setattr(trainer_mod, "_COMPILE_RECLASS_RATIO", 50.0)
     ev = R.EventLog("warm")
     with R.use_event_log(ev):
         trainer = _make_trainer(mesh, log_every=5)

@@ -55,7 +55,7 @@ def test_cli_writes_registry(tmp_path):
         "--dataset", "synthetic", "--image_size", "16",
         "--batch_size", "16", "--architecture", "unet",
         "--model_config", json.dumps({
-            "feature_depths": [8, 16], "attention_configs": [None, None],
+            "feature_depths": [8], "attention_configs": [None],
             "emb_features": 16, "num_res_blocks": 1}),
         "--total_steps", "4", "--log_every", "2", "--warmup_steps", "2",
         "--save_every", "100", "--text_encoder", "none",
@@ -65,6 +65,15 @@ def test_cli_writes_registry(tmp_path):
     reg = ModelRegistry(str(tmp_path / "runs" / "registry.json"))
     assert "exp1" in reg.runs()
     assert reg.best_run("loss")["run"] == "exp1"
+
+    # the run's final save, of a model WITHOUT conditioning, builds a
+    # pipeline (tests/test_inference.py loads a conditional one)
+    from flaxdiff_tpu.inference import DiffusionInferencePipeline
+    pipe = DiffusionInferencePipeline.from_checkpoint(
+        str(tmp_path / "runs" / "exp1"))
+    out = pipe.generate_samples(num_samples=2, resolution=16,
+                                diffusion_steps=2, sampler="ddim")
+    assert out.shape == (2, 16, 16, 3)
 
 
 def test_registry_top_k_ranked(tmp_path):

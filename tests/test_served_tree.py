@@ -31,14 +31,19 @@ def _dit(dtype, layers=3):
           "patch_size": 4, "output_channels": 1}
     if dtype is not None:
         kw["dtype"] = dtype
-    params = build_model("simple_dit", **kw).init(
+    params = jax.jit(build_model("simple_dit", **kw).init)(
         jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 1)), jnp.zeros((1,)),
         None)
-    leaves, treedef = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(7), len(leaves))
-    params = treedef.unflatten(
-        [l + 0.05 * jax.random.normal(k, l.shape, l.dtype)
-         for l, k in zip(leaves, keys)])
+
+    @jax.jit
+    def perturbed(params):
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        keys = jax.random.split(jax.random.PRNGKey(7), len(leaves))
+        return treedef.unflatten(
+            [l + 0.05 * jax.random.normal(k, l.shape, l.dtype)
+             for l, k in zip(leaves, keys)])
+
+    params = perturbed(params)
     return DiffusionInferencePipeline.from_config(
         {"model": dict(kw, name="simple_dit"),
          "schedule": {"name": "cosine", "timesteps": 100},
@@ -113,15 +118,15 @@ def _cohere_bf16():
         layer_norm_eps=1e-5, norm_topk_prob=True, dtype="bfloat16",
         patch_size=2, output_channels=2)
     cond = jnp.zeros((1, 5, 12))
-    params = build_model("cohere2_moe_dn", **small).init(
+    params = jax.jit(build_model("cohere2_moe_dn", **small).init)(
         jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 2)), jnp.zeros((1,)),
         cond)
     pipe = DiffusionInferencePipeline.from_config(
         {"model": dict(small, name="cohere2_moe_dn"),
          "schedule": {"name": "cosine", "timesteps": 1000},
          "predictor": "v"},
-        params=jax.tree_util.tree_map(
-            lambda l: l.astype(jnp.bfloat16), params))
+        params=jax.jit(lambda p: jax.tree_util.tree_map(
+            lambda l: l.astype(jnp.bfloat16), p))(params))
     return pipe, jnp.zeros((1, 8, 8, 2)), cond
 
 

@@ -550,8 +550,9 @@ def tiny_pipe():
     }
     model = build_model("simple_dit", emb_features=32, num_heads=4,
                         num_layers=1, patch_size=4, output_channels=1)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 1)),
-                        jnp.zeros((1,)), None)
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 1)), jnp.zeros((1,)),
+        None)
     return DiffusionInferencePipeline.from_config(config, params=params)
 
 
@@ -661,7 +662,7 @@ def deep_pipe():
                                         build_model)
     kw = {"emb_features": 32, "num_heads": 4, "num_layers": 3,
           "patch_size": 4, "output_channels": 1}
-    params = build_model("simple_dit", **kw).init(
+    params = jax.jit(build_model("simple_dit", **kw).init)(
         jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 1)), jnp.zeros((1,)),
         None)
     leaves, treedef = jax.tree_util.tree_flatten(params)
@@ -1128,8 +1129,9 @@ def test_prompted_cfg_bit_identity():
     enc = HashTextEncoder.create(features=16, max_length=8)
     model = build_model("simple_dit", emb_features=32, num_heads=4,
                         num_layers=1, patch_size=4, output_channels=1)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 1)),
-                        jnp.zeros((1,)), jnp.asarray(enc([""])))
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 1)), jnp.zeros((1,)),
+        jnp.asarray(enc([""])))
     pipe = DiffusionInferencePipeline.from_config(
         {"model": {"name": "simple_dit", "emb_features": 32,
                    "num_heads": 4, "num_layers": 1, "patch_size": 4,

@@ -105,6 +105,7 @@ def test_plan_keys_never_collide():
 # Model forward contract (spatial + record_ref modes, 3 families)
 # ---------------------------------------------------------------------------
 
+@jax.jit
 def _perturb(params, scale=0.05, seed=7):
     # AdaLN-Zero blocks are exact identities at init (zero-init gates)
     leaves, treedef = jax.tree_util.tree_flatten(params)
@@ -144,19 +145,27 @@ def test_spatial_forward_contract(name, model, text, frac):
     mode-invariant."""
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 8, 8, 1))
     t = jnp.full((2,), 10.0)
-    params = _perturb(model.init(jax.random.PRNGKey(1), x, t, text))
+    params = _perturb(jax.jit(model.init)(jax.random.PRNGKey(1), x, t, text))
     split = model.cache_split_index(frac)
-    plain = model.apply(params, x, t, text)
-    out, taps, ref = model.apply(params, x, t, text,
-                                 cache_mode="record_ref",
-                                 cache_split=split)
+
+    def apply(mode=None, keep=None, **carries):
+        # mode / split / keep are Python values of the program, the
+        # carries its operands: one compiled program a call
+        static = {} if mode is None else dict(cache_mode=mode,
+                                              cache_split=split)
+        if keep is not None:
+            static["cache_keep"] = keep
+        return jax.jit(lambda p, c: model.apply(p, x, t, text, **static,
+                                                **c))(params, carries)
+
+    plain = apply()
+    out, taps, ref = apply("record_ref")
     np.testing.assert_array_equal(np.asarray(plain), np.asarray(out))
     L = taps.shape[1]
     # all-token spatial step ~= a full record step
-    o_all, taps_all, ref_all = model.apply(
-        params, x, t, text, cache_mode="spatial", cache_split=split,
-        cache_taps=jnp.zeros_like(taps), cache_ref=jnp.zeros_like(ref),
-        cache_keep=1.0)
+    o_all, taps_all, ref_all = apply(
+        "spatial", 1.0, cache_taps=jnp.zeros_like(taps),
+        cache_ref=jnp.zeros_like(ref))
     np.testing.assert_allclose(np.asarray(plain), np.asarray(o_all),
                                rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(ref_all),
@@ -164,10 +173,8 @@ def test_spatial_forward_contract(name, model, text, frac):
     # partial keep: finite output, exactly k carry slots rewritten
     # (the zero ref forces every token to score > 0, so selection is
     # the top-k of a strictly positive vector)
-    o_p, taps_p, ref_p = model.apply(
-        params, x, t, text, cache_mode="spatial", cache_split=split,
-        cache_taps=taps, cache_ref=jnp.zeros_like(ref),
-        cache_keep=0.5)
+    o_p, taps_p, ref_p = apply("spatial", 0.5, cache_taps=taps,
+                               cache_ref=jnp.zeros_like(ref))
     assert np.isfinite(np.asarray(o_p)).all()
     k = spatial_k(L, 0.5)
     changed_ref = np.any(np.asarray(ref_p) != 0.0, axis=(0, 2))
@@ -176,15 +183,16 @@ def test_spatial_forward_contract(name, model, text, frac):
                             axis=(0, 2))
     assert int(unchanged_taps.sum()) >= L - k
     # param tree is mode-invariant
-    p_sp = model.init(jax.random.PRNGKey(1), x, t, text,
-                      cache_mode="spatial", cache_split=split,
-                      cache_taps=taps, cache_ref=ref, cache_keep=0.5)
+    p_sp = jax.eval_shape(
+        lambda key: model.init(key, x, t, text, cache_mode="spatial",
+                               cache_split=split, cache_taps=taps,
+                               cache_ref=ref, cache_keep=0.5),
+        jax.random.PRNGKey(1))
     assert (jax.tree_util.tree_structure(p_sp)
             == jax.tree_util.tree_structure(params))
     # spatial requires both carries
     with pytest.raises(ValueError, match="spatial"):
-        model.apply(params, x, t, text, cache_mode="spatial",
-                    cache_split=split, cache_taps=taps)
+        apply("spatial", cache_taps=taps)
 
 
 # ---------------------------------------------------------------------------
@@ -204,8 +212,9 @@ def _pipe(num_layers=3, perturb=True):
     model = build_model("simple_dit", emb_features=32, num_heads=4,
                         num_layers=num_layers, patch_size=4,
                         output_channels=1)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 1)),
-                        jnp.zeros((1,)), None)
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 1)), jnp.zeros((1,)),
+        None)
     if perturb:
         params = _perturb(params)
     return DiffusionInferencePipeline.from_config(config, params=params)

@@ -394,8 +394,9 @@ def _data(rng, batch=8):
 def test_fit_telemetry_acceptance(mesh, tmp_path, rng):
     """ISSUE 3 acceptance: CPU fit with fault injection -> the JSONL
     stream holds per-step phase timings and pod aggregates (via
-    InMemoryTransport); productive+badput sums to fit wall-clock within
-    5%; diagnose_run renders; the trace file is valid Chrome JSON."""
+    InMemoryTransport); productive+badput holds every step row and
+    closes against fit's wall-clock; diagnose_run renders; the trace
+    file is valid Chrome JSON."""
     tel = T.Telemetry.create(str(tmp_path / "tel"),
                              transport=R.InMemoryTransport.make_world(1)[0])
     plan = R.FaultPlan(
@@ -433,10 +434,22 @@ def test_fit_telemetry_acceptance(mesh, tmp_path, rng):
     metrics = [r for r in recs if r.get("type") == "metrics"]
     assert metrics and metrics[-1]["goodput/fraction"] > 0
 
-    # goodput account closes against measured wall-clock within 5%
+    # The goodput account closes. Against its own clocks, under any load:
+    # every second of a step row is in it (what it holds beyond the rows
+    # is measured outside them: the final save, the rollback's restore).
     g = json.load(open(tmp_path / "tel" / "goodput.json"))
     attributed = g["productive_s"] + sum(g["badput_s"].values())
-    assert abs(attributed - wall) / wall < 0.05, (attributed, wall)
+    in_rows = sum(r["wall"] for r in steps)
+    assert in_rows <= attributed * (1 + 1e-3), (in_rows, attributed)
+    # Against the test's clock around `fit`: never more than it, and
+    # short of it by fit's prologue and epilogue only (thread start, the
+    # upload worker's join, the telemetry flush: host work outside every
+    # step). That remainder was held to 5% of a 3.4 s wall, which a
+    # 0.34 s stall beside six busy workers broke (9.97%, the largest of
+    # 19 loaded runs in PR 42; the driver's failed run at PR 41). It is
+    # held to 15% now; the rows above hold the account itself.
+    assert attributed <= wall * (1 + 1e-3), (attributed, wall)
+    assert (wall - attributed) / wall < 0.15, (attributed, wall)
     assert g["badput_s"]["compile"] > 0
     assert g["badput_s"]["checkpoint_commit"] > 0
     assert hist["goodput"]["productive_s"] > 0

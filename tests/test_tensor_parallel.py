@@ -98,9 +98,16 @@ def _batches(n, batch=8, seed=0):
     } for _ in range(n)]
 
 
+@pytest.fixture(scope="module")
+def tp_trainer(tp_mesh):
+    """The TP trainer whose shardings one test reads and which another
+    fits: built (and its state initialised) once."""
+    return _make_dit_trainer(tp_mesh)
+
+
 class TestTensorParallelTraining:
-    def test_dit_params_are_head_sharded(self, tp_mesh):
-        tr = _make_dit_trainer(tp_mesh)
+    def test_dit_params_are_head_sharded(self, tp_trainer):
+        tr = tp_trainer
         flat = {"/".join(str(getattr(p, "key", p)) for p in path): leaf
                 for path, leaf in
                 jax.tree_util.tree_leaves_with_path(tr.state.params)}
@@ -140,8 +147,8 @@ class TestTensorParallelTraining:
         np.testing.assert_allclose(losses_tp, losses_rep, rtol=2e-4,
                                    atol=1e-5)
 
-    def test_tp_loss_decreases(self, tp_mesh):
-        tr = _make_dit_trainer(tp_mesh)
+    def test_tp_loss_decreases(self, tp_trainer):
+        tr = tp_trainer
         hist = tr.fit(iter(_batches(40)), total_steps=40)
         assert np.isfinite(hist["final_loss"])
         assert hist["final_loss"] < hist["loss"][0]

@@ -33,7 +33,8 @@ KW = {"emb_features": 32, "num_heads": 4, "patch_size": 4,
 def _pipe(num_layers):
     """The tiny epsilon model on which batched equals solo to the bit
     (ROADMAP D9); with three blocks the cache plans can split it."""
-    params = build_model("simple_dit", num_layers=num_layers, **KW).init(
+    model = build_model("simple_dit", num_layers=num_layers, **KW)
+    params = jax.jit(model.init)(
         jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 1)), jnp.zeros((1,)),
         None)
     leaves, treedef = jax.tree_util.tree_flatten(params)

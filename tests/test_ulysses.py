@@ -59,9 +59,10 @@ def test_ulysses_gradients_match(seq_mesh, rng):
     k = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
 
-    g_u = jax.grad(lambda q: jnp.sum(
-        ulysses_self_attention(q, k, v, seq_mesh) ** 2))(q)
-    g_r = jax.grad(lambda q: jnp.sum(_reference_attention(q, k, v) ** 2))(q)
+    g_u = jax.jit(jax.grad(lambda q: jnp.sum(
+        ulysses_self_attention(q, k, v, seq_mesh) ** 2)))(q)
+    g_r = jax.jit(jax.grad(
+        lambda q: jnp.sum(_reference_attention(q, k, v) ** 2)))(q)
     np.testing.assert_allclose(np.asarray(g_u), np.asarray(g_r),
                                rtol=5e-4, atol=5e-4)
 
@@ -115,9 +116,10 @@ class TestDispatch:
                             backend="xla")
         x = jnp.asarray(rng.normal(size=(2, 16, 16, 3)), jnp.float32)
         t = jnp.full((2,), 500.0)
-        params = model_x.init(jax.random.PRNGKey(0), x, t, None)["params"]
+        params = jax.jit(model_x.init)(
+            jax.random.PRNGKey(0), x, t, None)["params"]
         with use_mesh(seq_mesh):
-            out_u = model_u.apply({"params": params}, x, t, None)
-        out_x = model_x.apply({"params": params}, x, t, None)
+            out_u = jax.jit(model_u.apply)({"params": params}, x, t, None)
+        out_x = jax.jit(model_x.apply)({"params": params}, x, t, None)
         np.testing.assert_allclose(np.asarray(out_u), np.asarray(out_x),
                                    rtol=1e-4, atol=1e-4)

@@ -110,9 +110,14 @@ def _compiled_sandwich(one_chip, monkeypatch, batch):
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
         jax.eval_shape(model.init, jax.random.PRNGKey(0),
                        jnp.zeros(x.shape, x.dtype)))
+    # compiled as the chip's programs are, not at the suite's cheap
+    # settings (conftest.py `CHEAP_COMPILE_FLAGS`): what is read here is
+    # what the TPU's compiler makes of the program
     instrs = entry_instructions(
         jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x)
-        .compile().as_text())
+        .compile(compiler_options={
+            "xla_backend_optimization_level": 3,
+            "xla_llvm_disable_expensive_passes": False}).as_text())
     kernels = sorted(n.rsplit(".", 1)[0] for n, (op, _, _) in instrs.items()
                      if op == "custom-call" and n.startswith("fdt_gn_silu_"))
     return instrs, kernels, batch * 64 * 64 * 128

@@ -18,8 +18,10 @@ import pytest
 
 sys.path.insert(0, ".")  # repo root (train.py lives there)
 
+# One resolution level: the tests here are about the artifact's round
+# trip, and every level is more to initialise, compile and checkpoint.
 TINY_MODEL = json.dumps({
-    "feature_depths": [8, 16], "attention_configs": [None, None],
+    "feature_depths": [8], "attention_configs": [None],
     "emb_features": 16, "num_res_blocks": 1,
 })
 
@@ -123,15 +125,36 @@ def fake_wandb(tmp_path, monkeypatch):
     return fake
 
 
-def test_wandb_resume_pulls_artifact_roundtrip(tmp_path, fake_wandb):
+@pytest.fixture(scope="module")
+def pushed(tmp_path_factory):
+    """ONE train-and-push run against a fake server of its own: (history,
+    its root). The two tests below start from a copy of what it left on
+    the server and in the registry, and never from its checkpoint
+    directory: a fresh host."""
+    root = tmp_path_factory.mktemp("pushed")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setitem(sys.modules, "wandb",
+                   make_fake_wandb(root / "wandb_server"))
+        hist = _run_cli(root, "--wandb_project", "proj")
+    return hist, root
+
+
+def _fresh_host(pushed, tmp_path):
+    """`tmp_path` as a host that has the server and the registry of the
+    pushed run, and no local checkpoint."""
+    shutil.copytree(pushed[1] / "wandb_server", tmp_path / "wandb_server",
+                    dirs_exist_ok=True)
+    shutil.copy(pushed[1] / "registry.json", tmp_path / "registry.json")
+
+
+def test_wandb_resume_pulls_artifact_roundtrip(tmp_path, fake_wandb, pushed):
     """Train+push, wipe local checkpoints, resume by run id: the model
     artifact is pulled back and training continues from the saved step."""
-    hist = _run_cli(tmp_path, "--wandb_project", "proj")
-    assert np.isfinite(hist["final_loss"])
+    assert np.isfinite(pushed[0]["final_loss"])
+    _fresh_host(pushed, tmp_path)
     # push_artifact stored the checkpoint dir server-side
     assert (tmp_path / "wandb_server" / "artifacts" / "resume-me").exists()
-
-    shutil.rmtree(tmp_path / "ckpt")   # simulate a fresh host
+    assert not (tmp_path / "ckpt").exists()
 
     hist2 = _run_cli(tmp_path, "--wandb_project", "proj",
                      "--wandb_resume", "run0", "--total_steps", "2")
@@ -147,8 +170,8 @@ def test_wandb_resume_pulls_artifact_roundtrip(tmp_path, fake_wandb):
     ck.close()
 
 
-def test_from_wandb_run_builds_pipeline(tmp_path, fake_wandb):
-    _run_cli(tmp_path, "--wandb_project", "proj")
+def test_from_wandb_run_builds_pipeline(tmp_path, fake_wandb, pushed):
+    _fresh_host(pushed, tmp_path)
     from flaxdiff_tpu.inference.pipeline import DiffusionInferencePipeline
     pipe = DiffusionInferencePipeline.from_wandb_run(
         "ent/proj/run0", cache_dir=str(tmp_path / "cache"))
