@@ -18,6 +18,7 @@ import numpy as np
 from ..inputs import DiffusionInputConfig
 from ..predictors import TRANSFORM_REGISTRY, PredictionTransform
 from ..samplers import SAMPLER_REGISTRY, DiffusionSampler, Sampler
+from ..samplers.common import rows_apart
 from ..schedulers import get_schedule
 from ..utils import RngSeq
 from .registry import build_model
@@ -232,19 +233,23 @@ class DiffusionInferencePipeline:
                 cache_fns = resolve_composed_fns(self.model, plan)
             else:
                 cache_fns = resolve_cache_fns(self.model, plan)
-            # a model with routed experts also returns its held picks by
-            # layer and expert: summed over the batch, they ride the
-            # serving programs as each row's tally (`moe/picks_*`)
-            picks_shape = getattr(self.model, "picks_shape", None)
-            if picks_shape is None:
+            # a model that counts what it does (routed experts: its held
+            # picks by layer and expert; a learned selection: the keys
+            # selected) also returns those counts by name: summed over
+            # the batch, they ride the serving programs as each row's
+            # tally (`moe/picks_*`, `dsa/keys_*`)
+            tally_shapes = getattr(self.model, "tally_shapes", None)
+            if tally_shapes is None:
                 model_fn = lambda p, x, t, c: self.model.apply(p, x, t, c)
             else:
                 def model_fn(p, x, t, c):
-                    raw, picks = self.model.apply(p, x, t, c,
-                                                  return_picks=True)
-                    return raw, picks.sum(axis=0)
+                    raw, tally = self.model.apply(p, x, t, c,
+                                                  return_tally=True)
+                    return raw, {k: v.sum(axis=0) for k, v in tally.items()}
+            if getattr(self.model, "serve_rows_apart", False):
+                model_fn = rows_apart(model_fn)
             self._sampler_cache[key] = DiffusionSampler(
-                model_fn=model_fn, tally_shape=picks_shape,
+                model_fn=model_fn, tally_shape=tally_shapes,
                 schedule=self.schedule, transform=self.transform,
                 autoencoder=self.autoencoder,
                 guidance_scale=guidance_scale,

@@ -10,6 +10,7 @@ from typing import Any, Dict, Tuple
 from ..models.brumby import BrumbyDenoiser
 from ..models.cohere2_moe import Cohere2MoEDenoiser
 from ..models.dit import SimpleDiT
+from ..models.glm_moe_dsa import GlmMoeDsaDenoiser
 from ..models.mmdit import HierarchicalMMDiT, SimpleMMDiT
 from ..models.ssm import HybridSSMAttentionDiT
 from ..models.unet import Unet
@@ -28,6 +29,7 @@ MODEL_REGISTRY: Dict[str, Any] = {
     "unet_3d": UNet3D,
     "cohere2_moe_dn": Cohere2MoEDenoiser,
     "brumby_dn": BrumbyDenoiser,
+    "glm_moe_dsa_dn": GlmMoeDsaDenoiser,
 }
 
 # Suffix -> constructor kwarg toggles (reference inference/utils.py:168-180).

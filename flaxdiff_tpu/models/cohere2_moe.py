@@ -40,13 +40,13 @@ share of a deployment that divides each layer by expert parallelism.
 Out: final LayerNorm, `Dense(hidden -> patch^2 * output_channels)` on
 the patch tokens, unpatchify.
 
-`return_picks=True` also returns the held picks by layer and expert,
-`[B, layers, num_experts]` int32: what the serving path counts
-(`moe/picks_*`, docs/OBSERVABILITY.md).
+`return_tally=True` also returns what the serving path counts
+(`moe/picks_*`, docs/OBSERVABILITY.md), by name: `picks`, the held
+picks by layer and expert, `[B, layers, num_experts]` int32.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -55,21 +55,8 @@ import jax.numpy as jnp
 from ..ops import moe
 from ..ops.attention import attend
 from ..typing import Dtype
-from .trunk import Kernel, SequenceEmbed, patch_head, sequence_tokens
-
-
-def rope_interleaved(x: jax.Array, theta: float) -> jax.Array:
-    """`rope_gptj` over [B, S, H, D]: the pairs (x[2i], x[2i+1]) rotated
-    by position * theta^(-2i/D), position = index in the sequence."""
-    s, d = x.shape[1], x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = jnp.outer(jnp.arange(s, dtype=jnp.float32), inv)     # [S, D/2]
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-    x32 = x.astype(jnp.float32)
-    even, odd = x32[..., 0::2], x32[..., 1::2]
-    out = jnp.stack([even * cos - odd * sin, even * sin + odd * cos],
-                    axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
+from .trunk import (Kernel, SequenceEmbed, patch_head, rope_interleaved,
+                    sequence_tokens)
 
 
 def _norm(eps: float, param_dtype, name: str) -> nn.Module:
@@ -225,10 +212,10 @@ class Cohere2MoEDenoiser(nn.Module):
             ("full_attention",) * self.num_hidden_layers)
 
     @property
-    def picks_shape(self) -> Tuple[int, int]:
-        """(layers, experts held): the shape of one evaluation's held
-        picks, which the serving path carries with a row."""
-        return self.num_hidden_layers, self.num_experts
+    def tally_shapes(self) -> Dict[str, Tuple[int, ...]]:
+        """What one evaluation counts, by name, which the serving path
+        carries with a row: the held picks by layer and expert."""
+        return {"picks": (self.num_hidden_layers, self.num_experts)}
 
     def routed_picks(self, sample_shape, context_tokens: int) -> int:
         """Token-picks the routers make in ONE evaluation of one sample
@@ -237,10 +224,18 @@ class Cohere2MoEDenoiser(nn.Module):
                                  context_tokens)
         return tokens * self.num_experts_per_tok * self.num_hidden_layers
 
+    def tally_counters(self, tally, evaluations: int, sample_shape,
+                       context_tokens: int) -> Dict[str, int]:
+        """The telemetry counters a finished request adds, from its
+        tally over `evaluations` evaluations."""
+        return moe.pick_counters(
+            tally["picks"],
+            evaluations * self.routed_picks(sample_shape, context_tokens))
+
     @nn.compact
     def __call__(self, x: jax.Array, temb: jax.Array,
                  textcontext: Optional[jax.Array] = None,
-                 return_picks: bool = False):
+                 return_tally: bool = False):
         tokens = SequenceEmbed(self.hidden_size, self.patch_size,
                                self.dtype, name="embed")(x, temb,
                                                          textcontext)
@@ -268,6 +263,6 @@ class Cohere2MoEDenoiser(nn.Module):
         out = patch_head(
             tokens, _norm(self.layer_norm_eps, jnp.float32, "final_norm"),
             x.shape, self.patch_size, self.output_channels)
-        if return_picks:
-            return out, jnp.stack(picks, axis=1)
+        if return_tally:
+            return out, {"picks": jnp.stack(picks, axis=1)}
         return out

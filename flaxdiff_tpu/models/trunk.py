@@ -1,6 +1,7 @@
 """What the decoder-layer denoiser trunks share (`models/cohere2_moe.py`,
-`models/brumby.py`): the token layout `[time token; text tokens; patch
-tokens]`, its embedding, a bare weight, and the patch head.
+`models/brumby.py`, `models/glm_moe_dsa.py`): the token layout `[time
+token; text tokens; patch tokens]`, its embedding, a bare weight, the
+interleaved rotation, and the patch head.
 
 The time token is the sinusoidal timestep embedding (`TIME_FEATURES`
 features) through the two-layer `TimeProjection` to `hidden_size`; text
@@ -34,6 +35,21 @@ def sequence_tokens(sample_shape, patch_size: int,
     the text tokens, the patch tokens."""
     return 1 + context_tokens + (sample_shape[0] // patch_size) * (
         sample_shape[1] // patch_size)
+
+
+def rope_interleaved(x: jax.Array, theta: float) -> jax.Array:
+    """Interleaved RoPE (`rope_gptj`, `rope_interleave`) over
+    [B, S, H, D]: the pairs (x[2i], x[2i+1]) rotated by position *
+    theta^(-2i/D), position = index in the sequence."""
+    s, d = x.shape[1], x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = jnp.outer(jnp.arange(s, dtype=jnp.float32), inv)     # [S, D/2]
+    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    even, odd = x32[..., 0::2], x32[..., 1::2]
+    out = jnp.stack([even * cos - odd * sin, even * sin + odd * cos],
+                    axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
 
 
 class Kernel(nn.Module):

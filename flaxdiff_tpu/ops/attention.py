@@ -88,10 +88,13 @@ def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                    scale: Optional[float] = None,
                    force_fp32_for_softmax: bool = True,
                    causal: bool = False,
-                   window: Optional[int] = None) -> jax.Array:
+                   window: Optional[int] = None,
+                   key_mask: Optional[jax.Array] = None) -> jax.Array:
     """Plain XLA attention over [B, L, H, D]; softmax in f32 for bf16
     stability. `k` / `v` may carry fewer heads (query head i reads
-    key/value head i // (H / KV)); `causal` / `window` as `_self_mask`."""
+    key/value head i // (H / KV)); `causal` / `window` as `_self_mask`;
+    `key_mask` [B, Lq, Lk] (data): the keys each query reads, in every
+    head, on top of them."""
     orig_dtype = q.dtype
     b, lq, h, d = q.shape
     kv = k.shape[2]
@@ -105,6 +108,8 @@ def _xla_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         preferred_element_type=jnp.float32) * scale
     if keep is not None:
         logits = jnp.where(keep, logits, -1e30)
+    if key_mask is not None:
+        logits = jnp.where(key_mask[:, None], logits, -1e30)
     if force_fp32_for_softmax:
         probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     else:
@@ -540,3 +545,32 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, *, bhld: bool = False,
     return fn(q, k, v, backend=backend, scale=scale,
               force_fp32_for_softmax=force_fp32_for_softmax,
               causal=causal, window=window)
+
+
+def attend_selected(q: jax.Array, k: jax.Array, v: jax.Array,
+                    key_mask: jax.Array, *,
+                    k_shared: Optional[jax.Array] = None,
+                    backend: str = "auto",
+                    scale: Optional[float] = None) -> jax.Array:
+    """Self-attention under a key mask that is data (a learned selection
+    of keys, `ops/dsa.py`): `q`, `k`, `v` [B, H, Lp, D] -> [B, H, Lp, D];
+    `key_mask` [B, L, L] (bool, L <= Lp) says which keys each query
+    reads, in every head, and is the whole mask; `k_shared` [B, Lp, D]
+    is a key part every head shares (`ops/flash_attention.py`
+    `flash_attention_selected`). The kernel on a TPU (or under
+    `FLAXDIFF_FLASH_INTERPRET=1`) from 128 tokens on, on one device; the
+    XLA composition elsewhere (no `shard_map` carries the mask yet: no
+    cell on more than one chip holds such a model)."""
+    from .flash_attention import (_selected_composition,
+                                  flash_attention_selected)
+    from ..parallel.context import get_active_mesh
+    mesh = get_active_mesh()
+    one_device = mesh is None or mesh.devices.size <= 1
+    if backend == "flash" and not attention_backend_available("flash"):
+        raise _flash_unavailable()
+    if (backend in ("auto", "flash") and one_device
+            and key_mask.shape[1] >= 128
+            and attention_backend_available("flash")):
+        return flash_attention_selected(q, k, v, key_mask, k_shared, scale,
+                                        None, None, _flash_interpret())
+    return _selected_composition(q, k, v, key_mask, k_shared, scale)

@@ -21,6 +21,13 @@ first-party implementation covering the whole autodiff path. Design:
   a fused XLA reduce before the kernels and streamed in lane-replicated
   like lse (computing it in-kernel cost an O-block HBM stream + VPU
   reduce per grid step in BOTH kernels).
+- A key mask that is DATA (`flash_attention_selected`; a learned
+  selection of keys, `ops/dsa.py`): the same forward kernel takes an int8
+  mask block a tile, one mask a batch entry for all its heads, and, by
+  scalar prefetch, each tile's count of read keys and the block whose
+  copies serve it, so a tile that reads no key costs neither work nor
+  copy. Its operands are head-major and may come padded to the blocks
+  (`padded_length`), so that nothing is copied on the way in.
 - kv-length masking via lane iota, so cross-attention (e.g. CLIP kv_len=77)
   works after padding to the lane-aligned block. Padded q rows are exact:
   zero-padded q gives finite lse, zero-padded dO zeroes their gradient
@@ -29,6 +36,7 @@ first-party implementation covering the whole autodiff path. Design:
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -81,18 +89,30 @@ def _mask_block_range(qi, block_q: int, block_k: int, num_kb: int,
     return first, last
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
-                scale: float, kv_len: int, block_k: int,
+def _fwd_kernel(*refs, scale: float, kv_len: int, block_k: int,
                 group: int = 1, causal: bool = False,
-                window: Optional[int] = None):
-    # rest = (lse_ref?, m_scr, l_scr, acc_scr); lse is only emitted on the
-    # custom_vjp fwd path — the plain primal skips the residual write.
+                window: Optional[int] = None, mask_heads: int = 0,
+                shared_key: bool = False):
+    # refs = (q, k, v, o, lse?, m_scr, l_scr, acc_scr); lse is only
+    # emitted on the custom_vjp fwd path — the plain primal skips the
+    # residual write. With a key mask that is data (`mask_heads`, the
+    # heads that share a batch entry's mask): (counts, fetch) scalar
+    # prefetch first, the mask block after v and, with `shared_key`, the
+    # key part every head shares after that.
+    counts_ref = mask_ref = ks_ref = None
+    if mask_heads:
+        counts_ref, _, q_ref, k_ref, v_ref, mask_ref, *rest = refs
+        if shared_key:
+            ks_ref, *rest = rest
+        o_ref, *rest = rest
+    else:
+        q_ref, k_ref, v_ref, o_ref, *rest = refs
     if len(rest) == 4:
         lse_ref, m_scr, l_scr, acc_scr = rest
     else:
         lse_ref, (m_scr, l_scr, acc_scr) = None, rest
     masked = causal or window is not None
-    qi = pl.program_id(1) if masked else None
+    qi = pl.program_id(1) if masked or mask_heads else None
     ki = pl.program_id(2)
     num_kb = pl.num_programs(2)
 
@@ -108,6 +128,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         v = v_ref[0]
         d = q.shape[-1]
         block_q = q.shape[-2]
+        if shared_key:      # it lies in lanes the heads' own part leaves 0
+            k = k + ks_ref[0]
         if group > 1:
             # the query heads that share this key/value head, stacked
             # on the rows: [group, block_q, d] -> [group * block_q, d]
@@ -117,9 +139,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         # math is f32.
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        kv_idx = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        keep = kv_idx < kv_len
+        if mask_heads:
+            # the mask says everything: which keys each query reads, the
+            # padding past either length among what it does not
+            keep = mask_ref[0].astype(jnp.int32) != 0
+        else:
+            kv_idx = ki * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            keep = kv_idx < kv_len
         if masked:
             row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             if group > 1:       # the row's position inside its head
@@ -138,7 +165,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         m_curr = jnp.max(s, axis=1, keepdims=True)   # [rows, 1]
         m_next = jnp.maximum(m_prev, m_curr)         # lane-replicated
         p = jnp.exp(s - _bcast(m_next, block_k))
-        if masked:
+        if masked or mask_heads:
             # a row whose every key so far is masked has m = NEG_INF and
             # exp(0) = 1 on each of them: they weigh nothing
             p = jnp.where(keep, p, 0.0)
@@ -150,7 +177,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
                             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
 
-    if masked:
+    if mask_heads:
+        # a key block none of whose keys this query block reads is
+        # skipped on the count the kernel was handed (its copies too: the
+        # index maps follow `fetch` to the nearest block that is read)
+        tile = ((pl.program_id(0) // mask_heads) * pl.num_programs(1)
+                + qi) * num_kb + ki
+        pl.when(counts_ref[tile] > 0)(_block)
+    elif masked:
         # blocks wholly outside the mask are skipped (their copies too:
         # the index maps clamp to the same range)
         first, last = _mask_block_range(qi, o_ref.shape[-2], block_k,
@@ -321,9 +355,34 @@ def _block_sizes(lq: int, lk: int, block_q: Optional[int],
     return bq, bk
 
 
+def _mask_tiles(key_mask, lq_pad: int, lk_pad: int, bq: int, bk: int):
+    """The key mask [B, Lq, Lk] (bool) as the kernel takes it: (int8
+    [B, lq_pad, lk_pad], zeros in the padding; counts [B * nq * nk]
+    int32, the keys each (query block, key block) tile reads; fetch, of
+    the same shape: the key block whose copies serve a tile: its own
+    where it reads any key, else the nearest read block before it in the
+    query block's row, else the first after, so that a skipped tile
+    moves nothing)."""
+    b = key_mask.shape[0]
+    m = jnp.pad(key_mask.astype(jnp.int8),
+                ((0, 0), (0, lq_pad - key_mask.shape[1]),
+                 (0, lk_pad - key_mask.shape[2])))
+    nq, nk = lq_pad // bq, lk_pad // bk
+    # keys first (the minor axis splits in place), then queries
+    counts = jnp.sum(m.reshape(b, nq * bq, nk, bk), axis=3, dtype=jnp.int32)
+    counts = jnp.sum(counts.reshape(b, nq, bq, nk), axis=2)
+    ids = jnp.arange(nk, dtype=jnp.int32)
+    live = counts > 0
+    before = jax.lax.cummax(jnp.where(live, ids, -1), axis=2)
+    after = jax.lax.cummin(jnp.where(live, ids, nk), axis=2, reverse=True)
+    fetch = jnp.where(before >= 0, before, jnp.where(after < nk, after, 0))
+    return m, counts.reshape(-1), fetch.reshape(-1)
+
+
 def _fwd_impl(q3, k3, v3, scale, block_q, block_k, interpret,
               save_residuals: bool = False, causal: bool = False,
-              window: Optional[int] = None):
+              window: Optional[int] = None, key_mask=None,
+              k_shared=None):
     """Forward over [B*H, L, D] operands (the layout the kernel grids
     over natively — BHLD callers reach here with FREE reshapes, BLHD
     callers pay one transpose in _to_bh).
@@ -334,15 +393,27 @@ def _fwd_impl(q3, k3, v3, scale, block_q, block_k, interpret,
     (static): query i sees keys j with j <= i / i - window < j; blocks
     wholly outside the mask are skipped. With none of the three the
     kernel, its grid and its block maps are what they were before them
-    (static Python branches)."""
+    (static Python branches).
+
+    `key_mask` [B, Lq, Lk] (bool, DATA): which keys each query reads,
+    one mask a batch entry shared by its B*H / B heads; it is the whole
+    mask (`causal` / `window` off, one head count). The kernel is handed
+    each tile's count of read keys ahead of the grid (scalar prefetch,
+    as `ops/moe.py`'s `tile_group`) and skips the tiles that read
+    none. `k_shared` [B, Lk, D]: a key part all of a batch entry's heads
+    share, added to each head's key block in the kernel."""
     group = q3.shape[1] if q3.ndim == 4 else 1
     masked = causal or window is not None
+    if key_mask is not None:
+        assert group == 1 and not masked and not save_residuals, (
+            "a key mask that is data is the whole mask of a call with "
+            "one head count; its backward is the XLA composition's")
     bh, lq, d = q3.shape[0], q3.shape[-2], q3.shape[-1]
     kv_len = k3.shape[1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     bq, bk = _block_sizes(lq, kv_len, block_q, block_k, interpret,
-                          group, masked)
+                          group, masked or key_mask is not None)
     lanes = _FORCE_LANES or (1 if interpret else LANES)
 
     qb = _pad_to(q3, q3.ndim - 2, bq)
@@ -378,26 +449,55 @@ def _fwd_impl(q3, k3, v3, scale, block_q, block_k, interpret,
     kernel_kwargs = dict(scale=scale, kv_len=kv_len, block_k=bk)
     if group > 1 or masked:
         kernel_kwargs.update(group=group, causal=causal, window=window)
+    in_specs = [pl.BlockSpec(q_block, q_map),
+                pl.BlockSpec((1, bk, d), kv_map),
+                pl.BlockSpec((1, bk, d), kv_map)]
+    scratch_shapes = [
+        pltpu.VMEM((group * bq, lanes), jnp.float32),   # running max
+        pltpu.VMEM((group * bq, lanes), jnp.float32),   # running sum
+        pltpu.VMEM((group * bq, d), jnp.float32),       # accumulator
+    ]
+    operands, grid_args = (qb, kb, vb), dict(
+        grid=grid, in_specs=in_specs, out_specs=out_specs,
+        scratch_shapes=scratch_shapes)
+    if key_mask is not None:
+        heads, nq = bh // key_mask.shape[0], lq_pad // bq
+        mask, counts, fetch = _mask_tiles(key_mask, lq_pad, lk_pad, bq, bk)
+        kernel_kwargs.update(mask_heads=heads)
+
+        def fetched(bh, qi, ki, fetch):
+            return fetch[((bh // heads) * nq + qi) * num_kb + ki]
+
+        read_map = lambda bh, qi, ki, counts, fetch: (
+            bh, fetched(bh, qi, ki, fetch), 0)
+        row_map = lambda bh, qi, ki, counts, fetch: (bh, qi, 0)
+        in_specs = [pl.BlockSpec(q_block, row_map),
+                    pl.BlockSpec((1, bk, d), read_map),
+                    pl.BlockSpec((1, bk, d), read_map),
+                    pl.BlockSpec((1, bq, bk),
+                                 lambda bh, qi, ki, counts, fetch: (
+                                     bh // heads, qi,
+                                     fetched(bh, qi, ki, fetch)))]
+        operands = (counts, fetch, qb, kb, vb, mask)
+        if k_shared is not None:
+            kernel_kwargs.update(shared_key=True)
+            in_specs.append(pl.BlockSpec(
+                (1, bk, d), lambda bh, qi, ki, counts, fetch: (
+                    bh // heads, fetched(bh, qi, ki, fetch), 0)))
+            operands += (_pad_to(k_shared, 1, bk),)
+        grid_args = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
+            out_specs=[pl.BlockSpec(q_block, row_map)],
+            scratch_shapes=scratch_shapes))
     res = pl.pallas_call(
         functools.partial(_fwd_kernel, **kernel_kwargs),
         name="fdt_flash_fwd",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(q_block, q_map),
-            pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bk, d), kv_map),
-        ],
-        out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((group * bq, lanes), jnp.float32),   # running max
-            pltpu.VMEM((group * bq, lanes), jnp.float32),   # running sum
-            pltpu.VMEM((group * bq, d), jnp.float32),       # accumulator
-        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(qb, kb, vb)
+        **grid_args,
+    )(*operands)
     return (res[0], res[1]) if save_residuals else (res[0], None)
 
 
@@ -581,6 +681,86 @@ def _bwd(scale, block_q, block_k, interpret, causal, window, res, g):
 
 
 flash_attention.defvjp(_fwd, _bwd)
+
+
+def padded_length(l: int) -> int:
+    """`l` rounded up to what `flash_attention_selected` would pad a
+    sequence of `l` tokens to at its default blocks."""
+    bq, bk = _block_sizes(l, l, None, None, False, 1, True)
+    both = bq * bk // math.gcd(bq, bk)
+    return -(-l // both) * both
+
+
+def _selected_composition(q, k, v, key_mask, k_shared, scale):
+    """`flash_attention_selected` as the XLA composition."""
+    from .attention import _xla_attention
+    lk = key_mask.shape[-1]
+    to_blhd = lambda a, n: a[:, :, :n].transpose(0, 2, 1, 3)
+    k4 = to_blhd(k, lk)
+    if k_shared is not None:
+        k4 = k4 + k_shared[:, :lk, None]
+    out = _xla_attention(to_blhd(q, key_mask.shape[1]), k4, to_blhd(v, lk),
+                         scale=scale, key_mask=key_mask)
+    out = out.transpose(0, 2, 1, 3)
+    return _pad_to(out, 2, q.shape[2]) if out.shape[2] < q.shape[2] else out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def flash_attention_selected(q: jax.Array, k: jax.Array, v: jax.Array,
+                             key_mask: jax.Array,
+                             k_shared: Optional[jax.Array] = None,
+                             scale: Optional[float] = None,
+                             block_q: Optional[int] = None,
+                             block_k: Optional[int] = None,
+                             interpret: bool = False) -> jax.Array:
+    """Self-attention under a key mask that is DATA: `q`, `k`, `v`
+    [B, H, Lp, D] (the kernel's own layout: a free reshape away from
+    [B*H, Lp, D]) -> [B, H, Lp, D].
+
+    `key_mask` [B, L, L] (bool; a learned selection of keys,
+    `ops/dsa.py`), L <= Lp: query i of batch entry b reads key j where
+    `key_mask[b, i, j]`, in every head. It is the WHOLE mask (it holds
+    the causal half if the call has one); the softmax runs over exactly
+    those keys; a block of keys that a block of queries reads none of is
+    skipped, copies and all, on per-tile counts taken from the mask and
+    handed to the kernel ahead of its grid (scalar prefetch, as
+    `ops/moe.py`'s `tile_group`). Rows L..Lp of the operands are
+    padding nobody reads (the output's are zeros): a caller that makes
+    its operands at a multiple of the blocks (`padded_length`) spares
+    the kernel's own padding, a copy of each of them.
+    `k_shared` [B, Lp, D]: a key part every head shares (a latent
+    attention's one rotated part, in the lanes the heads' own parts
+    leave zero), added in the kernel so that it is never copied once a
+    head. The forward is `fdt_flash_fwd`; the backward is the XLA
+    composition's (no cell trains it)."""
+    return _selected(q, k, v, key_mask, k_shared, scale, block_q, block_k,
+                     interpret)
+
+
+def _selected(q, k, v, key_mask, k_shared, scale, block_q, block_k,
+              interpret):
+    b, h, lp, d = q.shape
+    flat = lambda a: a.reshape(b * h, lp, d)
+    out, _ = _fwd_impl(flat(q), flat(k), flat(v), scale, block_q, block_k,
+                       interpret, key_mask=key_mask, k_shared=k_shared)
+    return out[:, :lp].reshape(b, h, lp, d)
+
+
+def _selected_fwd(q, k, v, key_mask, k_shared, scale, block_q, block_k,
+                  interpret):
+    return (_selected(q, k, v, key_mask, k_shared, scale, block_q, block_k,
+                      interpret), (q, k, v, key_mask, k_shared))
+
+
+def _selected_bwd(scale, block_q, block_k, interpret, res, g):
+    q, k, v, key_mask, k_shared = res
+    _, vjp = jax.vjp(lambda q, k, v, ks: _selected_composition(
+        q, k, v, key_mask, ks, scale), q, k, v, k_shared)
+    dq, dk, dv, dks = vjp(g)
+    return dq, dk, dv, None, dks
+
+
+flash_attention_selected.defvjp(_selected_fwd, _selected_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))

@@ -37,7 +37,7 @@ which would read every expert's weights once per row.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -57,17 +57,33 @@ def _on_tpu() -> bool:
 # ---------------------------------------------------------------------------
 
 def route(h32: jax.Array, router_kernel: jax.Array, top_k: int,
-          norm_topk_prob: bool = True) -> Tuple[jax.Array, jax.Array]:
+          norm_topk_prob: bool = True,
+          select_bias: Optional[jax.Array] = None,
+          scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
     """Sigmoid routing over ALL the layer's experts: (idx [N, K] int32,
     weights [N, K] float32). The product and the sigmoid run in float32
     (`Precision.HIGHEST`: a v5e's default rounds float32 operands to
-    bfloat16); the K largest scores, normalised over the K."""
+    bfloat16); the K largest scores, normalised over the K.
+
+    `select_bias` [E] (`topk_method` `noaux_tc`: a held correction bias)
+    moves which K are picked and nothing else: the K largest of score +
+    bias, weighted by their SCORES. `scale` (`routed_scaling_factor`)
+    multiplies the weights after the normalisation. Without either the
+    call is what it was before them."""
     logits = jnp.dot(h32.astype(jnp.float32),
                      router_kernel.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    vals, idx = jax.lax.top_k(jax.nn.sigmoid(logits), top_k)
+    scores = jax.nn.sigmoid(logits)
+    if select_bias is None:
+        vals, idx = jax.lax.top_k(scores, top_k)
+    else:
+        _, idx = jax.lax.top_k(scores + select_bias.astype(jnp.float32),
+                               top_k)
+        vals = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk_prob:
         vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+    if scale != 1.0:
+        vals = vals * scale
     return idx.astype(jnp.int32), vals
 
 
@@ -82,6 +98,18 @@ def held_picks(idx: jax.Array, first: int, held: int
     counts = jnp.sum(jax.nn.one_hot(local, held, dtype=jnp.int32),
                      axis=tuple(range(local.ndim)))
     return local, counts
+
+
+def pick_counters(held, routed: int) -> Dict[str, int]:
+    """The `moe/picks_*` telemetry counters a finished request adds
+    (docs/OBSERVABILITY.md), from its held picks by layer and expert
+    `held` [layers, experts] (host integers) and the token-picks
+    `routed` its routers made over its evaluations, wherever the experts
+    are (host arithmetic): the picks that landed here, and the largest
+    expert's of each layer."""
+    return {"moe/picks_routed": int(routed),
+            "moe/picks_held": int(held.sum()),
+            "moe/picks_hottest": int(held.max(axis=-1).sum())}
 
 
 # ---------------------------------------------------------------------------

@@ -16,7 +16,7 @@ schedule family instead).
 """
 from __future__ import annotations
 
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import flax.struct
 import jax
@@ -234,6 +234,30 @@ def _run_steps(step, carry, xs, steps):
     return jax.lax.scan(scan_step, carry, (xs, jnp.arange(size)))[0]
 
 
+def rows_apart(model_fn: Callable) -> Callable:
+    """`model_fn(params, *args)` as a `vmap` over `args` evaluates it:
+    one entry after another (`lax.map`), not side by side. The serving
+    round programs `vmap` a row's turn over the round's rows; a model
+    whose activations for ONE row's batch already fill the chip (rows of
+    thousands of tokens at wide heads) asks for this
+    (`serve_rows_apart`), and loses nothing where a row's tokens fill
+    the matrix units alone. `params` stay whole (a batch of them has no
+    such reading); no reverse mode: the serving path takes none."""
+    apart = jax.custom_batching.custom_vmap(model_fn)
+
+    @apart.def_vmap
+    def one_at_a_time(axis_size, in_batched, params, *args):
+        if any(jax.tree_util.tree_leaves(in_batched[0])):
+            raise NotImplementedError("rows_apart: a batch of parameters")
+        args = jax.tree_util.tree_map(
+            lambda a, batched: a if batched else jnp.broadcast_to(
+                a, (axis_size,) + a.shape), args, tuple(in_batched[1:]))
+        out = jax.lax.map(lambda a: apart(params, *a), args)
+        return out, jax.tree_util.tree_map(lambda _: True, out)
+
+    return apart
+
+
 class DiffusionSampler:
     """Builds and caches jitted scan programs for trajectory generation.
 
@@ -250,7 +274,7 @@ class DiffusionSampler:
                  timestep_spacing: str = "linear",
                  cache_plan: Optional[Any] = None,
                  cache_fns: Optional[Tuple[Callable, Callable]] = None,
-                 tally_shape: Optional[Tuple[int, ...]] = None):
+                 tally_shape: Optional[Dict[str, Tuple[int, ...]]] = None):
         # ONE trace of the network for every program of this sampler:
         # the solo scan and each serving bucket's round programs
         # call it with the same per-row shapes (the batch axis
@@ -277,11 +301,12 @@ class DiffusionSampler:
         self.cache_plan = cache_plan
         self.cache_fns = cache_fns
         # a model that counts what it does (routed experts: the picks
-        # that landed on the experts held): `model_fn` then returns
-        # (raw, tally), an int32 array of this shape summed over the
-        # batch it was given, and the serving programs carry each row's
-        # sum over its evaluations (`make_chunk_program`). None: no
-        # program differs by an operand.
+        # that landed on the experts held; a learned selection: the keys
+        # selected): `model_fn` then returns (raw, tally), a small NAMED
+        # SET of int32 arrays of these shapes ({name: shape}), each
+        # summed over the batch it was given, and the serving programs
+        # carry each row's sums over its evaluations
+        # (`make_chunk_program`). None: no program differs by an operand.
         self.tally_shape = tally_shape
         self._compiled = {}
         self._taps_specs = {}
@@ -843,11 +868,14 @@ class DiffusionSampler:
 
         x_n, s_n = self.sampler.step(remembering, x, pair[0], pair[1], key,
                                      state, self.schedule, index)
-        counted = sum(seen)
+        counted = jax.tree_util.tree_map(lambda *n: sum(n), *seen) \
+            if seen else None
         if terminal is not None:
             x_n = jnp.where(terminal, first[0], x_n)
             if seen:
-                counted = jnp.where(terminal, seen[0], counted)
+                counted = jax.tree_util.tree_map(
+                    lambda one, every: jnp.where(terminal, one, every),
+                    seen[0], counted)
         return x_n, s_n, counted
 
     def make_chunk_program(self, round_steps: int):
@@ -882,10 +910,11 @@ class DiffusionSampler:
                                           by all rows; never a Python int
           state    [R, ...] pytree        per-row sampler state carry
                                           (init_state at admission)
-          tally    [R, *tally_shape] int32  a counting model's sums so
-                                          far (zeros at admission); None
-                                          and absent from the result
-                                          for any other model
+          tally    {name: [R, *shape] int32}  a counting model's sums
+                                          so far by name (`tally_shape`;
+                                          zeros at admission); None and
+                                          absent from the result for any
+                                          other model
           term     [R] int32              the turn of this round that is
                                           the row's terminal denoise, -1
                                           for none; data like `n_act`,
@@ -912,7 +941,9 @@ class DiffusionSampler:
                     s_n = jax.tree_util.tree_map(
                         lambda a, b: jnp.where(active, a, b), s_n, s)
                     if seen:
-                        tl_c = jnp.where(active, tl_c + counted, tl_c)
+                        tl_c = jax.tree_util.tree_map(
+                            lambda n, more: jnp.where(active, n + more, n),
+                            tl_c, counted)
                     return x_n, rng, s_n, tl_c
 
                 out = _run_steps(step, (x_r, key, st, tl), row_pairs, steps)

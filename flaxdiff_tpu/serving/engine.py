@@ -147,7 +147,7 @@ def _handoff_program(autoencoder):
     Returns the whole bucket, `[bucket, num_samples, *sample_shape]` (a
     cut to the real rows would be a program per row count); for rows
     that carry a `tally` (a counting model): (that, the rows' tallies
-    `[bucket, *tally_shape]`)."""
+    `{name: [bucket, *shape]}`)."""
     def sampler_handoff(rows):
         out = _stacked(rows)
         x0 = out["x"]
@@ -202,8 +202,9 @@ class RequestState:
         self.taps = taps
         self.codes = codes
         self.ref = ref
-        # a counting model's sums over this row's evaluations (routed
-        # experts: held picks by layer and expert): host zeros at
+        # a counting model's sums over this row's evaluations, a small
+        # named set (routed experts: `picks`, held picks by layer and
+        # expert; a learned selection: `keys`): host zeros at
         # admission, then a device carry like `x`; `tally_out` is
         # (the handed-off batch's tallies on the device, this row's
         # index), which the completion thread fetches with the samples
@@ -251,6 +252,17 @@ class SamplerProgramEngine:
         # scheduler's request tracer right after the call. Host-side
         # dicts only; None until the first round.
         self.last_round_info: Optional[Dict[str, Any]] = None
+
+    @property
+    def rows_apart(self) -> bool:
+        """Does a round evaluate this pipeline's model ONE ROW AT A TIME
+        (the model's `serve_rows_apart`, `samplers/common.py`
+        `rows_apart`)? A turn of b rows then costs b turns of one, so a
+        wide round buys no throughput and holds every row until the
+        round's end: the scheduler serves such a model in rounds of its
+        smallest bucket (`ServingScheduler.batch_buckets`)."""
+        return bool(getattr(getattr(self.pipeline, "model", None),
+                            "serve_rows_apart", False))
 
     # -- keys -----------------------------------------------------------------
     def _plan_for(self, req: SampleRequest):
@@ -481,8 +493,9 @@ class SamplerProgramEngine:
             group=group, x=x, rng=loop_key, state=state, pairs=pairs,
             cond=cond, uncond=uncond, plan=plan,
             flags=flags, taps=taps, codes=codes, ref=ref,
-            tally=(None if ds.tally_shape is None
-                   else np.zeros(ds.tally_shape, np.int32)))
+            tally=(None if ds.tally_shape is None else
+                   {name: np.zeros(shape, np.int32)
+                    for name, shape in ds.tally_shape.items()}))
         if miss:            # both: they share the group's key
             compile_s = time.perf_counter() - t0
             st.compile_ms = compile_s * 1e3
@@ -649,7 +662,7 @@ class SamplerProgramEngine:
         *sample_shape]` device array whose first `len(rows)` entries
         are the rows' samples in row order, compile seconds). A counting
         model's tallies come out of the same launch and stay on the
-        device, on the rows (`tally_out`), for `count_picks`."""
+        device, on the rows (`tally_out`), for `count_tally`."""
         group = rows[0].group
         ds = self._sampler_for(rows[0].req)
         tallied = ds.tally_shape is not None
@@ -674,29 +687,27 @@ class SamplerProgramEngine:
                                     program, (carries,), compile_s)
         return out, compile_s
 
-    def count_picks(self, rows: List[RequestState], fetch) -> None:
-        """Add a handed-off batch's routed-expert picks to the telemetry
-        counters (docs/OBSERVABILITY.md): called by the completion
-        thread where it fetches the samples, with its `fetch`, for rows
-        that carry a `tally_out` (a model with routed experts).
-        `moe/picks_routed` is host
-        arithmetic: the token-picks the router made over the request's
-        evaluations, wherever the experts are; `moe/picks_held` the
-        picks that landed on the experts held here, `moe/picks_hottest`
-        the largest expert's of each layer."""
-        held = fetch(rows[0].tally_out[0])      # [bucket, layers, experts]
+    def count_tally(self, rows: List[RequestState], fetch) -> None:
+        """Add a handed-off batch's tallies to the telemetry counters
+        (docs/OBSERVABILITY.md): called by the completion thread where it
+        fetches the samples, with its `fetch`, for rows that carry a
+        `tally_out` (a counting model). What a tally's names mean is the
+        model's to say (`tally_counters`: the `moe/picks_*` three of a
+        model with routed experts, `dsa/keys_*` of a learned selection),
+        from the row's sums and the evaluations its request asked for
+        (host arithmetic: steps and the terminal one, twice guided)."""
+        tallies = fetch(rows[0].tally_out[0])   # {name: [bucket, ...]}
         count = self.telemetry.counter
         for r in rows:
-            n = held[r.tally_out[1]]
             evals = (r.nfe + 1) * int(r.req.num_samples) * (
                 2 if r.uncond is not None and r.req.guidance_scale > 0
                 else 1)
-            count("moe/picks_routed").inc(
-                evals * self.pipeline.model.routed_picks(
-                    r.x.shape[1:],
-                    0 if r.cond is None else r.cond.shape[-2]))
-            count("moe/picks_held").inc(int(n.sum()))
-            count("moe/picks_hottest").inc(int(n.max(axis=-1).sum()))
+            added = self.pipeline.model.tally_counters(
+                {name: n[r.tally_out[1]] for name, n in tallies.items()},
+                evals, r.x.shape[1:],
+                0 if r.cond is None else r.cond.shape[-2])
+            for name, n in added.items():
+                count(name).inc(n)
 
     # -- program-cache pre-warming -------------------------------------------
     def prewarm(self, reqs: List[SampleRequest], round_steps: int,

@@ -21,7 +21,10 @@ Architecture (docs/SERVING.md):
   its mates ride. Rows that complete exit mid-group ("continuous
   admission"): a 10-NFE request batched with a 50-NFE one returns after
   its own 11 turns, and its slot is refilled from the queue at the
-  next round.
+  next round. A model whose rows a round evaluates one at a time
+  (`serve_rows_apart`) is served in rounds of the smallest bucket
+  (`batch_buckets`): first come, first served, each result out when
+  its own last turn ends.
 - Completed rows are handed (still device-resident, dispatch still
   async) to a **completion thread** that performs the host syncs of a
   result — `_block_until_ready` + `_device_get`, module-level seams so
@@ -117,9 +120,11 @@ _ROUNDS_AHEAD = 2
 
 
 def _device_get(x):
+    """`x` (an array, or a pytree of them: a batch's named tallies) on
+    the host."""
     import jax
     import numpy as np
-    return np.asarray(jax.device_get(x))
+    return jax.tree_util.tree_map(np.asarray, jax.device_get(x))
 
 
 def _now() -> float:
@@ -313,9 +318,25 @@ class ServingScheduler:
         an engine rebuild after device loss replays the same prewarm,
         so rebuilt traffic is also retrace-free."""
         self._prewarm_args = (list(reqs), self.config.round_steps,
-                              self.config.batch_buckets)
+                              self.batch_buckets)
         return self.engine.prewarm(reqs, self.config.round_steps,
-                                   self.config.batch_buckets)
+                                   self.batch_buckets)
+
+    @property
+    def batch_buckets(self) -> Tuple[int, ...]:
+        """The buckets this scheduler's rounds are padded to: the
+        configuration's, or the smallest of them alone where a round
+        evaluates the engine's model one row at a time
+        (`SamplerProgramEngine.rows_apart`). There a turn of b rows costs
+        b turns of one, so a wide round gives the throughput of a narrow
+        one and returns every row at the END of the round: in rounds of
+        the smallest bucket the queue is served first come, first
+        served, a request's turns run back to back, and its result
+        leaves when ITS last turn ends (PERF.md section 6, PR 43)."""
+        buckets = self.config.batch_buckets
+        if getattr(self.engine, "rows_apart", False):
+            return (min(buckets),)
+        return buckets
 
     def start(self) -> "ServingScheduler":
         if not self._started:
@@ -799,16 +820,16 @@ class ServingScheduler:
         self._shed_expired_locked()
         gk = self._pick_group_locked()
         if gk is None:
-            return None, [], cfg.batch_buckets
+            return None, [], self.batch_buckets
         now = _now()
         # brownout tier 3: shrink rounds to the smallest bucket
         # (smaller blast radius + memory footprint) before any
         # shedding happens
         tier = (self.brownout.tier(len(self._queue), cfg.max_queue, now)
                 if self.brownout is not None else 0)
-        buckets = cfg.batch_buckets
+        buckets = self.batch_buckets
         if tier >= 3:
-            buckets = (min(cfg.batch_buckets),)
+            buckets = (min(buckets),)
         max_bucket = max(buckets)
         rows = self._shed_expired_active(self._active.pop(gk, []), now)
         if len(rows) > max_bucket:
@@ -1003,10 +1024,10 @@ class ServingScheduler:
                               args={"rows": len(rows)}):
                     _block_until_ready(out)
                     host = _device_get(out)
-                    # a routed-expert model's picks left the device in
-                    # the same launch: no read-back of the dispatch thread
+                    # a counting model's tallies left the device in the
+                    # same launch: no read-back of the dispatch thread
                     if getattr(rows[0], "tally_out", None) is not None:
-                        self.engine.count_picks(rows, _device_get)
+                        self.engine.count_tally(rows, _device_get)
             except (KeyboardInterrupt, SystemExit):
                 raise
             except BaseException as e:  # noqa: BLE001 — fault barrier

@@ -84,8 +84,10 @@ def test_forward_equals_the_plain_reference(window):
     model = build_model("cohere2_moe_dn", **dict(SMALL, sliding_window=window))
     params = _seeded(model)
     x, t, text = _inputs()
-    got, picks = jax.jit(lambda p: model.apply(
-        {"params": p}, x, t, text, return_picks=True))(params)
+    got, tally = jax.jit(lambda p: model.apply(
+        {"params": p}, x, t, text, return_tally=True))(params)
+    picks = tally["picks"]
+    assert set(tally) == set(model.tally_shapes) == {"picks"}
     with jax.default_matmul_precision("highest"):
         want = jax.jit(lambda p: ref.forward(
             p, _ref_cfg(sliding_window=window), x, t, text))(params)

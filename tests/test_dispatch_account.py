@@ -29,7 +29,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WAITS = ("pace", "wait", "backpressure")
 ACCOUNT = ("loop",) + WAITS + ("work",)
 GENERATE_CELLS = ("dit-xl-2.generate", "command-a-plus.generate-few",
-                  "brumby-14b.generate-fewer")
+                  "brumby-14b.generate-fewer", "glm-5.2.generate-fewer-1024")
 NEW_METRICS = {
     "serve.host_ms_per_round": ("serving/dispatch_work_ms",
                                 "serving/rounds"),

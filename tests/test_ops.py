@@ -386,3 +386,137 @@ def test_full_train_step_with_interpreted_kernels(monkeypatch):
         (8, 16, 16, 1)).astype(np.float32)}
     loss = trainer.train_step(trainer.put_batch(batch))
     assert np.isfinite(float(jax.device_get(loss)))
+
+
+# -- a key mask that is data (ops/dsa.py's selection) ------------------------
+
+def _selection_mask(case, b, l):
+    """[B, L, L] bool. `scattered`: a random half of each causal row (no
+    tile of the kernel's grid is empty below the diagonal: block skipping
+    idle); `blocks`: keys 16..47 read by nobody and keys 64..79 by the
+    last queries alone (whole tiles empty, in the middle of a query
+    block's row and at its start: block skipping at work); `ragged`: a
+    length that is no multiple of a block."""
+    causal = jnp.tril(jnp.ones((l, l), bool))
+    keep = causal & (jax.random.uniform(jax.random.PRNGKey(9), (b, l, l))
+                     < 0.5) | jnp.eye(l, dtype=bool)
+    if case == "blocks":
+        keep = keep.at[:, :, 16:48].set(False).at[:, :80, 64:80].set(False)
+        keep = keep | jnp.eye(l, dtype=bool) & (jnp.arange(l) < 16)[:, None]
+        keep = keep.at[:, 16:, 0].set(True)      # no query reads no key
+    return keep
+
+
+@pytest.mark.parametrize("case,l", [("scattered", 96), ("blocks", 96),
+                                    ("ragged", 75)])
+def test_flash_with_a_data_key_mask_in_interpret_mode(case, l):
+    from flaxdiff_tpu.ops.flash_attention import (
+        _mask_tiles, _selected_composition, flash_attention_selected)
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    # [B, H, L, D] operands, plus a key part every head shares
+    q, k, v = (jax.random.normal(ks[i], (2, 3, l, 32)) for i in range(3))
+    shared = jax.random.normal(ks[4], (2, l, 32))
+    cot = jax.random.normal(ks[3], q.shape)
+    keep = _selection_mask(case, 2, l)
+    n = -(-l // 16)
+    _, counts, fetch = _mask_tiles(keep, n * 16, n * 16, 16, 16)
+    counts, fetch = counts.reshape(2, n, n), fetch.reshape(2, n, n)
+    assert int(counts.sum()) == int(keep.sum())
+    empty_below = int(((counts == 0) & np.tril(np.ones((n, n), bool))).sum())
+    assert (empty_below > 0) == (case == "blocks")
+    # a skipped tile's copies are those of the nearest tile that is read
+    live = np.asarray(counts) > 0
+    assert (np.asarray(fetch)[live] == np.broadcast_to(
+        np.arange(n), live.shape)[live]).all()
+    assert live[np.arange(2)[:, None, None], np.arange(n)[None, :, None],
+                np.asarray(fetch)].all()
+    if case == "blocks":
+        # what a skipped tile holds is never touched: keys nobody reads
+        # may hold anything at all
+        k = k.at[:, :, 16:48].set(jnp.nan)
+        v = v.at[:, :, 16:48].set(jnp.inf)
+
+    def flash(q, k, v, shared):
+        return flash_attention_selected(q, k, v, keep, shared, None, 16, 16,
+                                        True)
+
+    def xla(q, k, v, shared):
+        dead = ~keep.any(axis=1)[:, None, :, None]
+        return _selected_composition(q, jnp.where(dead, 0.0, k),
+                                     jnp.where(dead, 0.0, v), keep, shared,
+                                     None)
+
+    got = jax.jit(flash)(q, k, v, shared)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got, jax.jit(xla)(q, k, v, shared), atol=2e-5,
+                               rtol=2e-5)
+    if case != "blocks":
+        # the backward is the composition's, the mask's cotangent nobody's
+        g = jax.jit(jax.grad(lambda *a: (flash(*a) * cot).sum(),
+                             argnums=(0, 1, 2, 3)))(q, k, v, shared)
+        w = jax.jit(jax.grad(lambda *a: (xla(*a) * cot).sum(),
+                             argnums=(0, 1, 2, 3)))(q, k, v, shared)
+        for a, b in zip(g, w):
+            np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5)
+
+
+def test_operands_made_at_the_padded_length_need_no_padding():
+    """`padded_length`: operands a caller makes at a multiple of the
+    blocks go to the kernel as they are; the rows past the mask's length
+    are padding nobody reads, and come back zero."""
+    from flaxdiff_tpu.ops.attention import attend_selected
+    from flaxdiff_tpu.ops.flash_attention import (
+        _selected_composition, flash_attention_selected, padded_length)
+    assert padded_length(4174) == 4608 and padded_length(150) == 256
+    l, lp = 150, padded_length(150)
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    q, k, v = (jax.random.normal(ks[i], (1, 2, lp, 16)) for i in range(3))
+    keep = _selection_mask("scattered", 1, l)
+    got = flash_attention_selected(q, k, v, keep, None, None, None, None,
+                                   True)
+    want = _selected_composition(q, k, v, keep, None, None)
+    assert got.shape == want.shape == (1, 2, lp, 16)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    assert float(jnp.abs(got[:, :, l:]).max()) == 0.0
+    # off the TPU and with no interpreter the dispatcher composes
+    np.testing.assert_allclose(attend_selected(q, k, v, keep), want,
+                               atol=1e-6)
+    # a causal mask as data is causal attention
+    causal = jnp.tril(jnp.ones((1, lp, lp), bool))
+    np.testing.assert_allclose(
+        attend_selected(q, k, v, causal).transpose(0, 2, 1, 3),
+        _xla_attention(*(a.transpose(0, 2, 1, 3) for a in (q, k, v)),
+                       causal=True), atol=1e-5)
+
+
+def test_route_without_bias_and_scale_is_the_call_it_was():
+    from flaxdiff_tpu.ops import moe
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    h = jax.random.normal(ks[0], (50, 32))
+    w = jax.random.normal(ks[1], (32, 16)) / 5
+
+    def before(h32, router_kernel, top_k, norm_topk_prob=True):
+        logits = jnp.dot(h32.astype(jnp.float32),
+                         router_kernel.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        vals, idx = jax.lax.top_k(jax.nn.sigmoid(logits), top_k)
+        if norm_topk_prob:
+            vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+        return idx.astype(jnp.int32), vals
+
+    for norm in (True, False):
+        want = jax.jit(lambda: before(h, w, 3, norm))()
+        for got in (jax.jit(lambda: moe.route(h, w, 3, norm))(),
+                    jax.jit(lambda: moe.route(h, w, 3, norm, None, 1.0))(),
+                    jax.jit(lambda: moe.route(
+                        h, w, 3, norm, jnp.zeros((16,)), 1.0))()):
+            assert (np.asarray(got[0]) == np.asarray(want[0])).all()
+            assert (np.asarray(got[1]) == np.asarray(want[1])).all()
+    # a bias moves the selection only; the scale the weights only
+    bias = jnp.zeros((16,)).at[5].set(10.0)
+    idx, vals = moe.route(h, w, 3, True, bias, 2.5)
+    assert bool((idx == 5).any(axis=1).all())
+    scores = jax.nn.sigmoid(h @ w)
+    picked = jnp.take_along_axis(scores, idx, axis=1)
+    np.testing.assert_allclose(vals, 2.5 * picked / picked.sum(1)[:, None],
+                               rtol=1e-5)

@@ -119,6 +119,40 @@ def _fake_scheduler(tel=None, **cfg_kwargs):
                                  autostart=False)
 
 
+@pytest.mark.parametrize("apart", [False, True])
+def test_rows_served_apart_ride_rounds_of_the_smallest_bucket(apart):
+    """An engine whose model a round evaluates one row at a time
+    (`rows_apart`) gets rounds of the smallest bucket: first come, first
+    served, a request's turns back to back in ONE round (its length is
+    inside `round_steps`), its result out when its own last turn ends.
+    Any other engine's rounds are as wide as the queue fills them."""
+    eng, sched = _fake_scheduler(round_steps=8)
+    eng.rows_apart = apart
+    warmed = []
+    eng.prewarm = lambda reqs, steps, buckets: warmed.append(buckets) or {}
+    assert sched.batch_buckets == ((1,) if apart else (1, 2, 4))
+    reqs = [SampleRequest(resolution=8, diffusion_steps=nfe, seed=7 + i)
+            for i, nfe in enumerate((4, 2, 3, 2, 2, 3))]
+    sched.prewarm(reqs[:1])
+    assert warmed == [sched.batch_buckets]
+    order, finalize = [], eng.finalize
+    eng.finalize = lambda rows, bucket: (
+        order.extend(r.req.seed for r in rows), finalize(rows, bucket))[1]
+    futs = [sched.submit(r) for r in reqs]
+    sched.start()
+    outs = [f.result(timeout=10) for f in futs]
+    sched.close()
+    assert all(np.all(o.samples == float(r.seed))
+               for r, o in zip(reqs, outs))
+    if apart:
+        assert eng.advance_calls == [(1, 1, 8)] * len(reqs)
+        assert eng.finalize_calls == [(1, 1)] * len(reqs)
+        assert [o.rounds for o in outs] == [1] * len(reqs)
+        assert order == [r.seed for r in reqs]
+    else:
+        assert eng.advance_calls == [(4, 4, 8), (2, 2, 8)]
+
+
 def test_scheduler_completes_all_and_routes_results():
     tel = Telemetry(enabled=False)
     eng, sched = _fake_scheduler(tel)

@@ -180,17 +180,18 @@ def test_a_cached_plans_terminal_turn_is_a_refresh(deep_pipe, kind):
 class CountingModel:
     """A model that counts the rows it is given, as the routed model
     counts its held picks: one 'pick' a row of every evaluation."""
-    picks_shape = (1, 1)
+    tally_shapes = {"picks": (1, 1)}
 
-    def apply(self, params, x, t, cond, return_picks=False):
+    def apply(self, params, x, t, cond, return_tally=False):
         raw = x * params["w"] + 0.01 * jnp.mean(cond, axis=(1, 2))[
             :, None, None, None]
-        if return_picks:
-            return raw, jnp.ones((x.shape[0], 1, 1), jnp.int32)
+        if return_tally:
+            return raw, {"picks": jnp.ones((x.shape[0], 1, 1), jnp.int32)}
         return raw
 
-    def routed_picks(self, sample_shape, cond_tokens):
-        return 1
+    def tally_counters(self, tally, evaluations, sample_shape, cond_tokens):
+        from flaxdiff_tpu.ops.moe import pick_counters
+        return pick_counters(tally["picks"], evaluations)
 
 
 @pytest.fixture(scope="module")
@@ -215,7 +216,7 @@ def test_a_counting_models_tally_is_the_rows_evaluations(
     was given for it: `per_step` evaluations a step and exactly ONE for
     the terminal turn (Heun's second evaluation on that turn is a lane
     of the batched call and is not charged), twice under guidance.
-    `count_picks`' `(nfe + 1)` evaluations a row are therefore exact
+    `count_tally`'s `(nfe + 1)` evaluations a row are therefore exact
     for a one-evaluation sampler."""
     tel = Telemetry(enabled=False)
     nfes = (2, 3, 4, 3, 2)
