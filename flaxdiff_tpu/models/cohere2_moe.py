@@ -42,7 +42,9 @@ the patch tokens, unpatchify.
 
 `return_tally=True` also returns what the serving path counts
 (`moe/picks_*`, docs/OBSERVABILITY.md), by name: `picks`, the held
-picks by layer and expert, `[B, layers, num_experts]` int32.
+picks by layer and expert, `[B, layers, num_experts]` int32, and
+`fitted` [B, layers] int32, those of them the routed layer's first pass
+served (`ops/moe.py`).
 """
 from __future__ import annotations
 
@@ -69,6 +71,7 @@ def _norm(eps: float, param_dtype, name: str) -> nn.Module:
 class Cohere2MoEBlock(nn.Module):
     """One parallel block: y = x + Attn(LN(x)) + MoE(LN(x)) over a
     float32 residual stream. Returns (y, held picks [B, num_experts]
+    int32, the held picks the routed layer's first pass served [B]
     int32)."""
 
     head_dim: int
@@ -89,7 +92,8 @@ class Cohere2MoEBlock(nn.Module):
     backend: str = "auto"
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    def __call__(self, x: jax.Array
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
         dt = self.dtype or jnp.float32      # products AND the weights held
         b, s, d = x.shape
         h32 = _norm(self.layer_norm_eps, dt, "norm")(x)
@@ -127,10 +131,10 @@ class Cohere2MoEBlock(nn.Module):
             idx.reshape(b, -1, idx.shape[-1]))
         local = local.reshape(idx.shape)
         tokens = tokens32.astype(dt)
-        routed = moe.routed_experts(
+        routed, fitted = moe.routed_experts(
             tokens, local, weights, kernel("experts_gate", held, d, f),
             kernel("experts_up", held, d, f),
-            kernel("experts_down", held, f, d))
+            kernel("experts_down", held, f, d), self.router_experts)
         gate = jnp.einsum("nd,edf->enf", tokens,
                           kernel("shared_experts_gate", n_sh, d, f),
                           preferred_element_type=jnp.float32)
@@ -142,7 +146,8 @@ class Cohere2MoEBlock(nn.Module):
                             kernel("shared_experts_down", n_sh, f, d),
                             preferred_element_type=jnp.float32) / n_sh
         m = (routed + shared).reshape(b, s, d)
-        return x + a.astype(jnp.float32) + m, picks
+        return (x + a.astype(jnp.float32) + m, picks,
+                jnp.sum(fitted.reshape(b, -1), axis=1))
 
 
 class Cohere2MoEDenoiser(nn.Module):
@@ -214,8 +219,10 @@ class Cohere2MoEDenoiser(nn.Module):
     @property
     def tally_shapes(self) -> Dict[str, Tuple[int, ...]]:
         """What one evaluation counts, by name, which the serving path
-        carries with a row: the held picks by layer and expert."""
-        return {"picks": (self.num_hidden_layers, self.num_experts)}
+        carries with a row: the held picks by layer and expert, and by
+        layer those the routed layer's first pass served."""
+        return {"picks": (self.num_hidden_layers, self.num_experts),
+                "fitted": (self.num_hidden_layers,)}
 
     def routed_picks(self, sample_shape, context_tokens: int) -> int:
         """Token-picks the routers make in ONE evaluation of one sample
@@ -230,7 +237,8 @@ class Cohere2MoEDenoiser(nn.Module):
         tally over `evaluations` evaluations."""
         return moe.pick_counters(
             tally["picks"],
-            evaluations * self.routed_picks(sample_shape, context_tokens))
+            evaluations * self.routed_picks(sample_shape, context_tokens),
+            tally["fitted"])
 
     @nn.compact
     def __call__(self, x: jax.Array, temb: jax.Array,
@@ -239,9 +247,9 @@ class Cohere2MoEDenoiser(nn.Module):
         tokens = SequenceEmbed(self.hidden_size, self.patch_size,
                                self.dtype, name="embed")(x, temb,
                                                          textcontext)
-        picks = []
+        picks, fitted = [], []
         for i, kind in enumerate(self._kinds()):
-            tokens, n = Cohere2MoEBlock(
+            tokens, n, fit = Cohere2MoEBlock(
                 head_dim=self.head_dim,
                 num_attention_heads=self.num_attention_heads,
                 num_key_value_heads=self.num_key_value_heads,
@@ -260,9 +268,11 @@ class Cohere2MoEDenoiser(nn.Module):
                 dtype=self.dtype, backend=self.backend,
                 name=f"layer_{i}")(tokens)
             picks.append(n)
+            fitted.append(fit)
         out = patch_head(
             tokens, _norm(self.layer_norm_eps, jnp.float32, "final_norm"),
             x.shape, self.patch_size, self.output_channels)
         if return_tally:
-            return out, {"picks": jnp.stack(picks, axis=1)}
+            return out, {"picks": jnp.stack(picks, axis=1),
+                         "fitted": jnp.stack(fitted, axis=1)}
         return out

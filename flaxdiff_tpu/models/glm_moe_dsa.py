@@ -58,8 +58,10 @@ of a deployment that divides each layer by expert parallelism.
 
 `return_tally=True` also returns what the serving path counts
 (docs/OBSERVABILITY.md): `picks` [B, sparse layers, n_routed_experts]
-int32, the held picks by layer and expert, and `keys` [B, layers]
-int32, the (query, key) pairs of the mask each layer's core was handed.
+int32, the held picks by layer and expert, `fitted` [B, sparse layers]
+int32, those of them the routed layer's first pass served
+(`ops/moe.py`), and `keys` [B, layers] int32, the (query, key) pairs of
+the mask each layer's core was handed.
 
 What the source's `config.json` does not give and is assumed (the
 benchmark's configuration lists each with its referent): the two
@@ -133,7 +135,8 @@ def _rotate_last(x: jax.Array, n: int, theta: float) -> jax.Array:
 
 class GlmMoeDsaBlock(nn.Module):
     """One sequential pre-norm block over a float32 residual stream:
-    `(x, keep) -> (y, keep, held picks [B, n_routed_experts] or None)`;
+    `(x, keep) -> (y, keep, (held picks [B, n_routed_experts], those the
+    routed layer's first pass served [B]) or None)`;
     `keep` [B, T, T] bool is the selection, made here on a `full` layer
     and handed on unchanged by a `shared` one."""
 
@@ -260,12 +263,14 @@ class GlmMoeDsaBlock(nn.Module):
         local, picks = jax.vmap(
             lambda i: moe.held_picks(i, self.first_expert, held))(
             idx.reshape(b, -1, idx.shape[-1]))
-        routed = moe.routed_experts(
+        routed, fitted = moe.routed_experts(
             tokens32.astype(dt), local.reshape(idx.shape), weights,
             Kernel((held, d, f), dt, name="experts_gate")(),
             Kernel((held, d, f), dt, name="experts_up")(),
-            Kernel((held, f, d), dt, name="experts_down")())
-        return routed.reshape(b, t, d), picks
+            Kernel((held, f, d), dt, name="experts_down")(),
+            self.router_experts)
+        return (routed.reshape(b, t, d),
+                (picks, jnp.sum(fitted.reshape(b, t), axis=1)))
 
     @nn.compact
     def __call__(self, x: jax.Array, keep: Optional[jax.Array] = None):
@@ -400,17 +405,18 @@ class GlmMoeDsaDenoiser(nn.Module):
     def tally_shapes(self) -> Dict[str, Tuple[int, ...]]:
         """What one evaluation counts, by name, which the serving path
         carries with a row: the held picks by sparse layer and expert,
+        by sparse layer those the routed layer's first pass served,
         and the selected (query, key) pairs by layer."""
-        return {"picks": (self.mlp_layer_types.count("sparse"),
-                          self.n_routed_experts),
+        sparse = self.mlp_layer_types.count("sparse")
+        return {"picks": (sparse, self.n_routed_experts),
+                "fitted": (sparse,),
                 "keys": (self.num_hidden_layers,)}
 
     # a guided bucket-8 turn is 16 sequences: at the published widths
-    # their expanded heads, the dense layer's products and the grouped
-    # product's worst-case buffer do not stand side by side on one chip
-    # (PERF.md section 4), and each row's tokens fill the MXU alone. So a
-    # serving round evaluates this model a row at a time
-    # (`samplers/common.py` `rows_apart`).
+    # their expanded heads and the dense layer's products do not stand
+    # side by side on one chip (PERF.md section 4), and each row's tokens
+    # fill the MXU alone. So a serving round evaluates this model a row
+    # at a time (`samplers/common.py` `rows_apart`).
     serve_rows_apart = True
 
     def tally_counters(self, tally, evaluations: int, sample_shape,
@@ -424,7 +430,8 @@ class GlmMoeDsaDenoiser(nn.Module):
         sparse = self.mlp_layer_types.count("sparse")
         out = moe.pick_counters(
             tally["picks"],
-            evaluations * t * self.num_experts_per_tok * sparse)
+            evaluations * t * self.num_experts_per_tok * sparse,
+            tally["fitted"])
         out["dsa/keys_selected"] = int(tally["keys"].sum())
         out["dsa/keys_visible"] = (evaluations * self.num_hidden_layers
                                    * (t * (t + 1) // 2))
@@ -473,7 +480,10 @@ class GlmMoeDsaDenoiser(nn.Module):
             tokens, _rms(self.rms_norm_eps, jnp.float32, "final_norm"),
             x.shape, self.patch_size, self.output_channels)
         if return_tally:
-            held = (jnp.stack(picks, axis=1) if picks else jnp.zeros(
-                (x.shape[0],) + self.tally_shapes["picks"], jnp.int32))
-            return out, {"picks": held, "keys": jnp.stack(keys, axis=1)}
+            held, fitted = (
+                tuple(jnp.stack(n, axis=1) for n in zip(*picks)) if picks
+                else (jnp.zeros((x.shape[0],) + self.tally_shapes[name],
+                                jnp.int32) for name in ("picks", "fitted")))
+            return out, {"picks": held, "fitted": fitted,
+                         "keys": jnp.stack(keys, axis=1)}
         return out

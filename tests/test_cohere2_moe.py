@@ -2,6 +2,7 @@
 new paths (ops/moe.py; the masked grouped-query flash forward) and the
 held-pick counters, against the plain reference
 (benchmark/reference/cohere2_moe.py) at small sizes on the CPU."""
+import functools
 import importlib.util
 import os
 import sys
@@ -87,7 +88,14 @@ def test_forward_equals_the_plain_reference(window):
     got, tally = jax.jit(lambda p: model.apply(
         {"params": p}, x, t, text, return_tally=True))(params)
     picks = tally["picks"]
-    assert set(tally) == set(model.tally_shapes) == {"picks"}
+    assert set(tally) == set(model.tally_shapes) == {"picks", "fitted"}
+    # a quarter of the picks land here, half of them fit a pass: all do
+    np.testing.assert_array_equal(tally["fitted"], picks.sum(axis=-1))
+    added = model.tally_counters(
+        jax.tree_util.tree_map(lambda a: np.asarray(a[0]), tally), 1,
+        (RES, RES, CH), TOK)
+    assert added["moe/picks_fitted"] == added["moe/picks_held"] \
+        == int(picks[0].sum()) > 0
     with jax.default_matmul_precision("highest"):
         want = jax.jit(lambda p: ref.forward(
             p, _ref_cfg(sliding_window=window), x, t, text))(params)
@@ -143,7 +151,7 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_reference():
         held = dict(layer, **{
             k: {"kernel": layer[k]["kernel"][4 * share:4 * share + 4]}
             for k in ("experts_gate", "experts_up", "experts_down")})
-        y, n = jax.jit(block.apply)({"params": held}, x)
+        y, n, _ = jax.jit(block.apply)({"params": held}, x)
         total = total + (y - base)
         picks.append(n)
     np.testing.assert_allclose(total + base, want, atol=2e-5, rtol=2e-5)
@@ -185,9 +193,20 @@ def test_the_grouped_product_in_interpret_mode_under_an_imbalance():
     local = _imbalanced(40, 4)
     counts = [int((local == e).sum()) for e in range(4)]
     assert counts[1] == 0 and counts[2] >= sum(counts) // 2
-    dest, src, padded, tile_group, num_tiles = moe.dispatch(local, 4, 8)
+    picks = moe.held_order(local, 4)
+    rows, src, padded, tile_group, num_tiles = moe.dispatch(
+        local, picks, 4, 8)
     assert [int(p) for p in padded] == [-(-c // 8) * 8 for c in counts]
     assert int(num_tiles) == int(padded.sum()) // 8
+    # the served picks in token order, each on a row of its own token's
+    # in its expert's group
+    live = np.asarray(picks) < 80
+    assert live.sum() == sum(counts) and (np.diff(picks[live]) > 0).all()
+    assert (np.asarray(src)[np.asarray(rows)[live]]
+            == np.asarray(picks)[live] // 2).all()
+    assert (np.asarray(tile_group)[np.asarray(rows)[live] // 8]
+            == np.asarray(local).reshape(-1)[np.asarray(picks)[live]]).all()
+    assert len(set(np.asarray(rows)[live].tolist())) == live.sum()
     got = moe._expert_ffn_pallas(x[src], wg, wu, wd, tile_group, num_tiles,
                                  tile_m=8, tile_n=16, interpret=True)
     live = int(padded.sum())
@@ -203,35 +222,178 @@ def test_the_grouped_product_in_interpret_mode_under_an_imbalance():
         atol=2e-5, rtol=2e-5)
 
 
-def test_routed_experts_drop_no_token_and_pool_a_vmap_over_rows():
+def _everywhere(n, e):
+    """Every pick is of an expert held here."""
+    return jnp.asarray(np.random.default_rng(1).integers(0, e, (n, 2)),
+                       jnp.int32)
+
+
+def _elsewhere(n, e):
+    """Three picks in eight land here, none on expert 1."""
+    return jnp.asarray(np.random.default_rng(2).choice(
+        [0, 2, 3] + [e] * 5, size=(n, 2)), jnp.int32)
+
+
+# name: (local [40, 2], the layer's experts over all chips, passes)
+PASSES = {
+    "every_pick_fits": (lambda: _elsewhere(40, 4), 16, 1),
+    "two_passes": (lambda: _everywhere(40, 4), 16, 2),
+    "three_passes": (lambda: _everywhere(40, 4), 24, 3),
+    "no_pick_lands": (lambda: jnp.full((40, 2), 4, jnp.int32), 16, 0),
+    "the_whole_layer_is_here": (lambda: _imbalanced(40, 4), 4, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(PASSES))
+def test_routed_experts_drop_no_token_and_pool_a_vmap_over_rows(case):
+    make, total, passes = PASSES[case]
     x, wg, wu, wd = _experts()
-    local = _imbalanced(40, 4)
+    local = make()
     w = jax.random.uniform(jax.random.PRNGKey(9), (40, 2))
+    held = int((local < 4).sum())
+    count = moe.capacity(80, 4, total)
+    assert -(-held // count) == passes
     want = jax.jit(_dense)(x, local, w, wg, wu, wd)
-    np.testing.assert_allclose(
-        jax.jit(moe.routed_experts)(x, local, w, wg, wu, wd), want,
-        atol=2e-5, rtol=2e-5)
-    rows = jax.jit(jax.vmap(moe.routed_experts,
-                            in_axes=(0, 0, 0, None, None, None)))(
-        x.reshape(4, 10, -1), local.reshape(4, 10, 2), w.reshape(4, 10, 2),
-        wg, wu, wd)
+
+    def routed(x, local, w, wg, wu, wd):
+        return moe.routed_experts(x, local, w, wg, wu, wd, total)
+    got, fitted = jax.jit(routed)(x, local, w, wg, wu, wd)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    # what the first pass served: every held pick, or a pass's capacity
+    assert fitted.shape == (40,) and int(fitted.sum()) == min(held, count)
+    assert int(fitted.sum()) == {"every_pick_fits": held, "two_passes": count,
+                                 "three_passes": count, "no_pick_lands": 0,
+                                 "the_whole_layer_is_here": held}[case]
+    if passes == 0:
+        assert not np.asarray(got).any()
+    by_row = jax.vmap(routed, in_axes=(0, 0, 0, None, None, None))
+    halves = (x.reshape(4, 10, -1), local.reshape(4, 10, 2),
+              w.reshape(4, 10, 2), wg, wu, wd)
+    rows, fitted_rows = jax.jit(by_row)(*halves)
     np.testing.assert_allclose(rows.reshape(40, -1), want, atol=2e-5,
                                rtol=2e-5)
-    # pooled: ONE grouped product for the four rows, not four
-    pooled = str(jax.make_jaxpr(jax.vmap(
-        moe.routed_experts, in_axes=(0, 0, 0, None, None, None)))(
-        x.reshape(4, 10, -1), local.reshape(4, 10, 2), w.reshape(4, 10, 2),
-        wg, wu, wd))
-    alone = str(jax.make_jaxpr(moe.routed_experts)(x, local, w, wg, wu, wd))
+    np.testing.assert_array_equal(fitted_rows.reshape(-1), fitted)
+    # pooled: ONE grouped product for the four rows, not four, in ONE
+    # loop over passes (of at most one where `held == total`: a pass
+    # then holds every pick); the one-pass form has none
+    pooled = str(jax.make_jaxpr(by_row)(*halves))
+    alone = str(jax.make_jaxpr(routed)(x, local, w, wg, wu, wd))
     assert pooled.count("ragged_dot") == alone.count("ragged_dot") > 0
-    grads = jax.jit(jax.grad(lambda *a: (moe.routed_experts(
-        a[0], local, w, *a[1:]) ** 2).sum(), argnums=(0, 1, 2, 3)))(
+    one_pass = str(jax.make_jaxpr(functools.partial(
+        moe._routed, total=total, one_pass=True))(x, local, w, wg, wu, wd))
+    assert alone.count("while[") == pooled.count("while[") \
+        == one_pass.count("while[") + 1
+    assert (count == 80) == (case == "the_whole_layer_is_here")
+    grads = jax.jit(jax.grad(lambda *a: (routed(
+        a[0], local, w, *a[1:])[0] ** 2).sum(), argnums=(0, 1, 2, 3)))(
         x, wg, wu, wd)
     wants = jax.jit(jax.grad(
         lambda *a: (_dense(a[0], local, w, *a[1:]) ** 2).sum(),
         argnums=(0, 1, 2, 3)))(x, wg, wu, wd)
-    for g, want_g in zip(grads, wants):
+    # the backward is the one-pass form's, whatever the passes
+    today = jax.jit(jax.grad(lambda *a: (moe._routed(
+        a[0], local, w, *a[1:], total, one_pass=True)[0] ** 2).sum(),
+        argnums=(0, 1, 2, 3)))(x, wg, wu, wd)
+    for g, want_g, same in zip(grads, wants, today):
         np.testing.assert_allclose(g, want_g, atol=1e-4, rtol=1e-4)
+        np.testing.assert_allclose(g, same, atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("case", ["every_pick_fits", "three_passes",
+                                  "no_pick_lands"])
+def test_the_combine_kernel_in_interpret_mode_pass_by_pass(case,
+                                                            monkeypatch):
+    """`fdt_moe_combine` against the XLA composition on every pass of a
+    call, at tiles of 8, to the last bit (both add a token's products
+    one by one in the order of its k); rows no group owns hold NaN, and
+    a served row that is not finite spoils its own token and no other."""
+    make, total, passes = PASSES[case]
+    x, _, _, _ = _experts(d=128)
+    local = make()
+    w = jax.random.uniform(jax.random.PRNGKey(9), (40, 2))
+    order = moe.held_order(local, 4)
+    count = moe.capacity(80, 4, total)
+    acc = x.astype(jnp.float32)
+    monkeypatch.setattr(moe, "TILE_M", 8)
+    spoilt = set()
+    for p in range(max(passes, 1)):
+        picks = jnp.pad(order, (0, count), constant_values=80)[
+            p * count:(p + 1) * count]
+        rows, src, _, _, _ = moe.dispatch(local, picks, 4, 8)
+        served = np.asarray(picks)[np.asarray(picks) < 80]
+        owned = np.zeros(src.shape[0], bool)
+        owned[np.asarray(rows)[np.asarray(picks) < 80]] = True
+        ys = jnp.where(owned[:, None], jax.random.normal(
+            jax.random.PRNGKey(p), (src.shape[0], 128)), jnp.nan)
+        if len(served):     # one served row overflows in one column
+            ys = ys.at[rows[len(served) // 2], 5].set(jnp.inf)
+            spoilt.add(int(served[len(served) // 2]) // 2)
+        for dtype in (jnp.float32, jnp.bfloat16):
+            want = moe._combine_xla(acc, ys.astype(dtype), picks, rows, w)
+            got = moe._combine_pallas(acc, ys.astype(dtype), picks, rows, w,
+                                      interpret=True)
+            np.testing.assert_array_equal(got, want)
+        acc = want
+        bad = ~np.isfinite(np.asarray(acc)).all(axis=1)
+        assert set(np.flatnonzero(bad).tolist()) == spoilt
+    assert passes == 0 or np.nanmax(np.abs(np.asarray(acc - x))) > 0.1
+
+
+def _rows_of_requests(case):
+    """Four requests of ten tokens each (d = 128): one spread evenly,
+    one that picks held experts only, one that picks none, one mixed."""
+    x, wg, wu, wd = _experts(d=128)
+    rng = np.random.default_rng(3)
+    local = np.stack([rng.choice([0, 1, 2, 3] + [4] * 12, size=(10, 2)),
+                      rng.integers(0, 4, (10, 2)),
+                      np.full((10, 2), 4),
+                      rng.choice([1, 3] + [4] * 6, size=(10, 2))])
+    if case == "overflow":      # every pick of every request lands here
+        local = rng.integers(0, 4, (4, 10, 2))
+    w = jax.random.uniform(jax.random.PRNGKey(9), (4, 10, 2))
+    return (x.reshape(4, 10, 128), jnp.asarray(local, jnp.int32), w,
+            wg, wu, wd)
+
+
+@pytest.mark.parametrize("form", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("case", ["fits", "overflow"])
+def test_a_request_pooled_with_others_equals_the_request_alone(
+        case, form, monkeypatch):
+    """docs/SERVING.md's determinism contract in the routed layer: the
+    rows of a round are pooled into one call, and a row comes out as it
+    does alone, to the last bit, whatever the others hold, whatever the
+    passes (`overflow`: the pooled call takes two, a row alone one or
+    two), and a row that is not finite leaves the others as they were."""
+    x, local, w, wg, wu, wd = _rows_of_requests(case)
+    if form == "pallas_interpret":
+        monkeypatch.setattr(moe, "TILE_M", 8)
+        monkeypatch.setattr(moe, "_combine", functools.partial(
+            moe._combine_pallas, interpret=True))
+        moe._pooled.cache_clear()
+    held = int((local < 4).sum())
+    assert -(-held // moe.capacity(80, 4, 16)) == {"fits": 1,
+                                                   "overflow": 2}[case]
+
+    def routed(x, local, w):
+        return moe.routed_experts(x, local, w, wg, wu, wd, 16)
+    pooled, fitted = jax.jit(jax.vmap(routed))(x, local, w)
+    assert int(fitted.sum()) == min(held, moe.capacity(80, 4, 16))
+    for r in range(4):
+        alone, _ = jax.jit(routed)(x[r], local[r], w[r])
+        np.testing.assert_array_equal(pooled[r], alone)
+        np.testing.assert_allclose(
+            alone, _dense(x[r], local[r], w[r], wg, wu, wd), atol=5e-5,
+            rtol=5e-5)
+    # request 1 overflows in one token: the others do not see it
+    spoilt, _ = jax.jit(jax.vmap(routed))(
+        x.at[1, 4, 7].set(jnp.inf), local, w)
+    bad = ~np.isfinite(np.asarray(spoilt)).all(axis=-1)
+    assert bad[1, 4] and bad.sum() == 1
+    keep = np.ones((4, 10), bool)
+    keep[1, 4] = False
+    np.testing.assert_array_equal(np.asarray(spoilt)[keep],
+                                  np.asarray(pooled)[keep])
+    moe._pooled.cache_clear()
 
 
 def test_route_scores_every_expert_and_normalises_over_the_picks():
@@ -378,6 +540,10 @@ def test_a_served_request_equals_the_references_trajectory_and_is_counted(
     hottest = tel.counter("moe/picks_hottest").value
     assert tel.counter("moe/picks_routed").value == routed
     assert 0 < hottest <= held < routed
+    # nearly every held pick fitted its layer's first pass (at these few
+    # tokens a layer now and then takes a second: 8 picks of 1,670 here,
+    # and the samples above are the reference's all the same)
+    assert 0.9 * held < tel.counter("moe/picks_fitted").value <= held
     assert hottest >= held / 4          # the largest of 4 experts a layer
     # the picks left the device with the samples: a round is one launch,
     # a request's admission two, a finalisation one

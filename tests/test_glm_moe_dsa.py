@@ -106,8 +106,12 @@ def test_forward_and_gradient_equal_the_plain_reference(top_k):
         want = jax.jit(lambda p: ref.forward(p, cfg, x, t, text))(params)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
     # what one evaluation counts, by name
-    assert set(tally) == set(model.tally_shapes) == {"picks", "keys"}
+    assert set(tally) == set(model.tally_shapes) == {"picks", "fitted",
+                                                     "keys"}
     assert tally["picks"].shape == (2, 4, 4) and tally["keys"].shape == (2, 5)
+    # a quarter of the picks land here, half of them fit a pass: all do
+    np.testing.assert_array_equal(tally["fitted"],
+                                  tally["picks"].sum(axis=-1))
     assert int(tally["picks"].sum()) <= 2 * 4 * TOKENS * 3
     # `dsa/keys_selected` is the closed form: every causal pair of the
     # first top_k queries, top_k a query beyond
@@ -121,7 +125,8 @@ def test_forward_and_gradient_equal_the_plain_reference(top_k):
     assert added["dsa/keys_selected"] == 5 * pairs
     assert added["dsa/keys_visible"] == 5 * TOKENS * (TOKENS + 1) // 2
     assert added["moe/picks_routed"] == TOKENS * 3 * 4
-    assert added["moe/picks_held"] == int(tally["picks"][0].sum())
+    assert added["moe/picks_fitted"] == added["moe/picks_held"] \
+        == int(tally["picks"][0].sum()) > 0
     # a gradient, through the selection (piecewise constant) and the router
     loss = lambda f: lambda p: jnp.mean(f(p) ** 2)
     g_got = jax.jit(jax.grad(loss(lambda p: model.apply(
@@ -186,7 +191,7 @@ def test_a_shared_layer_reads_its_full_layers_selection_and_holds_no_indexer(
     y_all, keep, picks = jax.jit(shared.apply)(p, x, causal)
     y_some, kept, _ = jax.jit(shared.apply)(p, x, sparse)
     assert keep is not None and bool((kept == sparse).all())
-    assert picks.shape == (2, 4)
+    assert picks[0].shape == (2, 4) and picks[1].shape == (2,)
     assert float(jnp.abs(y_all - y_some).max()) > 1e-3
     with pytest.raises(ValueError, match="none came before"):
         shared.apply(p, x, None)
@@ -249,7 +254,7 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_reference():
         held = dict(layer, **{
             k: {"kernel": layer[k]["kernel"][4 * share:4 * share + 4]}
             for k in stacks})
-        y, kept, n = jax.jit(block.apply)({"params": held}, x)
+        y, kept, (n, _) = jax.jit(block.apply)({"params": held}, x)
         assert bool((kept == keep).all())
         total = total + (y - base)
         picks.append(n)
@@ -322,7 +327,7 @@ def test_a_served_request_equals_the_references_trajectory_and_is_counted(
             encoder=SeededContextEncoder(null_ctx))])
     assert pipe.model.serve_rows_apart
     assert pipe.get_sampler("ddim", 3.0).tally_shape == {
-        "picks": (4, 4), "keys": (5,)}
+        "picks": (4, 4), "fitted": (4,), "keys": (5,)}
     tel = Telemetry(enabled=False)
     sched = ServingScheduler(pipeline=pipe, telemetry=tel,
                              config=SchedulerConfig())
@@ -358,6 +363,7 @@ def test_a_served_request_equals_the_references_trajectory_and_is_counted(
     held = tel.counter("moe/picks_held").value
     assert 0 < tel.counter("moe/picks_hottest").value <= held \
         < tel.counter("moe/picks_routed").value
+    assert 0.9 * held < tel.counter("moe/picks_fitted").value <= held
 
 
 def test_rows_apart_is_the_vmap_an_entry_at_a_time():

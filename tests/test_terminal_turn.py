@@ -191,7 +191,8 @@ class CountingModel:
 
     def tally_counters(self, tally, evaluations, sample_shape, cond_tokens):
         from flaxdiff_tpu.ops.moe import pick_counters
-        return pick_counters(tally["picks"], evaluations)
+        return pick_counters(tally["picks"], evaluations,
+                             tally["picks"].sum(axis=-1))
 
 
 @pytest.fixture(scope="module")

@@ -468,7 +468,7 @@ def _pallas_calls():
 
 def test_every_pallas_call_carries_a_distinct_name():
     calls = _pallas_calls()
-    assert len(calls) == 15
+    assert len(calls) == 16
     names = []
     for path, line, name in calls:
         assert isinstance(name, ast.Constant) \
@@ -476,10 +476,15 @@ def test_every_pallas_call_carries_a_distinct_name():
         prefix = {"flash_attention.py": "fdt_flash_",
                   "fused_norm.py": "fdt_gn_silu_",
                   "fused_adaln.py": "fdt_adaln_",
-                  "moe.py": "fdt_moe_gmm_"}[path]
+                  "moe.py": "fdt_moe_"}[path]
         assert name.value.startswith(prefix), (path, line, name.value)
         names.append(name.value)
     assert len(set(names)) == len(names)
+    # the grouped product's two are the ones `kernel.moe_gmm_*` read by
+    # name; the combine does no counted operation and is not among them
+    assert sorted(n for n in names if n.startswith("fdt_moe_gmm")) == [
+        "fdt_moe_gmm_down", "fdt_moe_gmm_gate_up"]
+    assert "fdt_moe_combine" in names
     assert {"fdt_flash_fwd", "fdt_flash_bwd_dq",
             "fdt_flash_bwd_dkv"} <= set(names)
 

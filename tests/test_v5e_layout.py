@@ -4,9 +4,12 @@ with its gradient, compiled for a described v5e chip (nothing attached,
 nothing runs). At batch 32 the shape picks the XLA composition: no
 `fdt_gn_silu_*` custom call, and the one activation-sized `copy` left is
 the program's own result. At batch 8 the four kernels run, each fed by
-a `copy` out of the convolutions' layout: the cost the rule avoids. The
-one file of `tests/` that loads the TPU's compiler: the topology is
-described inside a fixture, never at import.
+a `copy` out of the convolutions' layout: the cost the rule avoids.
+Beside it, the routed experts' layer (`ops/moe.py`) at the widths of the
+benchmark's two cells that run it: Mosaic accepts its three kernels and
+no buffer of the program holds the worst case. The one file of `tests/`
+that loads the TPU's compiler: the topology is described inside a
+fixture, never at import.
 """
 from __future__ import annotations
 
@@ -140,3 +143,38 @@ def test_small_batch_runs_the_kernels_behind_layout_copies(
     fed = {call for call, _ in
            copies_feeding(instrs, "fdt_gn_silu_", activation)}
     assert len(fed) == 4, fed
+
+
+# cell: (tokens a call, picks a token, hidden, expert width, experts
+# held, the layer's experts)
+ROUTED = {"glm-5.2.generate-fewer-1024": (8348, 8, 6144, 2048, 16, 256),
+          "command-a-plus.generate-few": (5344, 8, 4096, 4096, 16, 128)}
+
+
+@pytest.mark.parametrize("cell", list(ROUTED))
+def test_the_routed_layer_is_sized_by_the_picks_that_land_here(
+        one_chip, monkeypatch, cell):
+    from flaxdiff_tpu.ops import moe
+    n, k, d, f, held, total = ROUTED[cell]
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+
+    def on(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(lambda *a: moe.routed_experts(*a, total)).lower(
+        on(n, d), on(n, k, dtype=jnp.int32), on(n, k, dtype=jnp.float32),
+        on(held, d, f), on(held, d, f), on(held, f, d)).compile()
+    text = compiled.as_text()
+    for kernel in ("fdt_moe_gmm_gate_up", "fdt_moe_gmm_down",
+                   "fdt_moe_combine"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
+    # the grouped buffer holds a pass's capacity, a sixth (GLM) or under
+    # a third (Command A+) of the worst case, which nothing holds
+    rows = moe.buffer_rows(moe.capacity(n * k, held, total), held)
+    worst = moe.buffer_rows(n * k, held)
+    assert rows * 3 < worst
+    assert f"bf16[{rows},{d}]" in text and f"[{worst},{d}]" not in text \
+        and f"[{n},{k},{d}]" not in text
+    # the accumulator is updated in place: no copy of it a pass
+    assert not re.search(rf"= f32\[{n},{d}\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 4 * rows * d * 2 + 2 * n * d * 4
