@@ -67,6 +67,33 @@ class Done:
         return (self.done_t - self.due_t) * 1e3
 
 
+def row_turns_per_s(done: List["Done"]) -> float:
+    """Row-turns completed a second, between completions: with the
+    requests that completed in order of `done_t`, d_1 ... d_n, and
+    `turns(d) = images x (nfe + 1)`,
+
+        sum_{i = 2..n} turns(d_i) / (done_t(d_n) - done_t(d_1)).
+
+    A row's trajectory is its `nfe` sampler turns and one terminal
+    denoise, which rides the round as a turn like any other: the
+    program counts all `nfe + 1` in `serving/row_steps_live`, and the
+    terminal ones once more, apart, in `serving/terminal_turns`. Both
+    edges of the interval are completions, so no edge cuts a request:
+    where requests are served one after another the rate is exact, and
+    where a round pools rows the requests in flight at the two edges
+    cancel in expectation. It reads `Done.done_t` and `Done.fields`
+    and nothing the program made. Fewer than three completions, or
+    none apart in time, are not a rate: ValueError."""
+    ds = sorted(done, key=lambda d: d.done_t)
+    span = ds[-1].done_t - ds[0].done_t if ds else 0.0
+    if len(ds) < 3 or not span > 0:
+        raise ValueError(
+            f"{len(ds)} completion(s) over {span:.3f} s are not a rate of "
+            "row-turns: three or more, apart in time, are")
+    return sum(int(d.fields["images"]) * (int(d.fields["nfe"]) + 1)
+               for d in ds[1:]) / span
+
+
 class Recorder:
     def __init__(self):
         self.lock = threading.Lock()

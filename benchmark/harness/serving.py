@@ -263,6 +263,16 @@ def run(cell, args, found, meter, t_start) -> Dict[str, Any]:
         images = sum(int(d.fields["images"]) for d in good)
         print(f"latency sample count: {len(lat)}", flush=True)
         out["metrics"]["gen_img_per_s"] = images / (t1 - t0)
+        # the same completions, counted in row-turns between the first
+        # and the last of them: the throughput of a cell whose requests
+        # are few. A window too short for it fails a cell that lists it.
+        try:
+            out["metrics"]["gen_row_turns_per_s"] = \
+                loadgen.row_turns_per_s(good)
+        except ValueError as e:
+            if any(m["name"] == "gen_row_turns_per_s"
+                   for m in cell.end_to_end):
+                raise RuntimeError(f"gen_row_turns_per_s: {e}") from e
         if len(lat):
             out["metrics"]["request_ms_p50"] = float(np.percentile(lat, 50))
             out["metrics"]["request_ms_p95"] = float(np.percentile(lat, 95))

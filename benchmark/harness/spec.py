@@ -33,13 +33,20 @@ CONFIG_FILE_KEYS = {
 # a catalog row's own sizes outside its `config`: accepted at the top
 # level of the file under their names, not required
 ROW_SIZE_KEYS = {"layers", "expert_width", "dense_width", "context_length"}
-# what `reduced` may name: a count of layers, of experts held, of heads
-# held or of vocabulary rows, or a list with one entry a layer whose name
-# is not a width's (the contract's widths)
+# what `reduced` may name. A LIST is told by its shape: one entry a layer
+# (as many as the row's `layers`) is the list that goes with depth,
+# whatever it is called (`layer_types`, `sliding_window_layout`); a list
+# of another length (a rope group's factors) is not. A SCALAR is told by
+# its name: a count of layers, of experts held, of heads held or of
+# vocabulary rows (COUNT_RE), unless the name says "a token's"
+# (TOKEN_RE): the experts a token picks are a width by the contract.
+COUNT_RE = re.compile(r"(^|_)(layers?|experts|heads|vocab_size)$")
+TOKEN_RE = re.compile(r"(^|_)(active|per_tok|per_token|top_k|topk|used)(_|$)")
+# the contract's widths by name: `models.build` refuses a run in which the
+# model would drop such a key; `reduced` does not ask it
 WIDTH_RE = re.compile(
     r"hidden|intermediate|latent|state|proj|width|window|expan|ratio|"
     r"per_tok|top_k|head_dim|head_size|_dim$|_rank$")
-COUNT_RE = re.compile(r"(^|_)(layers?|experts|heads|vocab_size)$")
 TRAFFIC_KEYS = {
     "train_steady": {"kind", "why", "mesh", "trace_steps",
                      "check_steps"},
@@ -225,6 +232,12 @@ def _under_floor(k: str, got: Any, want: Any) -> str:
     return ""
 
 
+def _per_layer(want: Any, row: Dict[str, Any]) -> bool:
+    """Whether a source value is a per-layer list: a list with one entry
+    a layer, as many as the row's `layers`. Its name is not asked."""
+    return isinstance(want, list) and len(want) == row.get("layers")
+
+
 def lint_against_source(what: str, cfg: Dict[str, Any], row: Dict[str, Any],
                         entry: Optional[Dict[str, Any]] = None) -> None:
     """Hold a configuration file to its catalog row, in the driver's
@@ -232,9 +245,11 @@ def lint_against_source(what: str, cfg: Dict[str, Any], row: Dict[str, Any],
     nested group of the source's entry under the same key, at its top
     level; a key not listed in `reduced` equals the source's value
     exactly; a key listed there is a count of layers, of experts held,
-    of heads held or of vocabulary rows (or the list that goes with
-    depth), never a width, and only smaller; the file states the
-    published count beside each (`published`) and the deployment."""
+    of heads held or of vocabulary rows (a scalar, told by its name:
+    never one that says "a token's"), or a list with one entry a layer
+    (told by its length, the row's `layers`, whatever its name), never
+    a width, and only smaller; the file states the published count
+    beside each (`published`) and the deployment."""
     src = row["config"]
     clash = sorted(set(src) & CONFIG_FILE_KEYS)
     if clash:
@@ -271,9 +286,13 @@ def lint_against_source(what: str, cfg: Dict[str, Any], row: Dict[str, Any],
                     f"{what}: key {k!r} is {got!r} and its source gives "
                     f"{want!r}; it is not listed in `reduced`")
             continue
-        depth_list = (isinstance(want, list) and not WIDTH_RE.search(k)
-                      and len(want) == row.get("layers", len(want)))
-        if not (COUNT_RE.search(k) or depth_list):
+        count = not isinstance(want, list) and COUNT_RE.search(k)
+        if count and TOKEN_RE.search(k):
+            raise SpecError(
+                f"{what}: `reduced` names {k!r}: the experts a token picks "
+                "are a width, and a width is never reduced; only the "
+                "experts HELD are a count")
+        if not (count or _per_layer(want, row)):
             raise SpecError(
                 f"{what}: `reduced` names {k!r}: only a count of layers, "
                 "of experts held, of heads held or of vocabulary rows, or "
@@ -296,7 +315,7 @@ def lint_against_source(what: str, cfg: Dict[str, Any], row: Dict[str, Any],
                 f"({want!r}) beside the reduced value")
     for k, want in src.items():
         # a per-layer list and the count of layers say one depth
-        if not (isinstance(want, list) and len(want) == row.get("layers")):
+        if not _per_layer(want, row):
             continue
         for n, depth in src.items():
             if re.search(r"(^|_)layers?$", n) and _same(depth, len(want)) \

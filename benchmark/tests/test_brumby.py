@@ -13,11 +13,12 @@ import sys
 
 import pytest
 
+from . import round_program
 from .conftest import BENCH, ROOT
 
 NAME = "brumby-14b-dn-384"
 CELL = "brumby-14b.generate-fewer"
-HBM = 15.75e9        # what the v5e compiler allows a program
+HBM = round_program.HBM
 
 
 def _entry():
@@ -49,12 +50,12 @@ def test_the_configuration_loads_and_is_held_to_its_source():
         "2": 6, "3": 3, "4": 1}
     assert cell.traffic["guidance_scale"] == 3.0
     names = {m["name"] for m in cell.per_layer}
-    assert {"serve.mfu_pct", "sampler.step_device_ms",
-            "kernel.mosaic_share_pct.gen"} <= names
+    assert {"serve.mfu_pct", "sampler.step_device_ms"} <= names
     # the retention is XLA's composition (the A/B in the round program,
-    # PERF.md PR 38): no kernel of its own, so none is read in the cell
-    assert not {"kernel.flash_share_pct.gen", "kernel.adaln_share_pct.gen",
-                "moe.held_pick_share"} & names
+    # PERF.md PR 38): no Mosaic kernel on its path, so none is read in
+    # the cell, not as a class either (0.0 / 0.0 in the ledger: PR 45)
+    assert not {"kernel.mosaic_share_pct.gen", "kernel.flash_share_pct.gen",
+                "kernel.adaln_share_pct.gen", "moe.held_pick_share"} & names
     assert not [n for n in names
                 if n.startswith(("kernel.power_", "retention."))]
 
@@ -135,71 +136,14 @@ def test_the_cell_rehearses_on_the_cpu_with_correct_true():
 
 @pytest.fixture(scope="module")
 def topo():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    import jax
-    from jax.experimental import topologies
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-
-
-def compile_round_program(topo, bucket: int = 8, round_steps: int = 8):
-    """The serving round program of the configuration at its real size
-    (`bucket` guided rows), compiled for one described v5e chip. Returns
-    (compiled, bytes dict)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import SingleDeviceSharding
-
-    from flaxdiff_tpu.inference import DiffusionInferencePipeline
-    from flaxdiff_tpu.serving.engine import _round_program
-    from harness import models, spec
-
-    cfg = models.effective_config(
-        spec.load_benchmark(ROOT).cell(CELL).config, False)
-    _, _, _, shapes = models.build(cfg)
-    pipe = DiffusionInferencePipeline.from_config(
-        {"model": dict(cfg["model"], name=cfg["registry_name"]),
-         "schedule": dict(cfg["schedule"]), "predictor": cfg["predictor"]},
-        params=None)
-    ds = pipe.get_sampler("ddim", 3.0)
-    one = SingleDeviceSharding(topo.devices[0])
-
-    def on(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    res, ch = cfg["input"]["resolution"], cfg["input"]["channels"]
-    tok, feat = cfg["conditioning"]["tokens"], cfg["conditioning"]["features"]
-    row = {"x": on((1, res, res, ch), jnp.float32),
-           "keys": on((2,), jnp.uint32), "state": (),
-           "cond": on((1, tok, feat), jnp.float32),
-           "uncond": on((1, tok, feat), jnp.float32)}
-    assert ds.tally_shape is None       # the model counts nothing itself
-    batch = {"pairs": on((bucket, round_steps, 2), jnp.float32),
-             "n_act": on((bucket,), jnp.int32),
-             "offsets": on((bucket,), jnp.int32),
-             "steps": on((), jnp.int32)}
-    params = {"params": jax.tree_util.tree_map(
-        lambda s: on(s.shape, s.dtype), shapes)}
-    compiled = _round_program(ds.make_chunk_program(round_steps)).lower(
-        params, (row,) * bucket, batch).compile()
-    ma = compiled.memory_analysis()
-    return compiled, {
-        "argument": ma.argument_size_in_bytes,
-        "output": ma.output_size_in_bytes, "temp": ma.temp_size_in_bytes,
-        "alias": ma.alias_size_in_bytes,
-        "total": (ma.argument_size_in_bytes + ma.output_size_in_bytes
-                  + ma.temp_size_in_bytes - ma.alias_size_in_bytes),
-        "parameters": models.count_params(shapes)}
+    return round_program.describe_v5e()
 
 
 @pytest.mark.slow
 def test_the_round_program_fits_a_v5e_chip(topo):
-    compiled, mem = compile_round_program(topo)
+    compiled, mem = round_program.compile_round_program(topo, CELL)
     print(NAME, mem)
+    assert mem["tally"] == []           # the model counts nothing itself
     assert mem["parameters"] == pytest.approx(1.353e9, rel=0.01)
     assert mem["argument"] > 2.7e9           # the bfloat16 tree
     assert 0.125 * 16e9 < mem["total"] < HBM
@@ -208,17 +152,4 @@ def test_the_round_program_fits_a_v5e_chip(topo):
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    import time
-
-    import jax
-    from jax.experimental import topologies
-    jax.config.update("jax_enable_compilation_cache", False)
-    sys.path[:0] = [ROOT, BENCH]
-    t = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    t0 = time.time()
-    c, mem = compile_round_program(t, *(int(a) for a in sys.argv[1:3]))
-    txt = c.as_text()
-    print("RESULT", NAME, mem, "mosaic_calls", txt.count("tpu_custom_call"),
-          f"{time.time() - t0:.0f}s")
+    round_program.main(CELL, *sys.argv[1:3])

@@ -15,11 +15,12 @@ import sys
 
 import pytest
 
+from . import round_program
 from .conftest import BENCH, ROOT
 
 NAME = "glm-5.2-dn-1024"
 CELL = "glm-5.2.generate-fewer-1024"
-HBM = 15.75e9        # what the v5e compiler allows a program
+HBM = round_program.HBM
 TOKENS = 1 + 77 + 64 * 64
 
 
@@ -66,8 +67,9 @@ def test_the_configuration_loads_and_is_held_to_its_source():
         "2": 6, "3": 3, "4": 1}
     assert cell.traffic["guidance_scale"] == 3.0
     assert cell.traffic["check_requests"] == cell.traffic["trace_rounds"] == 4
-    assert {m["name"] for m in cell.end_to_end} == {"gen_img_per_s",
-                                                    "setup_s"}
+    # few requests a window: the throughput is also counted in row-turns
+    assert {m["name"] for m in cell.end_to_end} == {
+        "gen_img_per_s", "gen_row_turns_per_s", "setup_s"}
     names = {m["name"] for m in cell.per_layer}
     assert {"serve.mfu_pct", "sampler.step_device_ms",
             "dsa.selected_key_share", "moe.held_pick_share",
@@ -151,7 +153,8 @@ def test_the_cell_rehearses_on_the_cpu_with_correct_true():
     assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
     line = json.loads(out.stdout.strip().splitlines()[-1])
     assert line["correct"] is True and line["failed"] == 0
-    assert line["metrics"]["serve.rows_per_round"]["value"] > 1
+    # rows evaluated one at a time are served in rounds of ONE row (PR 43)
+    assert line["metrics"]["serve.rows_per_round"]["value"] == 1.0
     # 150 tokens, the 96 largest: (96 x 97 / 2 + 54 x 96) / (150 x 151 / 2)
     assert line["metrics"]["dsa.selected_key_share"]["value"] \
         == pytest.approx(9840 / 11325, abs=0.01)
@@ -160,79 +163,14 @@ def test_the_cell_rehearses_on_the_cpu_with_correct_true():
 
 @pytest.fixture(scope="module")
 def topo():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    import jax
-    from jax.experimental import topologies
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        return topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-
-
-def compile_round_program(topo, bucket: int = 8, round_steps: int = 8):
-    """The serving round program of the configuration at its real size
-    (`bucket` guided rows, the model's Pallas kernels on), compiled for
-    one described v5e chip. Returns (compiled, bytes dict)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import SingleDeviceSharding
-
-    from flaxdiff_tpu.inference import DiffusionInferencePipeline
-    from flaxdiff_tpu.ops import attention as att, moe
-    from flaxdiff_tpu.serving.engine import _round_program
-    from harness import models, spec
-
-    cfg = models.effective_config(
-        spec.load_benchmark(ROOT).cell(CELL).config, False)
-    # the program picks its kernels by asking jax for its first device;
-    # here that is the CPU, so the test steers it to the TPU path
-    att._flash_on_tpu = lambda: True
-    moe._on_tpu = lambda: True
-    _, _, _, shapes = models.build(cfg)
-    pipe = DiffusionInferencePipeline.from_config(
-        {"model": dict(cfg["model"], name=cfg["registry_name"]),
-         "schedule": dict(cfg["schedule"]), "predictor": cfg["predictor"]},
-        params=None)
-    ds = pipe.get_sampler("ddim", 3.0)
-    one = SingleDeviceSharding(topo.devices[0])
-
-    def on(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
-    res, ch = cfg["input"]["resolution"], cfg["input"]["channels"]
-    tok, feat = cfg["conditioning"]["tokens"], cfg["conditioning"]["features"]
-    assert set(ds.tally_shape) == {"picks", "keys"}
-    row = {"x": on((1, res, res, ch), jnp.float32),
-           "keys": on((2,), jnp.uint32), "state": (),
-           "cond": on((1, tok, feat), jnp.float32),
-           "uncond": on((1, tok, feat), jnp.float32),
-           "tally": {name: on(shape, jnp.int32)
-                     for name, shape in ds.tally_shape.items()}}
-    batch = {"pairs": on((bucket, round_steps, 2), jnp.float32),
-             "n_act": on((bucket,), jnp.int32),
-             "offsets": on((bucket,), jnp.int32),
-             "steps": on((), jnp.int32),
-             "term": on((bucket,), jnp.int32)}
-    params = {"params": jax.tree_util.tree_map(
-        lambda s: on(s.shape, s.dtype), shapes)}
-    compiled = _round_program(ds.make_chunk_program(round_steps)).lower(
-        params, (row,) * bucket, batch).compile()
-    ma = compiled.memory_analysis()
-    return compiled, {
-        "argument": ma.argument_size_in_bytes,
-        "output": ma.output_size_in_bytes, "temp": ma.temp_size_in_bytes,
-        "alias": ma.alias_size_in_bytes,
-        "total": (ma.argument_size_in_bytes + ma.output_size_in_bytes
-                  + ma.temp_size_in_bytes - ma.alias_size_in_bytes),
-        "parameters": models.count_params(shapes)}
+    return round_program.describe_v5e()
 
 
 @pytest.mark.slow
 def test_the_round_program_fits_a_v5e_chip(topo):
-    compiled, mem = compile_round_program(topo)
+    compiled, mem = round_program.compile_round_program(topo, CELL)
     print(NAME, mem)
+    assert {"picks", "keys"} <= set(mem["tally"])
     assert mem["parameters"] == pytest.approx(3.688e9, rel=0.01)
     assert mem["argument"] > 7.4e9           # the bfloat16 tree: 46% of HBM
     assert mem["total"] < HBM
@@ -247,18 +185,4 @@ def test_the_round_program_fits_a_v5e_chip(topo):
 
 
 if __name__ == "__main__":
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    import time
-
-    import jax
-    from jax.experimental import topologies
-    jax.config.update("jax_enable_compilation_cache", False)
-    sys.path[:0] = [ROOT, BENCH]
-    t = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    t0 = time.time()
-    c, mem = compile_round_program(
-        t, *(int(a) for a in sys.argv[1:3]))
-    txt = c.as_text()
-    print("RESULT", NAME, mem, "mosaic_calls", txt.count("tpu_custom_call"),
-          f"{time.time() - t0:.0f}s")
+    round_program.main(CELL, *sys.argv[1:3])

@@ -31,16 +31,16 @@ def test_benchmark_json_passes_the_contracts_lint():
     assert len(json.dumps(raw)) < 64 * 1024
 
 
-def test_every_cell_resolves_and_reports_what_the_contract_asks():
-    bench = spec.load_benchmark(ROOT)
-    for name in bench.cell_names():
-        cell = bench.cell(name)
-        e2e = {m["name"] for m in cell.end_to_end}
-        assert "setup_s" in e2e and len(e2e) >= 2
-        assert cell.per_layer, name
-        for m in cell.per_layer:
-            assert m["moves"] in e2e, (name, m["name"])
-            assert cell.traffic["kind"] in m["file"]["kinds"]
+@pytest.mark.parametrize("name", [w["name"] for w in _raw()["workloads"]])
+def test_every_cell_resolves_and_reports_what_the_contract_asks(name):
+    """Data only: what guards the files a `model_config` PR adds."""
+    cell = spec.load_benchmark(ROOT).cell(name)
+    e2e = {m["name"] for m in cell.end_to_end}
+    assert "setup_s" in e2e and len(e2e) >= 2
+    assert cell.per_layer, name
+    for m in cell.per_layer:
+        assert m["moves"] in e2e, (name, m["name"])
+        assert cell.traffic["kind"] in m["file"]["kinds"]
 
 
 @pytest.mark.parametrize("name", ["a b", "a,b", "a/b", "", "x" * 65, "-a",
@@ -341,14 +341,101 @@ def test_a_file_that_differs_from_its_source_is_refused(tmp_path, breaks,
         spec.load_config(str(path), entry)
 
 
+# -- `reduced`: a list is told by its shape, a token's experts by name ------
+
+LAYOUTS_ROW = os.path.join(TOY_DIR, "sources", "toy-layouts.json")
+
+
+def _layouts(tmp_path, depth, lists=("rope_layout", "sliding_window_layout"),
+             **cut):
+    """A configuration file built from the toy row whose per-layer lists
+    are called layouts (SmallThinker's names): `num_hidden_layers` and
+    the named lists cut to their first `depth` entries, `cut` the other
+    keys changed; everything that differs is listed in `reduced`."""
+    (tmp_path / "configs" / "sources").mkdir(parents=True)
+    shutil.copy(LAYOUTS_ROW, tmp_path / "configs" / "sources")
+    with open(LAYOUTS_ROW) as f:
+        row = json.load(f)
+    with open(os.path.join(TOY_DIR, "toy-catalog.json")) as f:
+        toy = json.load(f)
+    src = row["config"]
+    cfg = {k: toy[k] for k in ("family", "registry_name", "model", "input",
+                               "conditioning", "schedule", "predictor",
+                               "assumed", "deployment")}
+    cfg.update(src, name=row["name"], source=row["source_url"],
+               num_hidden_layers=depth)
+    cfg.update({k: src[k][:depth] for k in lists}, **cut)
+    cfg["reduced"] = [k for k in src if not spec._same(cfg[k], src[k])]
+    cfg["published"] = {k: src[k] for k in cfg["reduced"]}
+    path = tmp_path / "configs" / "toy-layouts.json"
+    path.write_text(json.dumps(cfg))
+    entry = {"name": cfg["name"], "source": cfg["source"],
+             "file": "configs/toy-layouts.json",
+             "reduced": list(cfg["reduced"]), "why": "a toy"}
+    return str(path), entry
+
+
+@pytest.mark.parametrize("how,refused", [
+    (dict(depth=8), None),
+    (dict(depth=8, moe_num_primary_experts=8), None),
+    (dict(depth=8, lists=("rope_layout",)),
+     "'sliding_window_layout' lists 16 layers and 'num_hidden_layers' is 8"),
+    (dict(depth=4, lists=()), "'rope_layout' lists 16 layers"),
+    (dict(depth=3), "num_hidden_layers.*at least four layers"),
+    (dict(depth=8, rope_layout=[0, 1, 1]), "rope_layout.*whole period"),
+    (dict(depth=8, sliding_window_layout=[0, 1, 1, 1, 1, 1, 1, 1]),
+     "sliding_window_layout.*may only be smaller"),
+    (dict(depth=8, sliding_window_size=8),
+     "sliding_window_size.*never a width"),
+    (dict(depth=8, moe_num_active_primary_experts=1),
+     "moe_num_active_primary_experts.*the experts a token picks are a "
+     "width"),
+    (dict(depth=8, moe_ffn_hidden_size=16),
+     "moe_ffn_hidden_size.*never a width"),
+    (dict(depth=8, rope_short_factor=[1.0, 1.5]),
+     "rope_short_factor.*the list that goes with depth.*never a width"),
+], ids=["both-lists-two-periods", "and-the-experts-held", "one-list-cut",
+        "the-count-alone", "under-four-layers", "less-than-a-period",
+        "not-a-run-of-the-published-entries", "the-window", "a-tokens-experts",
+        "an-experts-width", "a-list-of-another-length"])
+def test_reduced_knows_a_per_layer_list_by_its_shape(tmp_path, how, refused):
+    """A list with one entry a layer may be cut with the depth whatever
+    it is called (at the parent `sliding_window_layout` was refused as
+    a width, for the `window` in its name); a scalar is a count by its
+    name, and never where the name says "a token's"."""
+    path, entry = _layouts(tmp_path, **how)
+    if refused is None:
+        cfg = spec.load_config(path, entry)
+        assert cfg["num_hidden_layers"] == len(cfg["rope_layout"]) == len(
+            cfg["sliding_window_layout"]) == 8
+        assert {"num_hidden_layers", "rope_layout",
+                "sliding_window_layout"} <= set(cfg["reduced"])
+    else:
+        with pytest.raises(spec.SpecError, match=refused):
+            spec.load_config(path, entry)
+
+
+@pytest.mark.parametrize("name,token", [
+    ("moe_num_active_primary_experts", True), ("num_used_experts", True),
+    ("top_k_experts", True), ("topk_experts", True),
+    ("n_experts_per_token_heads", True), ("num_active_heads", True),
+    ("moe_num_primary_experts", False), ("n_routed_experts", False),
+    ("num_key_value_heads", False), ("num_hidden_layers", False),
+    ("deactivated_experts", False), ("unused_experts", False)])
+def test_a_count_whose_name_says_a_token_is_a_width(name, token):
+    assert spec.COUNT_RE.search(name)
+    assert bool(spec.TOKEN_RE.search(name)) == token
+
+
 def test_the_configurations_without_a_source_copy_load_as_before():
-    raw = _raw()
-    for c in raw["configs"]:
+    plain = [c for c in _raw()["configs"] if not os.path.exists(os.path.join(
+        BENCH, "configs", "sources", c["name"] + ".json"))]
+    assert {c["name"] for c in plain} >= {"unet-flaxdiff-128",
+                                          "dit-xl-2-256"}
+    for c in plain:
         cfg = spec.load_config(os.path.join(ROOT, c["file"]), c)
         assert not spec.source_keys(cfg)
         assert cfg["reduced"] == c["reduced"]
-        assert not os.path.exists(os.path.join(
-            BENCH, "configs", "sources", c["name"] + ".json"))
 
 
 @pytest.mark.parametrize("kinds,want", [

@@ -299,14 +299,27 @@ def test_flash_costs_match_the_reference_attentions_own_count():
     assert cost["bytes"] == m["num_layers"] * 4 * q.size * 2    # bfloat16
 
 
-def test_the_counters_a_cell_reads_are_the_ones_its_files_name():
+SERVING_COUNTERS = {
+    "serving/rows_real", "serving/rounds", "serving/row_steps_live",
+    "serving/row_steps_run", "serving/terminal_turns",
+    "serving/dispatch_work_ms", "serving/dispatch_loop_ms"}
+MOE_COUNTERS = {"moe/picks_routed", "moe/picks_held", "moe/picks_hottest",
+                "moe/picks_fitted"}
+
+
+@pytest.mark.parametrize("cell,counters", [
+    ("unet128.train", {"fit/log_step_stall_ms"}),
+    ("dit-xl-2.generate", SERVING_COUNTERS),
+    ("brumby-14b.generate-fewer", SERVING_COUNTERS),
+    ("command-a-plus.generate-few", SERVING_COUNTERS | MOE_COUNTERS),
+    ("glm-5.2.generate-fewer-1024", SERVING_COUNTERS | MOE_COUNTERS
+     | {"dsa/keys_selected", "dsa/keys_visible"})])
+def test_the_counters_a_cell_reads_are_the_ones_its_files_name(cell,
+                                                               counters):
     bench = spec.load_benchmark(os.path.dirname(BENCH))
-    named = layer_metrics.counters_named(
-        bench.cell("dit-xl-2.generate").per_layer)
-    assert set(named) == {"serving/rows_real", "serving/rounds",
-                          "serving/row_steps_live", "serving/row_steps_run"}
-    assert layer_metrics.counters_named(
-        bench.cell("unet128.train").per_layer) == ("fit/log_step_stall_ms",)
+    named = layer_metrics.counters_named(bench.cell(cell).per_layer)
+    assert len(set(named)) == len(named)
+    assert set(named) == counters
 
 
 def test_dump_rows_keeps_host_spans_and_the_tail(tmp_path, probe):
