@@ -12,6 +12,7 @@ from ..models.cohere2_moe import Cohere2MoEDenoiser
 from ..models.dit import SimpleDiT
 from ..models.glm_moe_dsa import GlmMoeDsaDenoiser
 from ..models.mmdit import HierarchicalMMDiT, SimpleMMDiT
+from ..models.smallthinker import SmallThinkerDenoiser
 from ..models.ssm import HybridSSMAttentionDiT
 from ..models.unet import Unet
 from ..models.unet3d import UNet3D
@@ -30,6 +31,7 @@ MODEL_REGISTRY: Dict[str, Any] = {
     "cohere2_moe_dn": Cohere2MoEDenoiser,
     "brumby_dn": BrumbyDenoiser,
     "glm_moe_dsa_dn": GlmMoeDsaDenoiser,
+    "smallthinker_dn": SmallThinkerDenoiser,
 }
 
 # Suffix -> constructor kwarg toggles (reference inference/utils.py:168-180).

@@ -49,23 +49,10 @@ import jax.numpy as jnp
 
 from ..ops import power_retention as retention
 from ..typing import Dtype
-from .trunk import SequenceEmbed, patch_head
+from .trunk import SequenceEmbed, patch_head, rope_half_split
 
 POWER_DEGREE = 2
 NORM_EPS = 1e-6         # eps_n, beside the retention's normaliser
-
-
-def rope_half_split(x: jax.Array, theta: float) -> jax.Array:
-    """Over [B, S, H, D]: (x[i], x[i + D/2]) rotated by position *
-    theta^(-2i/D), position = index in the sequence."""
-    s, d = x.shape[1], x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = jnp.outer(jnp.arange(s, dtype=jnp.float32), inv)     # [S, D/2]
-    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
-    x32 = x.astype(jnp.float32)
-    lo, hi = x32[..., :d // 2], x32[..., d // 2:]
-    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin],
-                           axis=-1).astype(x.dtype)
 
 
 def _rms(eps: float, param_dtype, name: str) -> nn.Module:

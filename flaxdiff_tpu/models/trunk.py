@@ -1,7 +1,8 @@
 """What the decoder-layer denoiser trunks share (`models/cohere2_moe.py`,
-`models/brumby.py`, `models/glm_moe_dsa.py`): the token layout `[time
-token; text tokens; patch tokens]`, its embedding, a bare weight, the
-interleaved rotation, and the patch head.
+`models/brumby.py`, `models/glm_moe_dsa.py`, `models/smallthinker.py`):
+the token layout `[time token; text tokens; patch tokens]`, its
+embedding, a bare weight, the two rotations (interleaved pairs and
+half-split), and the patch head.
 
 The time token is the sinusoidal timestep embedding (`TIME_FEATURES`
 features) through the two-layer `TimeProjection` to `hidden_size`; text
@@ -50,6 +51,20 @@ def rope_interleaved(x: jax.Array, theta: float) -> jax.Array:
     out = jnp.stack([even * cos - odd * sin, even * sin + odd * cos],
                     axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def rope_half_split(x: jax.Array, theta: float) -> jax.Array:
+    """Half-split RoPE (rotate-half, the Llama lineage's pairing) over
+    [B, S, H, D]: (x[i], x[i + D/2]) rotated by position *
+    theta^(-2i/D), position = index in the sequence."""
+    s, d = x.shape[1], x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = jnp.outer(jnp.arange(s, dtype=jnp.float32), inv)     # [S, D/2]
+    cos, sin = jnp.cos(ang)[None, :, None, :], jnp.sin(ang)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    lo, hi = x32[..., :d // 2], x32[..., d // 2:]
+    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin],
+                           axis=-1).astype(x.dtype)
 
 
 class Kernel(nn.Module):

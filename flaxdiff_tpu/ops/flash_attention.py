@@ -393,7 +393,9 @@ def _fwd_impl(q3, k3, v3, scale, block_q, block_k, interpret,
     (static): query i sees keys j with j <= i / i - window < j; blocks
     wholly outside the mask are skipped. With none of the three the
     kernel, its grid and its block maps are what they were before them
-    (static Python branches).
+    (static Python branches). The call is named `fdt_flash_fwd` on the
+    device, and `fdt_flash_fwd_window` where the window binds (it is
+    shorter than the sequence).
 
     `key_mask` [B, Lq, Lk] (bool, DATA): which keys each query reads,
     one mask a batch entry shared by its B*H / B heads; it is the whole
@@ -489,9 +491,13 @@ def _fwd_impl(q3, k3, v3, scale, block_q, block_k, interpret,
             num_scalar_prefetch=2, grid=grid, in_specs=in_specs,
             out_specs=[pl.BlockSpec(q_block, row_map)],
             scratch_shapes=scratch_shapes))
+    # a window that BINDS has a name of its own on the device, so that a
+    # trace tells a windowed layer's time from a full layer's; one the
+    # sequence never reaches reads every causal pair and keeps the name
+    binds = window is not None and window < lq
     res = pl.pallas_call(
         functools.partial(_fwd_kernel, **kernel_kwargs),
-        name="fdt_flash_fwd",
+        name="fdt_flash_fwd_window" if binds else "fdt_flash_fwd",
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),

@@ -37,7 +37,8 @@ N x K of them:
   masked; the contraction runs whole in one step, so an expert's weight
   block stays in VMEM across the consecutive row tiles of its group and
   is read once per column tile. `gate_up` computes
-  silu(x Wgate) * (x Wup) in one pass over x.
+  act(x Wgate) * (x Wup) in one pass over x, `act` the model's own gate
+  (static: `silu`, or `relu` for a ReGLU expert).
 - **The combine** reads the rows that exist, not one for every one of
   the N x K picks: XLA gathers the pass's served rows in token order
   (standalone, at the memory's rate), and `fdt_moe_combine` (Pallas,
@@ -97,29 +98,41 @@ def _on_tpu() -> bool:
 def route(h32: jax.Array, router_kernel: jax.Array, top_k: int,
           norm_topk_prob: bool = True,
           select_bias: Optional[jax.Array] = None,
-          scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
-    """Sigmoid routing over ALL the layer's experts: (idx [N, K] int32,
-    weights [N, K] float32). The product and the sigmoid run in float32
+          scale: float = 1.0,
+          weigh: str = "sigmoid") -> Tuple[jax.Array, jax.Array]:
+    """Routing over ALL the layer's experts: (idx [N, K] int32, weights
+    [N, K] float32). The product and the scores run in float32
     (`Precision.HIGHEST`: a v5e's default rounds float32 operands to
-    bfloat16); the K largest scores, normalised over the K.
+    bfloat16). `weigh` (static) is how the model states its scores:
 
-    `select_bias` [E] (`topk_method` `noaux_tc`: a held correction bias)
-    moves which K are picked and nothing else: the K largest of score +
-    bias, weighted by their SCORES. `scale` (`routed_scaling_factor`)
-    multiplies the weights after the normalisation. Without either the
-    call is what it was before them."""
+    - `"sigmoid"`: a sigmoid of every logit; the K largest scores,
+      normalised over the K (`norm_topk_prob`).
+    - `"softmax_picked"`: the K largest LOGITS, weighted by the softmax
+      over those K alone (they sum to 1 as they stand).
+
+    `select_bias` [E] (`topk_method` `noaux_tc`: a held correction bias;
+    sigmoid scores only) moves which K are picked and nothing else: the
+    K largest of score + bias, weighted by their SCORES. `scale`
+    (`routed_scaling_factor`) multiplies the weights last. A call that
+    names none of them is what it was before them."""
     logits = jnp.dot(h32.astype(jnp.float32),
                      router_kernel.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
-    if select_bias is None:
-        vals, idx = jax.lax.top_k(scores, top_k)
+    if weigh == "softmax_picked":
+        assert select_bias is None, "a selection bias corrects sigmoid scores"
+        vals, idx = jax.lax.top_k(logits, top_k)
+        vals = jax.nn.softmax(vals, axis=-1)
     else:
-        _, idx = jax.lax.top_k(scores + select_bias.astype(jnp.float32),
-                               top_k)
-        vals = jnp.take_along_axis(scores, idx, axis=-1)
-    if norm_topk_prob:
-        vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+        assert weigh == "sigmoid", weigh
+        scores = jax.nn.sigmoid(logits)
+        if select_bias is None:
+            vals, idx = jax.lax.top_k(scores, top_k)
+        else:
+            _, idx = jax.lax.top_k(
+                scores + select_bias.astype(jnp.float32), top_k)
+            vals = jnp.take_along_axis(scores, idx, axis=-1)
+        if norm_topk_prob:
+            vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
     if scale != 1.0:
         vals = vals * scale
     return idx.astype(jnp.int32), vals
@@ -219,12 +232,22 @@ def dispatch(local: jax.Array, picks: jax.Array, held: int,
 # The grouped product
 # ---------------------------------------------------------------------------
 
-def _gate_up_kernel(tile_group, num_tiles, x_ref, wg_ref, wu_ref, o_ref):
+def _gated(g, u, act: str):
+    """act(gate) * up in float32: the model's own gate (`silu`, or
+    `relu`: a ReGLU expert), static."""
+    if act == "relu":
+        return jnp.maximum(g, 0.0) * u
+    assert act == "silu", act
+    return g * jax.nn.sigmoid(g) * u
+
+
+def _gate_up_kernel(tile_group, num_tiles, x_ref, wg_ref, wu_ref, o_ref, *,
+                    act: str):
     del tile_group, num_tiles
     x = x_ref[...]
     g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
     u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
-    o_ref[...] = (g * jax.nn.sigmoid(g) * u).astype(o_ref.dtype)
+    o_ref[...] = _gated(g, u, act).astype(o_ref.dtype)
 
 
 def _down_kernel(tile_group, num_tiles, x_ref, w_ref, o_ref):
@@ -273,10 +296,12 @@ def _gmm_grid(x, weights, num_tiles, tile_m: int, tile_n: int,
 
 
 def _expert_ffn_pallas(xs, wg, wu, wd, tile_group, num_tiles,
-                       tile_m=TILE_M, tile_n=TILE_N, interpret=False):
+                       tile_m=TILE_M, tile_n=TILE_N, interpret=False,
+                       act="silu"):
     scalars = (tile_group, num_tiles.reshape(1))
     mid = pl.pallas_call(
-        _gate_up_kernel, name="fdt_moe_gmm_gate_up",
+        functools.partial(_gate_up_kernel, act=act),
+        name="fdt_moe_gmm_gate_up",
         **_gmm_grid(xs, (wg, wu), num_tiles, tile_m, tile_n, interpret)
     )(*scalars, xs, wg, wu)
     return pl.pallas_call(
@@ -285,41 +310,44 @@ def _expert_ffn_pallas(xs, wg, wu, wd, tile_group, num_tiles,
     )(*scalars, mid, wd)
 
 
-def _expert_ffn_xla(xs, wg, wu, wd, padded):
+def _expert_ffn_xla(xs, wg, wu, wd, padded, act="silu"):
     """The exact XLA composition: `ragged_dot` over the same buffer with
     the same tile-padded group sizes (rows past their sum come out 0)."""
     dot = functools.partial(jax.lax.ragged_dot, group_sizes=padded,
                             preferred_element_type=jnp.float32)
     g, u = dot(xs, wg), dot(xs, wu)
-    mid = (g * jax.nn.sigmoid(g) * u).astype(xs.dtype)
+    mid = _gated(g, u, act).astype(xs.dtype)
     return dot(mid, wd).astype(xs.dtype)
 
 
-def _expert_ffn(xs, wg, wu, wd, padded, tile_group, num_tiles):
+def _expert_ffn(xs, wg, wu, wd, padded, tile_group, num_tiles, act):
     if _on_tpu():
-        return _expert_ffn_pallas(xs, wg, wu, wd, tile_group, num_tiles)
-    return _expert_ffn_xla(xs, wg, wu, wd, padded)
+        return _expert_ffn_pallas(xs, wg, wu, wd, tile_group, num_tiles,
+                                  act=act)
+    return _expert_ffn_xla(xs, wg, wu, wd, padded, act)
 
 
-@jax.custom_vjp
-def expert_ffn(xs, wg, wu, wd, padded, tile_group, num_tiles):
-    """down(silu(gate(x)) * up(x)) of every row of the grouped buffer by
-    its group's expert: [M, D] -> [M, D]. Rows past the last group are
-    unspecified (the kernels do not write them)."""
-    return _expert_ffn(xs, wg, wu, wd, padded, tile_group, num_tiles)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def expert_ffn(xs, wg, wu, wd, padded, tile_group, num_tiles, act="silu"):
+    """down(act(gate(x)) * up(x)) of every row of the grouped buffer by
+    its group's expert: [M, D] -> [M, D]; `act` (static) is the model's
+    gate, `silu` or `relu`. Rows past the last group are unspecified
+    (the kernels do not write them)."""
+    return _expert_ffn(xs, wg, wu, wd, padded, tile_group, num_tiles, act)
 
 
-def _expert_ffn_fwd(xs, wg, wu, wd, padded, tile_group, num_tiles):
-    return (_expert_ffn(xs, wg, wu, wd, padded, tile_group, num_tiles),
+def _expert_ffn_fwd(xs, wg, wu, wd, padded, tile_group, num_tiles, act):
+    return (_expert_ffn(xs, wg, wu, wd, padded, tile_group, num_tiles, act),
             (xs, wg, wu, wd, padded))
 
 
-def _expert_ffn_bwd(res, g):
+def _expert_ffn_bwd(act, res, g):
     xs, wg, wu, wd, padded = res
     # rows past the groups hold whatever the kernel left: their
     # cotangent is nobody's
     live = jnp.arange(xs.shape[0]) < jnp.sum(padded)
-    _, vjp = jax.vjp(lambda *a: _expert_ffn_xla(*a, padded), xs, wg, wu, wd)
+    _, vjp = jax.vjp(lambda *a: _expert_ffn_xla(*a, padded, act),
+                     xs, wg, wu, wd)
     return vjp(jnp.where(live[:, None], g, 0).astype(xs.dtype)) + (
         None, None, None)
 
@@ -505,7 +533,8 @@ def _combine(acc, ys, picks, rows, weights):
 # The routed part of a layer
 # ---------------------------------------------------------------------------
 
-def _routed(x, local, weights, wg, wu, wd, total, one_pass=False):
+def _routed(x, local, weights, wg, wu, wd, total, one_pass=False,
+            act="silu"):
     """(sum over a token's held picks of weight * expert(x) [N, D]
     float32, the token's picks the first pass served [N] int32), in
     passes of `capacity` picks each; `one_pass`: a pass holds every pick
@@ -524,7 +553,8 @@ def _routed(x, local, weights, wg, wu, wd, total, one_pass=False):
         picks = jax.lax.dynamic_slice(slots, (p * count,), (count,))
         rows, src, padded, tile_group, num_tiles = dispatch(
             local, picks, held)
-        ys = expert_ffn(x[src], wg, wu, wd, padded, tile_group, num_tiles)
+        ys = expert_ffn(x[src], wg, wu, wd, padded, tile_group, num_tiles,
+                        act)
         return combine(acc, ys, picks, rows, weights)
 
     acc = jnp.zeros(x.shape, jnp.float32)
@@ -540,13 +570,14 @@ def _routed(x, local, weights, wg, wu, wd, total, one_pass=False):
 
 
 @functools.lru_cache(maxsize=None)
-def _pooled(total: int):
-    """`_routed` of a layer of `total` experts under a `custom_vmap`
-    that pools rows: [R, N, ...] tokens are R*N tokens of one call. (A
-    batch of WEIGHTS has no such reading and runs a call per entry.)"""
+def _pooled(total: int, act: str = "silu"):
+    """`_routed` of a layer of `total` experts gated by `act` under a
+    `custom_vmap` that pools rows: [R, N, ...] tokens are R*N tokens of
+    one call. (A batch of WEIGHTS has no such reading and runs a call
+    per entry.)"""
     @jax.custom_batching.custom_vmap
     def pooled(x, local, weights, wg, wu, wd):
-        return _routed(x, local, weights, wg, wu, wd, total)
+        return _routed(x, local, weights, wg, wu, wd, total, act=act)
 
     @pooled.def_vmap
     def _(axis_size, in_batched, x, local, weights, wg, wu, wd):
@@ -562,29 +593,31 @@ def _pooled(total: int):
     return pooled
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def routed_experts(x, local, weights, wg, wu, wd, total):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def routed_experts(x, local, weights, wg, wu, wd, total, act="silu"):
     """sum over a token's picks held here of weight * expert(x), and how
     many of the token's held picks the first pass served (all of them
     while the capacity holds):
     x [N, D], local [N, K] int32 (`held` for an absent expert's pick),
     weights [N, K] float32, wg / wu [held, D, F], wd [held, F, D],
-    `total` the layer's experts over all chips (static)
+    `total` the layer's experts over all chips, `act` the experts' gate
+    (`silu` or `relu`; both static)
     -> ([N, D] float32, [N] int32). Under `vmap` the rows' tokens are
     pooled into one call; `custom_vmap` and the loop over passes have no
     reverse mode, so the gradient is taken of the one-pass form."""
-    return _pooled(total)(x, local, weights, wg, wu, wd)
+    return _pooled(total, act)(x, local, weights, wg, wu, wd)
 
 
-def _routed_fwd(x, local, weights, wg, wu, wd, total):
-    return (_pooled(total)(x, local, weights, wg, wu, wd),
+def _routed_fwd(x, local, weights, wg, wu, wd, total, act):
+    return (_pooled(total, act)(x, local, weights, wg, wu, wd),
             (x, local, weights, wg, wu, wd))
 
 
-def _routed_bwd(total, res, g):
+def _routed_bwd(total, act, res, g):
     x, local, weights, wg, wu, wd = res
     _, vjp = jax.vjp(
-        lambda x, w, *e: _routed(x, local, w, *e, total, one_pass=True)[0],
+        lambda x, w, *e: _routed(x, local, w, *e, total, one_pass=True,
+                                 act=act)[0],
         x, weights, wg, wu, wd)
     dx, dw, *de = vjp(g[0])
     return (dx, None, dw, *de)

@@ -523,6 +523,17 @@ class SamplerProgramEngine:
         0 on a cache hit). One launch; `serve.stack` is host arithmetic,
         and `serve.unstack` hands each row its own outputs of the
         program."""
+        if bucket > 1 and self.rows_apart:
+            # a model evaluated a row at a time has ONE round program, a
+            # row's: a round of b rows is b launches of it (the turns a
+            # wider program would run one after another, less the padded
+            # slots', and no wider program to compile and hold)
+            finished, compile_s = [], 0.0
+            for r in rows:
+                done, spent = self.advance([r], 1, round_steps)
+                finished += done
+                compile_s += spent
+            return finished, compile_s
         group = rows[0].group
         ds = self._sampler_for(rows[0].req)
         plan = rows[0].plan             # group-uniform (plan is in the key)

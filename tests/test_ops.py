@@ -520,3 +520,39 @@ def test_route_without_bias_and_scale_is_the_call_it_was():
     picked = jnp.take_along_axis(scores, idx, axis=1)
     np.testing.assert_allclose(vals, 2.5 * picked / picked.sum(1)[:, None],
                                rtol=1e-5)
+
+
+@pytest.mark.parametrize("window", [40, 200])   # binds / never reached
+def test_seven_queries_a_kv_head_under_a_window_in_interpret_mode(window):
+    """`group` = 7 (a q block of 7 x 32 rows, no power of two of heads)
+    at 150 tokens, no multiple of either block (32 / 16), so both
+    lengths are padded: against the XLA composition. A window the
+    sequence never reaches reads every causal pair."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (2, 150, 14, 16))
+    k = jax.random.normal(ks[1], (2, 150, 2, 16))
+    v = jax.random.normal(ks[2], (2, 150, 2, 16))
+    got = jax.jit(lambda *a: flash_attention(*a, None, 32, 16, True, True,
+                                             window))(q, k, v)
+    want = jax.jit(lambda *a: _xla_attention(*a, causal=True,
+                                             window=window))(q, k, v)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    causal = jax.jit(lambda *a: _xla_attention(*a, causal=True))(q, k, v)
+    assert (np.abs(np.asarray(want - causal)).max() > 1e-3) == (window < 150)
+
+
+def test_a_window_that_binds_has_a_kernel_name_of_its_own():
+    """`fdt_flash_fwd_window` on the device where the window is shorter
+    than the sequence, `fdt_flash_fwd` otherwise: a trace tells a
+    windowed layer's time from a full layer's."""
+    q = jax.ShapeDtypeStruct((1, 1024, 7, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 1024, 1, 128), jnp.bfloat16)
+
+    def lowered(window):
+        return jax.export.export(jax.jit(lambda q, k, v: flash_attention(
+            q, k, v, None, None, None, False, True, window)),
+            platforms=["tpu"])(q, kv, kv).mlir_module()
+    binds, idle, none = lowered(512), lowered(1024), lowered(None)
+    assert "fdt_flash_fwd_window" in binds
+    for text in (idle, none):
+        assert "fdt_flash_fwd" in text and "fdt_flash_fwd_window" not in text

@@ -6,8 +6,10 @@ nothing runs). At batch 32 the shape picks the XLA composition: no
 the program's own result. At batch 8 the four kernels run, each fed by
 a `copy` out of the convolutions' layout: the cost the rule avoids.
 Beside it, the routed experts' layer (`ops/moe.py`) at the widths of the
-benchmark's two cells that run it: Mosaic accepts its three kernels and
-no buffer of the program holds the worst case. The one file of `tests/`
+benchmark's three cells that run it: Mosaic accepts its three kernels and
+no buffer of the program holds the worst case (the third cell holds every
+expert: there the worst case IS the case; its windowed grouped flash call
+is compiled with it). The one file of `tests/`
 that loads the TPU's compiler: the topology is described inside a
 fixture, never at import.
 """
@@ -178,3 +180,37 @@ def test_the_routed_layer_is_sized_by_the_picks_that_land_here(
     assert not re.search(rf"= f32\[{n},{d}\]\S* copy\(", text)
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 4 * rows * d * 2 + 2 * n * d * 4
+
+
+def test_a_layer_whose_experts_are_all_held_and_its_windowed_attention(
+        one_chip, monkeypatch):
+    """The third routed cell's widths (`smallthinker-21b-dn-1536`): a
+    guided row's 18,588 tokens x 6 picks over ALL 64 ReLU-gated experts
+    of width 768 (`held == total`: one pass holds every pick), and the
+    flash forward at 7 query heads a key/value head over 9,294 tokens
+    under the 4,096 window, which binds and is named so."""
+    from flaxdiff_tpu.ops import moe
+    from flaxdiff_tpu.ops.flash_attention import flash_attention
+    n, k, d, f, held = 18588, 6, 2560, 768, 64
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+
+    def on(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = jax.jit(lambda *a: moe.routed_experts(*a, held, "relu")).lower(
+        on(n, d), on(n, k, dtype=jnp.int32), on(n, k, dtype=jnp.float32),
+        on(held, d, f), on(held, d, f), on(held, f, d)).compile().as_text()
+    for kernel in ("fdt_moe_gmm_gate_up", "fdt_moe_gmm_down",
+                   "fdt_moe_combine"):
+        assert len(re.findall(rf"%{kernel}[.\d]* = ", text)) == 1, kernel
+    assert moe.capacity(n * k, held, held) == n * k
+    assert f"bf16[{moe.buffer_rows(n * k, held)},{d}]" in text
+    assert f"[{n},{k},{d}]" not in text
+    q, kv = on(2, 9294, 28, 128), on(2, 9294, 4, 128)
+    for window, name in ((4096, "fdt_flash_fwd_window"),
+                         (None, "fdt_flash_fwd")):
+        text = jax.jit(lambda q, k, v: flash_attention(
+            q, k, v, None, None, None, False, True, window)).lower(
+            q, kv, kv).compile().as_text()
+        assert len(re.findall(rf"%{name}[.\d]* = ", text)) == 1, name
+        # seven heads' rows a block, padded to the blocks: 9,344 / 9,728
+        assert "bf16[8,7,9344,128]" in text and "bf16[8,9728,128]" in text
