@@ -38,7 +38,16 @@ N x K of them:
   block stays in VMEM across the consecutive row tiles of its group and
   is read once per column tile. `gate_up` computes
   act(x Wgate) * (x Wup) in one pass over x, `act` the model's own gate
-  (static: `silu`, or `relu` for a ReGLU expert).
+  (static: `silu`, or `relu` for a ReGLU expert). The COLUMN tile is a
+  rule of the call's shapes (`column_tile`: the contraction's depth k,
+  the columns n, the matrices of the call, the element size): a grid
+  step costs about 0.4 us beside its product, so a step takes all n
+  columns where their blocks fit half the VMEM the call asks for (a
+  shallow contraction: x is read once, a row tile is one step), and
+  else the narrowest multiple of `TILE_N` whose product is `STEP_OPS`
+  operations (docs/KERNELS.md has the arms read in the round programs,
+  PR 47). `TILE_M` is not the rule's: it sizes the buffer, `dispatch`
+  and the combine's tiles.
 - **The combine** reads the rows that exist, not one for every one of
   the N x K picks: XLA gathers the pass's served rows in token order
   (standalone, at the memory's rate), and `fdt_moe_combine` (Pallas,
@@ -83,7 +92,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 TILE_M = 128        # rows of a group's tile (the MXU's side on a v5e)
-TILE_N = 256        # output columns of one grid step
+TILE_N = 256        # the narrowest column tile of a grid step
+STEP_OPS = 200e6    # operations a grid step's product has at least
+VMEM_LIMIT = 64 * 1024 * 1024       # what each kernel's call asks for
 SLACK = 2           # a pass's capacity over the picks' even share
 
 
@@ -257,15 +268,47 @@ def _down_kernel(tile_group, num_tiles, x_ref, w_ref, o_ref):
                          ).astype(o_ref.dtype)
 
 
-def _gmm_grid(x, weights, num_tiles, tile_m: int, tile_n: int,
+def _gmm_vmem(k: int, tile_n: int, matrices: int, itemsize: int,
+              tile_m: int = TILE_M) -> int:
+    """Bytes of VMEM one grid step of a grouped product keeps: the
+    weight blocks [k, tile_n], the x tile and the output tile, each
+    double-buffered, and the float32 products before the cast."""
+    return (2 * itemsize * (matrices * k * tile_n + tile_m * k
+                            + tile_m * tile_n)
+            + 4 * matrices * tile_m * tile_n)
+
+
+def column_tile(k: int, n: int, matrices: int, itemsize: int,
+                tile_m: int = TILE_M) -> int:
+    """Output columns of one grid step of a grouped product [.., k] x
+    `matrices` of [k, n], from the shapes alone.
+
+    A step costs about 0.4 us beside its product (PR 47's fit of the two
+    kernels at 2560/768 on a v5e), so (1) where the blocks of ALL n
+    columns fit half the VMEM the call asks for, the step takes all of
+    them: x is read once and a row tile is one step; else (2) the
+    narrowest multiple of `TILE_N` dividing n whose product is
+    `STEP_OPS` operations or more, or the widest that fits."""
+    tiles = [t for t in range(TILE_N, n, TILE_N) if n % t == 0] + [n]
+    fits = [t for t in tiles if _gmm_vmem(k, t, matrices, itemsize, tile_m)
+            <= VMEM_LIMIT // 2] or tiles[:1]
+    if fits[-1] == n:
+        return n
+    deep = [t for t in fits if 2 * tile_m * k * t * matrices >= STEP_OPS]
+    return deep[0] if deep else fits[-1]
+
+
+def _gmm_grid(x, weights, num_tiles, tile_m: int, tile_n: Optional[int],
               interpret: bool) -> dict:
     """The `pallas_call` arguments both kernels share: out[M, N] over
     `num_tiles` row tiles, tile i times the weights of expert
     `tile_group[i]`, every matrix of `weights` ([held, K, N]) entering
-    the kernel as its [K, tile_n] block. Rows past the last tile are not
-    written."""
+    the kernel as its [K, tile_n] block (`column_tile`'s, unless a test
+    names one). Rows past the last tile are not written."""
     m, k = x.shape
     n = weights[0].shape[-1]
+    if tile_n is None:
+        tile_n = column_tile(k, n, len(weights), x.dtype.itemsize, tile_m)
     tile_n = min(tile_n, n)
     assert m % tile_m == 0 and n % tile_n == 0, (m, n, tile_m, tile_n)
 
@@ -291,12 +334,12 @@ def _gmm_grid(x, weights, num_tiles, tile_m: int, tile_n: int,
             grid=(n // tile_n, num_tiles)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=64 * 1024 * 1024),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret)
 
 
 def _expert_ffn_pallas(xs, wg, wu, wd, tile_group, num_tiles,
-                       tile_m=TILE_M, tile_n=TILE_N, interpret=False,
+                       tile_m=TILE_M, tile_n=None, interpret=False,
                        act="silu"):
     scalars = (tile_group, num_tiles.reshape(1))
     mid = pl.pallas_call(
@@ -515,7 +558,7 @@ def _combine_pallas(acc, ys, picks, rows, weights, interpret=False):
         input_output_aliases={5: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=64 * 1024 * 1024),
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(tile, chunk, first, rounds,
       jnp.any(spoilt.reshape(-1, TILE_M), axis=1).astype(jnp.int32), acc,

@@ -188,8 +188,48 @@ def _imbalanced(n, e):
     return jnp.asarray(local, jnp.int32)
 
 
-def test_the_grouped_product_in_interpret_mode_under_an_imbalance():
-    x, wg, wu, wd = _experts()
+@functools.lru_cache(maxsize=None)
+def _experts_summed_exactly(n=40, d=32, f=48, e=4):
+    """Experts whose every sum is exact in float32 in any order (small
+    whole numbers into gate and up; a down matrix with one +-1 a
+    column), so that two runs differ only where one is WRONG: the CPU's
+    `dot` orders a contraction by its operands' shapes, the MXU does
+    not."""
+    rng = np.random.default_rng(7)
+    wd = np.zeros((e, f, d), np.float32)
+    for i in range(e):
+        wd[i, (np.arange(d) * 5 + i) % f, np.arange(d)] = rng.choice(
+            [-1.0, 1.0], d)
+    return (jnp.asarray(rng.integers(-2, 3, (n, d)), jnp.float32),
+            jnp.asarray(rng.integers(-1, 2, (e, d, f)), jnp.float32),
+            jnp.asarray(rng.integers(-1, 2, (e, d, f)), jnp.float32),
+            jnp.asarray(wd))
+
+
+_ffn_xla = jax.jit(moe._expert_ffn_xla, static_argnums=5)
+
+
+@functools.lru_cache(maxsize=None)
+def _imbalanced_product(act, tile_n, exact=False):
+    """An imbalanced layout (expert 2's group is five row tiles of 8,
+    expert 3's a single one, expert 1's none) through both kernels in
+    interpret mode at one column tile, under `jit`: (got [M, 32], the
+    rows that groups own, x[src], tile_group, padded)."""
+    x, wg, wu, wd = _experts_summed_exactly() if exact else _experts()
+    local = np.asarray(_imbalanced(40, 4))
+    third = local == 3          # all but five of expert 3's picks to 0
+    local = jnp.asarray(np.where(
+        third & (np.cumsum(third).reshape(local.shape) > 5), 0, local))
+    rows, src, padded, tile_group, num_tiles = moe.dispatch(
+        local, moe.held_order(local, 4), 4, 8)
+    ffn = jax.jit(functools.partial(
+        moe._expert_ffn_pallas, tile_m=8, tile_n=tile_n, interpret=True,
+        act=act))
+    return (np.asarray(ffn(x[src], wg, wu, wd, tile_group, num_tiles)),
+            int(padded.sum()), x[src], np.asarray(tile_group), padded)
+
+
+def test_dispatch_lays_an_imbalance_out_by_expert_in_token_order():
     local = _imbalanced(40, 4)
     counts = [int((local == e).sum()) for e in range(4)]
     assert counts[1] == 0 and counts[2] >= sum(counts) // 2
@@ -207,19 +247,62 @@ def test_the_grouped_product_in_interpret_mode_under_an_imbalance():
     assert (np.asarray(tile_group)[np.asarray(rows)[live] // 8]
             == np.asarray(local).reshape(-1)[np.asarray(picks)[live]]).all()
     assert len(set(np.asarray(rows)[live].tolist())) == live.sum()
-    got = moe._expert_ffn_pallas(x[src], wg, wu, wd, tile_group, num_tiles,
-                                 tile_m=8, tile_n=16, interpret=True)
-    live = int(padded.sum())
-    xs = np.asarray(x[src])
+
+
+# column tiles of the two products (gate/up: n = 48, down: n = 32):
+# narrower than n and dividing it, n itself (48 is cut to down's 32),
+# and the rule's own (`column_tile`: all of so small an n)
+@pytest.mark.parametrize("tile_n", [8, 16, 48, None])
+@pytest.mark.parametrize("act", ["silu", "relu"])
+def test_the_grouped_product_in_interpret_mode_under_an_imbalance(act,
+                                                                  tile_n):
+    _, wg, wu, wd = _experts()
+    got, live, xs, group, padded = _imbalanced_product(act, tile_n)
+    tiles = np.bincount(group[:live // 8], minlength=4)
+    assert list(tiles) == [3, 0, 5, 1]
+    xs = np.asarray(xs)
     for row in range(live):     # per-expert dot, row by row
-        e = int(tile_group[row // 8])
+        e = int(group[row // 8])
         g = xs[row] @ np.asarray(wg[e])
-        want = (g / (1 + np.exp(-g)) * (xs[row] @ np.asarray(wu[e]))) \
-            @ np.asarray(wd[e])
+        g = np.maximum(g, 0) if act == "relu" else g / (1 + np.exp(-g))
+        want = (g * (xs[row] @ np.asarray(wu[e]))) @ np.asarray(wd[e])
         np.testing.assert_allclose(got[row], want, atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(
-        got[:live], moe._expert_ffn_xla(x[src], wg, wu, wd, padded)[:live],
+        got[:live], _ffn_xla(xs, wg, wu, wd, padded, act)[:live],
         atol=2e-5, rtol=2e-5)
+    # a column tile says which step writes a column, not what its sum
+    # is: to the last bit between tile widths and against XLA's form
+    got, _, xs, _, _ = _imbalanced_product(act, tile_n, exact=True)
+    assert np.abs(got[:live]).max() > 8
+    np.testing.assert_array_equal(
+        got[:live], _imbalanced_product(act, 16, exact=True)[0][:live])
+    np.testing.assert_array_equal(
+        got[:live], _ffn_xla(xs, *_experts_summed_exactly()[1:], padded,
+                             act)[:live])
+
+
+# a layer's (model width, expert width) and the column tiles of its
+# gate/up and down products at bfloat16
+COLUMN_TILES = {
+    "command_a_plus": (4096, 4096, 256, 256),
+    "smallthinker": (2560, 768, 768, 2560),
+    "glm": (6144, 2048, 256, 512),
+    "a_small_preset": (64, 96, 96, 64),
+}
+
+
+@pytest.mark.parametrize("layer", list(COLUMN_TILES))
+def test_the_column_tile_is_a_rule_of_the_shapes(layer):
+    d, f, gate_up, down = COLUMN_TILES[layer]
+    assert moe.column_tile(d, f, 2, 2) == gate_up
+    assert moe.column_tile(f, d, 1, 2) == down
+    for k, n, matrices, tile_n in ((d, f, 2, gate_up), (f, d, 1, down)):
+        assert n % tile_n == 0
+        # the step's blocks, double-buffered, inside what the call asks
+        assert 2 * 2 * matrices * k * tile_n < moe._gmm_vmem(
+            k, tile_n, matrices, 2) <= moe.VMEM_LIMIT
+        # and no narrower than the floor wherever n is cut at all
+        assert tile_n == n or tile_n >= moe.TILE_N
 
 
 def _everywhere(n, e):
